@@ -34,9 +34,9 @@ A100_ROWS_PER_SEC = 110e12 / (2 * D * D)
 
 
 def main() -> None:
-    from benchmarks import setup_platform
+    from spark_rapids_ml_tpu.utils.compile_cache import ensure_compile_cache
 
-    setup_platform()
+    ensure_compile_cache()
     import jax
     import jax.numpy as jnp
 
@@ -60,7 +60,7 @@ def main() -> None:
         y = jax.device_put(y, NamedSharding(mesh, P("data")))
     mask = jnp.ones((ROWS,), dtype=jnp.float32)
 
-    from benchmarks import slope_dt, sync
+    from benchmarks import slope_dt
 
     def measure(use_pallas: bool) -> float:
         stats = _normal_eq_stats_fn(mesh, "bfloat16", "float32", use_pallas)
@@ -69,7 +69,7 @@ def main() -> None:
             out = None
             for _ in range(n):
                 out = stats(x, y, mask)
-            sync(out)  # one sync; calls queue on device
+            jax.block_until_ready(out)  # one sync; calls queue on device
             assert np.isfinite(float(out[5]))
             return out
 

@@ -26,9 +26,9 @@ A100_ROW_ITERS_PER_SEC = 110e12 / (2 * D * D)
 
 
 def main() -> None:
-    from benchmarks import setup_platform
+    from spark_rapids_ml_tpu.utils.compile_cache import ensure_compile_cache
 
-    setup_platform()
+    ensure_compile_cache()
     import jax
     import jax.numpy as jnp
 
@@ -56,7 +56,7 @@ def main() -> None:
 
     # tol=0 → exactly n Newton steps: throughput, not convergence. Two
     # iteration counts + slope_dt cancel the fixed sync overhead.
-    from benchmarks import slope_dt, sync
+    from benchmarks import slope_dt
 
     fns = {
         n: _newton_fn(mesh, 1e-4, True, n, 0.0, "float32")
@@ -65,7 +65,7 @@ def main() -> None:
 
     def run(n):
         w, b, n_iter, loss = fns[n](x, y, mask)
-        sync(w)
+        jax.block_until_ready(w)
         assert int(n_iter) == n and np.isfinite(float(loss))
         return w
 
@@ -105,7 +105,7 @@ def main() -> None:
                     state, W, b, x_mm, y_mm, mask_mm
                 )
                 W, b, _ = mm_step(gw, gb, hw, hwb, hbb, nn, W, b)
-            sync(W)
+            jax.block_until_ready(W)
             return W
 
         mm_iters = max(2, ITERS // 2)

@@ -32,13 +32,17 @@ K = int(os.environ.get("SRML_BENCH_K", 100))
 ROWS = int(os.environ.get("SRML_BENCH_ROWS", 4096))
 PASSES = int(os.environ.get("SRML_BENCH_PASSES", 5))
 
+# Workers are pinned to the CPU: two daemon PROCESSES cannot share one
+# chip. The READY line carries the backend each worker actually got.
 _WORKER = """
 import sys
 import jax
 jax.config.update("jax_platforms", "cpu")
 from spark_rapids_ml_tpu.serve.daemon import DataPlaneDaemon
+from spark_rapids_ml_tpu.utils.compile_cache import ensure_compile_cache
+ensure_compile_cache()
 d = DataPlaneDaemon(host="127.0.0.1", port=0, ttl=600.0).start()
-print(f"READY {d.address[1]}", flush=True)
+print(f"READY {d.address[1]} {jax.default_backend()}", flush=True)
 sys.stdin.read()
 d.stop()
 """
@@ -49,6 +53,7 @@ def main() -> None:
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     workers = []
+    backends = set()
     try:
         for _ in range(2):
             env = {k: v for k, v in os.environ.items()
@@ -61,8 +66,9 @@ def main() -> None:
                 stdin=subprocess.PIPE, stdout=subprocess.PIPE,
                 cwd=repo, env=env, text=True,
             )
-            port = int(proc.stdout.readline().split()[1])
-            workers.append((proc, port))
+            _, port, backend = proc.stdout.readline().split()
+            backends.add(backend)
+            workers.append((proc, int(port)))
         (pa, port_a), (pb, port_b) = workers
         ca = DataPlaneClient("127.0.0.1", port_a)
         cb = DataPlaneClient("127.0.0.1", port_b)
@@ -145,6 +151,7 @@ def main() -> None:
             "value": round(km_ms_1x * 1e3, 2),
             "unit": "ms/pass",
             "vs_baseline": 0.0,
+            "backend": ",".join(sorted(backends)),
             "kmeans_wire_mb_per_pass": round(km_wire / 2**20, 3),
             "kmeans_boundary_ms_8x_rows": round(km_ms_8x * 1e3, 2),
             "rows_independent": bool(km_ms_8x < 3 * km_ms_1x),

@@ -33,9 +33,10 @@ CYCLES = int(os.environ.get("SRML_BENCH_CYCLES", 5))
 
 
 def main() -> None:
-    from benchmarks import setup_platform, slope_dt, sync
+    from benchmarks import slope_dt
+    from spark_rapids_ml_tpu.utils.compile_cache import ensure_compile_cache
 
-    setup_platform()
+    ensure_compile_cache()
     import jax
     import jax.numpy as jnp
 
@@ -89,7 +90,7 @@ def main() -> None:
             out = None
             for _ in range(n):
                 _, out = fn(*dev, queries, resid_norms=norms, lists_lo=lists_lo)
-            sync(out)
+            jax.block_until_ready(out)
             return out
         return run
 

@@ -22,6 +22,9 @@ import threading
 import numpy as np
 
 from spark_rapids_ml_tpu.serve import DataPlaneClient, DataPlaneDaemon
+from spark_rapids_ml_tpu.utils.compile_cache import ensure_compile_cache
+
+ensure_compile_cache()  # one rule for where compiled programs are kept
 
 rng = np.random.default_rng(0)
 data = (rng.normal(size=(200_000, 128)) * np.logspace(0, -1.5, 128)).astype(np.float32)

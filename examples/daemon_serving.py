@@ -22,9 +22,11 @@ import numpy as np
 
 from spark_rapids_ml_tpu.models.pca import PCA
 from spark_rapids_ml_tpu.serve import DataPlaneClient, DataPlaneDaemon
+from spark_rapids_ml_tpu.utils.compile_cache import ensure_compile_cache
 
 
 def main() -> None:
+    ensure_compile_cache()  # one rule for where compiled programs are kept
     rng = np.random.default_rng(0)
     x = rng.normal(size=(20_000, 64)).astype(np.float32)
 

@@ -16,6 +16,9 @@ import tempfile
 import numpy as np
 
 import spark_rapids_ml_tpu as srml
+from spark_rapids_ml_tpu.utils.compile_cache import ensure_compile_cache
+
+ensure_compile_cache()  # one rule for where compiled programs are kept
 
 rng = np.random.default_rng(0)
 x = (rng.normal(size=(100_000, 256)) * np.logspace(0, -2, 256)).astype(np.float32)

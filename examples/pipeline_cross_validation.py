@@ -22,6 +22,9 @@ from spark_rapids_ml_tpu import (
     RegressionEvaluator,
     StandardScaler,
 )
+from spark_rapids_ml_tpu.utils.compile_cache import ensure_compile_cache
+
+ensure_compile_cache()  # one rule for where compiled programs are kept
 
 rng = np.random.default_rng(0)
 n, d = 20_000, 64

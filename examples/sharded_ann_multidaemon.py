@@ -32,9 +32,11 @@ import numpy as np
 
 from spark_rapids_ml_tpu.models.knn import merge_topk
 from spark_rapids_ml_tpu.serve import DataPlaneClient, DataPlaneDaemon
+from spark_rapids_ml_tpu.utils.compile_cache import ensure_compile_cache
 
 
 def main() -> None:
+    ensure_compile_cache()  # one rule for where compiled programs are kept
     rng = np.random.default_rng(0)
     kc, d, k, nlist = 16, 64, 5, 32
     centers = rng.normal(size=(kc, d)) * 8

@@ -15,6 +15,9 @@ if __package__ in (None, ""):  # runnable without installation
 import numpy as np
 
 from spark_rapids_ml_tpu.models.kmeans import fit_kmeans_stream
+from spark_rapids_ml_tpu.utils.compile_cache import ensure_compile_cache
+
+ensure_compile_cache()  # one rule for where compiled programs are kept
 
 rng = np.random.default_rng(0)
 true_centers = rng.normal(size=(16, 128)) * 8

@@ -23,18 +23,6 @@ __version__ = "0.1.0"
 
 from spark_rapids_ml_tpu import config as config
 
-# Persistent XLA compilation cache (ROADMAP 2b): wire the config key to
-# jax at package init, before any model import can compile a program —
-# identical programs from an earlier process (a restarted daemon, the
-# next bench round) become disk hits, counted by
-# srml_xla_persistent_cache_hits_total (utils/xprof.py).
-_compile_cache_dir = config.get("compile_cache_dir")
-if _compile_cache_dir:
-    import jax as _jax
-
-    _jax.config.update("jax_compilation_cache_dir", str(_compile_cache_dir))
-del _compile_cache_dir
-
 # Re-export the user-facing estimator namespace, mirroring the reference's
 # thin `com.nvidia.spark.ml.feature.PCA` shim (reference PCA.scala:27-37).
 from spark_rapids_ml_tpu.models.pca import PCA, PCAModel

@@ -4,7 +4,8 @@ The reference packages its native library inside the jar and extracts it at
 first use (JniRAPIDSML.java:34-58). Here the .so is built from
 ``native/src/columnar.cpp`` (``make -C native``) and looked up next to the
 package and in the repo's ``native/build`` dir; if absent or disabled via
-config ``use_native_bridge``, callers fall back to the pure-NumPy path.
+config ``use_native_bridge``, callers take the pure-NumPy path (the
+portable one). Which path was chosen is logged once per process.
 """
 
 from __future__ import annotations
@@ -17,6 +18,9 @@ from typing import Optional
 import numpy as np
 
 from spark_rapids_ml_tpu import config
+from spark_rapids_ml_tpu.utils.logging import get_logger
+
+logger = get_logger("bridge.native")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -50,12 +54,22 @@ def get_lib() -> Optional[ctypes.CDLL]:
                 try:
                     lib = ctypes.CDLL(path)
                     _configure(lib)
-                    _lib = lib
-                    break
-                except (OSError, AttributeError):
+                except (OSError, AttributeError) as e:
                     # AttributeError: stale .so missing a newer export —
-                    # fall through to the next candidate / NumPy fallback.
+                    # try the next candidate, then the NumPy path.
+                    logger.warning(
+                        "native columnar library %s failed to load: %s",
+                        path, e,
+                    )
                     continue
+                _lib = lib
+                logger.info("columnar bridge: native library %s", path)
+                break
+        if _lib is None:
+            logger.info(
+                "columnar bridge: NumPy path (no loadable %s; build it "
+                "with `make -C native`)", _SO_NAME,
+            )
         return _lib
 
 

@@ -43,13 +43,14 @@ def _as_bool_or_auto(s: str):
     return "auto" if s.strip().lower() == "auto" else _as_bool(s)
 
 
-def _backend_is_tpu() -> bool:
-    try:
-        import jax
+def backend_is_tpu() -> bool:
+    """THE predicate for "this process computes on a TPU": every "auto"
+    profile key, every Pallas gate and every interpret-mode choice asks
+    it. A backend that fails to initialise raises here — answering False
+    would silently select the float32/XLA profile on a TPU host."""
+    import jax
 
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - backend init failure
-        return False
+    return jax.default_backend() == "tpu"
 
 
 # Lazy resolution of "auto" defaults at get() time: the shipped TPU profile
@@ -59,8 +60,8 @@ def _backend_is_tpu() -> bool:
 # resolve to the portable f32/XLA path unchanged. Explicit values (set()
 # or SRML_TPU_* env) always win over "auto".
 _AUTO_RESOLVERS: Dict[str, Callable[[], Any]] = {
-    "use_pallas": _backend_is_tpu,
-    "compute_dtype": lambda: "bfloat16" if _backend_is_tpu() else "float32",
+    "use_pallas": backend_is_tpu,
+    "compute_dtype": lambda: "bfloat16" if backend_is_tpu() else "float32",
 }
 
 # One visible breadcrumb per process when an "auto" key flips to the TPU
@@ -109,15 +110,6 @@ _DEFAULTS: Dict[str, Any] = {
     # forces the hub path everywhere (the degraded mode the parity tests
     # pin against the collective path bitwise).
     "mesh_collectives": _env("MESH_COLLECTIVES", True, _as_bool),
-    # Persistent XLA compilation cache directory (ROADMAP 2b): wired to
-    # jax.config.compilation_cache_dir at package init, so identical
-    # programs compiled by an earlier process (a restarted daemon, the
-    # next bench round, a fleet twin) are disk hits instead of
-    # recompiles. None = off. Env key is SRML_COMPILE_CACHE_DIR —
-    # deployment-facing like SRML_DAEMON_STATE_DIR, hence no SRML_TPU_
-    # prefix. Persistent-cache hits are counted by
-    # srml_xla_persistent_cache_hits_total (utils/xprof.py).
-    "compile_cache_dir": os.environ.get("SRML_COMPILE_CACHE_DIR") or None,
     # Max rows per device batch when streaming host data to device.
     "stream_batch_rows": _env("STREAM_BATCH_ROWS", 1 << 20, int),
     # Use the native C++ columnar bridge if the shared library is present.

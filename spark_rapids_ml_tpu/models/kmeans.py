@@ -55,7 +55,6 @@ from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, default_mesh
 from spark_rapids_ml_tpu.parallel import mapreduce as mr
 from spark_rapids_ml_tpu.parallel.sharding import pad_rows, shard_rows
 from spark_rapids_ml_tpu.utils.profiling import trace_span
-from spark_rapids_ml_tpu.parallel.compat import shard_map
 from spark_rapids_ml_tpu.utils.xprof import ledgered_jit
 
 
@@ -278,7 +277,7 @@ def _lloyd_fn(
         final_cost = mr.reduce_sum(jnp.sum(min_d2 * maskc), DATA_AXIS)
         return centers, final_cost, n_iter
 
-    f = shard_map(
+    f = jax.shard_map(
         lloyd_shard,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P()),
@@ -381,7 +380,7 @@ def _stream_step_fn(mesh: Mesh, k: int, cd: str, ad: str):
             cost + mr.reduce_sum(bcost, DATA_AXIS),
         )
 
-    f = shard_map(
+    f = jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(DATA_AXIS, None), P(DATA_AXIS)),

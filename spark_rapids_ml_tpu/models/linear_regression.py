@@ -50,7 +50,6 @@ from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, default_mesh
 from spark_rapids_ml_tpu.parallel import mapreduce as mr
 from spark_rapids_ml_tpu.parallel.sharding import shard_rows
 from spark_rapids_ml_tpu.utils.profiling import trace_span
-from spark_rapids_ml_tpu.parallel.compat import shard_map
 from spark_rapids_ml_tpu.utils.xprof import ledgered_jit
 
 
@@ -108,7 +107,7 @@ def _normal_eq_stats_fn(mesh: Mesh, cd: str, ad: str, use_pallas: Optional[bool]
             xtx, xty, sx, sy, syy, n = linreg_stats_pallas(
                 x.astype(compute_dtype), y, mask,
                 block_n=min(512, n_local),
-                interpret=jax.default_backend() != "tpu",
+                interpret=not config.backend_is_tpu(),
             )
             return tuple(
                 mr.reduce_sum(v, DATA_AXIS)
@@ -133,7 +132,7 @@ def _normal_eq_stats_fn(mesh: Mesh, cd: str, ad: str, use_pallas: Optional[bool]
             mr.reduce_sum(v, DATA_AXIS) for v in (xtx, xty, sx, sy, syy, n)
         )
 
-    f = shard_map(
+    f = jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS)),
@@ -170,7 +169,7 @@ def streaming_normal_eq_update(mesh: Mesh, compute_dtype=None, accum_dtype=None)
     # for tests calling the private fns directly; ops/gram.py convention).
     return _streaming_normal_eq_update(
         mesh, cd, ad,
-        bool(config.get("use_pallas")) and jax.default_backend() == "tpu",
+        bool(config.get("use_pallas")) and config.backend_is_tpu(),
     )
 
 
@@ -293,7 +292,7 @@ def fit_linear_regression(
         stats = _normal_eq_stats_fn(
             mesh, config.get("compute_dtype"), config.get("accum_dtype"),
             bool(config.get("use_pallas"))
-            and jax.default_backend() == "tpu",  # see streaming_normal_eq_update
+            and config.backend_is_tpu(),  # see streaming_normal_eq_update
         )(xs, ys, mask)
     return finalize_normal_eq_stats(
         stats, reg, elastic_net, fit_intercept, max_iter, tol, n_true

@@ -48,7 +48,6 @@ from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, default_mesh
 from spark_rapids_ml_tpu.parallel import mapreduce as mr
 from spark_rapids_ml_tpu.parallel.sharding import shard_rows
 from spark_rapids_ml_tpu.utils.profiling import trace_span
-from spark_rapids_ml_tpu.parallel.compat import shard_map
 from spark_rapids_ml_tpu.utils.xprof import ledgered_jit
 
 
@@ -287,7 +286,7 @@ def _newton_fn_cached(
         # d=1024 on TPU (more than the whole stats pass), so accelerator
         # backends solve with warm-started Jacobi-CG; on CPU LAPACK's
         # direct factorization is fast AND exact — keep it.
-        direct_solve = jax.default_backend() == "cpu"
+        direct_solve = not config.backend_is_tpu()
 
         def body(carry):
             w, b, _, it, prev_dir = carry
@@ -359,7 +358,7 @@ def _newton_fn_cached(
         )
         return w, b, n_iter, loss_of(w, b)
 
-    f = shard_map(
+    f = jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS)),
@@ -497,7 +496,7 @@ def _stream_grad_hess_fn(mesh: Mesh, ad: str):
                 n + mr.reduce_sum(bn, DATA_AXIS),
             )
 
-    f = shard_map(
+    f = jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(), P(), P(), P(),
@@ -669,7 +668,7 @@ def _stream_softmax_stats_cached(
                 n + mr.reduce_sum(bn, DATA_AXIS),
             )
 
-    f = shard_map(
+    f = jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(), P(), P(), P(),

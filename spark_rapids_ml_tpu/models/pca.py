@@ -57,7 +57,6 @@ from spark_rapids_ml_tpu.parallel.mesh import (
 )
 from spark_rapids_ml_tpu.parallel.sharding import pad_rows, row_sharding, shard_rows
 from spark_rapids_ml_tpu.utils.profiling import trace_span
-from spark_rapids_ml_tpu.parallel.compat import shard_map
 from spark_rapids_ml_tpu.utils.xprof import ledgered_jit
 
 
@@ -88,7 +87,7 @@ def _use_host_finalize(mesh: Mesh) -> bool:
     if mode == "device":
         return False
     platform = next(iter(mesh.devices.flat)).platform
-    return platform != "cpu"
+    return platform == "tpu"
 
 
 @functools.lru_cache(maxsize=32)
@@ -126,7 +125,7 @@ def _fit_fn(
                 )
             else:
                 shard_fn = lambda xb, mb: gram_ops._stats_shard_2d(xb, mb, cd, ad)
-            stats = shard_map(
+            stats = jax.shard_map(
                 shard_fn,
                 mesh=mesh,
                 in_specs=(P(DATA_AXIS, MODEL_AXIS), P(DATA_AXIS)),
@@ -136,7 +135,7 @@ def _fit_fn(
                 check_vma=False,
             )
         else:
-            stats = shard_map(
+            stats = jax.shard_map(
                 lambda xb, mb: gram_ops._stats_shard(xb, mb, cd, ad),
                 mesh=mesh,
                 in_specs=(P(DATA_AXIS, None), P(DATA_AXIS)),

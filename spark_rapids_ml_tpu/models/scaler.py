@@ -40,7 +40,6 @@ from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, default_mesh
 from spark_rapids_ml_tpu.parallel import mapreduce as mr
 from spark_rapids_ml_tpu.parallel.sharding import shard_rows
 from spark_rapids_ml_tpu.utils.profiling import trace_span
-from spark_rapids_ml_tpu.parallel.compat import shard_map
 from spark_rapids_ml_tpu.utils.xprof import ledgered_jit
 
 
@@ -60,7 +59,7 @@ def _moments_fn(mesh: Mesh, ad: str):
             s2 = mr.reduce_sum(jnp.sum(jnp.square(xc), axis=0), DATA_AXIS)
             return n, s1, s2
 
-    f = shard_map(
+    f = jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS)),

@@ -31,7 +31,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from spark_rapids_ml_tpu import config
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
-from spark_rapids_ml_tpu.parallel.compat import shard_map
 from spark_rapids_ml_tpu.parallel import mapreduce as mr
 from spark_rapids_ml_tpu.utils.xprof import ledgered_jit
 
@@ -111,10 +110,7 @@ def _pallas_backend_ok(use_pallas: Optional[bool] = None) -> bool:
     """Shared Pallas-gate preamble: flag on (None = read config) + TPU backend."""
     if not (config.get("use_pallas") if use_pallas is None else use_pallas):
         return False
-    try:
-        return jax.default_backend() != "cpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    return config.backend_is_tpu()
 
 
 def _pallas_gram_applicable(shape, cd, ad, use_pallas: Optional[bool] = None) -> bool:
@@ -190,13 +186,16 @@ def sharded_stats(mesh: Mesh, compute_dtype=None, accum_dtype=None):
 
     One compiled SPMD program: per-shard fused stats + psum over ``data``.
     """
-    f = shard_map(
+    f = jax.shard_map(
         functools.partial(
             _stats_shard, compute_dtype=compute_dtype, accum_dtype=accum_dtype
         ),
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS)),
         out_specs=(P(), P(), P()),
+        # pallas_call outputs (gram_pallas under float32 compute) carry no
+        # VMA annotation; the psum-ed values are replicated.
+        check_vma=False,
     )
     return ledgered_jit("gram.sharded_stats", f)
 
@@ -227,7 +226,7 @@ def _stats_shard_2d(x, mask, compute_dtype, accum_dtype):
 
 def sharded_stats_2d(mesh: Mesh, compute_dtype=None, accum_dtype=None):
     """fn(x_2dsharded, mask) -> (count repl, colsum repl, gram model-sharded)."""
-    f = shard_map(
+    f = jax.shard_map(
         functools.partial(
             _stats_shard_2d, compute_dtype=compute_dtype, accum_dtype=accum_dtype
         ),
@@ -292,7 +291,7 @@ def sharded_stats_ring(mesh: Mesh, compute_dtype=None, accum_dtype=None):
     """fn(x_2dsharded, mask) -> (count repl, colsum repl, gram model-sharded),
     computed with the ppermute ring instead of all_gather."""
     n_model = mesh.shape[MODEL_AXIS]
-    f = shard_map(
+    f = jax.shard_map(
         functools.partial(
             _stats_shard_ring,
             compute_dtype=compute_dtype,
@@ -334,11 +333,12 @@ def _streaming_update_cached(mesh: Mesh, compute_dtype, accum_dtype, use_pallas:
         c, s, g = _stats_shard(x, mask, compute_dtype, accum_dtype, use_pallas)
         return count + c, colsum + s, gram + g
 
-    f = shard_map(
+    f = jax.shard_map(
         shard_update,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(DATA_AXIS, None), P(DATA_AXIS)),
         out_specs=(P(), P(), P()),
+        check_vma=False,  # same as sharded_stats above
     )
 
     @functools.partial(ledgered_jit, "gram.streaming_update", donate_argnums=(0,))
@@ -440,7 +440,7 @@ def _streaming_update_rows_cached(
         g = mr.reduce_sum(g, DATA_AXIS)
         return count + c, colsum + cs, gram + g
 
-    f = shard_map(
+    f = jax.shard_map(
         shard_update,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(DATA_AXIS, None), P()),

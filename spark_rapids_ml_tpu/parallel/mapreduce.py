@@ -29,7 +29,6 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from spark_rapids_ml_tpu.parallel.compat import shard_map
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from spark_rapids_ml_tpu.utils import metrics as metrics_mod
 
@@ -58,7 +57,7 @@ def map_fn(fn, mesh, in_specs, out_specs, check_vma: Optional[bool] = None):
     veneer over the version-compat ``shard_map`` so call sites read as
     map/reduce pairs rather than sharding plumbing."""
     kwargs = {} if check_vma is None else {"check_vma": check_vma}
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
     )
 

@@ -826,9 +826,16 @@ class DataPlaneClient:
         )
         return bool(resp["created"])
 
-    def model_exists(self, name: str) -> bool:
+    def model_status(self, name: str) -> Dict[str, Any]:
+        """The registration's status: ``exists``, ``algo`` and, once a
+        warmup primed it, the ``aot`` compile ledger (primed buckets,
+        executables, serve-time hits/misses — docs/protocol.md "AOT at
+        registration")."""
         resp, _ = self._roundtrip({"op": "model_status", "model": name})
-        return bool(resp["exists"])
+        return {k: v for k, v in resp.items() if k != "ok"}
+
+    def model_exists(self, name: str) -> bool:
+        return bool(self.model_status(name)["exists"])
 
     def transform(
         self,

@@ -7,8 +7,8 @@ matter (the property the reference's ``RDD.reduce`` relied on,
 RapidsRowMatrix.scala:139). Feeds to different jobs interleave on the
 host side (Arrow decode, validation, staging bookkeeping); the DEVICE
 dispatch itself single-files through a process-wide ``_DEVICE_LOCK`` —
-one process owns the host's chips, concurrent sharded programs on one
-device set buy nothing and can deadlock the CPU backend outright.
+one process owns the host's chips, and concurrent sharded programs on one
+device set buy nothing.
 
 Serving plane: with ``serve_batching`` on (the DEFAULT since the fleet
 PR — ``SRML_SERVE_BATCHING=0`` is the documented opt-out), concurrent
@@ -192,10 +192,12 @@ _SHEDDABLE_OPS = (
 #: Process-wide device-execution lock. One process owns the host's chips
 #: (the daemon's deployment unit); concurrent sharded dispatches from
 #: multiple connection threads buy no throughput — the device set is one
-#: resource — and on the CPU backend they can DEADLOCK outright (jax
-#: 0.4.x host-platform device threads: two in-process daemons folding
-#: concurrently wedge inside their jitted updates at 0% CPU, observed
-#: under the chaos/multidaemon suites). Every device-touching section
+#: resource. (The lock was introduced against a CPU-backend deadlock of
+#: an older jax; on the installed jax 0.9 the chaos / multidaemon / serve
+#: / scheduler / mesh-collectives / elastic suites pass with it replaced
+#: by a no-op — PR 21 — so "one resource" is the reason that remains.
+#: Whether to keep it is ROADMAP D4, decided by a chip trace.) Every
+#: device-touching section
 #: (fold/step/merge/finalize/build/serve) takes this lock INNERMOST —
 #: after any job/model lock, never before one — so lock order stays
 #: acyclic. This contract is machine-checked: srml-check's

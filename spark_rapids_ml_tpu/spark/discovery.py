@@ -45,16 +45,9 @@ def _probe_jax() -> List[str]:
     try:
         import jax
 
-        # Honor JAX_PLATFORMS even when a sitecustomize pre-set the config
-        # (the env var is how operators scope discovery, e.g. to "cpu" on
-        # non-TPU workers).
-        plats = os.environ.get("JAX_PLATFORMS")
-        if plats:
-            try:
-                jax.config.update("jax_platforms", plats)
-            except RuntimeError:
-                pass
-        return [str(d.id) for d in jax.devices() if d.platform != "cpu"]
+        # JAX_PLATFORMS scopes discovery (e.g. "cpu" on non-TPU workers);
+        # JAX reads it itself.
+        return [str(d.id) for d in jax.devices() if d.platform == "tpu"]
     except Exception:  # noqa: BLE001 - discovery must never crash the worker
         return []
 

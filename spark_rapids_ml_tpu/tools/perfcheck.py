@@ -1,6 +1,6 @@
 """Perf regression gate: a fresh BENCH record vs the recorded trajectory.
 
-Five rounds of BENCH_r*.json gave the repo a throughput history; this
+Rounds of BENCH_r*.json give the repo a throughput history; this
 tool makes that history a GATE instead of a graph. It compares one fresh
 ``bench.py`` record against the trajectory and exits nonzero when:
 
@@ -143,8 +143,6 @@ def check(
         # run embeds it, so its absence means the record cannot prove
         # the no-compile-storm property at all — the overall verdict
         # must be FAIL, not a quiet pass on throughput alone.
-        # (Pre-ledger BENCH_r01–r05 are HISTORY, never the fresh record
-        # — they are unaffected.)
         lines.append(
             "compile storm [SKIP-not-pass] fresh record embeds no `xla` "
             "ledger breakdown at all — post-r06 BENCH records must embed "

@@ -1,8 +1,7 @@
 """Jit ledger: per-(function, shape-signature) device-cost attribution.
 
-Five bench rounds of a flat headline (~21.5–22M rows/s/chip, BENCH_r01–
-r05) produced zero insight into WHY, because ``trace_span`` measures host
-wall-clock only: a phase that is 90% XLA compile looks identical to one
+Five bench rounds of a flat headline produced zero insight into WHY,
+because ``trace_span`` measures host wall-clock only: a phase that is 90% XLA compile looks identical to one
 that is 90% HBM-bound GEMM. The reference could at least point Nsight at
 its NVTX ranges (RapidsRowMatrix.scala:62,70); the TPU-native equivalent
 of that attribution is XLA's own cost model — and it is queryable, not
@@ -92,8 +91,8 @@ _M_BYTES = metrics_mod.counter(
 )
 _M_PCACHE_HITS = metrics_mod.counter(
     "srml_xla_persistent_cache_hits_total",
-    "XLA programs served from the persistent compilation cache (config "
-    "compile_cache_dir / SRML_COMPILE_CACHE_DIR) instead of recompiling",
+    "XLA programs served from the persistent compilation cache "
+    "(utils/compile_cache.py) instead of recompiling",
 )
 
 _tls = threading.local()  # .current: (entry, sig) of the innermost call
@@ -210,9 +209,8 @@ class _Entry:
     ``analysis`` caches the once-per-signature cost/memory analysis
     SEPARATELY from the mutable records: :meth:`JitLedger.reset` clears
     counters at a bench epoch boundary, and the first post-reset call
-    must not pay a retrace+lowering (or, in the timing mode, a throwaway
-    backend compile) INSIDE the timed window it is supposed to
-    measure."""
+    must not pay a retrace+lowering (or, in the timing mode, a backend
+    compile) INSIDE the timed window it is supposed to measure."""
 
     def __init__(self, name: str):
         self.name = name
@@ -416,14 +414,13 @@ class LedgeredJit:
             with entry.lock:
                 ana = entry.analysis.get(sig)
             if ana is None:
-                ana = self._analyze(args, kwargs, _device_timing())
+                ana = self._analyze(sig, args, kwargs, _device_timing())
                 with entry.lock:
                     entry.analysis[sig] = ana
             with entry.lock:
                 rec.update(
                     {k: v for k, v in ana.items() if not k.startswith("_")}
                 )
-        _ensure_listener()
         prev = getattr(_tls, "current", None)
         _tls.current = (entry, sig)
         try:
@@ -463,73 +460,51 @@ class LedgeredJit:
         self.aot_hits += 1
         return out
 
-    def _analyze(self, args, kwargs, timed: bool) -> Dict[str, Any]:
+    def _analyze(self, sig: Any, args, kwargs, timed: bool) -> Dict[str, Any]:
         """Once per signature (cached on the entry across resets):
         lowering-level cost analysis (cheap — trace + StableHLO, no
-        backend compile), plus, only in the timing mode, a throwaway AOT
-        compile for ``memory_analysis`` (the jit cache keeps its own
-        executable; measurement modes may pay a duplicate compile, the
-        default path never does). ``_timed`` records which mode produced
-        the cache so a later timing-mode call can upgrade it."""
+        backend compile), plus, only in the timing mode, an AOT compile
+        for ``memory_analysis``. That compile is not a throwaway: the jit
+        and a later ``lower().compile()`` of the same signature reuse its
+        executable and fire no compile event of their own, so it is THE
+        compile of this signature and is booked to this entry — never to
+        whatever entry/annotation encloses the call. ``_timed`` records
+        which mode produced the cache so a later timing-mode call can
+        upgrade it."""
         out: Dict[str, Any] = {"_timed": timed}
-        # Analysis may itself fire backend-compile monitoring events (the
-        # throwaway timing-mode compile below; on some jax versions even
-        # Lowered.cost_analysis compiles) — suspend the thread's
-        # attribution context for the whole body so none of it is booked
-        # to whatever entry/annotation encloses this call (it is
-        # analysis, not dispatched work).
+        _ensure_listener()
         prev = getattr(_tls, "current", None)
-        _tls.current = None
-        try:
-            return self._analyze_inner(out, args, kwargs, timed)
-        finally:
-            _tls.current = prev
-
-    def _analyze_inner(
-        self, out: Dict[str, Any], args, kwargs, timed: bool
-    ) -> Dict[str, Any]:
+        _tls.current = (self._entry, sig)
         try:
             lowered = self._jit.lower(*args, **kwargs)
-        except Exception:  # lowering is best-effort attribution, not work
-            return out
-        try:
-            ca = lowered.cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            if "flops" in ca:
-                out["flops"] = float(ca["flops"])
-            if "bytes accessed" in ca:
-                out["bytes_accessed"] = float(ca["bytes accessed"])
-        except Exception:
-            pass
-        if not timed:
-            return out
-        try:
-            compiled = lowered.compile()
-            ma = compiled.memory_analysis()
-            out["peak_bytes"] = int(getattr(ma, "temp_size_in_bytes"))
-            out["argument_bytes"] = int(getattr(ma, "argument_size_in_bytes"))
-            out["output_bytes"] = int(getattr(ma, "output_size_in_bytes"))
-            # Post-optimization cost analysis outranks the lowering-level
-            # estimate where the backend provides it.
-            cca = compiled.cost_analysis()
-            if isinstance(cca, (list, tuple)):
-                cca = cca[0] if cca else {}
-            if "flops" in cca:
-                out["flops"] = float(cca["flops"])
-            if "bytes accessed" in cca:
-                out["bytes_accessed"] = float(cca["bytes accessed"])
-        except Exception:
-            pass
+            self._harvest_cost(out, lowered.cost_analysis())
+            if timed:
+                compiled = lowered.compile()
+                ma = compiled.memory_analysis()
+                if ma is not None:
+                    out["peak_bytes"] = int(ma.temp_size_in_bytes)
+                    out["argument_bytes"] = int(ma.argument_size_in_bytes)
+                    out["output_bytes"] = int(ma.output_size_in_bytes)
+                # Post-optimization cost analysis outranks the
+                # lowering-level estimate where the backend provides it.
+                self._harvest_cost(out, compiled.cost_analysis())
+        finally:
+            _tls.current = prev
         return out
+
+    @staticmethod
+    def _harvest_cost(out: Dict[str, Any], ca: Optional[Dict[str, Any]]) -> None:
+        """flops / bytes from a cost-analysis dict (None where the
+        backend reports nothing for this program)."""
+        if not ca:
+            return
+        if "flops" in ca:
+            out["flops"] = float(ca["flops"])
+        if "bytes accessed" in ca:
+            out["bytes_accessed"] = float(ca["bytes accessed"])
 
     def __call__(self, *args: Any, **kwargs: Any):
         import jax
-
-        if not _enabled():
-            if self._aot and jax.core.trace_state_clean():
-                return self._dispatch(self._sig(args, kwargs), args, kwargs)
-            return self._jit(*args, **kwargs)
 
         # Inside another trace (a ledgered jit calling a ledgered jit —
         # every pallas.* kernel under a streaming update), this call is
@@ -539,35 +514,42 @@ class LedgeredJit:
         # phantom call (and phantom flops) per compile, so the ledger
         # counts device dispatches from Python only — direct calls. (An
         # AOT executable is likewise uncallable under a trace.)
-        if not jax.core.trace_state_clean():
+        if not jax.core.trace_ctx.is_top_level():
+            return self._jit(*args, **kwargs)
+
+        if not _enabled():
+            if self._aot:
+                return self._dispatch(self._sig(args, kwargs), args, kwargs)
             return self._jit(*args, **kwargs)
 
         entry = self._entry
         sig = self._sig(args, kwargs)
         timing = _device_timing()
         rec, new = entry.record(sig)
+        _ensure_listener()
+        # Taken BEFORE the analysis: in the timing mode its compile is
+        # this signature's compile (see _analyze), and the call that paid
+        # it is the compile-bearing one.
+        compiles_before = rec["compiles"]
+        t0 = time.perf_counter()
         if new:
             _M_CACHE_MISSES.inc(fn=entry.name)
-            # Analyze BEFORE executing: donated buffers are still alive
-            # (lowering only reads avals, but a deleted donated input
-            # can't even report its dtype on some jax versions). Cached
-            # on the entry: a post-reset re-record reuses it instead of
-            # paying the retrace inside the window reset() opened.
+            # Analyze BEFORE executing, while donated buffers are still
+            # alive. Cached on the entry: a post-reset re-record reuses it
+            # instead of paying the retrace inside the window reset()
+            # opened.
             with entry.lock:
                 ana = entry.analysis.get(sig)
             if ana is None or (timing and not ana.get("_timed")):
-                ana = self._analyze(args, kwargs, timing)
+                ana = self._analyze(sig, args, kwargs, timing)
                 with entry.lock:
                     entry.analysis[sig] = ana
             with entry.lock:
                 rec.update(
                     {k: v for k, v in ana.items() if not k.startswith("_")}
                 )
-        _ensure_listener()
-        compiles_before = rec["compiles"]
         prev = getattr(_tls, "current", None)
         _tls.current = (entry, sig)
-        t0 = time.perf_counter()
         try:
             out = self._dispatch(sig, args, kwargs)
             if timing:

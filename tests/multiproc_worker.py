@@ -23,6 +23,10 @@ def main() -> None:
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_enable_x64", True)
 
+    from spark_rapids_ml_tpu.utils.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
+
     from spark_rapids_ml_tpu.parallel.distributed import (
         global_mesh,
         initialize_cluster,

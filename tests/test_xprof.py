@@ -381,22 +381,35 @@ def test_perfcheck_skips_throughput_without_matching_history():
     assert any("[SKIP]" in l for l in lines)
 
 
-def test_perfcheck_reads_the_repo_trajectory():
-    """The shipped BENCH_r*.json wrapper format parses: the five flat
-    TPU rounds (r01–r05, one shared d2048_k32 metric — the flat line
-    that motivated the fusion PR) agree with each other within the
-    gate, and later rounds (r06+: sandbox shapes under their own
-    metrics) parse alongside without perturbing that trajectory."""
-    import glob
+def _write_wrapper_history(directory):
+    """Five driver-side wrapper records ({n, cmd, rc, tail, parsed}) of one
+    flat metric, written under ``directory`` — the BENCH_r*.json file
+    format, built here so the test owns its history."""
+    for n, value in enumerate(
+        (22.05e6, 21.49e6, 21.62e6, 21.81e6, 21.70e6), start=1
+    ):
+        parsed = {"metric": _METRIC, "value": value, "unit": "rows/s/chip"}
+        (directory / f"BENCH_w{n:02d}.json").write_text(json.dumps({
+            "n": n, "cmd": "python bench.py", "rc": 0,
+            "tail": json.dumps(parsed) + "\n", "parsed": parsed,
+        }))
+    return str(directory / "BENCH_w*.json")
+
+
+def test_perfcheck_reads_a_wrapper_trajectory(tmp_path):
+    """The driver-side BENCH_r*.json wrapper format parses: five flat
+    rounds of one shared metric agree with each other within the gate,
+    and a later round under its own metric (the repo's r06: sandbox
+    shapes, first embedded-ledger round) parses alongside without
+    perturbing that trajectory."""
     import pathlib
 
     root = pathlib.Path(__file__).resolve().parent.parent
-    history = perfcheck.load_history([str(root / "BENCH_r0*.json")])
-    assert len(history) >= 6  # r01–r05 TPU + r06 (first embedded-ledger round)
-    values = [
-        h["value"] for h in history
-        if h.get("metric") == "pca_fit_streaming_rows_per_sec_per_chip_d2048_k32"
-    ]
+    history = perfcheck.load_history(
+        [_write_wrapper_history(tmp_path), str(root / "BENCH_r06.json")]
+    )
+    assert len(history) == 6
+    values = [h["value"] for h in history if h.get("metric") == _METRIC]
     assert len(values) == 5
     ok, lines = perfcheck.check(
         _record(min(values)), history
@@ -430,7 +443,7 @@ def test_perfcheck_gates_a_real_smoke_bench(tmp_path):
     rec = perfcheck.parse_record(json.loads(out.stdout.strip().splitlines()[-1]))
     assert "steady" in rec["xla"]
     ok, lines = perfcheck.check(
-        rec, perfcheck.load_history([str(root / "BENCH_r0*.json")])
+        rec, perfcheck.load_history([_write_wrapper_history(tmp_path)])
     )
     assert ok, lines
 
