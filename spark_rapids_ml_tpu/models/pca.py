@@ -182,14 +182,27 @@ def _resolve_solver(solver: Optional[str]) -> str:
 
 
 def _finalize_on_host(count, colsum, gram, mean_center: bool, k: int):
-    """Centering + calSVD-equivalent on host float64 (TPU finalize path)."""
-    count = float(np.asarray(count))
-    colsum = np.asarray(colsum, dtype=np.float64)
-    g = np.asarray(gram, dtype=np.float64)
-    n = max(count, 1.0)
-    mean = colsum / n
-    if mean_center:
-        g = g - np.outer(mean, colsum)
+    """Centering + calSVD-equivalent on host float64 (TPU finalize path).
+
+    Runs inside the caller's ``eig finalize`` span and splits it into
+    children (docs/observability.md "Phases"): ``finalize.wait`` (device
+    work the caller had not waited for — the folds a daemon acked after
+    dispatch land here), ``finalize.fetch`` (the state's copy to host
+    memory, nothing else), ``finalize.center`` (float64 casts, mean,
+    centring), then ``finalize.lapack`` / ``finalize.post`` inside
+    :func:`pca_from_gram_host`."""
+    with trace_span("finalize.wait"):
+        jax.block_until_ready((count, colsum, gram))
+    with trace_span("finalize.fetch"):
+        count, colsum, gram = jax.device_get((count, colsum, gram))
+    with trace_span("finalize.center"):
+        count = float(count)
+        colsum = np.asarray(colsum, dtype=np.float64)
+        g = np.asarray(gram, dtype=np.float64)
+        n = max(count, 1.0)
+        mean = colsum / n
+        if mean_center:
+            g = g - np.outer(mean, colsum)
     pc, ev, s = pca_from_gram_host(g, k)
     return pc, ev, s, mean, count
 
@@ -262,8 +275,9 @@ def fit_pca(
             count, colsum, g = out
             pc, ev, s, mean, _ = _finalize_on_host(count, colsum, g, mean_center, k)
         else:
-            pc, ev, s, mean, count = out
-            pc, ev, s, mean = jax.device_get((pc, ev, s, mean))
+            with trace_span("finalize.device"):
+                pc, ev, s, mean, count = out
+                pc, ev, s, mean = jax.device_get((pc, ev, s, mean))
     return PCASolution(
         pc=np.asarray(pc, dtype=np.float64),
         explained_variance=np.asarray(ev, dtype=np.float64),
@@ -387,7 +401,7 @@ def finalize_pca_stats(
     executor-fed Arrow batches."""
     solver = _resolve_solver(solver)
     count, colsum, g = state
-    n_cols = int(np.asarray(colsum).shape[0])
+    n_cols = int(np.shape(colsum)[0])  # no copy: a D2H here would wait outside the span
     if not 0 < k <= n_cols:
         # require(k > 0 && k <= n) — RapidsRowMatrix.scala:60; without this
         # the top-k slice silently clamps and returns fewer components
@@ -405,8 +419,9 @@ def finalize_pca_stats(
                     gram_ops.finalize_gram(c, cs, gg, mean_center)[0], k
                 )
             )
-            pc, ev, s = jax.device_get(finalize(count, colsum, g))
-            mean = jax.device_get(colsum / jnp.maximum(count, 1))
+            with trace_span("finalize.device"):
+                pc, ev, s = jax.device_get(finalize(count, colsum, g))
+                mean = jax.device_get(colsum / jnp.maximum(count, 1))
     return PCASolution(
         pc=np.asarray(pc, dtype=np.float64),
         explained_variance=np.asarray(ev, dtype=np.float64),

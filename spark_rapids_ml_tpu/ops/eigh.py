@@ -17,6 +17,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from spark_rapids_ml_tpu.utils.profiling import trace_span
+
 
 def eigh_descending(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Symmetric eigendecomposition, eigenvalues descending.
@@ -199,15 +201,21 @@ def pca_from_gram_host(gram, k: int):
     the d×d Gram is tiny to fetch). Architecturally this matches the
     reference, where the eig ran as its own single-device stage separate
     from the distributed reduction (RapidsRowMatrix.scala:70-86).
+
+    Two child spans of the caller's ``eig finalize``: ``finalize.lapack``
+    (the full-spectrum ``eigh`` and nothing else) and ``finalize.post``
+    (order flip, sign flip, σ, ratio, top-k slice).
     """
     import numpy as np
 
     a = np.asarray(gram, dtype=np.float64)
-    w, v = np.linalg.eigh(a)
-    w, v = w[::-1], v[:, ::-1]
-    idx = np.argmax(np.abs(v), axis=0)
-    signs = np.where(v[idx, np.arange(v.shape[1])] < 0, -1.0, 1.0)
-    v = v * signs
-    s = np.sqrt(np.clip(w, 0, None))
-    ev = s / max(s.sum(), 1e-300)
-    return v[:, :k], ev[:k], s
+    with trace_span("finalize.lapack"):
+        w, v = np.linalg.eigh(a)
+    with trace_span("finalize.post"):
+        w, v = w[::-1], v[:, ::-1]
+        idx = np.argmax(np.abs(v), axis=0)
+        signs = np.where(v[idx, np.arange(v.shape[1])] < 0, -1.0, 1.0)
+        v = v * signs
+        s = np.sqrt(np.clip(w, 0, None))
+        ev = s / max(s.sum(), 1e-300)
+        return v[:, :k], ev[:k], s

@@ -21,6 +21,15 @@ Line schema (all events)::
      "run_id": hex, "span_id": hex, "parent_id": hex | null,
      "name": str, ...}
 
+**Clock.** ``ts`` is ``time.time()``: ``CLOCK_REALTIME``, which is also
+the JAX profiler's host clock. ``ts`` minus the profile's
+``profile_start_time`` (``Task Environment`` plane) is therefore the
+span's position in a recorded profile, next to the device events —
+``trace_span`` puts the same span there as a ``TraceAnnotation``, and
+the two starts agree to well under a millisecond
+(tests/test_finalize_spans.py). ``duration_s`` comes from
+``perf_counter``.
+
 ``tid`` (additive) is the OS thread id — ``tools/trace.py`` lays spans
 out on (pid, tid) tracks when emitting Chrome-trace JSON.
 

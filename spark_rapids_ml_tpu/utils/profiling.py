@@ -5,7 +5,10 @@ Nsight (``NvtxRange("compute cov", RED)`` / ``NvtxRange("cuSolver SVD",
 BLUE)``, RapidsRowMatrix.scala:62,70, closed in ``finally``). The TPU
 equivalent is ``jax.profiler.TraceAnnotation``, which names the span in
 xprof/Perfetto traces. ``trace_span`` keeps the same phase-named-span
-idiom and additionally feeds the two always-on observability sinks:
+idiom: every span opens a ``TraceAnnotation`` (while no profile is being
+recorded that is one atomic load; while one is, the span is an event on
+the ``/host:CPU`` plane, on the clock the device events are on) and
+additionally feeds the two always-on observability sinks:
 
 * the process-wide metrics registry — every span's wall-clock lands in
   the ``srml_phase_duration_seconds{phase=...}`` histogram (so bench
@@ -13,8 +16,9 @@ idiom and additionally feeds the two always-on observability sinks:
 * the run journal (``utils/journal.py``, env ``SRML_RUN_JOURNAL``) —
   one JSON line per phase with run/span/parent ids.
 
-With tracing off, the journal unset, and metrics disabled, a span is a
-Timer plus three cheap flag checks — safe on hot paths.
+With no profile recording, the journal unset, and metrics disabled, a
+span is a Timer plus three cheap flag checks — safe on hot paths. Config
+``tracing`` only adds the per-span debug log line.
 """
 
 from __future__ import annotations
@@ -22,6 +26,8 @@ from __future__ import annotations
 import contextlib
 import time
 from typing import Iterator, Optional
+
+import jax.profiler
 
 from spark_rapids_ml_tpu import config
 from spark_rapids_ml_tpu.utils import journal
@@ -60,18 +66,11 @@ def trace_span(name: str, log: bool = False) -> Iterator[Timer]:
             gram = compute_gram(...)
     """
     timer = Timer()
-    tracing = config.get("tracing")
-    if tracing:
-        import jax.profiler
-
-        cm: contextlib.AbstractContextManager = jax.profiler.TraceAnnotation(name)
-    else:
-        cm = contextlib.nullcontext()
-    with cm, journal.span(name):
+    with jax.profiler.TraceAnnotation(name), journal.span(name):
         try:
             yield timer
         finally:
             timer.stop()
             PHASE_SECONDS.observe(timer.elapsed, phase=name)
-            if log or tracing:
+            if log or config.get("tracing"):
                 _logger.debug("phase %s: %.3fs", name, timer.elapsed)

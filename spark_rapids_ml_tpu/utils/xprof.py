@@ -28,6 +28,12 @@ tests/test_lint.py), recording per (function name, shape signature):
   ``block_until_ready``, so async dispatch is serialized per call. OFF
   by default: the production hot path keeps its pipelining, and the
   wrapper is signature lookup + counter bumps.
+* **dispatch seconds** — always: the host seconds each top-level call
+  spent on the wrapper's clock, signature found → dispatch returned
+  (``srml_xla_dispatch_seconds_total{fn}``); over
+  ``srml_xla_calls_total{fn}`` it is what one dispatch costs the host,
+  or, once the runtime's queue is full, how long it held the caller at
+  the device's pace.
 
 With config ``metrics`` off the wrapper is a passthrough (one lock-free
 ``config.peek`` then straight into the jitted callable) — the acceptance
@@ -79,15 +85,11 @@ _M_EXEC_SECONDS = metrics_mod.histogram(
     "Blocked (block_until_ready) execution wall-clock per call, by fn — "
     "recorded only in the SRML_DEVICE_TIMING mode",
 )
-_M_FLOPS = metrics_mod.counter(
-    "srml_xla_executed_flops_total",
-    "Model flops dispatched through ledgered calls (cost-analysis flops "
-    "x calls), by fn",
-)
-_M_BYTES = metrics_mod.counter(
-    "srml_xla_executed_bytes_total",
-    "Model bytes-accessed dispatched through ledgered calls "
-    "(cost-analysis bytes x calls), by fn",
+_M_DISPATCH_SECONDS = metrics_mod.counter(
+    "srml_xla_dispatch_seconds_total",
+    "Host seconds top-level calls spent in the ledger wrapper's clock "
+    "(signature found -> dispatch returned: first-call analysis and "
+    "compile, jit dispatch, any wait on a full runtime queue), by fn",
 )
 _M_PCACHE_HITS = metrics_mod.counter(
     "srml_xla_persistent_cache_hits_total",
@@ -568,12 +570,10 @@ class LedgeredJit:
                 rec["execute_calls"] += 1
                 rec["execute_s"] += dt
         _M_CALLS.inc(fn=entry.name)
+        # In the timing mode dt holds the blocked execution too.
+        _M_DISPATCH_SECONDS.inc(dt, fn=entry.name)
         if timing and not compiled_now:
             _M_EXEC_SECONDS.observe(dt, fn=entry.name)
-        if rec["flops"] is not None:
-            _M_FLOPS.inc(rec["flops"], fn=entry.name)
-        if rec["bytes_accessed"] is not None:
-            _M_BYTES.inc(rec["bytes_accessed"], fn=entry.name)
         return out
 
 

@@ -16,6 +16,8 @@ SO = os.path.join(REPO, "native", "build", "libsrml_tpu.so")
 
 @pytest.fixture(scope="session")
 def native_lib():
+    from spark_rapids_ml_tpu.bridge import native
+
     if not os.path.exists(SO):
         try:
             subprocess.run(
@@ -26,8 +28,9 @@ def native_lib():
             )
         except (subprocess.SubprocessError, FileNotFoundError) as e:
             pytest.skip(f"cannot build native library: {e}")
-    from spark_rapids_ml_tpu.bridge import native
-
+        # An earlier test of this worker may have asked for the library
+        # before it was built; get_lib() latches that miss for the process.
+        native._lib_tried = False
     lib = native.get_lib()
     if lib is None:
         pytest.skip("native library failed to load")

@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from spark_rapids_ml_tpu import config
-from spark_rapids_ml_tpu.utils import journal, xprof
+from spark_rapids_ml_tpu.utils import journal, metrics, xprof
 from spark_rapids_ml_tpu.tools import perfcheck, trace
 
 
@@ -92,6 +92,53 @@ def test_ledger_passthrough_when_metrics_off():
         out = f(jnp.arange(4.0))
     np.testing.assert_array_equal(np.asarray(out), [-1.0, 0.0, 1.0, 2.0])
     assert "test.off" not in xprof.snapshot()
+
+
+def _xla_counter(name, fn):
+    return metrics.REGISTRY.counter(name).value(fn=fn)
+
+
+def test_dispatch_seconds_are_kept_for_every_top_level_call():
+    """The host's cost of a dispatch: Δseconds ÷ Δcalls, by fn — the `dt`
+    the wrapper always computed and used to throw away."""
+    f = xprof.ledgered_jit("test.dispatch", lambda x: x * 2 + 1)
+    x = jnp.ones((8,), jnp.float32)
+    f(x)  # the compile-bearing call counts too (a measured window has none)
+    first = _xla_counter("srml_xla_dispatch_seconds_total", "test.dispatch")
+    calls = _xla_counter("srml_xla_calls_total", "test.dispatch")
+    assert first > 0
+    n = 5
+    for _ in range(n):
+        f(x)
+    warm = _xla_counter("srml_xla_dispatch_seconds_total", "test.dispatch") - first
+    assert _xla_counter("srml_xla_calls_total", "test.dispatch") - calls == n
+    assert warm > 0
+
+
+def test_dispatch_seconds_do_not_move_with_metrics_off():
+    f = xprof.ledgered_jit("test.dispatch_off", lambda x: x + 2)
+    with config.option("metrics", False):
+        f(jnp.ones((4,)))
+        f(jnp.ones((4,)))
+    assert _xla_counter("srml_xla_dispatch_seconds_total", "test.dispatch_off") == 0.0
+    assert _xla_counter("srml_xla_calls_total", "test.dispatch_off") == 0.0
+
+
+def test_a_call_inlined_into_another_trace_books_no_dispatch():
+    inner = xprof.ledgered_jit("test.dispatch_inner", lambda x: x * 3)
+    outer = xprof.ledgered_jit("test.dispatch_outer", lambda x: inner(x) + 1)
+    outer(jnp.ones((4,)))
+    assert _xla_counter("srml_xla_dispatch_seconds_total", "test.dispatch_outer") > 0
+    assert _xla_counter("srml_xla_dispatch_seconds_total", "test.dispatch_inner") == 0.0
+
+
+def test_the_registry_holds_no_cost_analysis_counters():
+    """`srml_xla_executed_{flops,bytes}_total` (cost-analysis × calls, blind
+    inside a custom call, read by nothing) left the fold's hot path."""
+    f = xprof.ledgered_jit("test.no_executed", lambda a: a @ a.T)
+    f(jnp.ones((8, 4), jnp.float32))
+    assert not [name for name in metrics.snapshot() if "executed" in name]
+    assert "srml_xla_executed" not in metrics.render_prometheus()
 
 
 def test_device_timing_mode_records_execution_seconds():
