@@ -188,9 +188,11 @@ def _finalize_on_host(count, colsum, gram, mean_center: bool, k: int):
     children (docs/observability.md "Phases"): ``finalize.wait`` (device
     work the caller had not waited for — the folds a daemon acked after
     dispatch land here), ``finalize.fetch`` (the state's copy to host
-    memory, nothing else), ``finalize.center`` (float64 casts, mean,
-    centring), then ``finalize.lapack`` / ``finalize.post`` inside
-    :func:`pca_from_gram_host`."""
+    memory, nothing else), ``finalize.center`` (one float64 cast of the
+    Gram into an array of this call's own, the mean, and the rank-1
+    centring update written into that array), then ``finalize.lapack`` /
+    ``finalize.post`` inside :func:`pca_from_gram_host`, which is handed
+    the array to work in. Nothing the caller holds is written."""
     with trace_span("finalize.wait"):
         jax.block_until_ready((count, colsum, gram))
     with trace_span("finalize.fetch"):
@@ -198,12 +200,16 @@ def _finalize_on_host(count, colsum, gram, mean_center: bool, k: int):
     with trace_span("finalize.center"):
         count = float(count)
         colsum = np.asarray(colsum, dtype=np.float64)
-        g = np.asarray(gram, dtype=np.float64)
+        g = np.array(gram, dtype=np.float64, order="C")
         n = max(count, 1.0)
         mean = colsum / n
         if mean_center:
-            g = g - np.outer(mean, colsum)
-    pc, ev, s = pca_from_gram_host(g, k)
+            from scipy.linalg.blas import dger
+
+            # g -= mean ⊗ colsum with no outer product materialised: g.T is
+            # g's memory read as a Fortran array, where it is colsum ⊗ mean
+            g = dger(-1.0, colsum, mean, a=g.T, overwrite_a=1).T
+    pc, ev, s = pca_from_gram_host(g, k, overwrite_gram=True)
     return pc, ev, s, mean, count
 
 

@@ -17,6 +17,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from spark_rapids_ml_tpu.utils import metrics
 from spark_rapids_ml_tpu.utils.profiling import trace_span
 
 
@@ -193,8 +194,78 @@ def pca_from_gram_model_sharded(
     )
 
 
-def pca_from_gram_host(gram, k: int):
-    """Host (NumPy/LAPACK, float64) version of :func:`pca_from_gram`.
+#: Largest k/d at which the host finalize takes the partial solve (one
+#: tridiagonalisation, all d eigenvalues, k eigenvectors). Above it LAPACK's
+#: full ``dsyevd`` (``np.linalg.eigh``) is the faster route to the same
+#: answer. Set on the safe side of the crossover measured on the chip's host
+#: (PERF.md §6, PR 25).
+PARTIAL_SOLVE_MAX_K_OVER_D = 0.125
+
+_M_SOLVES = metrics.counter(
+    "srml_pca_finalize_solves_total",
+    "Host PCA finalizes by the LAPACK route they took: path=partial "
+    "(tridiagonalise once, k eigenvectors) or path=full (np.linalg.eigh, "
+    "also the fallback when a partial step reports info != 0)",
+)
+
+
+def _eigh_topk_partial(a, k: int):
+    """All d eigenvalues (ascending) and the eigenvectors of the k largest
+    (as columns, ascending) of the symmetric float64 C-ordered ``a``, which
+    is OVERWRITTEN: Householder tridiagonalisation once (``dsytrd``),
+    eigenvalues of the tridiagonal (``dsterf``), its k top eigenvectors
+    (``dstemr``), back-transformed by the stored reflectors (``dormqr`` on
+    the sub-block: scipy has no ``dormtr``).
+
+    LAPACK works in ``a``'s own memory: a symmetric C-ordered array is its
+    own Fortran-ordered transpose. ``lower=1`` there is ``a``'s upper
+    triangle, so the strict lower triangle is never referenced; if any step
+    reports ``info != 0`` the diagonal is put back and None returned, and
+    ``np.linalg.eigh`` (which reads the lower triangle) still finds the
+    matrix it was given.
+    """
+    import numpy as np
+    from scipy.linalg import lapack
+
+    d = a.shape[0]
+    diag = a.diagonal().copy()
+    lwork, info = lapack.dsytrd_lwork(d, lower=1)
+    if info == 0:
+        qt, td, te, tau, info = lapack.dsytrd(
+            a.T, lower=1, lwork=int(lwork), overwrite_a=1
+        )
+    if info == 0:
+        w, info = lapack.dsterf(td, te)
+    if info == 0:
+        te_n = np.zeros(d)  # dstemr wants e with n entries
+        te_n[:-1] = te
+        select = (2, 0.0, 0.0, d - k + 1, d)  # range 'I', il..iu from 1
+        lwork, liwork, info = lapack.dstemr_lwork(td, te_n, *select)
+    if info == 0:
+        z, info = lapack.dstemr(
+            td, te_n, *select, lwork=int(lwork), liwork=int(liwork)
+        )[2:]
+    if info == 0:
+        # Q = H(1)..H(d-1) leaves row 0 alone; on rows 1: it is the Q of a
+        # QR factorisation whose reflectors sit below the first subdiagonal
+        refl = np.asfortranarray(qt[1:, :-1])
+        zq = np.asfortranarray(z[1:, :k])
+        work, info = lapack.dormqr("L", "N", refl, tau, zq, -1)[1:]
+    if info == 0:
+        zq, _, info = lapack.dormqr(
+            "L", "N", refl, tau, zq, int(work[0]), overwrite_c=1
+        )
+    if info != 0:
+        a.flat[:: d + 1] = diag
+        return None
+    v = np.empty((d, k))
+    v[0] = z[0, :k]
+    v[1:] = zq
+    return w, v
+
+
+def pca_from_gram_host(gram, k: int, overwrite_gram: bool = False):
+    """Host (LAPACK, float64) version of :func:`pca_from_gram`.
 
     Used when the mesh's devices execute eigh poorly (TPU: eigh is an
     iterative algorithm that XLA compiles/executes badly for large d, while
@@ -202,20 +273,41 @@ def pca_from_gram_host(gram, k: int):
     reference, where the eig ran as its own single-device stage separate
     from the distributed reduction (RapidsRowMatrix.scala:70-86).
 
+    The contract needs all d eigenvalues (σ is returned whole, Σσ is the
+    ratio's denominator) but k eigenvectors, so while k ≤
+    :data:`PARTIAL_SOLVE_MAX_K_OVER_D` · d the solve stops there
+    (:func:`_eigh_topk_partial`); above it, and whenever a partial step
+    reports ``info != 0``, it is ``np.linalg.eigh``. Both are exact direct
+    float64 solves; ``srml_pca_finalize_solves_total{path}`` says which ran.
+    The partial solve works in place: on a copy, unless ``overwrite_gram``
+    hands over a float64 array the caller made for this call.
+
     Two child spans of the caller's ``eig finalize``: ``finalize.lapack``
-    (the full-spectrum ``eigh`` and nothing else) and ``finalize.post``
-    (order flip, sign flip, σ, ratio, top-k slice).
+    (every LAPACK call and nothing else) and ``finalize.post`` (on the k
+    kept columns: descending order, sign flip; σ over all d, ratio). ``pc``
+    owns its (d, k) memory.
     """
     import numpy as np
 
     a = np.asarray(gram, dtype=np.float64)
+    d = a.shape[0]
+    try_partial = k <= PARTIAL_SOLVE_MAX_K_OVER_D * d
+    if try_partial and not (
+        overwrite_gram and a.flags.c_contiguous and a.flags.writeable
+    ):
+        a = a.copy(order="C")
     with trace_span("finalize.lapack"):
-        w, v = np.linalg.eigh(a)
+        solved = _eigh_topk_partial(a, k) if try_partial else None
+        path = "full" if solved is None else "partial"
+        if solved is None:
+            w, v = np.linalg.eigh(a)
+            solved = w, v[:, d - k:]
+    _M_SOLVES.inc(path=path)
     with trace_span("finalize.post"):
-        w, v = w[::-1], v[:, ::-1]
-        idx = np.argmax(np.abs(v), axis=0)
-        signs = np.where(v[idx, np.arange(v.shape[1])] < 0, -1.0, 1.0)
-        v = v * signs
-        s = np.sqrt(np.clip(w, 0, None))
+        w, v = solved
+        pc = np.array(v[:, ::-1], order="C")
+        idx = np.argmax(np.abs(pc), axis=0)
+        pc *= np.where(pc[idx, np.arange(k)] < 0, -1.0, 1.0)
+        s = np.sqrt(np.clip(w[::-1], 0, None))
         ev = s / max(s.sum(), 1e-300)
-        return v[:, :k], ev[:k], s
+        return pc, ev[:k], s

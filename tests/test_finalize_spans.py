@@ -1,7 +1,7 @@
 """The finalize, opened: `eig finalize` and its child spans in all three
 sinks of `trace_span` — the journal (ring), the phase histogram and the
-profile a `jax.profiler` trace records — and the host path's arithmetic
-held bit-equal to the way it was computed before the split."""
+profile a `jax.profiler` trace records — and the host path's results held
+to the way they were computed before it solved for k components only."""
 
 import glob
 import os
@@ -13,7 +13,6 @@ import pytest
 
 from spark_rapids_ml_tpu import config
 from spark_rapids_ml_tpu.models.pca import finalize_pca_stats, fit_pca
-from spark_rapids_ml_tpu.ops.eigh import pca_from_gram_host
 from spark_rapids_ml_tpu.parallel.mesh import make_mesh
 from spark_rapids_ml_tpu.utils import journal, metrics
 
@@ -107,24 +106,32 @@ def test_without_a_ring_or_a_path_no_event_is_made_but_the_histogram_counts(
 
 
 @pytest.mark.parametrize("mean_center", [True, False])
-def test_host_path_outputs_are_bit_equal_to_the_old_computation(state, mesh1, mean_center):
-    """The split moved the copy out of the casts and nothing else: the same
-    float32 values, cast to float64 in the same order."""
+def test_host_path_outputs_agree_with_the_old_computation(state, mesh1, mean_center):
+    """The old computation: an outer product subtracted out of place, the
+    full-spectrum `eigh`, sign flip over all d columns, then the slice. An
+    in-place rank-1 update may round the last bit otherwise (FMA) and another
+    LAPACK route does: float64 rounding is what may differ, nothing more."""
     count, colsum, gram = state
     with config.option("finalize", "host"):
         sol = finalize_pca_stats(state, K, mean_center, mesh1, ROWS)
-    # the parent commit's _finalize_on_host, line for line
     n = max(float(np.asarray(count)), 1.0)
     cs = np.asarray(colsum, dtype=np.float64)
     g = np.asarray(gram, dtype=np.float64)
     mean = cs / n
     if mean_center:
         g = g - np.outer(mean, cs)
-    pc, ev, s = pca_from_gram_host(g, K)
-    assert np.array_equal(sol.pc, pc) and sol.pc.dtype == np.float64
-    assert np.array_equal(sol.explained_variance, ev)
-    assert np.array_equal(sol.sigma, s)
-    assert np.array_equal(sol.mean, mean)
+    w, v = np.linalg.eigh(g)
+    w, v = w[::-1], v[:, ::-1]
+    idx = np.argmax(np.abs(v), axis=0)
+    v = v * np.where(v[idx, np.arange(D)] < 0, -1.0, 1.0)
+    s = np.sqrt(np.clip(w, 0, None))
+    ev = s / s.sum()
+    assert sol.pc.shape == (D, K) and sol.pc.dtype == np.float64
+    # same sign, so the plain dot product: 1 - cos <= 1e-12 a component
+    assert np.all(np.sum(sol.pc * v[:, :K], axis=0) >= 1 - 1e-12)
+    assert np.allclose(sol.sigma, s, rtol=0, atol=1e-12 * s[0]) and sol.sigma.shape == (D,)
+    assert np.allclose(sol.explained_variance, ev[:K], rtol=1e-12, atol=0)
+    assert np.allclose(sol.mean, mean, rtol=1e-12, atol=0)
     assert sol.n_rows == ROWS
 
 
