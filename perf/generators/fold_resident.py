@@ -70,7 +70,8 @@ def run(ctx):
                      "mean": np.asarray(sol.mean)}
             seconds = time.monotonic() - t0
         # the rows the state says it folded (a float32 count of multiples
-        # of 2^16 under 2^27: exact), read after both timed parts
+        # of the fold's rows, 2^14 or more, under 2^27: exact), read after
+        # both timed parts
         model["rows"] = int(np.asarray(state[0]))
         return start, end, seconds, model
 
@@ -106,6 +107,7 @@ def run(ctx):
     problems += agree.check_pca_fits(obs.fits, {0: ref}, cfg["tolerances"], d, k, say)
     if not any(pa["end"] <= deadline for pa in obs.passes):
         problems.append("no pass completed inside the window")
+    obs.compared = agree.compared_pca(obs.fits, cfg["tolerances"], rows_per_fit)
     if not agree.summarize(problems, say):
         obs.correct = False
     for fit in obs.fits:

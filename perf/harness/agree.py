@@ -63,6 +63,23 @@ def check_pca_fits(fits: List[Dict], refs: Dict[int, Dict[str, np.ndarray]],
     return problems
 
 
+def compared_pca(fits: List[Dict], tol: Dict[str, float], rows_per_fit: int
+                 ) -> Dict[str, List[float]]:
+    """Each number `check_pca_fits` compared, the worst over the fits, beside
+    its limit: name → [number, limit]. `min_cos` must not fall under its
+    limit, the others must not pass theirs. For the result line."""
+    seen = [f["model"]["_agreement"] for f in fits if "_agreement" in f["model"]]
+    out = {"rows_not_folded": [float(max((abs(rows_per_fit - f["model"]["rows"])
+                                          for f in fits), default=rows_per_fit)), 0.0]}
+    if seen:
+        out["min_cos"] = [min(min(a["min_cos"], a["min_principal_cos"]) for a in seen),
+                          tol["min_cos"]]
+        out["explained_variance_rel"] = [max(a["ev_rel"] for a in seen),
+                                         tol["explained_variance_rel"]]
+        out["mean_abs"] = [max(a["mean_abs"] for a in seen), tol["mean_abs"]]
+    return out
+
+
 def summarize(problems: List[str], say) -> bool:
     for p in problems[:20]:
         say(f"  DISAGREES: {p}")

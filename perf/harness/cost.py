@@ -7,6 +7,8 @@ from __future__ import annotations
 
 from typing import Dict, Tuple
 
+from perf.harness import layout
+
 
 def pca_fold(n_rows: int, d: int) -> Tuple[float, float]:
     """One fold of `n_rows` float32 rows into (count, colsum, Gram):
@@ -15,10 +17,18 @@ def pca_fold(n_rows: int, d: int) -> Tuple[float, float]:
     return 2.0 * n_rows * d * d + n_rows * d, 4.0 * n_rows * d + 2.0 * 4.0 * d * d
 
 
-def fold_cost(config: Dict, rows_per_chip: int) -> Tuple[float, float]:
-    if config["algo"] == "pca":
-        return pca_fold(rows_per_chip, config["n_cols"])
-    raise KeyError(f"no fold cost function for algo {config['algo']!r}")
+def fold_cost(config: Dict, rows_per_chip: int, root: str = layout.REPO_ROOT
+              ) -> Tuple[float, float]:
+    """(operations, bytes) of one chip's rows of one fold, by the function
+    `fold(config, rows_per_chip)` of `<root>/perf/costs/<algo>.py` — found
+    by the configuration's `algo` the way a reader is found by its metric's
+    name, so a later PR brings a second algorithm's cost as a file. `root`
+    is the tree the run is of (`obs.root`)."""
+    try:
+        module = layout.load_module(root, "costs", config["algo"])
+    except layout.LayoutError as e:
+        raise KeyError(f"no fold cost function for algo {config['algo']!r}: {e}") from e
+    return module.fold(config, rows_per_chip)
 
 
 def roofline(flops: float, nbytes: float, seconds: float, peaks: Dict) -> Dict:

@@ -14,7 +14,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from perf.harness import stats
+from perf.harness import layout, stats
 from perf.harness.device import WindowMemory
 
 
@@ -70,7 +70,11 @@ class Observation:
     `time.monotonic()` (one clock for every process of the machine)."""
 
     def __init__(self, config: Dict[str, Any], params: Dict[str, Any],
-                 seconds: float, device: Dict[str, Any]) -> None:
+                 seconds: float, device: Dict[str, Any],
+                 root: str = layout.REPO_ROOT) -> None:
+        #: the tree the run is of: where a reader finds files by name
+        #: (`perf/costs/<algo>.py`)
+        self.root = root
         self.config = config
         self.params = params
         self.seconds = float(seconds)
@@ -92,6 +96,9 @@ class Observation:
         self.attempted = 0
         self.failed = 0
         self.correct = True
+        #: each number the comparison with the reference read, beside its
+        #: limit: name → [number, limit]; the result line's last key
+        self.compared: Dict[str, List[float]] = {}
         self.notes: Dict[str, Any] = {}
 
     # -- counters ---------------------------------------------------------
@@ -144,7 +151,7 @@ class Context:
         self._runtime_s = float(runtime_s)
         self._memory = WindowMemory()
         self.watch = CompileWatch()
-        self.obs = Observation(config, params, seconds, device)
+        self.obs = Observation(config, params, seconds, device, root)
 
     @contextlib.contextmanager
     def span(self, name: str):

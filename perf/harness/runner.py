@@ -66,6 +66,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool, *
         dev["window_s"] = obs.trace["window_s"]
         result["breakdown"] = {"device_ops": obs.trace["device_ops"],
                                "idle_gaps": obs.trace["idle_gaps"]}
+    result["compared"] = {**obs.compared,
+                          "compiles_in_window": [float(compiles), 0.0]}
     if out_dir:
         os.makedirs(out_dir, exist_ok=True)
         with open(os.path.join(out_dir, f"{workload}.seed{seed}.trace{int(trace)}.json"),

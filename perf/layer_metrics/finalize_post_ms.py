@@ -1,6 +1,6 @@
 """Finalize: mean milliseconds of the program's `finalize.post` span
-(`ops/eigh.py` `pca_from_gram_host`: descending order, the `argmax|v|` sign
-flip, `v * signs`, σ, the ratio and the top-k slice) — Δsum ÷ Δcount of
+(`ops/eigh.py` `pca_from_gram_host`: on the k kept columns, descending order
+and the `argmax|v|` sign flip; σ over all d and the ratio) — Δsum ÷ Δcount of
 `srml_phase_duration_seconds{phase=finalize.post}` across the window.
 Nothing to read from a program without the span."""
 

@@ -4,10 +4,11 @@
     python3 perf/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The last line of standard output is one JSON object: `correct`,
-`attempted`, `failed`, `metrics`, `device` and, in a traced run,
-`breakdown`. With `--trace 0` the metrics are the cell's end-to-end
-metrics, with `--trace 1` its per-layer metrics. Everything else is on
-earlier lines (or, with `--out DIR`, in files there). It needs a TPU with
+`attempted`, `failed`, `metrics`, `device`, in a traced run `breakdown`, and
+last `compared`: each number that decided `correct` beside its limit, which
+are also the last lines of standard error. With `--trace 0` the metrics are
+the cell's end-to-end metrics, with `--trace 1` its per-layer metrics.
+Everything else is on earlier lines (or, with `--out DIR`, in files there). It needs a TPU with
 the chips the cell asks for: without, it exits non-zero and prints no
 result. See perf/README.md.
 """
@@ -71,6 +72,8 @@ def main(argv=None) -> int:
               flush=True)
         return 1
     print(json.dumps(result), flush=True)
+    for name, (value, limit) in result["compared"].items():
+        print(f"compared {name}: {value!r} (limit {limit!r})", file=sys.stderr, flush=True)
     return 0
 
 

@@ -1,10 +1,13 @@
 """The six per-layer metrics that read the program's finalize children and
 the fold's dispatch counter: unit cases on hand-made snapshots, and the CPU
-rehearsal of the one-chip cell reporting them while the x4-like cell's set
-stays what it was."""
+rehearsal of the one-chip and the x4-like cell reporting them — each what
+BENCHMARK.json lists for the admitted cell it stands for."""
+
+import json
 
 import pytest
 
+import contract
 import perf_rehearse
 from perf.harness import layout, observe
 from perf.layer_metrics import (finalize_center_ms, finalize_fetch_ms,
@@ -93,19 +96,33 @@ def test_fold_dispatch_is_left_out_when_there_is_nothing_to_read(before, after):
     assert fold_dispatch_ms.read(_observation(before, after)) is None
 
 
-def test_the_new_entries_belong_to_the_one_chip_cell_only_and_are_appended():
+def test_the_new_entries_belong_to_the_one_chip_cell_only_and_are_appended(tmp_path):
+    """Its name is PR 24's; what it holds since PR 27 is a contract by name:
+    each accepted per-layer metric exists once with the fields it was accepted
+    with, and each of the six is still read in the one-chip cell. Nothing
+    about their place in the list, what follows them, or which other cells
+    a later PR appended to their `workloads`."""
+    held = contract.the_accepted_per_layer_metrics_are_what_they_were
+    assert NEW == contract.SPLIT and ONE_CHIP == contract.ONE_CHIP
+    held(layout.REPO_ROOT)
+
+    def write(bench):
+        with open(tmp_path / "BENCHMARK.json", "w", encoding="utf-8") as f:
+            json.dump(bench, f)
+        return str(tmp_path)
+
     bench = layout.load_benchmark(layout.REPO_ROOT)
-    tail = bench["per_layer"][-len(NEW):]
-    assert {m["name"] for m in tail} == NEW
-    assert all(m["workloads"] == [ONE_CHIP] and m["unit"] == "ms"
-               and m["better"] == "lower" for m in tail)
-    assert {m["name"]: (m["source"], m["layer"], m["moves"]) for m in tail} == {
-        **{n: ("program_span", "finalize", "finalize_s") for n in NEW - {"fold_dispatch_ms"}},
-        "fold_dispatch_ms": ("program_counter", "model_programs", "fold_rows_per_s")}
-    assert [m["name"] for m in bench["per_layer"][:-len(NEW)]] == [
-        "fold_device_ms", "fold_roofline", "collective_ms_per_fold",
-        "collective_exposed_share", "finalize_eig_ms", "device_idle_share",
-        "compiles_in_window"]
+    bench["per_layer"].reverse()  # order is free
+    for m in bench["per_layer"]:
+        m.setdefault("workloads", []).append("a_later.cell")  # lists may grow
+    bench["per_layer"].append({"name": "a_later_metric", "unit": "ms", "better": "lower",
+                               "source": "program_span", "layer": "a later layer",
+                               "moves": "finalize_s", "workloads": ["a_later.cell"]})
+    held(write(bench))
+    dispatch = next(m for m in bench["per_layer"] if m["name"] == "fold_dispatch_ms")
+    dispatch["moves"] = "setup_s"  # a field of an accepted entry may not change
+    with pytest.raises(AssertionError):
+        held(write(bench))
 
 
 @pytest.fixture(scope="module")
@@ -119,7 +136,8 @@ def test_the_one_chip_rehearsal_reports_the_six_and_the_split_adds_up(root):
     text = "\n".join(lines)
     assert result["correct"] is True, text
     got = {name: m["value"] for name, m in result["metrics"].items()}
-    assert set(got) == NEW | {"finalize_eig_ms", "compiles_in_window"}
+    assert set(got) == perf_rehearse.reports(root, "tiny_pca.fold_resident", "per_layer")
+    assert NEW | {"finalize_eig_ms", "compiles_in_window"} <= set(got)
     assert all(got[name] >= 0 for name in NEW) and got["compiles_in_window"] == 0
     assert got["finalize_self_ms"] < got["finalize_eig_ms"]
     named = sum(got[n] for n in NEW - {"finalize_self_ms", "fold_dispatch_ms"})
@@ -132,4 +150,7 @@ def test_the_one_chip_rehearsal_reports_the_six_and_the_split_adds_up(root):
 def test_the_x4_like_rehearsal_cell_reports_what_it_did(root):
     result, _ = perf_rehearse.run(root, "tiny_pca.fold_resident_x4", seconds=0.5,
                                   trace=True)
-    assert set(result["metrics"]) == {"finalize_eig_ms", "compiles_in_window"}
+    want = perf_rehearse.reports(root, "tiny_pca.fold_resident_x4", "per_layer")
+    assert set(result["metrics"]) == want
+    # since PR 27 the x4 cell lists the finalize split and the dispatch counter
+    assert NEW | {"finalize_eig_ms", "compiles_in_window"} <= want

@@ -1,80 +1,75 @@
 """BENCHMARK.json against the builder's contract, and every name in it
-against the files under perf/."""
+against the files under perf/. The checks themselves are functions of a tree
+(`contract.py`); here they run on the repo, and
+`test_perf_rehearse_fold.py` runs them again on a copy to which the next PR's
+files have been added. What is specific to the admitted PCA cells (the
+published widths, the ring's size) is held here only."""
 
 import json
-import os
-import re
 
 import pytest
 
+import contract
 from perf.harness import layout
 
 ROOT = layout.REPO_ROOT
 BENCH = layout.load_benchmark(ROOT)
-NAME = re.compile(r"^[A-Za-z0-9][A-Za-z0-9_.-]{0,63}$")
-PLAIN_PATH = re.compile(r"^[A-Za-z0-9_./-]+$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-CELLS = [w["name"] for w in BENCH["workloads"]]
+CELLS = contract.cells(BENCH)
 
 
 def test_benchmark_json_has_exactly_the_contracts_keys_and_limits():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs", "workloads",
-                          "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
-    assert BENCH["command"] == ["python3", "perf/run.py"]
-    assert BENCH["paths"] == ["perf", "tests/perf"]
-    assert isinstance(BENCH["run_seconds"], int) and 1 <= BENCH["run_seconds"] <= 51
-    assert 1 <= len(BENCH["configs"]) <= 24 and 2 <= len(BENCH["workloads"]) <= 24
-    assert 1 <= len(BENCH["end_to_end"]) <= 16 and 1 <= len(BENCH["per_layer"]) <= 128
-    names = [e["name"] for key in ("configs", "workloads", "end_to_end", "per_layer")
-             for e in BENCH[key]]
-    assert len(names) == len(set(names)), "a name is used twice"
-    assert all(NAME.match(n) for n in names)
-    assert all(len(e["why"]) <= 200 for key in ("configs", "workloads")
-               for e in BENCH[key])
+    contract.keys_and_limits(ROOT)
+
+
+def _rename(key, index, name):
+    def edit(bench):
+        bench[key][index]["name"] = name
+    return edit
+
+
+@pytest.mark.parametrize("edit", [
+    # no two of configs, workloads and metrics share a name, across the lists
+    _rename("configs", 0, "fold_rows_per_s"),
+    _rename("per_layer", 0, "pca_d2048_k32.fold_resident"),
+    _rename("end_to_end", 0, "fold_device_ms"),
+    # a name starts with a letter or a digit
+    _rename("per_layer", 0, "_fold_device_ms"),
+    _rename("workloads", 0, ".fold_resident"),
+], ids=["config_like_a_metric", "metric_like_a_cell", "two_metrics", "underscore_first",
+        "dot_first"])
+def test_a_name_used_twice_or_badly_begun_is_refused(tmp_path, edit):
+    bench = json.loads(json.dumps(BENCH))
+    edit(bench)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench), encoding="utf-8")
+    with pytest.raises(AssertionError):
+        contract.keys_and_limits(str(tmp_path))
+
+
+def test_a_configuration_without_a_width_does_not_resolve(monkeypatch):
+    config = dict(layout.load_config(ROOT, BENCH, "pca_d2048_k32"), n_cols=0)
+    monkeypatch.setattr(layout, "load_config", lambda *a: config)
+    with pytest.raises(AssertionError):
+        contract.cell_resolves(ROOT, BENCH, CELLS[0])
 
 
 def test_a_full_check_fits_its_time_with_all_24_cells():
-    runs = 2 + 14 * 24
-    total = runs * (BENCH["run_seconds"] + 60) + 24 * 2 * 90 + 1200
-    assert total <= 43200
+    contract.a_full_check_fits_its_time_with_all_24_cells(ROOT)
 
 
 def test_cells_chips_and_the_share_of_four_chip_cells():
-    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
-    assert len(pairs) == len(set(pairs))
-    assert all(w["chips"] in (1, 4) for w in BENCH["workloads"])
-    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
-    assert four <= max(1, len(BENCH["workloads"]) // 4)
-    used = {w["config"] for w in BENCH["workloads"]}
-    assert used == {c["name"] for c in BENCH["configs"]}, "a config no cell uses"
-
-
-WIDTH = re.compile(r"(_dim|_rank)$|hidden|intermediate|latent|state|proj|head|expan"
-                   r"|n_cols|^k$|width|arrow_batch_rows")
+    contract.chips_and_the_share_of_four_chip_cells(ROOT)
 
 
 def test_configurations_name_their_file_source_and_cuts():
-    files = [c["file"] for c in BENCH["configs"]]
-    assert len(files) == len(set(files))
-    sources = [c["source"] for c in BENCH["configs"]]
-    assert len(sources) == len(set(sources)), "two deployments need sources that differ"
-    for c in BENCH["configs"]:
-        assert c["file"].startswith("perf/configs/") and c["source"].startswith("https://")
-        config = layout.read_json(os.path.join(ROOT, c["file"]))
-        assert config["name"] == c["name"] and config["source"] == c["source"]
-        assert sorted(c["reduced"]) == sorted(config["reduced"])
-        assert not any(WIDTH.search(key) for key in c["reduced"]), "a width was cut"
-        assert set(config["tolerances"]) <= set(config["tolerance_reasons"])
-        assert config["guarantees"]
+    contract.configurations_name_their_file_source_and_cuts(ROOT)
 
 
 def test_published_widths_are_what_the_files_state():
     pca = layout.load_config(ROOT, BENCH, "pca_d2048_k32")
     assert (pca["n_cols"], pca["k"], pca["rows"]) == (2048, 32, 100_000_000)
     assert pca["arrow_batch_rows"] == 65536
-    assert pca["tolerances"] == {"min_cos": 1 - 1e-4, "explained_variance_rel": 2.0**-9,
-                                 "mean_abs": 2.0**-10}
+    assert pca["tolerances"] == {"min_cos": 1 - 2.0**-25, "explained_variance_rel": 2.0**-12,
+                                 "mean_abs": 2.0**-14}
     for name, rows_per_fit in (("pca_d2048_k32.fold_resident", 25_165_824),
                                ("pca_d2048_k32.fold_resident_x4", 100_663_296)):
         _, cell, _, _, p = layout.resolve(ROOT, name)
@@ -100,24 +95,7 @@ def test_every_resident_cell_fills_a_quarter_of_a_chip_with_rows_its_folds_read(
 
 @pytest.mark.parametrize("cell_name", CELLS)
 def test_cell_resolves_to_files_that_exist_and_matches_its_entry(cell_name):
-    cell = layout.load_cell(ROOT, BENCH, cell_name)  # raises where they differ
-    config = layout.load_config(ROOT, BENCH, cell["config"])
-    traffic = layout.load_traffic(ROOT, cell["traffic"])
-    generator = layout.load_module(ROOT, "generators", traffic["generator"])
-    assert callable(generator.run)
-    assert set(cell.get("params", {})) <= set(traffic["params"]), \
-        "a cell overrides a parameter its traffic mix does not have"
-    assert config["algo"] and config["n_cols"] > 0
-    end_to_end = layout.metric_entries(BENCH, "end_to_end", cell_name)
-    per_layer = layout.metric_entries(BENCH, "per_layer", cell_name)
-    e2e_names = {m["name"] for m in end_to_end}
-    assert "setup_s" in e2e_names and len(e2e_names) >= 2 and per_layer
-    for kind, entries in (("end_to_end", end_to_end), ("per_layer", per_layer)):
-        for m in entries:
-            reader = layout.load_module(ROOT, layout.READER_DIRS[kind], m["name"])
-            assert callable(reader.read) and reader.__doc__
-    # a per-layer metric is reported only where the metric it moves is
-    assert all(m["moves"] in e2e_names for m in per_layer)
+    contract.cell_resolves(ROOT, BENCH, cell_name)
 
 
 def test_a_cell_file_that_contradicts_benchmark_json_is_refused(tmp_path):
@@ -132,44 +110,29 @@ def test_a_cell_file_that_contradicts_benchmark_json_is_refused(tmp_path):
 
 
 def test_metrics_follow_the_contract():
-    for m in BENCH["end_to_end"]:
-        assert set(m) <= {"name", "unit", "better", "bound", "source", "workloads"}
-        assert m["source"] in ("host_clock", "device_trace")
-        assert 0.01 <= m["bound"] <= 0.1 and m["better"] in ("lower", "higher")
-    setup = [m for m in BENCH["end_to_end"] if m["name"] == "setup_s"]
-    assert len(setup) == 1 and setup[0]["bound"] == 0.1 and "workloads" not in setup[0]
-    e2e = {m["name"] for m in BENCH["end_to_end"]}
-    layers = set()
-    for m in BENCH["per_layer"]:
-        assert set(m) <= {"name", "unit", "better", "source", "layer", "moves",
-                          "workloads"}
-        assert m["source"] in SOURCES and m["moves"] in e2e and "bound" not in m
-        assert set(m.get("workloads", CELLS)) <= set(CELLS)
-        if m["name"].endswith("_roofline"):
-            assert m["unit"] == "%"
-        layers.add(m["layer"])
-    assert {"model_programs", "kernels", "collectives", "finalize", "device"} <= layers
+    contract.metrics_follow_the_contract(ROOT)
 
 
 def test_every_file_under_paths_has_a_plain_name_and_every_reader_is_listed():
-    listed = {kind: {m["name"] for m in BENCH[kind]} for kind in ("end_to_end", "per_layer")}
-    for path in BENCH["paths"]:
-        for folder, dirs, files in os.walk(os.path.join(ROOT, path)):
-            dirs[:] = [d for d in dirs if d != "__pycache__"]
-            for name in files:
-                rel = os.path.relpath(os.path.join(folder, name), ROOT)
-                assert PLAIN_PATH.match(rel), rel
-    for cell in os.listdir(os.path.join(ROOT, "perf", "cells")):
-        assert cell[:-len(".json")] in CELLS, f"{cell} is in no workloads entry"
-    used = {w["traffic"] for w in BENCH["workloads"]}
-    generators = set()
-    for mix in os.listdir(os.path.join(ROOT, "perf", "traffic")):
-        assert mix[:-len(".json")] in used, f"{mix} is the mix of no cell"
-        generators.add(layout.load_traffic(ROOT, mix[:-len(".json")])["generator"])
-    here = {f[:-3] for f in os.listdir(os.path.join(ROOT, "perf", "generators"))
-            if f.endswith(".py") and f != "__init__.py"}
-    assert here == generators, "a generator no mix names"
-    for kind, directory in layout.READER_DIRS.items():
-        here = {f[:-3] for f in os.listdir(os.path.join(ROOT, "perf", directory))
-                if f.endswith(".py") and f != "__init__.py"}
-        assert here == listed[kind], "a reader without an entry, or the reverse"
+    contract.every_file_has_a_plain_name_and_every_reader_is_listed(ROOT)
+
+
+def test_the_small_fold_cell_is_the_one_chip_fit_in_16384_row_folds():
+    """`pca_d2048_k32.fold_resident_small` (PR 27): the one-chip cell's ring
+    and fit, in the folds a Spark job at its default Arrow batch sends."""
+    _, big, _, _, p1 = layout.resolve(ROOT, "pca_d2048_k32.fold_resident")
+    _, cell, _, traffic, p = layout.resolve(ROOT, "pca_d2048_k32.fold_resident_small")
+    assert (cell["chips"], traffic["generator"]) == (1, "fold_resident")
+    assert p == {"global_batch_rows": 16384, "ring_batches": 64, "folds_per_fit": 1536,
+                 "trace_s": 5.0}
+    assert p["folds_per_fit"] * p["global_batch_rows"] == cell["rows_per_fit"] \
+        == big["rows_per_fit"] == 25_165_824
+    assert p["ring_batches"] * p["global_batch_rows"] \
+        == p1["ring_batches"] * p1["global_batch_rows"]
+    assert p["folds_per_fit"] % p["ring_batches"] == 0
+    reported = {kind: {m["name"] for m in layout.metric_entries(BENCH, kind, cell["name"])}
+                for kind in ("end_to_end", "per_layer")}
+    # not `finalize_s`, and so none of the metrics that move it
+    assert reported == {"end_to_end": {"fold_rows_per_s", "setup_s"},
+                        "per_layer": {"fold_device_ms", "fold_roofline", "fold_dispatch_ms",
+                                      "compiles_in_window"}}

@@ -1,19 +1,21 @@
 """CPU rehearsal of the resident fold at a tiny size (d=64) on one device
-and on a mesh over 4 of the 8 virtual devices, and a cell, a mix, a generator
-and two metrics added as new files to a copy of the tree — the way a later
-PR adds them."""
+and on a mesh over 4 of the 8 virtual devices, and the rehearsal of the next
+`model_config` PR: a second algorithm (`fixtures/next_pr`: configuration,
+generator, reference, cost file, mix, cell, five metrics) laid over a copy
+of the tree as new files and appended entries, run there, and the whole
+contract (`contract.py`) checked on the tree AFTER the addition."""
 
-import json
 import os
 
 import pytest
 
+import contract
 import perf_rehearse
+from perf.harness import cost, layout, observe
 
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
-END_TO_END = {"fold_rows_per_s", "finalize_s", "setup_s"}
 
 
 @pytest.fixture(scope="module")
@@ -33,12 +35,21 @@ def test_fold_resident_end_to_end(root, cell, mesh, rows_per_fit):
     assert result["correct"] is True, text
     assert set(result) == RESULT_KEYS and set(result["device"]) == DEVICE_KEYS
     assert f"mesh {mesh}" in text and f"= {rows_per_fit} rows" in text
-    assert set(result["metrics"]) == END_TO_END
+    # what BENCHMARK.json lists for the admitted cell this one stands for
+    assert set(result["metrics"]) == perf_rehearse.reports(root, cell, "end_to_end")
+    assert {"fold_rows_per_s", "setup_s"} <= set(result["metrics"])
     assert all(m["value"] > 0 and set(m) == {"value", "unit"}
                for m in result["metrics"].values())
     assert result["failed"] == 0 and result["attempted"] > 4
     assert "compiles in window: 0" in text and "agreement over" in text
     assert result["device"]["platform"] == "cpu"  # named for what it is
+    # each number that decided `correct`, beside its limit, as the last key
+    assert list(result)[-1] == "compared"
+    assert set(result["compared"]) == {"rows_not_folded", "min_cos", "explained_variance_rel",
+                                       "mean_abs", "compiles_in_window"}
+    assert result["compared"]["rows_not_folded"] == [0.0, 0.0]
+    value, limit = result["compared"]["min_cos"]
+    assert 0.9999 < limit <= value <= 1.0
 
 
 def test_fold_resident_per_layer_reads_spans_and_leaves_out_the_trace(root):
@@ -47,18 +58,18 @@ def test_fold_resident_per_layer_reads_spans_and_leaves_out_the_trace(root):
     text = "\n".join(lines)
     assert result["correct"] is True, text
     got = result["metrics"]
-    # the program's own span and the compile count; the rest needs a TPU trace
-    assert set(got) == {"finalize_eig_ms", "compiles_in_window"}
+    # the program's own spans and counters; the rest needs a TPU trace
+    assert set(got) == perf_rehearse.reports(root, "tiny_pca.fold_resident_x4", "per_layer")
+    assert {"finalize_eig_ms", "compiles_in_window"} <= set(got)
     assert got["compiles_in_window"]["value"] == 0 and got["finalize_eig_ms"]["value"] > 0
     for name in ("fold_device_ms", "fold_roofline", "collective_ms_per_fold",
                  "device_idle_share"):
         assert f"metric {name}: nothing to read, left out" in text
     assert "busy_s" not in result["device"] and "breakdown" not in result
+    assert list(result)[-1] == "compared"
 
 
-def test_a_fit_that_lost_a_fold_is_not_correct(root, monkeypatch):
-    """The models would still agree (the ring's batches are alike): the
-    state's own row count is what catches work that was not done."""
+def _a_fold_returns_its_state_unchanged(monkeypatch):
     from spark_rapids_ml_tpu.ops import gram
 
     real = gram.streaming_update
@@ -73,83 +84,182 @@ def test_a_fit_that_lost_a_fold_is_not_correct(root, monkeypatch):
         return skipping
 
     monkeypatch.setattr(gram, "streaming_update", lossy)
-    result, lines = perf_rehearse.run(root, "tiny_pca.fold_resident", seconds=0.5)
+
+
+def _half_of_a_batch_left_out(monkeypatch):
+    from spark_rapids_ml_tpu.ops import gram
+
+    real = gram.streaming_update
+
+    def halving(mesh):
+        update = real(mesh)
+        return lambda state, x, mask: update(state, x, mask.at[::2].set(0.0))
+
+    monkeypatch.setattr(gram, "streaming_update", halving)
+
+
+def _the_exchange_between_chips_left_out(monkeypatch):
+    from spark_rapids_ml_tpu.ops import gram
+
+    # the fold is traced once a mesh: trace it anew without its psum, and
+    # leave no such trace behind (monkeypatch undoes in reverse order)
+    monkeypatch.setattr(gram, "_streaming_update_cached",
+                        gram._streaming_update_cached.__wrapped__)
+    monkeypatch.setattr(gram.mr, "reduce_sum", lambda value, axis: value)
+
+
+def _the_model_altered_where_it_is_produced(monkeypatch):
+    from spark_rapids_ml_tpu.models import pca
+
+    real = pca.finalize_pca_stats
+
+    def altered(*args, **kwargs):
+        sol = real(*args, **kwargs)
+        return sol._replace(explained_variance=sol.explained_variance * (1 + 2.0**-8))
+
+    monkeypatch.setattr(pca, "finalize_pca_stats", altered)
+
+
+@pytest.mark.parametrize("fault,cell,caught_by,reads", [
+    # at the cells' own size the models would still agree (the ring's batches
+    # are alike): the state's own row count is what catches work not done —
+    # one fold of four, half of 4 x 256 rows, three of four chips' 4 x 512
+    (_a_fold_returns_its_state_unchanged, "tiny_pca.fold_resident", "rows_not_folded", 256),
+    (_half_of_a_batch_left_out, "tiny_pca.fold_resident", "rows_not_folded", 512),
+    (_the_exchange_between_chips_left_out, "tiny_pca.fold_resident_x4", "rows_not_folded",
+     1536),
+    (_the_model_altered_where_it_is_produced, "tiny_pca.fold_resident",
+     "explained_variance_rel", None),
+], ids=["a_fold_returns_its_state_unchanged", "half_of_a_batch_left_out",
+        "the_exchange_between_chips_left_out", "the_model_altered_where_it_is_produced"])
+def test_a_fit_that_lost_a_fold_is_not_correct(root, monkeypatch, fault, cell, caught_by,
+                                               reads):
+    """A whole run, off the chip, with the timed path broken underneath:
+    `correct` comes out false, and by the number that is to catch the fault."""
+    fault(monkeypatch)
+    result, lines = perf_rehearse.run(root, cell, seconds=0.5)
     assert result["correct"] is False
-    assert any("the state counts" in line for line in lines)
+    assert any("DISAGREES" in line for line in lines)
+    value, limit = result["compared"][caught_by]
+    assert value > limit
+    if caught_by == "rows_not_folded":
+        assert any("the state counts" in line for line in lines)
+        assert value == reads
 
 
-GENERATOR = '''
-"""A generator a later PR brought: counts loop turns for the window."""
-import time
-
-
-def run(ctx):
-    obs = ctx.obs
-    start = ctx.begin_window()
-    while time.monotonic() < obs.window[1]:
-        obs.attempted += ctx.params["turns"]
-        obs.passes.append({"rows": ctx.params["turns"], "start": start,
-                           "end": time.monotonic()})
-    ctx.end_window()
-    return obs
-'''
-READER = '''
-"""Turns of the loop in a second."""
-
-
-def read(obs):
-    return obs.attempted / obs.seconds
-'''
-LAYER_READER = '''
-"""How many passes the loop recorded."""
-
-
-def read(obs):
-    return len(obs.passes)
-'''
-
-
-def test_a_later_pr_adds_a_cell_a_mix_and_metrics_as_files_of_their_own(tmp_path):
-    root = perf_rehearse.tiny_root(tmp_path)
-    before = {}
+def _files(root):
+    out = {}
     for folder, _, files in os.walk(root):
         for name in files:
             path = os.path.join(folder, name)
-            if name != "BENCHMARK.json":
-                before[path] = open(path, "rb").read()
-    perf = os.path.join(root, "perf")
-    files = {
-        "generators/turns.py": GENERATOR,
-        "end_to_end/turns_per_s.py": READER,
-        "layer_metrics/turns_passes.py": LAYER_READER,
-        "traffic/turns.json": json.dumps({"generator": "turns", "params": {"turns": 3}}),
-        "cells/tiny_pca.turns.json": json.dumps({
-            "config": "tiny_pca", "traffic": "turns", "chips": 1, "why": "added"}),
-    }
-    for rel, content in files.items():
-        with open(os.path.join(perf, rel), "w", encoding="utf-8") as f:
-            f.write(content)
-    with open(os.path.join(root, "BENCHMARK.json"), encoding="utf-8") as f:
-        bench = json.load(f)
-    cell = "tiny_pca.turns"
-    bench["workloads"].append({"name": cell, "config": "tiny_pca", "traffic": "turns",
-                               "chips": 1, "why": "added"})
-    bench["end_to_end"].append({"name": "turns_per_s", "unit": "1/s", "better": "higher",
-                                "bound": 0.05, "source": "host_clock",
-                                "workloads": [cell]})
-    bench["per_layer"].append({"name": "turns_passes", "unit": "passes",
-                               "better": "higher", "source": "program_counter",
-                               "layer": "loop", "moves": "turns_per_s",
-                               "workloads": [cell]})
-    with open(os.path.join(root, "BENCHMARK.json"), "w", encoding="utf-8") as f:
-        json.dump(bench, f)
+            with open(path, "rb") as f:
+                out[path] = f.read()
+    return out
 
-    result, _ = perf_rehearse.run(root, cell, seconds=0.2)
-    assert set(result["metrics"]) == {"turns_per_s", "setup_s"}
-    assert result["metrics"]["turns_per_s"] == {
-        "value": result["attempted"] / 0.2, "unit": "1/s"}
-    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
-    result, _ = perf_rehearse.run(root, cell, seconds=0.2, trace=True)
-    assert set(result["metrics"]) == {"turns_passes", "compiles_in_window"}
-    # ... and no file that was there was edited
-    assert all(open(path, "rb").read() == content for path, content in before.items())
+
+@pytest.fixture(scope="module")
+def next_pr(tmp_path_factory):
+    """(the copy with the next PR laid over it, its cell, the copy's files
+    before, BENCHMARK.json before)."""
+    root = perf_rehearse.plain_root(tmp_path_factory.mktemp("next_pr"))
+    before = _files(root)
+    bench = layout.load_benchmark(root)
+    return root, perf_rehearse.add_next_pr(root), before, bench
+
+
+def test_a_later_pr_adds_a_cell_a_mix_and_metrics_as_files_of_their_own(next_pr):
+    root, cell, before, bench_before = next_pr
+    added = layout.load_benchmark(root)
+    assert layout.load_config(root, added, "colsum_d64")["algo"] == "colsum"
+
+    # (a) the cell runs, end to end and traced
+    result, lines = perf_rehearse.run(root, cell, seconds=0.5)
+    assert result["correct"] is True, "\n".join(lines)
+    assert set(result) == RESULT_KEYS and result["failed"] == 0
+    assert set(result["metrics"]) == {"fold_rows_per_s", "colsum_passes_per_s", "setup_s"}
+    assert result["metrics"]["colsum_passes_per_s"]["unit"] == "passes/s"
+    assert all(m["value"] > 0 for m in result["metrics"].values())
+    result, lines = perf_rehearse.run(root, cell, seconds=0.5, trace=True)
+    text = "\n".join(lines)
+    assert result["correct"] is True, text
+    got = {name: m["value"] for name, m in result["metrics"].items()}
+    assert set(got) == perf_rehearse.reports(root, cell, "per_layer") == {
+        "colsum_scale_ms", "colsum_folds_per_pass", "compiles_in_window"}
+    assert got["colsum_folds_per_pass"] == 8 and got["colsum_scale_ms"] > 0
+    assert got["compiles_in_window"] == 0
+    for name in ("fold_device_ms", "fold_roofline", "colsum_scale_device_ms",
+                 "colsum_scale_roofline"):
+        assert f"metric {name}: nothing to read, left out" in text
+
+    # (b) every check of the contract passes on the tree after the addition
+    contract.check(root)
+
+    # (c) no file that was there was edited; BENCHMARK.json's entries are
+    # what they were, and its lists only grew at their ends
+    after = _files(root)
+    bench_path = os.path.join(root, "BENCHMARK.json")
+    assert all(after[path] == content for path, content in before.items()
+               if path != bench_path)
+    assert len(after) == len(before) + 11
+    assert perf_rehearse.only_grew(bench_before, added) == []
+    assert [len(added[key]) - len(bench_before[key]) for key in (
+        "configs", "workloads", "end_to_end", "per_layer")] == [1, 1, 1, 4]
+
+
+@pytest.mark.parametrize("check", contract.CHECKS, ids=lambda c: c.__name__)
+def test_the_contract_holds_on_the_tree_after_the_addition(next_pr, check):
+    """Each check by itself, so that the one that forbids an addition is
+    named. Every one of them also runs on the repo (test_perf_layout.py,
+    test_perf_finalize_split.py)."""
+    check(next_pr[0])
+
+
+def test_the_device_readers_of_a_second_algorithm_find_its_cost_in_their_own_tree(next_pr):
+    """A TPU trace is not to be had here: a reduced trace made by hand, read
+    by the copy's readers, with the costs from the copy's `perf/costs/`."""
+    root, cell, _, _ = next_pr
+    _, _, config, _, params = layout.resolve(root, cell)
+    rows, d = params["batch_rows"], config["n_cols"]
+    assert cost.fold_cost(config, rows, root) == (rows * d, 4.0 * rows * d + 8.0 * d)
+    with pytest.raises(KeyError, match="colsum"):
+        cost.fold_cost(config, rows)  # the repo has no such file: found by root only
+    obs = observe.Observation(config, params, 1.0, {"kind": "TPU v5 lite"}, root)
+    obs.fold_rows_per_chip = rows
+    obs.trace = {"devices": {0: {"programs": {
+        "jit_colsum_fold": {"count": 8, "seconds": 8 * 1e-6},
+        "jit_colsum_scale": {"count": 2, "seconds": 2 * 1e-6}}}}}
+    read = {name: layout.load_module(root, "layer_metrics", name).read(obs)
+            for name in ("fold_device_ms", "fold_roofline", "colsum_scale_device_ms",
+                         "colsum_scale_roofline")}
+    assert read["fold_device_ms"] == pytest.approx(1e-3)
+    assert read["colsum_scale_device_ms"] == pytest.approx(1e-3)
+    # memory-bound, both: bytes ÷ 819 GB/s over a microsecond
+    assert obs.notes["fold_roofline_bound"] == "memory"
+    assert read["fold_roofline"] == pytest.approx(100 * (4 * rows * d + 8 * d) / 819e9 / 1e-6)
+    assert read["colsum_scale_roofline"] == pytest.approx(100 * 8 * d / 819e9 / 1e-6)
+
+
+def test_an_addition_may_not_replace_a_file_that_is_there(next_pr):
+    with pytest.raises(FileExistsError, match="may not replace"):
+        perf_rehearse.add_next_pr(next_pr[0])
+
+
+@pytest.mark.parametrize("edit,problem", [
+    (lambda b: b["per_layer"][3].update(layer="other"), "per_layer[3].layer"),
+    (lambda b: b["per_layer"].pop(0), "per_layer[0]"),  # every later entry moved up
+    (lambda b: b["workloads"].pop(), "workloads: 1 entries went"),
+    (lambda b: b["end_to_end"][0]["workloads"].insert(0, "a.cell"),
+     "end_to_end[0].workloads[0]"),  # not at the end
+    (lambda b: b["end_to_end"][1].update(bound=0.1), "end_to_end[1].bound"),
+    (lambda b: b["per_layer"][6].update(workloads=[]), "per_layer[6]: keys ['workloads']"),
+], ids=["a_field_edited", "an_entry_taken_out", "a_cell_taken_out", "a_cell_put_in_front",
+        "a_bound_loosened", "a_key_added"])
+def test_only_grew_names_what_an_addition_may_not_do(edit, problem):
+    before = layout.load_benchmark(layout.REPO_ROOT)
+    after = layout.load_benchmark(layout.REPO_ROOT)
+    assert perf_rehearse.only_grew(before, after) == []
+    after["per_layer"].append({"name": "more"})
+    after["end_to_end"][0]["workloads"].append("a_later.cell")
+    assert perf_rehearse.only_grew(before, after) == []
+    edit(after)
+    assert any(problem in p for p in perf_rehearse.only_grew(before, after))
