@@ -195,6 +195,17 @@ _DEFAULTS: Dict[str, Any] = {
     # work it will thrash on; pressure-relieving ops always pass.
     "daemon_max_connections": _env("DAEMON_MAX_CONNECTIONS", 0, int),
     "daemon_max_staged_bytes": _env("DAEMON_MAX_STAGED_BYTES", 0, int),
+    # Pass cache (serve/daemon.py `_Job`; docs/protocol.md "rescan"): bytes
+    # per device, in MiB, that ONE iterative job may keep of the batches
+    # its fold placed on the device, so that the passes after the first
+    # are scanned from HBM (`rescan`) and no row crosses the wire twice.
+    # 0 (default) = off: no op, ack, metric or allocation differs. All or
+    # nothing: the first batch that would pass the budget drops the job's
+    # whole cache for the fit, which then re-feeds every pass as before.
+    # The daemon reads it for the budget; the Spark estimator reads it
+    # ($SRML_DAEMON_PASS_CACHE_MB / spark.srml.daemon.pass_cache_mb /
+    # this key, spark/daemon_session.py) to ask for cached passes.
+    "daemon_pass_cache_mb": _env_named("SRML_DAEMON_PASS_CACHE_MB", 0, int),
     # The retry hint (seconds) a shed client is told to wait; clients
     # jitter around it so a shed fleet doesn't return as one wave.
     "daemon_retry_after_s": _env("DAEMON_RETRY_AFTER_S", 1.0, float),

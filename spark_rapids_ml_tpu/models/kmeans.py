@@ -341,15 +341,10 @@ def fit_kmeans(
 
 
 @functools.lru_cache(maxsize=32)
-def _stream_step_fn(mesh: Mesh, k: int, cd: str, ad: str):
-    """Jitted donated accumulate of one batch's Lloyd statistics at fixed
-    centers: (state, centers, x, mask) -> state with
-    state = (sums (k, d), counts (k,), cost ()).
-
-    Uses the XLA assign path (not the fused Pallas step): streaming batches
-    are modest, and materializing (batch, k) distances buys the running
-    cost for free — convergence monitoring the fused kernel can't provide.
-    """
+def _stream_shard_fn(mesh: Mesh, k: int, cd: str, ad: str):
+    """One batch's Lloyd statistics at fixed centers, sharded over the
+    data axis and added to (sums, counts, cost): the fold's arithmetic,
+    shared by the one-batch program and the grouped one below."""
     compute_dtype = jnp.dtype(cd)
     accum_dtype = jnp.dtype(ad)
 
@@ -380,18 +375,54 @@ def _stream_step_fn(mesh: Mesh, k: int, cd: str, ad: str):
             cost + mr.reduce_sum(bcost, DATA_AXIS),
         )
 
-    f = jax.shard_map(
+    return jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(DATA_AXIS, None), P(DATA_AXIS)),
         out_specs=(P(), P(), P()),
     )
 
+
+@functools.lru_cache(maxsize=32)
+def _stream_step_fn(mesh: Mesh, k: int, cd: str, ad: str):
+    """Jitted donated accumulate of one batch's Lloyd statistics at fixed
+    centers: (state, centers, x, mask) -> state with
+    state = (sums (k, d), counts (k,), cost ()).
+
+    Uses the XLA assign path (not the fused Pallas step): streaming batches
+    are modest, and materializing (batch, k) distances buys the running
+    cost for free — convergence monitoring the fused kernel can't provide.
+    """
+    f = _stream_shard_fn(mesh, k, cd, ad)
+
     @functools.partial(ledgered_jit, "kmeans.streaming_update", donate_argnums=(0,))
     def update(state, centers, x, mask):
         return f(state[0], state[1], state[2], centers, x, mask)
 
     return update
+
+
+@functools.lru_cache(maxsize=32)
+def _stream_group_fn(mesh: Mesh, k: int, cd: str, ad: str):
+    """The same accumulate over a GROUP of device-resident batches in one
+    program: (state, centers, xs, masks) -> state, `xs` and `masks` tuples
+    of equal length. Batch by batch, in order, through the one shard
+    function `_stream_step_fn` runs — the arithmetic of len(xs) calls of
+    it, for one dispatch. For a caller that holds its batches already
+    (the daemon's cached pass): at 65,536 x 256 rows a fold takes the
+    device 0.22 ms and the host 0.3 ms to dispatch (PERF.md §5), so one
+    program a batch leaves the device waiting for the host. One compiled
+    program per (group length, batch shape)."""
+    f = _stream_shard_fn(mesh, k, cd, ad)
+
+    @functools.partial(ledgered_jit, "kmeans.streaming_update_group",
+                       donate_argnums=(0,))
+    def update_group(state, centers, xs, masks):
+        for x, mask in zip(xs, masks):
+            state = f(state[0], state[1], state[2], centers, x, mask)
+        return state
+
+    return update_group
 
 
 def stream_zero_state(k: int, n_cols: int, accum_dtype) -> tuple:

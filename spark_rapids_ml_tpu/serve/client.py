@@ -47,7 +47,7 @@ logger = get_logger("serve.client")
 #: harmless (every row lands on the new incarnation), and counting it
 #: would fail a fully consistent pass.
 _STATE_ACK_OPS = frozenset((
-    "feed", "feed_raw", "seed", "commit", "step", "set_iterate",
+    "feed", "feed_raw", "seed", "commit", "rescan", "step", "set_iterate",
     "merge_state", "finalize",
 ))
 
@@ -258,6 +258,8 @@ class DataPlaneClient:
                     f"daemon busy: {resp.get('error')}",
                     float(resp.get("retry_after_s", 1.0)),
                 )
+            if resp.get("no_cached_pass"):
+                raise protocol.NoCachedPass(f"daemon: {resp.get('error')}")
             raise RuntimeError(f"daemon error: {resp.get('error')}")
         boot = resp.get("boot_id")
         if boot is not None and req.get("op") in _STATE_ACK_OPS:
@@ -571,10 +573,13 @@ class DataPlaneClient:
 
     def commit(
         self, job: str, partition: int, attempt: int = 0,
-        pass_id: Optional[int] = None,
-    ) -> int:
+        pass_id: Optional[int] = None, with_meta: bool = False,
+    ):
         """Commit a partition's staged feeds into the job state
-        (idempotent; see :meth:`feed`). Returns total committed rows."""
+        (idempotent; see :meth:`feed`). Returns total committed rows —
+        or, with ``with_meta=True``, (rows, meta) where ``meta`` carries
+        the ack's additive fields (``cached``, ``cached_rows``: present
+        when the daemon keeps a pass cache, docs/protocol.md "rescan")."""
         resp, _ = self._roundtrip(
             {
                 "op": "commit",
@@ -584,7 +589,26 @@ class DataPlaneClient:
                 "pass_id": pass_id,
             }
         )
+        if with_meta:
+            return int(resp["rows"]), {
+                k: v for k, v in resp.items() if k not in ("ok", "rows")
+            }
         return int(resp["rows"])
+
+    def rescan(self, job: str, pass_id: Optional[int] = None) -> Dict[str, Any]:
+        """One pass from the job's cached pass (additive op;
+        docs/protocol.md "rescan"): the daemon folds every batch it kept
+        of the pass that filled its cache against the current iterate,
+        and acks ``{pass_rows, cached_rows, cached_batches}`` without
+        waiting for the device. Raises :class:`protocol.NoCachedPass`
+        when the job cannot answer for exactly the rows it committed —
+        re-feed that pass. Carries a ``rescan_id`` so a replay whose
+        first ack was lost gets that ack again instead of an error."""
+        resp, _ = self._roundtrip(
+            {"op": "rescan", "job": job, "pass_id": pass_id,
+             "rescan_id": self._op_id()}
+        )
+        return {k: v for k, v in resp.items() if k != "ok"}
 
     def seed_kmeans(
         self,

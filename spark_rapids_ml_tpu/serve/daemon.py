@@ -103,6 +103,7 @@ from spark_rapids_ml_tpu.utils import metrics as metrics_mod
 from spark_rapids_ml_tpu.utils import slo as slo_mod
 from spark_rapids_ml_tpu.utils import xprof as xprof_mod
 from spark_rapids_ml_tpu.utils.logging import get_logger
+from spark_rapids_ml_tpu.utils.profiling import trace_span
 
 logger = get_logger("serve.daemon")
 
@@ -141,6 +142,24 @@ _M_STAGED = metrics_mod.gauge(
 )
 _M_JOBS = metrics_mod.gauge(
     "srml_daemon_active_jobs", "Registered accumulation jobs (at scrape)"
+)
+#: The pass cache (docs/protocol.md "rescan"); counted only for jobs that
+#: were given a budget, so a daemon with the cache off exports none of them.
+_M_PASS_ROWS = metrics_mod.counter(
+    "srml_daemon_pass_rows_total",
+    "Rows folded into a pass's statistics, by where they came from "
+    "(source = wire: fed and committed | cache: scanned again from the "
+    "job's cached pass)",
+)
+_M_PASSES = metrics_mod.counter(
+    "srml_daemon_passes_total",
+    "Passes that folded rows, by source (wire = the pass's first fed "
+    "rows entered the statistics | cache = one rescan)",
+)
+_M_PASS_CACHE = metrics_mod.gauge(
+    "srml_daemon_pass_cache_bytes",
+    "Bytes per device held by jobs' pass caches, cached passes and "
+    "uncommitted stages' batches together (at scrape)",
 )
 _M_MODELS = metrics_mod.gauge(
     "srml_daemon_served_models", "Registered served models (at scrape)"
@@ -217,7 +236,8 @@ _DEVICE_LOCK = threading.Lock()
 #: registry series; unknown op strings all land under op="unknown".
 _KNOWN_OPS = frozenset((
     "ping", "health", "metrics", "status", "feed", "feed_raw", "seed",
-    "commit", "step", "finalize", "drop", "export_state", "merge_state",
+    "commit", "rescan", "step", "finalize", "drop", "export_state",
+    "merge_state",
     "get_iterate", "set_iterate", "ensure_model", "transform",
     "kneighbors", "model_status", "drop_model", "warmup", "sample_rows",
     "mesh_info", "reduce_mesh", "gossip_push", "gossip_pull",
@@ -385,13 +405,65 @@ class _Stage:
     (exactly-once REPLAY: a self-healing client that lost an ack resends
     the same feed_id, which must not double-count)."""
 
-    __slots__ = ("state", "rows", "nbytes", "seen")
+    __slots__ = ("state", "rows", "nbytes", "seen", "batches", "batch_bytes")
 
     def __init__(self, state, rows: int = 0, nbytes: int = 0):
         self.state = state
         self.rows = rows
         self.nbytes = nbytes
         self.seen: set = set()
+        # Pass cache (off: stays empty): the (xs, ms) this stage's folds
+        # placed, kept until its commit moves them into the job's cached
+        # pass — a losing attempt's are freed with its stage — and their
+        # bytes per device (counted apart from `nbytes`: not back-pressure).
+        self.batches: list = []
+        self.batch_bytes = 0
+
+
+#: Cached batches a `rescan` dispatch folds. One program a batch leaves
+#: the device waiting: at the documented 65,536-row batch a kmeans fold
+#: takes the device 0.22 ms and the host 0.3 ms to dispatch (PERF.md §5).
+#: Eight in a program — the fold's arithmetic batch by batch, in order —
+#: put the host at an eighth of that. One constant, no option.
+_RESCAN_GROUP = 8
+
+
+def _rescan_groups(batches):
+    """Runs of up to `_RESCAN_GROUP` consecutive cached batches of one
+    shape (one compiled program per group length and shape), in order."""
+    group: list = []
+    for batch in batches:
+        if group and (
+            len(group) == _RESCAN_GROUP or batch[0].shape != group[0][0].shape
+        ):
+            yield group
+            group = []
+        group.append(batch)
+    if group:
+        yield group
+
+
+class _PassCache:
+    """One pass's batches as the fold placed them on the device — the
+    fold's operands ``(xs, ms)``: float32 rows padded to their bucket
+    under the job's row sharding, and the row mask — in commit order.
+    ``filled_at`` is the pass that fed them; ``pass_rows`` is what that
+    pass committed from the wire, set when the pass closed (None while it
+    is open): a cached pass answers `rescan` only if it holds exactly
+    those rows. ``committed`` is that pass's partition → rows map, which
+    a rescan restores so that `export_state` / `reduce_mesh` reconcile a
+    cached pass against the same task acks as the fed one."""
+
+    __slots__ = ("batches", "rows", "nbytes", "filled_at", "pass_rows",
+                 "committed")
+
+    def __init__(self, filled_at: int):
+        self.batches: list = []
+        self.rows = 0
+        self.nbytes = 0
+        self.filled_at = filled_at
+        self.pass_rows: Optional[int] = None
+        self.committed: Dict[int, int] = {}
 
 
 def _state_nbytes(state) -> int:
@@ -494,6 +566,27 @@ class _Job:
         # immediately too — a replayed merge must not double-apply).
         self._seen_feed_ids = _FifoSet()
         self._seen_merge_ids = _FifoSet()
+        # Pass cache (docs/protocol.md "rescan"; docs/mesh.md "Capacity"):
+        # the budget in bytes per device, 0 = off. Written over the fold's
+        # device operands (xs, ms), so any iterative algo can take it;
+        # only kmeans is given one. `_cache_ok` falls for the rest of the
+        # fit with the first batch that would pass the budget (all or
+        # nothing); `_cache_bytes` counts the cached pass and the
+        # uncommitted stages' batches together, apart from staged_bytes.
+        self._cache_budget = (
+            max(int(config.get("daemon_pass_cache_mb")), 0) << 20
+            if algo == "kmeans" else 0
+        )
+        self._cache: Optional[_PassCache] = None
+        self._cache_ok = self._cache_budget > 0
+        self._cache_bytes = 0
+        # Rows this pass took from the wire (direct folds + commits): what
+        # a cached pass must hold to stand for it.
+        self._pass_wire_rows = 0
+        # Rescan idempotency, as step's: a replayed rescan (ack lost)
+        # gets the ack of the one already applied.
+        self._last_rescan_id: Optional[str] = None
+        self._last_rescan_ack: Optional[Dict[str, Any]] = None
         # Capacity gate (docs/mesh.md): daemon job state is REPLICATED
         # on every device, so a (d, d)-block accumulator (pca Gram,
         # linreg XᵀX, logreg Hessian) over the per-device budget must
@@ -527,7 +620,10 @@ class _Job:
             self.state = init_normal_eq_stats(n_cols)
             self.update = streaming_normal_eq_update(mesh)
         elif algo == "kmeans":
-            from spark_rapids_ml_tpu.models.kmeans import _stream_step_fn
+            from spark_rapids_ml_tpu.models.kmeans import (
+                _stream_group_fn,
+                _stream_step_fn,
+            )
 
             self.k = int(params.get("k", 0))
             if self.k <= 0:
@@ -538,6 +634,10 @@ class _Job:
                 raise ValueError(f"unknown init {self.init!r} (k-means++|random)")
             self.centers = None  # initialized from the first batch's rows
             self.update = _stream_step_fn(
+                mesh, self.k, config.get("compute_dtype"), config.get("accum_dtype")
+            )
+            # `rescan` folds its cached batches a group a dispatch
+            self.update_group = _stream_group_fn(
                 mesh, self.k, config.get("compute_dtype"), config.get("accum_dtype")
             )
             self.state = self._kmeans_zero_state()
@@ -860,11 +960,193 @@ class _Job:
         self._seen_feed_ids.add(feed_id)
 
     def _drop_stage(self, key: tuple) -> Optional[_Stage]:
-        """Remove one stage, keeping the staged-bytes account balanced."""
+        """Remove one stage, keeping the staged-bytes account balanced
+        (and the pass cache's: the stage's batches go with it)."""
         stage = self.staged.pop(key, None)
         if stage is not None:
             self.staged_bytes -= stage.nbytes
+            self._cache_bytes -= stage.batch_bytes
         return stage
+
+    def _clear_stages(self) -> None:
+        """A pass boundary: every stage goes, with its batches."""
+        self.staged.clear()
+        self.staged_bytes = 0
+        self._cache_bytes = 0 if self._cache is None else self._cache.nbytes
+
+    # -- pass cache (docs/protocol.md "rescan") ----------------------------
+    # All under the job lock. The cache changes the transport of a pass,
+    # never its result: it holds the fold's own operands and `rescan`
+    # folds them with the fold's own program.
+
+    @property
+    def pass_cache_bytes(self) -> int:
+        """Bytes per device the cache holds (`health`, the gauge)."""
+        return self._cache_bytes
+
+    def cache_ack(self) -> Dict[str, Any]:
+        """The additive fields of a feed / commit ack: none for a job
+        without a budget; else whether the cache still holds every row
+        the pass committed, and how many it holds. A driver that reads
+        ``cached: true`` on every commit ack of a pass may `rescan`."""
+        if not self._cache_budget:
+            return {}
+        cache = self._cache if self._cache_ok else None
+        return {
+            "cached": cache is not None,
+            "cached_rows": 0 if cache is None else cache.rows,
+        }
+
+    def _wire_rows(self, n: int) -> None:
+        """`n` rows from the wire entered this pass's statistics."""
+        if self._pass_wire_rows == 0:
+            _M_PASSES.inc(source="wire")
+        self._pass_wire_rows += n
+        _M_PASS_ROWS.inc(n, source="wire")
+
+    def _free_cached_pass(self) -> None:
+        if self._cache is not None:
+            self._cache_bytes -= self._cache.nbytes
+            self._cache = None
+
+    def _open_cache(self) -> _PassCache:
+        """The cache this pass fills. A pass that is fed again replaces
+        the cached one (the driver re-feeds only when it will not rescan)."""
+        if self._cache is not None and self._cache.filled_at != self.iteration:
+            self._free_cached_pass()
+        if self._cache is None:
+            self._cache = _PassCache(self.iteration)
+        return self._cache
+
+    def _drop_cache(self) -> None:
+        """Free everything the cache holds and keep nothing more this
+        fit: a part of a pass is of no use to `rescan`."""
+        self._cache = None
+        self._cache_ok = False
+        self._cache_bytes = 0
+        for stage in self.staged.values():
+            stage.batches, stage.batch_bytes = [], 0
+
+    def _keep_batch(self, stage: Optional[_Stage], xs, ms, n: int) -> None:
+        """Keep the operands a fold has just placed: in the job's cached
+        pass (direct feed) or in the stage until its commit."""
+        if not self._cache_ok:
+            return
+        cache = self._open_cache()
+        nbytes = (int(xs.nbytes) + int(ms.nbytes)) // self.n_data
+        if self._cache_bytes + nbytes > self._cache_budget:
+            self._drop_cache()  # this stage's too: fold has published it
+            logger.warning(
+                "pass cache over its budget (%d MiB a device) after %d "
+                "rows: dropped for this fit, every pass is re-fed",
+                self._cache_budget >> 20, cache.rows,
+            )
+            return
+        self._cache_bytes += nbytes
+        if stage is None:
+            cache.batches.append((xs, ms))
+            cache.rows += n
+            cache.nbytes += nbytes
+        else:
+            stage.batches.append((xs, ms))
+            stage.batch_bytes += nbytes
+
+    def _commit_batches(self, partition: int, stage: _Stage) -> None:
+        """The winning stage's batches join the cached pass, in commit
+        order (the stage has left `self.staged` and the byte account)."""
+        if not self._cache_ok:
+            return
+        cache = self._open_cache()
+        cache.batches.extend(stage.batches)
+        cache.rows += stage.rows
+        cache.nbytes += stage.batch_bytes
+        cache.committed[partition] = stage.rows
+        self._cache_bytes += stage.batch_bytes
+
+    def _close_pass(self, opens: Optional[int] = None) -> None:
+        """A pass boundary (step, set_iterate): the pass that filled the
+        cache is over, and the cache stands for what it committed. A
+        boundary that opens the filling pass again, or an earlier one
+        (`opens`, a recovery rewind), finds a part of a pass: freed."""
+        cache = self._cache
+        if cache is not None and opens is not None and cache.filled_at >= opens:
+            self._free_cached_pass()
+        elif cache is not None and cache.pass_rows is None:
+            cache.pass_rows = self._pass_wire_rows
+        self._pass_wire_rows = 0
+        self._last_rescan_id = self._last_rescan_ack = None
+
+    def release(self) -> None:
+        """The job is over (drop, finalize with drop, TTL eviction): no op
+        folds into it again, and its cached pass is freed."""
+        self.dropped = True
+        self._free_cached_pass()
+
+    def rescan(
+        self, pass_id: Optional[int] = None, rescan_id: Optional[str] = None
+    ) -> Dict[str, Any]:
+        """One pass from the cache: fold every cached batch, in commit
+        order, against the current iterate, with the fold's own program.
+        Dispatches only — the wait for the device belongs to `step`."""
+        with self.lock:
+            if self.dropped:
+                raise KeyError("job was finalized/dropped")
+            self._check_pass(pass_id)
+            self.touched = self._clock()
+            if (
+                rescan_id is not None
+                and self._last_rescan_ack is not None
+                and str(rescan_id) == self._last_rescan_id
+            ):
+                _M_REPLAY_HITS.inc(kind="rescan")
+                return dict(self._last_rescan_ack)
+            cache = self._cache
+            if not self._cache_ok or cache is None:
+                raise protocol.NoCachedPass(
+                    "no cached pass: this job keeps none (cache off, over "
+                    "its budget, or the job was restored from a snapshot, "
+                    "which does not hold it); re-feed the pass"
+                )
+            if cache.pass_rows is None or cache.filled_at >= self.iteration:
+                raise protocol.NoCachedPass(
+                    f"no cached pass: pass {cache.filled_at}, which fills "
+                    "it, is still open; re-feed the pass"
+                )
+            if cache.rows != cache.pass_rows:
+                raise protocol.NoCachedPass(
+                    f"no cached pass: it holds {cache.rows} rows, the pass "
+                    f"that filled it committed {cache.pass_rows}; re-feed "
+                    "the pass"
+                )
+            if self.pass_rows or self.staged or self.committed:
+                raise ValueError(
+                    f"rescan into pass {self.iteration}, which already "
+                    f"holds {self.pass_rows} rows"
+                )
+            state = self.state
+            with trace_span("pass.rescan"):
+                with _DEVICE_LOCK:
+                    for group in _rescan_groups(cache.batches):
+                        state = self.update_group(
+                            state, self.centers,
+                            tuple(xs for xs, _ in group),
+                            tuple(ms for _, ms in group),
+                        )
+            self.state = state
+            self.committed = dict(cache.committed)
+            self.rows += cache.rows
+            self.pass_rows = cache.rows
+            _M_PASSES.inc(source="cache")
+            _M_PASS_ROWS.inc(cache.rows, source="cache")
+            ack = {
+                "pass_rows": self.pass_rows,
+                "cached_rows": cache.rows,
+                "cached_batches": len(cache.batches),
+            }
+            self._last_rescan_id = None if rescan_id is None else str(rescan_id)
+            self._last_rescan_ack = dict(ack)
+            self.touched = self._clock()  # exit stamp (see fold)
+            return ack
 
     def fold(
         self,
@@ -1032,6 +1314,10 @@ class _Job:
                     # creation comment above).
                     self.staged[(partition, attempt)] = stage
                     self.staged_bytes += stage.nbytes
+            if self._cache_budget:
+                if partition is None:
+                    self._wire_rows(n)
+                self._keep_batch(stage, xs, ms, n)
             # Only now — after the device fold succeeded — is the feed_id
             # burned; an id recorded before a failing update would turn
             # the client's replay into a silent ack-without-fold.
@@ -1075,6 +1361,9 @@ class _Job:
             self.committed[partition] = n
             self.rows += n
             self.pass_rows += n
+            if self._cache_budget:
+                self._wire_rows(n)
+                self._commit_batches(partition, staged)
             # losing attempts' stages for this partition free their buffers
             for key in [k for k in self.staged if k[0] == partition]:
                 self._drop_stage(key)
@@ -1328,8 +1617,10 @@ class _Job:
             self._install_iterate(arrays)
             with _DEVICE_LOCK:
                 self.state = self._zero_state()
-            self.staged.clear()
-            self.staged_bytes = 0
+            # The cached pass outlives the boundary (a rewind of the pass
+            # that was filling it does not); stages go.
+            self._close_pass(opens=int(iteration))
+            self._clear_stages()
             self.committed.clear()
             self.iteration = int(iteration)
             self.pass_rows = 0
@@ -1362,8 +1653,7 @@ class _Job:
             # A new pass re-feeds every partition against the new iterate:
             # clear this pass's staging + committed set (zombie traffic from
             # the finished pass is fenced by pass_id, not by these maps).
-            self.staged.clear()
-            self.staged_bytes = 0
+            self._clear_stages()
             self.committed.clear()
             if self.pass_rows == 0:
                 # A retried/premature step over an empty pass would corrupt
@@ -1401,22 +1691,27 @@ class _Job:
             if self.algo == "kmeans":
                 from spark_rapids_ml_tpu.models.kmeans import apply_lloyd_update
 
-                sums, counts, cost = self.state
-                with _DEVICE_LOCK:
-                    self.centers, moved2 = apply_lloyd_update(
-                        sums, counts, self.centers
-                    )
-                    self.state = self._kmeans_zero_state()
-                self.iteration += 1
-                info = {
-                    "iteration": self.iteration,
-                    "moved2": float(moved2),
-                    "cost": float(cost),
-                    "pass_rows": self.pass_rows,
-                }
-                self.pass_rows = 0
-                self.touched = self._clock()  # exit stamp (see fold)
-                return self._cache_step(step_id, info)
+                # `lloyd.boundary`: what the device waits for between two
+                # passes — the wait for this pass's folds, the update, the
+                # two scalars to the host, the snapshot callback.
+                with trace_span("lloyd.boundary"):
+                    sums, counts, cost = self.state
+                    with _DEVICE_LOCK:
+                        self.centers, moved2 = apply_lloyd_update(
+                            sums, counts, self.centers
+                        )
+                        self.state = self._kmeans_zero_state()
+                    self._close_pass()
+                    self.iteration += 1
+                    info = {
+                        "iteration": self.iteration,
+                        "moved2": float(moved2),
+                        "cost": float(cost),
+                        "pass_rows": self.pass_rows,
+                    }
+                    self.pass_rows = 0
+                    self.touched = self._clock()  # exit stamp (see fold)
+                    return self._cache_step(step_id, info)
             reg = float(params.get("reg", 0.0))
             fit_intercept = bool(params.get("fit_intercept", True))
             if getattr(self, "n_classes", 2) > 2:
@@ -1644,6 +1939,8 @@ class _Job:
         with self.lock:
             with _DEVICE_LOCK:
                 result = self._finalize_locked(params)
+            # The model is out: the fit scans no further pass.
+            self._free_cached_pass()
             if drop:
                 # set under the same lock acquisition so a straggler feed
                 # blocked on it sees the flag and errors instead of folding
@@ -2405,7 +2702,7 @@ class DataPlaneDaemon:
                             # evicted job must not be resurrectable, so
                             # the file dies before the registry entry.
                             self._discard_job_state(name)
-                            job.dropped = True
+                            job.release()
                             del self._jobs[name]
                             evicted.append((name, job))
                     finally:
@@ -3064,7 +3361,13 @@ class DataPlaneDaemon:
                 int(_opt(req, "attempt", 0)),
                 req.get("pass_id"),
             )
-            protocol.send_json(conn, {"ok": True, "rows": rows, **self._identity()})
+            protocol.send_json(
+                conn,
+                {"ok": True, "rows": rows, **self._identity(),
+                 **job.cache_ack()},
+            )
+        elif op == "rescan":
+            self._op_rescan(conn, req)
         elif op == "finalize":
             self._op_finalize(conn, req)
         elif op == "step":
@@ -3172,6 +3475,19 @@ class DataPlaneDaemon:
         with self._jobs_lock:
             return sum(j.staged_bytes for j in self._jobs.values())
 
+    def _pass_cache_bytes_total(self) -> Optional[int]:
+        """Bytes per device in jobs' pass caches; None when the key is off
+        and no job was given a budget (the field and the gauge then stay
+        away)."""
+        from spark_rapids_ml_tpu import config
+
+        with self._jobs_lock:
+            held = [j.pass_cache_bytes for j in self._jobs.values()
+                    if j._cache_budget]
+        if not held and not int(config.get("daemon_pass_cache_mb")):
+            return None
+        return sum(held)
+
     def _overloaded(self, staged: Optional[int] = None) -> Optional[str]:
         """The watermark breach (None = healthy). Reads counters without
         job locks — a watermark is a load signal, not an invariant.
@@ -3239,6 +3555,11 @@ class DataPlaneDaemon:
         if reason is not None:
             resp["retry_after_s"] = self._retry_after_s
             resp["busy_reason"] = reason
+        cache_bytes = self._pass_cache_bytes_total()
+        if cache_bytes is not None:
+            # Additive, and apart from staged_bytes: cached passes are
+            # not back-pressure, they are what the budget was given for.
+            resp["pass_cache_bytes"] = cache_bytes
         protocol.send_json(conn, resp)
 
     def _op_metrics(self, conn, req: Dict[str, Any]) -> None:
@@ -3273,6 +3594,9 @@ class DataPlaneDaemon:
         models, connections, scheduler queue depths), so every exported
         snapshot is self-consistent with what `health` would report."""
         _M_STAGED.set(self._staged_bytes_total())
+        cache_bytes = self._pass_cache_bytes_total()
+        if cache_bytes is not None:
+            _M_PASS_CACHE.set(cache_bytes)
         with self._jobs_lock:
             _M_JOBS.set(len(self._jobs))
         with self._models_lock:
@@ -3349,7 +3673,7 @@ class DataPlaneDaemon:
             job = self._jobs.pop(name, None)
         if job is not None:
             with job.lock:
-                job.dropped = True
+                job.release()
         return job is not None
 
     def _op_feed(self, conn, req: Dict[str, Any]) -> None:
@@ -3542,7 +3866,33 @@ class DataPlaneDaemon:
                 )
                 job = None
         protocol.send_json(
-            conn, {"ok": True, "rows": job.rows, **self._identity()}
+            conn,
+            {"ok": True, "rows": job.rows, **self._identity(),
+             **job.cache_ack()},
+        )
+
+    def _op_rescan(self, conn, req: Dict[str, Any]) -> None:
+        """One pass from the job's cached pass (docs/protocol.md
+        "rescan"): every cached batch folded again against the current
+        iterate; the ack does not wait for the device. A job that cannot
+        answer for exactly the rows it committed says so with
+        ``no_cached_pass`` beside the error — not a transport fault, not
+        a failure of the fit: the driver re-feeds that pass."""
+        job = self._get_job(req)
+        try:
+            ack = job.rescan(req.get("pass_id"), rescan_id=req.get("rescan_id"))
+        except protocol.NoCachedPass as e:
+            protocol.send_json(
+                conn,
+                {"ok": False, "no_cached_pass": True, "error": str(e),
+                 **self._identity()},
+            )
+            return
+        protocol.send_json(
+            conn,
+            {"ok": True, **self._identity(), "pass_rows": ack["pass_rows"],
+             "cached_rows": ack["cached_rows"],
+             "cached_batches": ack["cached_batches"]},
         )
 
     def _op_seed(self, conn, req: Dict[str, Any]) -> None:

@@ -63,6 +63,17 @@ class ProtocolError(RuntimeError):
     pass
 
 
+class NoCachedPass(RuntimeError):
+    """`rescan` (additive op, docs/protocol.md) asked a job that cannot
+    answer for exactly the rows it committed: it keeps no cached pass
+    (cache off, over its budget, restored from a snapshot), or the pass
+    that fills it is still open. The daemon raises it in the job and
+    answers ``{"ok": false, "no_cached_pass": true, "error": …}``; the
+    client raises it from that ack. Not a ProtocolError and not an
+    OSError: the connection is sound and a replay cannot help — the
+    driver re-feeds the pass."""
+
+
 class FrameTooLarge(ProtocolError):
     """Sender-side MAX_FRAME rejection: deterministic (the payload will
     never fit), so retry loops must surface it instead of replaying."""

@@ -176,6 +176,22 @@ def _env_conf_config(spark, env_name: str, conf_key: str, config_key: str,
         return floor if floor is not None else cast(0)
 
 
+def pass_cache_mb(spark=None) -> int:
+    """Whether this fit asks its daemons for cached passes
+    (spark/estimator.py; docs/protocol.md "rescan"), as the budget the
+    daemons were given: MiB per device, 0 (the default) = off — the fit
+    sends the parent's ops and not one more. The daemon holds the budget
+    itself (its own ``daemon_pass_cache_mb``); a fit that asks a daemon
+    without one is told ``cached: false`` and re-feeds. Sources:
+    ``$SRML_DAEMON_PASS_CACHE_MB`` / ``spark.srml.daemon.pass_cache_mb``
+    / ``config "daemon_pass_cache_mb"``."""
+    return _env_conf_config(
+        spark, "SRML_DAEMON_PASS_CACHE_MB",
+        "spark.srml.daemon.pass_cache_mb",
+        "daemon_pass_cache_mb", int, floor=0,
+    )
+
+
 def daemon_loss_tolerance(spark=None) -> int:
     """Elastic-fit death budget (spark/estimator.py; docs/protocol.md
     "Permanent daemon loss"): how many peer daemons one fit may declare

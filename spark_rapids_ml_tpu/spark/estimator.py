@@ -207,8 +207,12 @@ class _FeedTask:
     exactly-once accumulation (see serve/daemon.py)."""
 
     def __init__(self, host, port, token, job, algo, input_col, label_col,
-                 params, pass_id, evict_routes=()):
+                 params, pass_id, evict_routes=(), want_cache=False):
         self.host, self.port, self.token = host, port, token
+        # Pass cache (docs/protocol.md "rescan"): the ack gains a column,
+        # whether the daemon's commit ack said it holds every row of this
+        # pass so far. Off (the default): the ack is the parent's.
+        self.want_cache = bool(want_cache)
         self.job, self.algo = job, algo
         self.input_col, self.label_col = input_col, label_col
         self.params, self.pass_id = params, pass_id
@@ -272,10 +276,13 @@ class _FeedTask:
                     pass_id=self.pass_id,
                 )
                 rows += batch.num_rows
+            cached = True  # nothing committed: nothing the cache lacks
             if rows > 0:
-                c.commit(
-                    self.job, partition=pid, attempt=attempt, pass_id=self.pass_id
+                _, meta = c.commit(
+                    self.job, partition=pid, attempt=attempt,
+                    pass_id=self.pass_id, with_meta=True,
                 )
+                cached = bool(meta.get("cached", False))
             if c.last_server_id and c.last_server_id != daemon_id:
                 # The daemon ANSWERED with a different identity than the
                 # cached ping: it restarted (volatile, new instance id)
@@ -292,17 +299,18 @@ class _FeedTask:
         # daemon restarted under the scan and rows acked to the dead
         # incarnation are gone — the driver's fence (docs/protocol.md
         # "Crash recovery").
-        yield pa.RecordBatch.from_pydict(
-            {
-                "partition": pa.array([pid], pa.int32()),
-                "rows": pa.array([rows], pa.int64()),
-                "daemon": pa.array([f"{h}:{p}"], pa.string()),
-                "daemon_id": pa.array([daemon_id], pa.string()),
-                "boots": pa.array(
-                    [",".join(sorted(c.seen_boot_ids))], pa.string()
-                ),
-            }
-        )
+        ack = {
+            "partition": pa.array([pid], pa.int32()),
+            "rows": pa.array([rows], pa.int64()),
+            "daemon": pa.array([f"{h}:{p}"], pa.string()),
+            "daemon_id": pa.array([daemon_id], pa.string()),
+            "boots": pa.array(
+                [",".join(sorted(c.seen_boot_ids))], pa.string()
+            ),
+        }
+        if self.want_cache:
+            ack["cached"] = pa.array([cached], pa.bool_())
+        yield pa.RecordBatch.from_pydict(ack)
 
 
 class _LabelMaxTask:
@@ -876,6 +884,19 @@ class _SparkAdapter:
         join_limit = daemon_session.daemon_join_limit(spark)
         grow = join_policy == "boundary"
         ledger_on = bool(rec_attempts) or elastic or grow
+        # Pass cache (docs/protocol.md "rescan"): with a budget configured,
+        # the passes of a kmeans fit after the first are asked of the
+        # daemons' caches (`rescan`), and rows cross the wire once. 0 (the
+        # default) = off: not one op, ack column or branch more than before.
+        want_cache = (
+            algo == "kmeans" and daemon_session.pass_cache_mb(spark) > 0
+        )
+        # What the last FED pass left in the daemons' caches, as its task
+        # acks saw it — {"per", "addr_of", "owner", "boots"} — when every
+        # commit ack said `cached: true`; empty = the next pass is fed.
+        # Emptied by every recovery (a reboot, a quarantine, a join: the
+        # fit's membership moved) and by any daemon's "no cached pass".
+        cache_view: dict = {}
         job = f"{core.uid}-{uuid.uuid4().hex[:8]}"
         input_col = core.getOrDefault(
             "inputCol" if core.hasParam("inputCol") else "featuresCol"
@@ -893,6 +914,7 @@ class _SparkAdapter:
         if multi_pass:
             sel = sel.persist()
 
+        from spark_rapids_ml_tpu.serve import protocol
         from spark_rapids_ml_tpu.serve.client import DataPlaneClient
 
         feed_params = {}
@@ -1086,14 +1108,25 @@ class _SparkAdapter:
                     evict_routes=sorted(
                         addr for addr in quarantined.values() if addr
                     ),
+                    want_cache=want_cache,
                 )
                 with trace_span("feed pass"):
                     acks = sel.mapInArrow(
                         fn,
                         "partition int, rows long, daemon string, "
-                        "daemon_id string, boots string",
+                        "daemon_id string, boots string"
+                        + (", cached boolean" if want_cache else ""),
                     ).collect()
                 n, per, addr_of, owner, boots = _ack_rows(acks)
+                # A fed pass refills the caches: what it leaves is what
+                # the next pass may ask for.
+                cache_view.clear()
+                if want_cache and n > 0 and all(
+                    bool(r["cached"]) for r in acks if int(r["rows"]) > 0
+                ):
+                    cache_view.update(
+                        per=per, addr_of=addr_of, owner=owner, boots=boots
+                    )
                 for did, cnt in per.items():
                     if cnt > 0 and did in quarantined:
                         # The amputation's safety valve: a daemon that
@@ -1483,6 +1516,33 @@ class _SparkAdapter:
                     )
                 return True
 
+            def reseed(arrays, iteration):
+                """Open pass ``iteration`` at ``arrays`` on EVERY daemon
+                (set_iterate discards the pass-local state and recreates
+                a lost job) and resynchronize the row accounting from
+                the primary's authoritative total."""
+                nonlocal total_fed
+                # Registration-table shape dispatch: which array carries
+                # the feature width per iterate layout (kmeans centers /
+                # forest bin edges / logreg w).
+                n_cols = int(
+                    arrays["centers"].shape[1]
+                    if "centers" in arrays
+                    else arrays["bin_edges"].shape[0]
+                    if "bin_edges" in arrays
+                    else arrays["w"].shape[0]
+                )
+                client.set_iterate(
+                    job, arrays, iteration, algo=wire_algo,
+                    n_cols=n_cols, params=feed_params,
+                )
+                for did in sorted(peers):
+                    peer_client(did).set_iterate(
+                        job, arrays, iteration, algo=wire_algo,
+                        n_cols=n_cols, params=feed_params,
+                    )
+                total_fed = int(client.status(job)["rows"])
+
             def recover(err):
                 """Rewind the fit to the last pass boundary: re-seed the
                 iterate from the driver ledger on EVERY daemon
@@ -1512,29 +1572,10 @@ class _SparkAdapter:
                         addr_by_id[new_id] = f"{host}:{port}"
                         peers.pop(new_id, None)
                         primary_id = new_id
+                    cache_view.clear()  # the replay is a fed pass
                     arrays = ledger["arrays"]
                     if arrays is not None:
-                        # Registration-table shape dispatch: which array
-                        # carries the feature width per iterate layout
-                        # (kmeans centers / forest bin edges / logreg w).
-                        n_cols = int(
-                            arrays["centers"].shape[1]
-                            if "centers" in arrays
-                            else arrays["bin_edges"].shape[0]
-                            if "bin_edges" in arrays
-                            else arrays["w"].shape[0]
-                        )
-                        iteration = int(ledger["iteration"])
-                        client.set_iterate(
-                            job, arrays, iteration, algo=wire_algo,
-                            n_cols=n_cols, params=feed_params,
-                        )
-                        for did in sorted(peers):
-                            peer_client(did).set_iterate(
-                                job, arrays, iteration, algo=wire_algo,
-                                n_cols=n_cols, params=feed_params,
-                            )
-                        total_fed = int(client.status(job)["rows"])
+                        reseed(arrays, int(ledger["iteration"]))
                     else:
                         for c_ in [client] + [
                             peer_client(d) for d in sorted(peers)
@@ -1680,8 +1721,81 @@ class _SparkAdapter:
                 tol2 = core.getTol() ** 2
                 info = {"cost": float("nan"), "iteration": 0}
 
+                def cached_pass(pass_id):
+                    """One pass asked of the daemons' caches (`rescan`,
+                    docs/protocol.md): every daemon that held rows in
+                    the last fed pass folds its cached pass against its
+                    current iterate, and the peers' partials are merged
+                    as after a scan. Each must answer for exactly the
+                    rows its tasks acked then, from the incarnation
+                    that acked them. Returns the pass total, or None
+                    when a daemon has no cached pass: the pass is then
+                    fed (daemons that already folded theirs are rewound
+                    to this pass's open boundary first)."""
+                    nonlocal total_fed
+                    view = dict(cache_view)
+                    per, addr_of = view["per"], view["addr_of"]
+                    asked = 0
+                    try:
+                        with trace_span("rescan pass"):
+                            for did in sorted(d for d, c in per.items() if c > 0):
+                                c_ = (
+                                    client if did == primary_id
+                                    else peer_client(did, addr_of[did])
+                                )
+                                ack = c_.rescan(job, pass_id)
+                                asked += 1
+                                boot = ack.get("boot_id")
+                                seen = view["boots"].get(did) or set()
+                                if boot is not None and seen and str(boot) not in seen:
+                                    raise _incarnation_change(
+                                        addr_of.get(did, did),
+                                        set(seen) | {str(boot)},
+                                    )
+                                if int(ack["pass_rows"]) != per[did]:
+                                    raise _split_brain(
+                                        f"rescan (pass {pass_id}) on "
+                                        f"{addr_of.get(did, did)}", per[did],
+                                        int(ack["pass_rows"]), _fed_detail(),
+                                    )
+                    except protocol.NoCachedPass as e:
+                        logger.info(
+                            "pass %s is re-fed: %s", pass_id, e
+                        )
+                        cache_view.clear()
+                        if asked:
+                            arrays, iteration = client.get_iterate(job)
+                            reseed(arrays, iteration)
+                        return None
+                    with trace_span("merge peers"):
+                        if not _reduce_on_mesh(
+                            client, job, primary_id, per, addr_of,
+                            view["owner"], view["boots"], wire_algo,
+                            feed_params, False, mesh_cache,
+                        ):
+                            _merge_peer_daemons(
+                                client, job, primary_id, per, addr_of,
+                                view["owner"], peer_client, wire_algo,
+                                feed_params, drop_peer=False,
+                            )
+                    n = sum(per.values())
+                    for did, cnt in per.items():
+                        fed_by_daemon[did] = fed_by_daemon.get(did, 0) + cnt
+                    total_fed += n
+                    return n
+
+                def scan(pass_id):
+                    """This pass's rows into the daemons' statistics:
+                    from their caches when the last fed pass left every
+                    row there, else over the wire (which refills them)."""
+                    if cache_view:
+                        n = cached_pass(pass_id)
+                        if n is not None:
+                            return n
+                    return run_pass(pass_id)
+
                 def kmeans_pass(pass_id):
-                    n = run_pass(pass_id)
+                    n = scan(pass_id)
                     if n == 0:
                         raise ValueError("cannot fit on an empty DataFrame")
                     with trace_span("step"):
@@ -1719,7 +1833,7 @@ class _SparkAdapter:
                 # stale). finalize reads the unstepped pass's inertia —
                 # the exact fit_kmeans_stream trainingCost semantics.
                 def kmeans_final():
-                    n = run_pass(info["iteration"])
+                    n = scan(info["iteration"])
                     fin_arrays, _ = finalize_guarded(
                         {}, pass_rows_expected=n
                     )

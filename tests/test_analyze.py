@@ -1314,17 +1314,23 @@ def test_whole_package_analysis_stays_under_budget():
     """The interprocedural fixpoints must not quietly make tier-1
     unaffordable: a fresh whole-package parse + call graph + every rule
     stays under the pinned budget, and no fixpoint hit its iteration cap
-    (the cap is loud by contract)."""
+    (the cap is loud by contract).
+
+    The budget is CPU seconds of this thread, not wall clock: under the
+    tier-1 run's six workers the same analysis read 10-13 s of wall clock
+    (over the 10 s it was held to: a flake) and 4.3-6.3 s of CPU, alone or
+    beside ten busy processes on eight cores (PR 28). 8 s still fails an
+    analysis that doubles (8.6 s at the least)."""
     import time as _time
 
-    t0 = _time.perf_counter()
+    t0 = _time.thread_time()
     project = Project.from_package()
     project.graph  # force the call graph + dataflow fixpoints
     findings = project.run(baseline=Baseline.load())
-    elapsed = _time.perf_counter() - t0
-    assert elapsed < 10.0, (
-        f"whole-package analysis took {elapsed:.1f}s (budget 10s) — the "
-        "interprocedural passes regressed; profile CallGraph._link/_solve"
+    elapsed = _time.thread_time() - t0
+    assert elapsed < 8.0, (
+        f"whole-package analysis took {elapsed:.1f}s of CPU (budget 8s) — "
+        "the interprocedural passes regressed; profile CallGraph._link/_solve"
     )
     assert findings == []
     cap_hits = [n for n in project.notes if "fixpoint cap" in n]
