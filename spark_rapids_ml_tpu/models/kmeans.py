@@ -54,6 +54,7 @@ from spark_rapids_ml_tpu.ops.distances import sq_euclidean
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, default_mesh
 from spark_rapids_ml_tpu.parallel import mapreduce as mr
 from spark_rapids_ml_tpu.parallel.sharding import pad_rows, shard_rows
+from spark_rapids_ml_tpu.utils import metrics
 from spark_rapids_ml_tpu.utils.profiling import trace_span
 from spark_rapids_ml_tpu.utils.xprof import ledgered_jit
 
@@ -125,17 +126,25 @@ def _pallas_assign_applicable(m_local: int, k: int, d: int, cd, use_pallas=None)
     )
 
 
-def _lloyd_block_n(m_local: int, d: int, k_pad: int, itemsize: int) -> int:
+def _lloyd_block_n(
+    m_local: int, d: int, k_pad: int, itemsize: int, x_itemsize: Optional[int] = None
+) -> int:
     """Largest row-block whose full kernel working set fits a conservative
     VMEM budget: double-buffered x tile + d2/onehot intermediates + the
-    resident sums accumulator and centers block."""
+    resident sums accumulator and centers block. ``x_itemsize`` is the
+    width x arrives in where that is not the compute dtype's (float32 rows
+    cast in the kernel): the tile is reckoned at that width, and its cast
+    copy beside it."""
     from spark_rapids_ml_tpu.ops.pallas_kernels import LLOYD_STEP_BLOCK_N
 
+    if x_itemsize is None:
+        x_itemsize = itemsize
     for b in (16384, 8192, LLOYD_STEP_BLOCK_N, 2048, 1024, 512, 256, 128):
         if m_local % b:
             continue
         vmem = (
-            2 * b * d * itemsize  # double-buffered x tile
+            2 * b * d * x_itemsize  # double-buffered x tile
+            + (b * d * itemsize if x_itemsize != itemsize else 0)  # its cast
             + 2 * b * k_pad * 4  # d2 + onehot f32 intermediates
             + k_pad * d * (4 + itemsize)  # sums accumulator + centers
         )
@@ -144,7 +153,9 @@ def _lloyd_block_n(m_local: int, d: int, k_pad: int, itemsize: int) -> int:
     return 0
 
 
-def _pallas_step_applicable(m_local: int, k: int, d: int, cd, use_pallas=None) -> bool:
+def _pallas_step_applicable(
+    m_local: int, k: int, d: int, cd, use_pallas=None, x_itemsize: Optional[int] = None
+) -> bool:
     """Fused single-HBM-pass Lloyd step (ops/pallas_kernels.lloyd_step_pallas):
     TPU backend, bf16/f32 compute, lane-aligned d, block-divisible rows, and
     a full working set that fits VMEM (per _lloyd_block_n)."""
@@ -161,8 +172,16 @@ def _pallas_step_applicable(m_local: int, k: int, d: int, cd, use_pallas=None) -
         and d % 128 == 0
         and d <= 2048
         and k_pad <= 1024
-        and _lloyd_block_n(m_local, d, k_pad, cd.itemsize) > 0
+        and _lloyd_block_n(m_local, d, k_pad, cd.itemsize, x_itemsize) > 0
     )
+
+
+def _pad_centers(centers, k_pad: int, dtype):
+    """(k, d) centers as the kernel's (k_pad, d) block in the compute dtype;
+    the rows past k are zeros, and `lloyd_step_pallas` keeps them out of the
+    argmin."""
+    cpad = jnp.zeros((k_pad, centers.shape[1]), dtype)
+    return jax.lax.dynamic_update_slice(cpad, centers.astype(dtype), (0, 0))
 
 
 @functools.lru_cache(maxsize=32)
@@ -201,18 +220,17 @@ def _lloyd_fn(
             if pallas_step:
                 from spark_rapids_ml_tpu.ops.pallas_kernels import lloyd_step_pallas
 
-                cpad = jnp.zeros((k_pad, x.shape[1]), compute_dtype)
-                cpad = jax.lax.dynamic_update_slice(
-                    cpad, centers.astype(compute_dtype), (0, 0)
-                )
-                sums, counts = lloyd_step_pallas(
+                # No cost from the kernel: the loop wants it at the FINAL
+                # centers only, and on bfloat16 rows it is not free.
+                sums, counts, _ = lloyd_step_pallas(
                     xc,
-                    cpad,
+                    _pad_centers(centers, k_pad, compute_dtype),
                     nv_local,
                     k=k,
                     block_n=_lloyd_block_n(
                         x.shape[0], x.shape[1], k_pad, compute_dtype.itemsize
                     ),
+                    with_cost=False,
                 )
                 return sums[:k].astype(accum_dtype), counts[:k].astype(accum_dtype)
             assign, _ = _assign_min(centers)
@@ -340,15 +358,70 @@ def fit_kmeans(
 # ---------------------------------------------------------------------------
 
 
+_M_FOLD_PATH = metrics.counter(
+    "srml_kmeans_fold_path_total",
+    "Dispatches of the streaming KMeans fold (kmeans.streaming_update and "
+    "kmeans.streaming_update_group) by the body their program was built "
+    "with: path=fused (one HBM read of the batch through "
+    "lloyd_step_pallas) or path=xla (CPU, widths off the lane grid, shapes "
+    "over the kernel's VMEM budget)",
+)
+
+
+#: Largest row-block of the streaming fold's kernel call. The fold is one
+#: call a BATCH, and a call's first block is fetched with nothing to overlap
+#: it, so the largest block that fits VMEM (`_lloyd_block_n`, right for the
+#: in-memory fit's one call over all rows) is not the fastest here: 65,536 x
+#: 256 float32 rows took 0.0967 ms a batch in 2,048-row blocks, 0.0987 at
+#: 4,096, 0.1034 at 8,192 and 0.1061 at 1,024; 32,768 rows agree (PERF.md
+#: §5, PR 29).
+_STREAM_BLOCK_N = 2048
+
+
 @functools.lru_cache(maxsize=32)
-def _stream_shard_fn(mesh: Mesh, k: int, cd: str, ad: str):
+def _stream_shard_fn(mesh: Mesh, k: int, cd: str, ad: str, use_pallas: bool = False):
     """One batch's Lloyd statistics at fixed centers, sharded over the
     data axis and added to (sums, counts, cost): the fold's arithmetic,
-    shared by the one-batch program and the grouped one below."""
+    shared by the one-batch program and the grouped one below.
+
+    Where `_pallas_step_applicable` holds for the shard's rows (TPU
+    backend, lane-aligned d, a working set inside VMEM) the batch is read
+    from HBM ONCE: `lloyd_step_pallas` casts each float32 tile to the
+    compute dtype in VMEM and gives sums, counts and cost from the one
+    distance product. It takes the mask as a row count, so **each shard's
+    valid rows must be a prefix of the shard** — what `shard_rows` and the
+    daemon's bucket padding produce (padding at the global tail, contiguous
+    row sharding). Elsewhere the XLA body below runs: the only path a CPU
+    or an odd width can take, and the tests' twin. `use_pallas` is the
+    builder-time snapshot of the config flag (part of the cache key, never
+    read inside the trace)."""
     compute_dtype = jnp.dtype(cd)
     accum_dtype = jnp.dtype(ad)
 
-    def shard(sums, counts, cost, centers, x, mask):
+    def fused_stats(centers, x, mask):
+        from spark_rapids_ml_tpu.ops.pallas_kernels import _ceil_to, lloyd_step_pallas
+
+        k_pad = _ceil_to(k, 128)
+        bs, bc, bcost = lloyd_step_pallas(
+            x,
+            _pad_centers(centers, k_pad, compute_dtype),
+            jnp.sum(mask.astype(jnp.int32)),  # integer: exact past 2^24 rows
+            k=k,
+            block_n=min(
+                _STREAM_BLOCK_N,
+                _lloyd_block_n(
+                    x.shape[0], x.shape[1], k_pad, compute_dtype.itemsize,
+                    x.dtype.itemsize,
+                ),
+            ),
+        )
+        return (
+            bs[:k].astype(accum_dtype),
+            bc[:k].astype(accum_dtype),
+            bcost.astype(accum_dtype),
+        )
+
+    def xla_stats(centers, x, mask):
         from spark_rapids_ml_tpu.ops.gram import mm_precision
 
         xc = x.astype(compute_dtype)
@@ -368,7 +441,13 @@ def _stream_shard_fn(mesh: Mesh, k: int, cd: str, ad: str):
                 preferred_element_type=accum_dtype,
             )
         bc = jnp.sum(onehot.astype(accum_dtype), axis=0)
-        bcost = jnp.sum(min_d2 * maskc)
+        return bs, bc, jnp.sum(min_d2 * maskc)
+
+    def shard(sums, counts, cost, centers, x, mask):
+        fused = _pallas_step_applicable(
+            x.shape[0], k, x.shape[1], compute_dtype, use_pallas, x.dtype.itemsize
+        )
+        bs, bc, bcost = (fused_stats if fused else xla_stats)(centers, x, mask)
         return (
             sums + mr.reduce_sum(bs, DATA_AXIS),
             counts + mr.reduce_sum(bc, DATA_AXIS),
@@ -380,29 +459,57 @@ def _stream_shard_fn(mesh: Mesh, k: int, cd: str, ad: str):
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(DATA_AXIS, None), P(DATA_AXIS)),
         out_specs=(P(), P(), P()),
+        # pallas_call outputs carry no VMA annotation (as in _lloyd_fn).
+        check_vma=False,
     )
 
 
-@functools.lru_cache(maxsize=32)
+def _fold_path_counter(mesh: Mesh, k: int, cd: str, use_pallas: bool):
+    """`on_dispatch` hook of the fold's two programs: one increment of
+    `srml_kmeans_fold_path_total` a dispatch, under the path the program of
+    that batch shape was built with — `_stream_shard_fn`'s own predicate,
+    asked once a shape."""
+
+    @functools.lru_cache(maxsize=None)
+    def path(shape, dtype) -> str:
+        fused = _pallas_step_applicable(
+            shape[0] // mesh.shape[DATA_AXIS], k, shape[1], cd, use_pallas,
+            jnp.dtype(dtype).itemsize,
+        )
+        return "fused" if fused else "xla"
+
+    def count(state, centers, x, mask):
+        first = x[0] if isinstance(x, tuple) else x  # a group is one shape
+        _M_FOLD_PATH.inc(path=path(first.shape, first.dtype))
+
+    return count
+
+
 def _stream_step_fn(mesh: Mesh, k: int, cd: str, ad: str):
     """Jitted donated accumulate of one batch's Lloyd statistics at fixed
     centers: (state, centers, x, mask) -> state with
     state = (sums (k, d), counts (k,), cost ()).
 
-    Uses the XLA assign path (not the fused Pallas step): streaming batches
-    are modest, and materializing (batch, k) distances buys the running
-    cost for free — convergence monitoring the fused kernel can't provide.
+    The running cost comes with the statistics on both of
+    `_stream_shard_fn`'s paths — from the materialized (batch, k)
+    distances on the XLA one, from inside the fused kernel on the TPU —
+    so a scan is also the convergence monitor and the final cost scan.
     """
-    f = _stream_shard_fn(mesh, k, cd, ad)
+    return _stream_step_cached(mesh, k, cd, ad, bool(config.get("use_pallas")))
+
+
+@functools.lru_cache(maxsize=32)
+def _stream_step_cached(mesh: Mesh, k: int, cd: str, ad: str, use_pallas: bool):
+    f = _stream_shard_fn(mesh, k, cd, ad, use_pallas)
 
     @functools.partial(ledgered_jit, "kmeans.streaming_update", donate_argnums=(0,))
     def update(state, centers, x, mask):
         return f(state[0], state[1], state[2], centers, x, mask)
 
+    update.on_dispatch = _fold_path_counter(mesh, k, cd, use_pallas)
     return update
 
 
-@functools.lru_cache(maxsize=32)
 def _stream_group_fn(mesh: Mesh, k: int, cd: str, ad: str):
     """The same accumulate over a GROUP of device-resident batches in one
     program: (state, centers, xs, masks) -> state, `xs` and `masks` tuples
@@ -410,10 +517,16 @@ def _stream_group_fn(mesh: Mesh, k: int, cd: str, ad: str):
     function `_stream_step_fn` runs — the arithmetic of len(xs) calls of
     it, for one dispatch. For a caller that holds its batches already
     (the daemon's cached pass): at 65,536 x 256 rows a fold takes the
-    device 0.22 ms and the host 0.3 ms to dispatch (PERF.md §5), so one
-    program a batch leaves the device waiting for the host. One compiled
-    program per (group length, batch shape)."""
-    f = _stream_shard_fn(mesh, k, cd, ad)
+    device ~0.1 ms through the fused kernel (0.22 through the XLA body)
+    and the host 0.3–0.4 ms to dispatch (PERF.md §5), so one program a
+    batch leaves the device waiting for the host. One compiled program per
+    (group length, batch shape)."""
+    return _stream_group_cached(mesh, k, cd, ad, bool(config.get("use_pallas")))
+
+
+@functools.lru_cache(maxsize=32)
+def _stream_group_cached(mesh: Mesh, k: int, cd: str, ad: str, use_pallas: bool):
+    f = _stream_shard_fn(mesh, k, cd, ad, use_pallas)
 
     @functools.partial(ledgered_jit, "kmeans.streaming_update_group",
                        donate_argnums=(0,))
@@ -422,6 +535,7 @@ def _stream_group_fn(mesh: Mesh, k: int, cd: str, ad: str):
             state = f(state[0], state[1], state[2], centers, x, mask)
         return state
 
+    update_group.on_dispatch = _fold_path_counter(mesh, k, cd, use_pallas)
     return update_group
 
 

@@ -8,8 +8,11 @@ places hand-tiling pays:
 * ``gram_pallas`` / ``gram_colsum_pallas`` — tiled XᵀX with the mask (or
   n_valid boundary) fused into the load, accumulators VMEM-resident.
 * ``assign_min_dist_pallas`` / ``lloyd_step_pallas`` — KMeans assignment
-  (+ fused centroid-sum update): distance tile + argmin fused, never
-  materializing the (m, k) distance matrix in HBM.
+  (+ fused centroid-sum update and cost): distance tile + argmin fused,
+  never materializing the (m, k) distance matrix in HBM; the latter also
+  casts float32 rows to the compute dtype in VMEM and serves the
+  streaming fold (models/kmeans.py ``_stream_shard_fn``) as well as the
+  in-memory fit.
 * ``newton_stats_pallas`` — one-HBM-pass binomial Newton statistics.
 * ``ivf_scan_select_pallas`` — IVF bucketed scan: per-list residual GEMM
   + exact packed-key top-k selection, scores VMEM-resident (gated by
@@ -258,20 +261,26 @@ def gram_colsum_pallas(
 
 
 def _lloyd_step_kernel(
-    nvalid_ref, x_ref, c_ref, c2h_ref, sums_ref, counts_ref, *, block_n, dead_lane
+    nvalid_ref, x_ref, c_ref, c2h_ref, sums_ref, counts_ref, cost_ref=None,
+    *, block_n, dead_lane
 ):
     @pl.when(pl.program_id(0) == 0)
     def _init():
         sums_ref[:] = jnp.zeros_like(sums_ref)
         counts_ref[:] = jnp.zeros_like(counts_ref)
+        if cost_ref is not None:
+            cost_ref[:] = jnp.zeros_like(cost_ref)
 
     row0 = pl.program_id(0) * block_n
     nv = nvalid_ref[0]
 
     @pl.when(row0 < nv)
     def _accumulate():
-        xb = x_ref[:]  # (bn, d) compute dtype
         c = c_ref[:]  # (k_pad, d) compute dtype; padded rows are zeros
+        # x may arrive wider than the compute dtype (a float32 batch under
+        # the bfloat16 profile): the tile is cast HERE, in VMEM, so the
+        # narrow copy of the batch is never written to HBM and read back.
+        xb = x_ref[:].astype(c.dtype)  # (bn, d) compute dtype
         # TRANSPOSED distance layout (k_pad, bn): the argmin then reduces
         # over the SUBLANE axis instead of the 128-lane axis — sublane
         # reductions are the cheap direction on the VPU, and the profile
@@ -286,6 +295,7 @@ def _lloyd_step_kernel(
         d2 = c2h_ref[:] - xc  # (k_pad, bn); c2h is (k_pad, 1)
         assign = jnp.argmin(d2, axis=0).astype(jnp.int32)[None, :]  # (1, bn)
         cols = jax.lax.broadcasted_iota(jnp.int32, assign.shape, 1) + row0
+        valid = cols < nv  # (1, bn)
         ks = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 0)
         if dead_lane is not None:
             # Padded rows (x = 0) would argmin to the min-norm REAL center
@@ -293,15 +303,33 @@ def _lloyd_step_kernel(
             # — route them there ((1, bn) compare) and skip the
             # (k_pad, bn) row-mask pass entirely (sums[k:] are discarded
             # by the caller).
-            assign = jnp.where(cols < nv, assign, dead_lane)
+            assign = jnp.where(valid, assign, dead_lane)
             onehot = (ks == assign).astype(xb.dtype)  # (k_pad, bn)
         else:
-            onehot = ((ks == assign) & (cols < nv)).astype(xb.dtype)
+            onehot = ((ks == assign) & valid).astype(xb.dtype)
         sums_ref[:] += jax.lax.dot_general(
             onehot, xb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
             precision=_dot_prec(xb.dtype),
         )
         counts_ref[:] += jnp.sum(onehot.astype(jnp.float32), axis=1)[None, :]
+        if cost_ref is None:
+            return
+        # The batch's cost, ops/distances.sq_euclidean's formula term for
+        # term: max(‖x‖² + ‖c‖² − 2x·c, 0) at the nearest centre, ‖x‖² in
+        # float32 from the tile after the cast. ‖x‖² is wanted per row in
+        # the LANE layout min(d2) has: the 128-lane chunks of the squares
+        # are added on the VPU and that (bn, 128) remainder transposed, so
+        # the row sum is a sublane reduction as well.
+        xf = xb.astype(jnp.float32)
+        sq = xf * xf
+        part = sq[:, :128]
+        for j in range(1, sq.shape[1] // 128):
+            part = part + sq[:, j * 128:(j + 1) * 128]
+        x2 = jnp.sum(part.T, axis=0, keepdims=True)  # (1, bn)
+        row_cost = jnp.maximum(x2 + 2.0 * jnp.min(d2, axis=0, keepdims=True), 0.0)
+        cost_ref[:] += jnp.sum(
+            jnp.where(valid, row_cost, 0.0), axis=1, keepdims=True
+        )  # every lane of the (1, 128) block holds the running cost
 
 
 LLOYD_PAD_D2 = 1e30  # finite sentinel: padded centers never win the argmin
@@ -309,7 +337,8 @@ LLOYD_STEP_BLOCK_N = 4096
 
 
 @functools.partial(
-    ledgered_jit, "pallas.lloyd_step_pallas", static_argnames=("k", "block_n", "interpret")
+    ledgered_jit, "pallas.lloyd_step_pallas",
+    static_argnames=("k", "block_n", "with_cost", "interpret"),
 )
 def lloyd_step_pallas(
     x: jax.Array,
@@ -317,11 +346,15 @@ def lloyd_step_pallas(
     n_valid: jax.Array,
     k: int,
     block_n: int = LLOYD_STEP_BLOCK_N,
+    with_cost: bool = True,
     interpret: bool = False,
 ):
     """One fused Lloyd iteration's statistics in a single HBM pass over x.
 
-    x: (n, d) compute dtype; centers: (k_pad, d) compute dtype whose rows
+    x: (n, d), d a multiple of 128, in the compute dtype OR wider (float32
+    rows under the bfloat16 profile): each (block_n, d) tile is cast to
+    ``centers.dtype`` in VMEM, so a caller never writes a narrow copy of x
+    to HBM. centers: (k_pad, d) compute dtype whose rows
     beyond the true ``k`` are padding — they are excluded from the argmin
     via a LLOYD_PAD_D2 distance sentinel. Whole blocks past n_valid skip
     their GEMMs entirely; invalid rows of the boundary block are routed
@@ -337,7 +370,22 @@ def lloyd_step_pallas(
     is ever written back to HBM — the fusion the XLA path can't express
     (it materializes both the distance matrix and the one-hot matrix).
 
-    Returns (sums (k_pad, d) float32, counts (k_pad,) float32).
+    The third result is the cost of the first ``n_valid`` rows at these
+    centers: Σ max(‖x‖² + ‖c‖² − 2x·c, 0) at each row's nearest center —
+    ``ops/distances.sq_euclidean`` term for term, clip included, the
+    product in the compute dtype with float32 accumulation and both norms
+    in float32 from the operands after the cast. It comes from the
+    distances the assignment has already formed, so convergence
+    monitoring (the streaming fold's running cost) needs no second read
+    of x. Under the float32 rows' read it is free (0.0967 against 0.0963
+    ms a 65,536 x 256 batch); on bfloat16 rows in 16,384-row blocks, where
+    the VPU is the limit, it costs 10% (0.842 against 0.762 ms a
+    1,048,576-row call; PERF.md §5, PR 29), so the in-memory fit, which
+    wants the cost at its final centers only, passes ``with_cost=False``
+    and gets ``None`` for it.
+
+    Returns (sums (k_pad, d) float32, counts (k_pad,) float32, cost ()
+    float32 or None).
     """
     n, d = x.shape
     k_pad = centers.shape[0]
@@ -346,13 +394,26 @@ def lloyd_step_pallas(
         raise ValueError(f"n={n} not divisible by block_n={bn}")
     if k_pad % 128:
         raise ValueError(f"k_pad={k_pad} must be a multiple of 128 lanes")
+    if d % 128:
+        raise ValueError(f"d={d} must be a multiple of 128 lanes")
     c2h = 0.5 * jnp.sum(
         jnp.square(centers.astype(jnp.float32)), axis=1, keepdims=True
     )  # (k_pad, 1) — column vector for the transposed (k_pad, bn) layout
     ks = jax.lax.broadcasted_iota(jnp.int32, c2h.shape, 0)
     c2h = jnp.where(ks < k, c2h, LLOYD_PAD_D2)
     nv = jnp.asarray(n_valid, jnp.int32).reshape((1,))
-    sums, counts = pl.pallas_call(
+    out_specs = [
+        pl.BlockSpec((k_pad, d), lambda i, nv: (0, 0)),
+        pl.BlockSpec((1, k_pad), lambda i, nv: (0, 0)),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((k_pad, d), jnp.float32),
+        jax.ShapeDtypeStruct((1, k_pad), jnp.float32),
+    ]
+    if with_cost:
+        out_specs.append(pl.BlockSpec((1, 128), lambda i, nv: (0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((1, 128), jnp.float32))
+    sums, counts, *cost = pl.pallas_call(
         functools.partial(
             _lloyd_step_kernel, block_n=bn,
             dead_lane=k if k < k_pad else None,
@@ -365,15 +426,9 @@ def lloyd_step_pallas(
                 pl.BlockSpec((k_pad, d), lambda i, nv: (0, 0)),
                 pl.BlockSpec((k_pad, 1), lambda i, nv: (0, 0)),
             ],
-            out_specs=[
-                pl.BlockSpec((k_pad, d), lambda i, nv: (0, 0)),
-                pl.BlockSpec((1, k_pad), lambda i, nv: (0, 0)),
-            ],
+            out_specs=out_specs,
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((k_pad, d), jnp.float32),
-            jax.ShapeDtypeStruct((1, k_pad), jnp.float32),
-        ],
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=100 * 2**20
         )
@@ -381,7 +436,7 @@ def lloyd_step_pallas(
         else None,
         interpret=interpret,
     )(nv, x, centers, c2h)
-    return sums, counts[0]
+    return sums, counts[0], cost[0][0, 0] if with_cost else None
 
 
 # ---------------------------------------------------------------------------

@@ -422,9 +422,10 @@ class _Stage:
 
 #: Cached batches a `rescan` dispatch folds. One program a batch leaves
 #: the device waiting: at the documented 65,536-row batch a kmeans fold
-#: takes the device 0.22 ms and the host 0.3 ms to dispatch (PERF.md §5).
-#: Eight in a program — the fold's arithmetic batch by batch, in order —
-#: put the host at an eighth of that. One constant, no option.
+#: takes the device ~0.1 ms (0.22 before the fold read x once, PR 29) and
+#: the host 0.3–0.4 ms to dispatch (PERF.md §5). Eight in a program — the
+#: fold's arithmetic batch by batch, in order — put the host at an eighth
+#: of that. One constant, no option.
 _RESCAN_GROUP = 8
 
 

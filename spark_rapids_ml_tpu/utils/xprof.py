@@ -350,6 +350,11 @@ class LedgeredJit:
         self._aot: Dict[Any, Any] = {}
         self.aot_hits = 0
         self.aot_misses = 0
+        #: Optional ``(*args, **kwargs) -> None`` run once per ledgered
+        #: dispatch, after the ledger's own counts and outside the timed
+        #: dispatch — for a counter of the caller's beside them (the
+        #: KMeans fold counts its dispatches by path).
+        self.on_dispatch: Optional[Callable[..., None]] = None
         # Static args are value-keyed in the signature (each value is its
         # own compiled program); everything else is keyed like the jit
         # cache (shape/dtype for arrays, type for scalars).
@@ -574,6 +579,8 @@ class LedgeredJit:
         _M_DISPATCH_SECONDS.inc(dt, fn=entry.name)
         if timing and not compiled_now:
             _M_EXEC_SECONDS.observe(dt, fn=entry.name)
+        if self.on_dispatch is not None:
+            self.on_dispatch(*args, **kwargs)
         return out
 
 

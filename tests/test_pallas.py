@@ -288,9 +288,12 @@ def test_lloyd_step_parity(rng):
     cpad = np.zeros((k_pad, d), np.float32)
     cpad[:k] = centers
     for n_valid in (m, 700):  # full + boundary-straddling partial block
-        sums, counts = lloyd_step_pallas(
+        sums, counts, cost = lloyd_step_pallas(
             x, cpad, n_valid, k=k, block_n=256, interpret=True
         )
+        ref_cost = np.sum((x[:n_valid] - centers[lab[:n_valid]]) ** 2, dtype=np.float64)
+        # the Gram trick cancels ‖x‖² ≈ 12,800 a row in float32: ~1e-4 a row
+        np.testing.assert_allclose(float(cost), ref_cost, rtol=0, atol=5e-4 * n_valid)
         ref_sums = np.zeros((k, d))
         ref_counts = np.zeros(k)
         np.add.at(ref_sums, lab[:n_valid], x[:n_valid])

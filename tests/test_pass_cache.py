@@ -13,6 +13,7 @@ batch's own statistic).
 """
 
 import socket
+import time
 
 import jax
 import numpy as np
@@ -333,6 +334,20 @@ def _fit(x, max_iter=3):
     return SparkKMeans().setK(3).setMaxIter(max_iter).setTol(0.0).setSeed(5).fit(df)
 
 
+def _requests_by_op(daemon):
+    """The daemon's per-op request counts once a fit's requests are all
+    counted. A connection thread counts a request AFTER it has answered
+    it, so the driver can hold the last `drop`'s answer before the count
+    moves; the fit closes its clients, and a thread that has ended has
+    counted everything it served."""
+    deadline = time.monotonic() + 10.0
+    while daemon._active_conns and time.monotonic() < deadline:
+        time.sleep(0.002)
+    assert daemon._active_conns == 0
+    return {s["labels"]["op"]: s["value"] for s in metrics_mod.snapshot()[
+        "srml_daemon_requests_total"]["samples"]}
+
+
 def test_spark_kmeans_rows_cross_the_wire_once_and_the_model_is_the_key_off_model(
         mesh8, monkeypatch, blobs):
     from sparksim import SimDataFrame
@@ -344,8 +359,7 @@ def test_spark_kmeans_rows_cross_the_wire_once_and_the_model_is_the_key_off_mode
         monkeypatch.delenv("SRML_DAEMON_PASS_CACHE_MB", raising=False)
         metrics_mod.reset()
         off = _fit(blobs)
-        ops_off = {s["labels"]["op"]: s["value"] for s in metrics_mod.snapshot()[
-            "srml_daemon_requests_total"]["samples"]}
+        ops_off = _requests_by_op(daemon)
         assert "rescan" not in ops_off and _counter("srml_daemon_pass_rows_total") == 0
         assert ops_off["commit"] == 3 * 4  # 3 passes and the cost scan, all fed
 
@@ -353,8 +367,7 @@ def test_spark_kmeans_rows_cross_the_wire_once_and_the_model_is_the_key_off_mode
         metrics_mod.reset()
         with config.option("daemon_pass_cache_mb", 16):
             on = _fit(blobs)
-        ops_on = {s["labels"]["op"]: s["value"] for s in metrics_mod.snapshot()[
-            "srml_daemon_requests_total"]["samples"]}
+        ops_on = _requests_by_op(daemon)
     # rows crossed the wire in pass 0 only; three scans came from the cache
     assert _counter("srml_daemon_pass_rows_total", source="wire") == len(blobs)
     assert _counter("srml_daemon_pass_rows_total", source="cache") == 3 * len(blobs)
