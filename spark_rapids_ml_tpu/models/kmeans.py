@@ -50,6 +50,7 @@ from spark_rapids_ml_tpu.core.params import (
     TypeConverters,
 )
 from spark_rapids_ml_tpu.core.persistence import MLReadable, MLWritable
+from spark_rapids_ml_tpu.models.job_protocol import JobAlgorithm
 from spark_rapids_ml_tpu.ops.distances import sq_euclidean
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, default_mesh
 from spark_rapids_ml_tpu.parallel import mapreduce as mr
@@ -562,6 +563,100 @@ def apply_lloyd_update(sums, counts, centers):
     )
     moved2 = jnp.max(jnp.sum((new_centers - centers) ** 2, axis=1))
     return new_centers, moved2
+
+
+class KMeansJob(JobAlgorithm):
+    """Lloyd passes as a daemon job: the iterate is the (k, d) centers, a
+    pass's statistics are (sums, counts, cost) at those centers, a pass
+    boundary is :func:`apply_lloyd_update`. The centers come from a
+    ``seed`` op, a pushed iterate, or the first unpartitioned batch."""
+
+    name = "kmeans"
+    iterative = True
+    cacheable = True
+    # what the device waits for between two passes — the wait for the
+    # pass's folds, the update, the two scalars to the host, the snapshot
+    boundary_span = "lloyd.boundary"
+    no_iterate = {
+        "staged_feed": (
+            "partitioned kmeans feed before centers are seeded; "
+            "send a 'seed' op from the driver first "
+            "(deterministic init)"
+        ),
+        "get_iterate": "kmeans job has no centers yet (seed first)",
+        "finalize": "finalize before any feed: no centers",
+    }
+
+    def __init__(self, n_cols, mesh, params):
+        super().__init__(n_cols, mesh, params)
+        self.k = int(params.get("k", 0))
+        if self.k <= 0:
+            raise ValueError("kmeans job needs params={'k': > 0} on first feed")
+        self.seed_value = int(params.get("seed", 0))
+        self.init = str(params.get("init", "k-means++"))
+        if self.init not in ("k-means++", "random"):
+            raise ValueError(f"unknown init {self.init!r} (k-means++|random)")
+        self.centers = None
+        cd, ad = config.get("compute_dtype"), config.get("accum_dtype")
+        self._update = _stream_step_fn(mesh, self.k, cd, ad)
+        self._update_group = _stream_group_fn(mesh, self.k, cd, ad)
+
+    @classmethod
+    def check_first_batch(cls, params, x):
+        # a first batch smaller than k must not leave a centerless job
+        # parked under the name (whose params later feeds would inherit)
+        k_req = int(params.get("k", 0))
+        if x.shape[0] < k_req:
+            raise ValueError(
+                f"first kmeans batch has {x.shape[0]} rows < k={k_req}; "
+                f"feed a larger first batch (it seeds the centers)"
+            )
+
+    @property
+    def installed(self):
+        return self.centers is not None
+
+    def check_seed(self, x):
+        if x.shape[0] < self.k:
+            raise ValueError(f"seed batch has {x.shape[0]} rows < k={self.k}")
+
+    def seed(self, x):
+        init_fn = _kmeans_plus_plus if self.init == "k-means++" else _random_init
+        c0 = init_fn(x, self.k, np.random.default_rng(self.seed_value))
+        self.centers = jnp.asarray(c0, self.accum)
+
+    def iterate_arrays(self):
+        return {"centers": np.asarray(jax.device_get(self.centers))}
+
+    def install_iterate(self, arrays):
+        c = np.asarray(arrays["centers"])
+        if c.shape != (self.k, self.n_cols):
+            raise ValueError(
+                f"centers shape {c.shape} != ({self.k}, {self.n_cols})"
+            )
+        self.centers = jnp.asarray(c, self.accum)
+
+    def zero_state(self):
+        return stream_zero_state(self.k, self.n_cols, self.accum)
+
+    def fold(self, state, xs, ms, y=None, n=0, partition=None, offset=0):
+        return self._update(state, self.centers, xs, ms)
+
+    def fold_group(self, state, xs, ms):
+        return self._update_group(state, self.centers, xs, ms)
+
+    def step(self, state, params):
+        sums, counts, cost = state
+        self.centers, moved2 = apply_lloyd_update(sums, counts, self.centers)
+        return {"moved2": moved2, "cost": cost}
+
+    def finalize(self, state, params, rows, iteration):
+        _, _, cost = state
+        return {
+            "centers": np.asarray(jax.device_get(self.centers)),
+            "cost": np.asarray([float(cost)]),
+            "n_iter": np.asarray([iteration]),
+        }
 
 
 def fit_kmeans_stream(

@@ -46,6 +46,7 @@ from spark_rapids_ml_tpu.core.params import (
     TypeConverters,
 )
 from spark_rapids_ml_tpu.core.persistence import MLReadable, MLWritable
+from spark_rapids_ml_tpu.models.job_protocol import JobAlgorithm
 from spark_rapids_ml_tpu.ops.distances import fused_topk_fits, sq_euclidean
 from spark_rapids_ml_tpu.ops.pallas_kernels import (
     ivf_scan_select_pallas,
@@ -2006,3 +2007,18 @@ class ApproximateNearestNeighborsModel(Model, _ANNParams, MLWritable, MLReadable
 
         out = with_column(dataset, "knn_distances", dists)
         return with_column(out, "knn_indices", idx)
+
+
+class KnnRowsJob(JobAlgorithm):
+    """KNN's "sufficient statistic" IS the dataset (the model is the
+    database, SURVEY §2.3): the job's state is the fed row blocks, on the
+    host, in arrival order — not a device accumulator. Nothing folds,
+    merges or steps; the daemon's row-store job stages the blocks under
+    the same exactly-once rules, and its finalize builds the index from
+    them and REGISTERS it for serving."""
+
+    name = "knn"
+    mergeable = False
+
+    def zero_state(self):
+        return []

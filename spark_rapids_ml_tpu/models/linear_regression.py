@@ -45,6 +45,7 @@ from spark_rapids_ml_tpu.core.params import (
     Model,
 )
 from spark_rapids_ml_tpu.core.persistence import MLReadable, MLWritable
+from spark_rapids_ml_tpu.models.job_protocol import JobAlgorithm
 from spark_rapids_ml_tpu.ops.linalg import solve_spd
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, default_mesh
 from spark_rapids_ml_tpu.parallel import mapreduce as mr
@@ -343,6 +344,43 @@ def finalize_normal_eq_stats(
         n_rows=n_true,
         summary=summary,
     )
+
+
+class LinearRegressionJob(JobAlgorithm):
+    """(XᵀX, Xᵀy, Σx, Σy, Σy², n) folded in one pass; finalize is the
+    normal-equations (or elastic-net) solve."""
+
+    name = "linreg"
+    needs_labels = True
+
+    def __init__(self, n_cols, mesh, params):
+        super().__init__(n_cols, mesh, params)
+        self._require_gram_capacity()
+        self._update = streaming_normal_eq_update(mesh)
+
+    def zero_state(self):
+        return init_normal_eq_stats(self.n_cols)
+
+    def fold(self, state, xs, ms, y=None, n=0, partition=None, offset=0):
+        ys = self._place_column(y, xs.shape[0], np.asarray(y).dtype)
+        return self._update(state, xs, ys, ms)
+
+    def finalize(self, state, params, rows, iteration):
+        sol = finalize_normal_eq_stats(
+            state,
+            reg=float(params.get("reg", 0.0)),
+            elastic_net=float(params.get("elastic_net", 0.0)),
+            fit_intercept=bool(params.get("fit_intercept", True)),
+            max_iter=int(params.get("max_iter", 500)),
+            tol=float(params.get("tol", 1e-6)),
+            n_true=rows,
+        )
+        return {
+            "coefficients": sol.coefficients,
+            "intercept": np.asarray([sol.intercept]),
+            "rmse": np.asarray([sol.summary.rmse]),
+            "r2": np.asarray([sol.summary.r2]),
+        }
 
 
 # ---------------------------------------------------------------------------

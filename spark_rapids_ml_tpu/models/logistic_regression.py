@@ -44,6 +44,7 @@ from spark_rapids_ml_tpu.core.params import (
     Model,
 )
 from spark_rapids_ml_tpu.core.persistence import MLReadable, MLWritable
+from spark_rapids_ml_tpu.models.job_protocol import JobAlgorithm
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, default_mesh
 from spark_rapids_ml_tpu.parallel import mapreduce as mr
 from spark_rapids_ml_tpu.parallel.sharding import shard_rows
@@ -1049,6 +1050,118 @@ def fit_logistic_stream(
 # ---------------------------------------------------------------------------
 # Estimator / Model
 # ---------------------------------------------------------------------------
+
+
+class LogisticRegressionJob(JobAlgorithm):
+    """Newton passes (binary) or MM-Newton passes (``n_classes`` > 2: the
+    same feed/step/finalize op sequence over a per-class state). The
+    iterate is (w, b), zero at creation; a pass's statistics are the
+    gradient and Hessian blocks, the loss sum and the row count at it."""
+
+    name = "logreg"
+    needs_labels = True
+    iterative = True
+
+    def __init__(self, n_cols, mesh, params):
+        super().__init__(n_cols, mesh, params)
+        self._require_gram_capacity()
+        self.n_classes = self.feed_classes(params)
+        ad = config.get("accum_dtype")
+        if self.n_classes > 2:
+            self.w = jnp.zeros((n_cols, self.n_classes), self.accum)
+            self.b = jnp.zeros((self.n_classes,), self.accum)
+            self._update = _stream_softmax_stats_fn(mesh, self.n_classes, ad)
+            self._step_fn, self._objective = (
+                _stream_multinomial_step_fn, stream_softmax_objective)
+        else:
+            self.w = jnp.zeros((n_cols,), self.accum)
+            self.b = jnp.zeros((), self.accum)
+            self._update = _stream_grad_hess_fn(mesh, ad)
+            self._step_fn, self._objective = (
+                _stream_newton_step_fn, stream_objective)
+
+    @staticmethod
+    def feed_classes(params) -> int:
+        """The ONE parse of a request's ``n_classes`` (absent = binary),
+        shared by label validation and the job-mismatch guard so the two
+        cannot disagree on the coercion rule."""
+        return int(params.get("n_classes") or 2)
+
+    @classmethod
+    def check_labels(cls, params, y):
+        n_classes = cls.feed_classes(params)
+        if n_classes > 2:
+            validate_multiclass_labels(y, n_classes)
+        else:
+            validate_binary_labels(y)
+
+    def feed_mismatch(self, params):
+        want = self.feed_classes(params)
+        if want != self.n_classes:
+            return (f"has n_classes={self.n_classes}; "
+                    f"feed carried n_classes={want}")
+        return None
+
+    def iterate_arrays(self):
+        return {
+            "w": np.asarray(jax.device_get(self.w)),
+            "b": np.asarray(jax.device_get(self.b)).reshape(-1),
+        }
+
+    def install_iterate(self, arrays):
+        # Full shape validation at the boundary: a mis-shaped iterate
+        # installed here would otherwise crash opaquely inside the next
+        # feed's jitted update.
+        w = np.asarray(arrays["w"])
+        b = np.asarray(arrays["b"]).reshape(-1)
+        multi = self.n_classes > 2
+        want_w = (self.n_cols, self.n_classes) if multi else (self.n_cols,)
+        want_b = self.n_classes if multi else 1
+        if tuple(w.shape) != want_w:
+            raise ValueError(
+                f"coefficients shape {tuple(w.shape)} != {want_w} "
+                f"(n_cols={self.n_cols}, n_classes={self.n_classes})"
+            )
+        if b.shape[0] != want_b:
+            raise ValueError(
+                f"intercept length {b.shape[0]} != {want_b} "
+                f"(n_classes={self.n_classes})"
+            )
+        self.w = jnp.asarray(w, self.accum)
+        self.b = jnp.asarray(b if multi else b.reshape(()), self.accum)
+
+    def zero_state(self):
+        if self.n_classes > 2:
+            return stream_softmax_zero_state(
+                self.n_cols, self.n_classes, self.accum)
+        return stream_zero_state(self.n_cols, self.accum)
+
+    def fold(self, state, xs, ms, y=None, n=0, partition=None, offset=0):
+        ys = self._place_column(y, xs.shape[0], np.float32)
+        return self._update(state, self.w, self.b, xs, ys, ms)
+
+    def step(self, state, params):
+        reg = float(params.get("reg", 0.0))
+        fit_intercept = bool(params.get("fit_intercept", True))
+        gw, gb, hww, hwb, hbb, lsum, n = state
+        step_fn = self._step_fn(reg, fit_intercept, self.accum.name)
+        loss = self._objective(lsum, n, reg, self.w)
+        self.w, self.b, delta = step_fn(gw, gb, hww, hwb, hbb, n, self.w, self.b)
+        return {"delta": delta, "loss": loss}
+
+    def finalize(self, state, params, rows, iteration):
+        w = np.asarray(jax.device_get(self.w))
+        b = np.asarray(jax.device_get(self.b))
+        if self.n_classes > 2:
+            # Spark layout: (C, d) coefficientMatrix + (C,) intercepts.
+            w, b = w.T, b.reshape(-1)
+        else:
+            b = b.reshape(1)
+        return {
+            "coefficients": w,
+            "intercept": b,
+            "n_iter": np.asarray([iteration]),
+        }
 
 
 class _LogisticRegressionParams(

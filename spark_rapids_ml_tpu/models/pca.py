@@ -43,6 +43,7 @@ from spark_rapids_ml_tpu.core.params import (
     TypeConverters,
 )
 from spark_rapids_ml_tpu.core.persistence import MLReadable, MLWritable
+from spark_rapids_ml_tpu.models.job_protocol import JobAlgorithm
 from spark_rapids_ml_tpu.ops import gram as gram_ops
 from spark_rapids_ml_tpu.ops.eigh import (
     pca_from_gram,
@@ -435,6 +436,49 @@ def finalize_pca_stats(
         mean=np.asarray(mean, dtype=np.float64),
         n_rows=n_true,
     )
+
+
+class PCAJob(JobAlgorithm):
+    """(count, Σx, XᵀX) folded in one pass; finalize is the eigensolve —
+    or, with ``raw_moments``, the moments themselves: a StandardScaler fit
+    is a strict subset of these statistics (count, Σx, diag XᵀX), so
+    scaler fits ride the pca job protocol."""
+
+    name = "pca"
+
+    def __init__(self, n_cols, mesh, params):
+        super().__init__(n_cols, mesh, params)
+        self._require_gram_capacity()
+        self._update = gram_ops.streaming_update(mesh)
+
+    def zero_state(self):
+        return gram_ops.init_stats(self.n_cols)
+
+    def fold(self, state, xs, ms, y=None, n=0, partition=None, offset=0):
+        return self._update(state, xs, ms)
+
+    def finalize(self, state, params, rows, iteration):
+        if params.get("raw_moments"):
+            count, colsum, g = jax.device_get(state)
+            return {
+                "count": np.asarray([float(count)]),
+                "colsum": np.asarray(colsum),
+                "gram_diag": np.diagonal(np.asarray(g)).copy(),
+            }
+        sol = finalize_pca_stats(
+            state,
+            k=int(params["k"]),
+            mean_center=bool(params.get("mean_center", True)),
+            mesh=self.mesh,
+            n_true=rows,
+            solver=params.get("solver"),
+        )
+        return {
+            "pc": sol.pc,
+            "explained_variance": sol.explained_variance,
+            "sigma": sol.sigma,
+            "mean": sol.mean,
+        }
 
 
 # ---------------------------------------------------------------------------

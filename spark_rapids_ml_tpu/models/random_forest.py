@@ -52,6 +52,7 @@ from spark_rapids_ml_tpu.core.params import (
     TypeConverters,
 )
 from spark_rapids_ml_tpu.core.persistence import MLReadable, MLWritable
+from spark_rapids_ml_tpu.models.job_protocol import JobAlgorithm
 from spark_rapids_ml_tpu.ops import histogram as hist_ops
 from spark_rapids_ml_tpu.ops.histogram import LEAF, OPEN
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, default_mesh
@@ -413,6 +414,121 @@ def grow_level(
     _M_FIT_PASSES.inc(role=spec.role())
     tables["depth"] = np.asarray([depth + 1], np.int64)
     return {"open_nodes": opened, "splits": n_split, "depth": depth + 1}
+
+
+class RandomForestJob(JobAlgorithm):
+    """Histogram tree ensembles as a daemon job (docs/protocol.md "The
+    `rf` job algo"): one pass per tree depth. The iterate is the (bin
+    edges + node tables) bundle, installed by the driver's set_iterate
+    BEFORE the first scan (a peer daemon not pre-seeded rejects its feeds
+    loudly); a pass's state is ONE additive (tree, node, feature, bin,
+    stat) histogram of the installed depth's frontier, so the cross-daemon
+    merge plane carries it like any other."""
+
+    name = "rf"
+    needs_labels = True
+    iterative = True
+    no_iterate = {
+        # The kmeans-seed contract: a peer daemon the driver never
+        # configured fails its tasks loudly instead of binning differently.
+        "feed": (
+            "rf feed before the forest iterate is installed; the "
+            "driver sends set_iterate (bin edges + node tables) "
+            "to every configured daemon before the first scan "
+            "(spark.srml.daemon.addresses)"
+        ),
+        "get_iterate": "forest job has no iterate yet (set_iterate first)",
+        "step": "step before the forest iterate is installed",
+        "finalize": "finalize before any feed: no forest iterate",
+    }
+    no_iterate["staged_feed"] = no_iterate["feed"]
+
+    def __init__(self, n_cols, mesh, params):
+        super().__init__(n_cols, mesh, params)
+        self.spec = forest_spec_from_params(params, n_cols)
+        # Depth-0 capacity gate at creation (the Gram-capacity contract):
+        # a clean first-feed error, never a mid-pass OOM.
+        require_hist_capacity(self.spec, 0, n_cols)
+        self.tables = None
+
+    @staticmethod
+    def feed_classes(params) -> int:
+        """A request's ``n_classes``: 0 (or absent) is regression."""
+        return int(params.get("n_classes") or 0)
+
+    @classmethod
+    def check_labels(cls, params, y):
+        # a classifier feed's labels validate like multinomial logreg's
+        # (integers in [0, C))
+        n_classes = cls.feed_classes(params)
+        if n_classes > 0:
+            from spark_rapids_ml_tpu.models.logistic_regression import (
+                validate_multiclass_labels,
+            )
+
+            validate_multiclass_labels(y, n_classes)
+
+    def feed_mismatch(self, params):
+        want = self.feed_classes(params)
+        if want != self.spec.n_classes:
+            return (f"has n_classes={self.spec.n_classes}; "
+                    f"feed carried n_classes={want}")
+        return None
+
+    @property
+    def installed(self):
+        return self.tables is not None
+
+    def iterate_arrays(self):
+        # Host-side tables: copies, so a later in-place grow cannot
+        # mutate an already-shipped ledger/snapshot payload.
+        return {k: np.array(v) for k, v in self.tables.items()}
+
+    def install_iterate(self, arrays):
+        self.tables = validate_forest_arrays(arrays, self.spec, self.n_cols)
+
+    def zero_state(self):
+        if self.tables is None:
+            return ()  # no iterate yet — feeds are rejected anyway
+        depth = int(self.tables["depth"][0])
+        if open_frontier_nodes(self.tables["feature"], depth) == 0:
+            # Grown out (or this depth is fully closed): no scan will ever
+            # fold here — skip the frontier alloc AND its capacity gate
+            # (the final boundary's peer sync must not trip on a
+            # histogram nobody will build).
+            return ()
+        require_hist_capacity(self.spec, depth, self.n_cols)
+        return hist_ops.zero_hist(
+            self.spec.num_trees, depth, self.n_cols,
+            self.spec.max_bins, self.spec.n_stats, self.accum,
+        )
+
+    def fold(self, state, xs, ms, y=None, n=0, partition=None, offset=0):
+        # Bootstrap-bag identity: the batch's rows are (partition,
+        # offset..offset+n) — read before this fold, so replays of a
+        # restarted stage mint identical keys.
+        target = xs.shape[0]
+        ys = self._place_column(y, target, np.float64)
+        ks = self._place_column(
+            row_identity_keys(partition, offset, n), target, np.uint32)
+        return accumulate_histogram(
+            state, self.tables, xs, ys, ms, ks, self.spec, self.mesh,
+            n_valid=n,
+        )
+
+    def step(self, state, params):
+        # the tables grow first: the job's zero_state() that follows, in
+        # the same hold of the device lock, is of the NEW depth
+        grown = grow_level(self.tables, state, self.spec)
+        return {k: grown[k] for k in ("depth", "open_nodes", "splits")}
+
+    def finalize(self, state, params, rows, iteration):
+        out = {
+            k: np.array(v) for k, v in self.tables.items() if k != "depth"
+        }
+        out["n_classes"] = np.asarray([self.spec.n_classes], np.int64)
+        out["n_iter"] = np.asarray([iteration])
+        return out
 
 
 # ---------------------------------------------------------------------------

@@ -89,7 +89,7 @@ import jax
 import numpy as np
 
 from spark_rapids_ml_tpu.core import checkpoint as checkpoint_mod
-from spark_rapids_ml_tpu.ops import gram as gram_ops
+from spark_rapids_ml_tpu.models.jobs import JOB_ALGORITHMS, job_algorithm
 from spark_rapids_ml_tpu.parallel import membership as membership_mod
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, default_mesh
 from spark_rapids_ml_tpu.parallel.sharding import row_sharding
@@ -519,14 +519,16 @@ def _opt(req: Dict[str, Any], key: str, default):
 
 
 class _Job:
-    """One accumulation job: device state + its fold function + a lock."""
+    """One accumulation job: device state + the algorithm that folds into
+    it (a models/job_protocol.py `JobAlgorithm`, by wire name from
+    models/jobs.py) + a lock. Everything here is the same for every
+    algorithm: what differs is asked of `self.algorithm`, under the job
+    lock, and under `_DEVICE_LOCK` wherever the call can dispatch."""
 
     def __init__(
         self, algo: str, n_cols: int, mesh,
         params: Optional[Dict[str, Any]] = None, clock=time.monotonic,
     ):
-        import jax.numpy as jnp
-
         from spark_rapids_ml_tpu import config
 
         params = params or {}
@@ -567,16 +569,20 @@ class _Job:
         # immediately too — a replayed merge must not double-apply).
         self._seen_feed_ids = _FifoSet()
         self._seen_merge_ids = _FifoSet()
+        # The algorithm: param validation, the capacity gate and the
+        # update programs are its constructor's (a refusal there is the
+        # first feed's clean error; an unknown name is the table's).
+        self.algorithm = job_algorithm(algo)(n_cols, mesh, params)
         # Pass cache (docs/protocol.md "rescan"; docs/mesh.md "Capacity"):
         # the budget in bytes per device, 0 = off. Written over the fold's
-        # device operands (xs, ms), so any iterative algo can take it;
-        # only kmeans is given one. `_cache_ok` falls for the rest of the
-        # fit with the first batch that would pass the budget (all or
+        # device operands (xs, ms); an algorithm that gives `fold_group`
+        # says `cacheable` and takes it. `_cache_ok` falls for the rest of
+        # the fit with the first batch that would pass the budget (all or
         # nothing); `_cache_bytes` counts the cached pass and the
         # uncommitted stages' batches together, apart from staged_bytes.
         self._cache_budget = (
             max(int(config.get("daemon_pass_cache_mb")), 0) << 20
-            if algo == "kmeans" else 0
+            if self.algorithm.cacheable else 0
         )
         self._cache: Optional[_PassCache] = None
         self._cache_ok = self._cache_budget > 0
@@ -588,250 +594,13 @@ class _Job:
         # gets the ack of the one already applied.
         self._last_rescan_id: Optional[str] = None
         self._last_rescan_ack: Optional[Dict[str, Any]] = None
-        # Capacity gate (docs/mesh.md): daemon job state is REPLICATED
-        # on every device, so a (d, d)-block accumulator (pca Gram,
-        # linreg XᵀX, logreg Hessian) over the per-device budget must
-        # refuse at job creation — a clean first-feed error — never an
-        # opaque device OOM mid-pass. Widths past the budget belong on
-        # the in-memory model-sharded fit.
-        if algo in ("pca", "linreg", "logreg") and gram_ops.require_gram_capacity(
-            n_cols, mesh
-        ):
-            raise gram_ops.GramCapacityError(
-                f"the ({n_cols}, {n_cols}) job accumulator is over the "
-                "per-device budget and daemon job state is replicated; "
-                "use the in-memory fit with mesh_model_axis > 1 "
-                "(docs/mesh.md) or raise SRML_GRAM_DEVICE_BUDGET_MB"
-            )
         # Step idempotency: a replayed step (ack lost mid-connection)
         # carrying the step_id of the ALREADY-APPLIED step gets the
         # cached info back instead of double-advancing the iterate.
         self._last_step_id: Optional[str] = None
         self._last_step_info: Optional[Dict[str, Any]] = None
-        self._accum = jnp.dtype(config.get("accum_dtype"))
-        if algo == "pca":
-            self.state = gram_ops.init_stats(n_cols)
-            self.update = gram_ops.streaming_update(mesh)
-        elif algo == "linreg":
-            from spark_rapids_ml_tpu.models.linear_regression import (
-                init_normal_eq_stats,
-                streaming_normal_eq_update,
-            )
-
-            self.state = init_normal_eq_stats(n_cols)
-            self.update = streaming_normal_eq_update(mesh)
-        elif algo == "kmeans":
-            from spark_rapids_ml_tpu.models.kmeans import (
-                _stream_group_fn,
-                _stream_step_fn,
-            )
-
-            self.k = int(params.get("k", 0))
-            if self.k <= 0:
-                raise ValueError("kmeans job needs params={'k': > 0} on first feed")
-            self.seed = int(params.get("seed", 0))
-            self.init = str(params.get("init", "k-means++"))
-            if self.init not in ("k-means++", "random"):
-                raise ValueError(f"unknown init {self.init!r} (k-means++|random)")
-            self.centers = None  # initialized from the first batch's rows
-            self.update = _stream_step_fn(
-                mesh, self.k, config.get("compute_dtype"), config.get("accum_dtype")
-            )
-            # `rescan` folds its cached batches a group a dispatch
-            self.update_group = _stream_group_fn(
-                mesh, self.k, config.get("compute_dtype"), config.get("accum_dtype")
-            )
-            self.state = self._kmeans_zero_state()
-        elif algo == "logreg":
-            # n_classes > 2 switches the job to the multinomial MM-Newton
-            # protocol (same feed/step/finalize op sequence; the state is
-            # per-class, see models.logistic_regression).
-            self.n_classes = int(params.get("n_classes") or 2)
-            if self.n_classes > 2:
-                from spark_rapids_ml_tpu.models.logistic_regression import (
-                    _stream_softmax_stats_fn,
-                )
-
-                self.w = jnp.zeros((n_cols, self.n_classes), self._accum)
-                self.b = jnp.zeros((self.n_classes,), self._accum)
-                self.update = _stream_softmax_stats_fn(
-                    mesh, self.n_classes, config.get("accum_dtype")
-                )
-            else:
-                from spark_rapids_ml_tpu.models.logistic_regression import (
-                    _stream_grad_hess_fn,
-                )
-
-                self.w = jnp.zeros((n_cols,), self._accum)
-                self.b = jnp.zeros((), self._accum)
-                self.update = _stream_grad_hess_fn(mesh, config.get("accum_dtype"))
-            self.state = self._logreg_zero_state()
-        elif algo == "rf":
-            # Histogram tree ensembles (models/random_forest.py;
-            # docs/protocol.md "The `rf` job algo"): multi-pass like
-            # kmeans/logreg — one pass per tree depth. The iterate is the
-            # (bin edges + node tables) bundle, installed by the driver's
-            # set_iterate BEFORE the first scan (the kmeans-seed pattern:
-            # a peer daemon not pre-seeded rejects its feeds loudly); the
-            # pass state is ONE additive (tree, node, feature, bin, stat)
-            # histogram tensor, so the cross-daemon merge/reduce_mesh
-            # plane carries it with zero edits.
-            from spark_rapids_ml_tpu.models import random_forest as rf_mod
-
-            self.rf_spec = rf_mod.forest_spec_from_params(params, n_cols)
-            # Depth-0 capacity gate at creation (the Gram-capacity
-            # contract): a clean first-feed error, never a mid-pass OOM.
-            rf_mod.require_hist_capacity(self.rf_spec, 0, n_cols)
-            self.rf_tables = None  # installed via set_iterate / restore
-            self.state = ()
-            self.update = None
-        elif algo == "knn":
-            # KNN's "sufficient statistic" IS the dataset (the model is the
-            # database, SURVEY §2.3) — rows accumulate host-side per
-            # partition; finalize builds the device index and REGISTERS it
-            # for serving instead of shipping ~dataset-sized arrays to the
-            # driver (the round-2 full-collect gap, VERDICT missing #2).
-            self.state = []  # eager-fed row blocks, arrival order
-            self.part_rows: Dict[int, list] = {}  # partition → row blocks
-            self.update = None
-        else:
-            raise ValueError(
-                f"unknown algo {algo!r} (pca|linreg|kmeans|logreg|rf|knn)"
-            )
-
-    def _kmeans_zero_state(self):
-        from spark_rapids_ml_tpu.models.kmeans import stream_zero_state
-
-        return stream_zero_state(self.k, self.n_cols, self._accum)
-
-    def _logreg_zero_state(self):
-        if getattr(self, "n_classes", 2) > 2:
-            from spark_rapids_ml_tpu.models.logistic_regression import (
-                stream_softmax_zero_state,
-            )
-
-            return stream_softmax_zero_state(
-                self.n_cols, self.n_classes, self._accum
-            )
-        from spark_rapids_ml_tpu.models.logistic_regression import stream_zero_state
-
-        return stream_zero_state(self.n_cols, self._accum)
-
-    def _zero_state(self):
-        if self.algo == "knn":
-            return []
-        if self.algo == "rf":
-            if self.rf_tables is None:
-                return ()  # no iterate yet — feeds are rejected anyway
-            from spark_rapids_ml_tpu.models import random_forest as rf_mod
-            from spark_rapids_ml_tpu.ops import histogram as hist_ops
-
-            depth = int(self.rf_tables["depth"][0])
-            if rf_mod.open_frontier_nodes(
-                self.rf_tables["feature"], depth
-            ) == 0:
-                # Grown out (or this depth is fully closed): no scan
-                # will ever fold here — skip the frontier alloc AND its
-                # capacity gate (the final boundary's peer sync must
-                # not trip on a histogram nobody will build).
-                return ()
-            rf_mod.require_hist_capacity(self.rf_spec, depth, self.n_cols)
-            return hist_ops.zero_hist(
-                self.rf_spec.num_trees, depth, self.n_cols,
-                self.rf_spec.max_bins, self.rf_spec.n_stats, self._accum,
-            )
-        if self.algo == "pca":
-            return gram_ops.init_stats(self.n_cols)
-        if self.algo == "linreg":
-            from spark_rapids_ml_tpu.models.linear_regression import (
-                init_normal_eq_stats,
-            )
-
-            return init_normal_eq_stats(self.n_cols)
-        if self.algo == "kmeans":
-            return self._kmeans_zero_state()
-        return self._logreg_zero_state()
-
-    def _iterate_arrays(self) -> Dict[str, np.ndarray]:
-        """Device-fetch the iterate (call under the job lock): the ONE
-        extraction both the wire (get_iterate) and the durable snapshot
-        (durable_arrays) use — the two must never drift."""
-        if self.algo == "kmeans":
-            with _DEVICE_LOCK:
-                return {"centers": np.asarray(jax.device_get(self.centers))}
-        if self.algo == "logreg":
-            with _DEVICE_LOCK:
-                return {
-                    "w": np.asarray(jax.device_get(self.w)),
-                    "b": np.asarray(jax.device_get(self.b)).reshape(-1),
-                }
-        if self.algo == "rf":
-            if self.rf_tables is None:
-                raise ValueError(
-                    "forest job has no iterate yet (the driver's "
-                    "set_iterate installs bin edges + node tables first)"
-                )
-            # Host-side tables: copies, so a later in-place grow cannot
-            # mutate an already-shipped ledger/snapshot payload.
-            return {k: np.array(v) for k, v in self.rf_tables.items()}
-        raise ValueError(
-            f"algo {self.algo!r} is single-pass; it has no iterate"
-        )
-
-    def _install_iterate(self, arrays: Dict[str, np.ndarray]) -> None:
-        """Validate + device-install an iterate (call under the job
-        lock): shared by the wire (set_iterate) and the durable restore,
-        so the shape validation cannot drift between them."""
-        import jax.numpy as jnp
-
-        if self.algo == "kmeans":
-            c = np.asarray(arrays["centers"])
-            if c.shape != (self.k, self.n_cols):
-                raise ValueError(
-                    f"centers shape {c.shape} != ({self.k}, {self.n_cols})"
-                )
-            with _DEVICE_LOCK:
-                self.centers = jnp.asarray(c, self._accum)
-        elif self.algo == "logreg":
-            # Full shape validation at the boundary: a mis-shaped
-            # iterate installed here would otherwise crash opaquely
-            # inside the next feed's jitted update.
-            w = np.asarray(arrays["w"])
-            b = np.asarray(arrays["b"]).reshape(-1)
-            n_classes = getattr(self, "n_classes", 2)
-            want_w = (
-                (self.n_cols, n_classes) if n_classes > 2 else (self.n_cols,)
-            )
-            want_b = n_classes if n_classes > 2 else 1
-            if tuple(w.shape) != want_w:
-                raise ValueError(
-                    f"coefficients shape {tuple(w.shape)} != {want_w} "
-                    f"(n_cols={self.n_cols}, n_classes={n_classes})"
-                )
-            if b.shape[0] != want_b:
-                raise ValueError(
-                    f"intercept length {b.shape[0]} != {want_b} "
-                    f"(n_classes={n_classes})"
-                )
-            with _DEVICE_LOCK:
-                self.w = jnp.asarray(w, self._accum)
-                self.b = jnp.asarray(
-                    b if n_classes > 2 else b.reshape(()), self._accum
-                )
-        elif self.algo == "rf":
-            from spark_rapids_ml_tpu.models import random_forest as rf_mod
-
-            self.rf_tables = rf_mod.validate_forest_arrays(
-                arrays, self.rf_spec, self.n_cols
-            )
-            # The pass accumulator is NOT rebuilt here: set_iterate's
-            # generic tail zeroes it right after this install (with the
-            # tables — and therefore the frontier depth — already in
-            # place), and the durable-restore path rebuilds it itself.
-        else:
-            raise ValueError(
-                f"algo {self.algo!r} is single-pass; set_iterate not applicable"
-            )
+        # An unpublished job: no other thread can dispatch for it yet.
+        self.state = self.algorithm.zero_state()  # srml: disable=device-lock
 
     def durable_arrays(self) -> Dict[str, np.ndarray]:
         """The iterate arrays a pass-boundary snapshot stores (call under
@@ -839,13 +608,10 @@ class _Job:
         excluded: at a boundary it is zero by construction, so the
         snapshot is O(iterate) — the cheap-persistence property
         core/checkpoint.py already proved for the O(d²) case."""
-        if self.algo not in ("kmeans", "logreg", "rf"):
+        if not (self.algorithm.iterative and self.algorithm.installed):
             return {}
-        if self.algo == "kmeans" and self.centers is None:
-            return {}
-        if self.algo == "rf" and self.rf_tables is None:
-            return {}
-        return self._iterate_arrays()
+        with _DEVICE_LOCK:
+            return self.algorithm.iterate_arrays()
 
     def _maybe_snapshot(self) -> None:
         """Write the durable pass-boundary snapshot when configured (call
@@ -856,17 +622,6 @@ class _Job:
         cb = self.snapshot_cb
         if cb is not None:
             cb(self)
-
-    @staticmethod
-    def _merge(a, b):
-        """Combine two accumulated states. Every job state in this daemon
-        is a tuple of additive sufficient statistics (counts, Σx, XᵀX,
-        Xᵀy, per-center sums, gradient/Hessian blocks, inertia …), so the
-        device-side combine is an elementwise add — the ``accumulateCov``
-        the reference declared but never built (RAPIDSML.scala:95-97)."""
-        import jax.numpy as jnp
-
-        return jax.tree_util.tree_map(jnp.add, a, b)
 
     def _bucket(self, n: int) -> int:
         """Pad target: next power of two (≥ data-axis size).
@@ -880,6 +635,32 @@ class _Job:
         while b < n:
             b <<= 1
         return b
+
+    def _pad(self, x: np.ndarray, n: int):
+        """A batch padded to its bucket, and its row mask (host side,
+        before the job lock is taken)."""
+        target = self._bucket(n)
+        xb = np.zeros((target,) + x.shape[1:], dtype=x.dtype)
+        xb[:n] = x
+        mb = np.zeros((target,), dtype=np.float32)
+        mb[:n] = 1.0
+        return xb, mb
+
+    def _stage_zero(self):
+        """A fresh stage's empty statistics."""
+        with _DEVICE_LOCK:
+            return self.algorithm.zero_state()
+
+    def _fold_batch(self, state, xb, mb, y, n, partition, offset):
+        """Place a padded batch and fold it: → (state, xs, ms), the last
+        two the fold's device operands (what the pass cache keeps)."""
+        with _DEVICE_LOCK:
+            xs = jax.device_put(xb, self.x_sharding)
+            ms = jax.device_put(mb, self.v_sharding)
+            state = self.algorithm.fold(
+                state, xs, ms, y, n=n, partition=partition, offset=offset
+            )
+        return state, xs, ms
 
     def _check_pass(self, pass_id: Optional[int]) -> None:
         """Reject traffic from a zombie task of an earlier pass: its batch
@@ -908,23 +689,14 @@ class _Job:
         """Deterministic kmeans init from a driver-chosen batch: centers
         only, NO fold (the rows also live in some partition and will arrive
         through the scan — folding here would double-count them)."""
-        if self.algo != "kmeans":
-            raise ValueError(f"seed only applies to kmeans jobs, not {self.algo!r}")
-        if x.shape[0] < self.k:
-            raise ValueError(f"seed batch has {x.shape[0]} rows < k={self.k}")
-        import jax.numpy as jnp
-
-        from spark_rapids_ml_tpu.models.kmeans import _kmeans_plus_plus, _random_init
-
-        init_fn = _kmeans_plus_plus if self.init == "k-means++" else _random_init
+        self.algorithm.check_seed(x)
         with self.lock:
             if self.dropped:
                 raise KeyError("job was finalized/dropped")
-            if self.centers is not None:
+            if self.algorithm.installed:
                 return  # idempotent: a retried seed keeps the first init
             with _DEVICE_LOCK:
-                c0 = init_fn(x, self.k, np.random.default_rng(self.seed))
-                self.centers = jnp.asarray(c0, self._accum)
+                self.algorithm.seed(x)
             # Seeded centers are the pass-0 boundary: persist them so a
             # restarted daemon reopens pass 0 with identical centers.
             self._maybe_snapshot()
@@ -1125,11 +897,12 @@ class _Job:
                     f"holds {self.pass_rows} rows"
                 )
             state = self.state
+            fold_group = self.algorithm.fold_group
             with trace_span("pass.rescan"):
                 with _DEVICE_LOCK:
                     for group in _rescan_groups(cache.batches):
-                        state = self.update_group(
-                            state, self.centers,
+                        state = fold_group(
+                            state,
                             tuple(xs for xs, _ in group),
                             tuple(ms for _, ms in group),
                         )
@@ -1160,45 +933,10 @@ class _Job:
     ) -> None:
         if x.shape[1] != self.n_cols:
             raise ValueError(f"batch width {x.shape[1]} != job n_cols {self.n_cols}")
-        if self.algo in ("linreg", "logreg", "rf") and y is None:
+        if self.algorithm.needs_labels and y is None:
             raise ValueError(f"{self.algo} feed needs a label column")
         n = x.shape[0]
-        if self.algo == "knn":
-            # Host-side row accumulation (no device fold): the exactly-once
-            # staging applies unchanged — a block only counts at commit.
-            block = np.ascontiguousarray(x, dtype=np.float32)
-            with self.lock:
-                if self.dropped:
-                    raise KeyError("job was finalized/dropped; rows not accepted")
-                self.touched = self._clock()
-                if partition is not None and partition in self.committed:
-                    _M_REPLAY_HITS.inc(kind="committed_partition")
-                    return
-                if partition is None:
-                    if self._is_replay(feed_id, None):
-                        return
-                    self.state.append(block)
-                    self.rows += n
-                    self.pass_rows += n
-                    self._mark_folded(feed_id, None)
-                else:
-                    stage = self.staged.get((partition, attempt))
-                    if stage is None:
-                        stage = _Stage([], 0, 0)
-                        self.staged[(partition, attempt)] = stage
-                    if self._is_replay(feed_id, stage):
-                        return
-                    stage.state = stage.state + [block]
-                    stage.rows += n
-                    stage.nbytes += block.nbytes
-                    self.staged_bytes += block.nbytes
-                    self._mark_folded(feed_id, stage)
-            return
-        target = self._bucket(n)
-        xb = np.zeros((target,) + x.shape[1:], dtype=x.dtype)
-        xb[:n] = x
-        mb = np.zeros((target,), dtype=np.float32)
-        mb[:n] = 1.0
+        xb, mb = self._pad(x, n)
         with self.lock:
             if self.dropped:
                 raise KeyError("job was finalized/dropped; rows not accepted")
@@ -1208,42 +946,16 @@ class _Job:
                 # duplicate of a committed task (retry/speculation)
                 _M_REPLAY_HITS.inc(kind="committed_partition")
                 return
-            if self.algo == "kmeans" and self.centers is None:
-                if partition is not None:
-                    raise ValueError(
-                        "partitioned kmeans feed before centers are seeded; "
-                        "send a 'seed' op from the driver first "
-                        "(deterministic init)"
-                    )
-                if n < self.k:
-                    raise ValueError(
-                        f"first kmeans batch has {n} rows < k={self.k}; "
-                        f"feed a larger first batch (it seeds the centers)"
-                    )
-                import jax.numpy as jnp
-
-                from spark_rapids_ml_tpu.models.kmeans import (
-                    _kmeans_plus_plus,
-                    _random_init,
+            if not self.algorithm.installed:
+                # No iterate yet: the algorithm's own refusal, or — where
+                # it takes its iterate from rows — the first unpartitioned
+                # batch seeds it (same device section seed_centers locks).
+                self.algorithm.require_iterate(
+                    "feed" if partition is None else "staged_feed"
                 )
-
-                init_fn = (
-                    _kmeans_plus_plus if self.init == "k-means++" else _random_init
-                )
-                with _DEVICE_LOCK:  # same device section seed_centers locks
-                    c0 = init_fn(x, self.k, np.random.default_rng(self.seed))
-                    self.centers = jnp.asarray(c0, self._accum)
-            if self.algo == "rf" and self.rf_tables is None:
-                # The forest iterate (bin edges + node tables) must be
-                # installed before any scan — the kmeans-seed contract:
-                # a peer daemon the driver never configured fails its
-                # tasks loudly here instead of binning differently.
-                raise ValueError(
-                    "rf feed before the forest iterate is installed; the "
-                    "driver sends set_iterate (bin edges + node tables) "
-                    "to every configured daemon before the first scan "
-                    "(spark.srml.daemon.addresses)"
-                )
+                self.algorithm.check_first_batch(self.params, x)
+                with _DEVICE_LOCK:
+                    self.algorithm.seed(x)
             stage = None
             fresh_stage = False
             if partition is None:
@@ -1253,8 +965,7 @@ class _Job:
             else:
                 stage = self.staged.get((partition, attempt))
                 if stage is None:
-                    with _DEVICE_LOCK:
-                        zero = self._zero_state()
+                    zero = self._stage_zero()
                     # NOT registered in self.staged yet: a fallible device
                     # update follows, and a phantom empty stage would both
                     # inflate staged_bytes and let a later commit of this
@@ -1264,45 +975,14 @@ class _Job:
                 if self._is_replay(feed_id, stage):
                     return
                 state = stage.state
-            # Bootstrap-bag identity (rf): the batch's rows are
-            # (partition, offset..offset+n) — the stage's running count
-            # (or the pass count for direct feeds), read BEFORE this
-            # fold so replays of a restarted stage mint identical keys.
-            rf_offset = (
-                stage.rows if stage is not None else self.pass_rows
+            # The rows the batch's stage (the pass, for a direct feed)
+            # held BEFORE this fold: an algorithm that gives rows an
+            # identity counts from it, so a restarted stage replays the
+            # same identities.
+            offset = stage.rows if stage is not None else self.pass_rows
+            state, xs, ms = self._fold_batch(
+                state, xb, mb, y, n, partition, offset
             )
-            with _DEVICE_LOCK:
-                xs = jax.device_put(xb, self.x_sharding)
-                ms = jax.device_put(mb, self.v_sharding)
-                if self.algo == "pca":
-                    state = self.update(state, xs, ms)
-                elif self.algo == "kmeans":
-                    state = self.update(state, self.centers, xs, ms)
-                elif self.algo == "rf":
-                    from spark_rapids_ml_tpu.models import (
-                        random_forest as rf_mod,
-                    )
-
-                    yb = np.zeros((target,), dtype=np.float64)
-                    yb[:n] = np.asarray(y, np.float64).reshape(-1)
-                    kb = np.zeros((target,), dtype=np.uint32)
-                    kb[:n] = rf_mod.row_identity_keys(partition, rf_offset, n)
-                    ys = jax.device_put(yb, self.v_sharding)
-                    ks = jax.device_put(kb, self.v_sharding)
-                    state = rf_mod.accumulate_histogram(
-                        state, self.rf_tables, xs, ys, ms, ks,
-                        self.rf_spec, self.mesh, n_valid=n,
-                    )
-                elif self.algo == "logreg":
-                    yb = np.zeros((target,), dtype=np.float32)
-                    yb[:n] = np.asarray(y).reshape(-1)
-                    ys = jax.device_put(yb, self.v_sharding)
-                    state = self.update(state, self.w, self.b, xs, ys, ms)
-                else:
-                    yb = np.zeros((target,), dtype=np.asarray(y).dtype)
-                    yb[:n] = np.asarray(y).reshape(-1)
-                    ys = jax.device_put(yb, self.v_sharding)
-                    state = self.update(state, xs, ys, ms)
             if partition is None:
                 self.state = state
                 self.rows += n
@@ -1310,11 +990,15 @@ class _Job:
             else:
                 stage.state = state
                 stage.rows += n
+                # what the stage holds now (device statistics keep their
+                # size; a stage of rows grows by the batch)
+                held = _state_nbytes(state)
+                self.staged_bytes += held - (0 if fresh_stage else stage.nbytes)
+                stage.nbytes = held
                 if fresh_stage:
                     # Published only after the update succeeded (see the
                     # creation comment above).
                     self.staged[(partition, attempt)] = stage
-                    self.staged_bytes += stage.nbytes
             if self._cache_budget:
                 if partition is None:
                     self._wire_rows(n)
@@ -1349,16 +1033,8 @@ class _Job:
                     f"commit for partition {partition} attempt {attempt} "
                     "with no staged feed"
                 )
-            state, n = staged.state, staged.rows
-            if self.algo == "knn":
-                # Keyed by partition (not arrival order) so the finalize
-                # concatenation — and therefore the global row ids the
-                # index returns — is deterministic partition-major, however
-                # the concurrent commits interleaved.
-                self.part_rows[partition] = state
-            else:
-                with _DEVICE_LOCK:  # the merge is a device program
-                    self.state = self._merge(self.state, state)
+            n = staged.rows
+            self._merge_stage(partition, staged.state)
             self.committed[partition] = n
             self.rows += n
             self.pass_rows += n
@@ -1371,6 +1047,18 @@ class _Job:
             self.touched = self._clock()  # exit stamp (see fold)
             return self.rows
 
+    def _merge_stage(self, partition: int, state) -> None:
+        """The winning stage's statistics join the job's (under the job
+        lock). Every state here is a tree of additive sufficient
+        statistics (counts, Σx, XᵀX, Xᵀy, per-center sums, gradient/Hessian
+        blocks, inertia …), so the combine is an elementwise add — the
+        ``accumulateCov`` the reference declared but never built
+        (RAPIDSML.scala:95-97)."""
+        import jax.numpy as jnp
+
+        with _DEVICE_LOCK:  # the merge is a device program
+            self.state = jax.tree_util.tree_map(jnp.add, self.state, state)
+
     def export_state(self):
         """Snapshot the job's COMMITTED accumulated state for a cross-daemon
         merge (multi-host data plane): the O(d²) partials leave as raw
@@ -1380,13 +1068,6 @@ class _Job:
         with self.lock:
             if self.dropped:
                 raise KeyError("job was finalized/dropped")
-            if self.algo == "knn":
-                raise ValueError(
-                    "knn job state is the dataset itself and does not "
-                    "merge across daemons — multi-daemon knn fits instead "
-                    "BUILD A SHARD per daemon (finalize with row_id_base; "
-                    "docs/protocol.md 'Sharded index across daemons')"
-                )
             self.touched = self._clock()
             leaves = jax.tree_util.tree_leaves(self.state)
             with _DEVICE_LOCK:
@@ -1409,48 +1090,13 @@ class _Job:
             return arrays, meta
 
     def sample_rows(self, n: int, seed: int = 0) -> np.ndarray:
-        """Seeded uniform sample of this knn job's COMMITTED rows
-        (read-only; the job keeps accumulating). The cross-daemon
-        quantizer-training op: a sharded IVF fit samples EVERY daemon's
-        shard in proportion to its rows, so the shared quantizer's
-        centroids cover the whole dataset instead of whichever slice
-        locality-sticky routing parked on the primary (ADVICE r5(b))."""
         with self.lock:
             if self.dropped:
                 raise KeyError("job was finalized/dropped")
-            if self.algo != "knn":
-                raise ValueError(
-                    "sample_rows is a knn-job op (other algos hold O(d²) "
-                    "statistics, not rows)"
-                )
-            self.touched = self._clock()
-            blocks = list(self.state)
-            for pid in sorted(self.part_rows):
-                blocks.extend(self.part_rows[pid])
-            total = sum(b.shape[0] for b in blocks)
-            if total == 0:
-                raise ValueError("sample_rows before any committed feed")
-            if int(n) <= 0:
-                raise ValueError(f"sample_rows n must be positive, got {n}")
-            n = min(int(n), total)
-            # shuffle=False: Floyd's O(n) sampling (same rationale as
-            # build_ivf_flat's training pick).
-            pick = np.sort(
-                np.random.default_rng(int(seed)).choice(
-                    total, n, replace=False, shuffle=False
-                )
-            )
-            out = np.empty((n, blocks[0].shape[1]), blocks[0].dtype)
-            base = 0
-            taken = 0
-            for b in blocks:
-                hi = base + b.shape[0]
-                j = np.searchsorted(pick, hi, side="left")
-                if j > taken:
-                    out[taken:j] = b[pick[taken:j] - base]
-                    taken = j
-                base = hi
-            return out
+        raise ValueError(
+            "sample_rows is a knn-job op (other algos hold O(d²) "
+            "statistics, not rows)"
+        )
 
     def seen_reduce(self, reduce_id: Optional[str]) -> Optional[int]:
         """Replay-dedupe probe for ``reduce_mesh`` (call BEFORE any peer
@@ -1479,12 +1125,6 @@ class _Job:
         with self.lock:
             if self.dropped:
                 raise KeyError("job was finalized/dropped")
-            if self.algo == "knn":
-                raise ValueError(
-                    "knn job state is the dataset itself and does not "
-                    "reduce across daemons (build per-daemon shards "
-                    "instead; docs/protocol.md)"
-                )
             self.touched = self._clock()
             return self.state, self.pass_rows, dict(self.committed), self.iteration
 
@@ -1553,8 +1193,6 @@ class _Job:
         with self.lock:
             if self.dropped:
                 raise KeyError("job was finalized/dropped")
-            if self.algo == "knn":
-                raise ValueError("knn jobs cannot merge remote state")
             self.touched = self._clock()
             if merge_id is not None and str(merge_id) in self._seen_merge_ids:
                 _M_REPLAY_HITS.inc(kind="merge")
@@ -1597,13 +1235,28 @@ class _Job:
             if self.dropped:
                 raise KeyError("job was finalized/dropped")
             self.touched = self._clock()
-            if self.algo == "kmeans" and self.centers is None:
-                raise ValueError("kmeans job has no centers yet (seed first)")
-            if self.algo == "rf" and self.rf_tables is None:
+            if not self.algorithm.iterative:
                 raise ValueError(
-                    "forest job has no iterate yet (set_iterate first)"
+                    f"algo {self.algo!r} is single-pass; it has no iterate"
                 )
-            return self._iterate_arrays(), {"iteration": self.iteration}
+            self.algorithm.require_iterate("get_iterate")
+            with _DEVICE_LOCK:
+                arrays = self.algorithm.iterate_arrays()
+            return arrays, {"iteration": self.iteration}
+
+    def _install_iterate(self, arrays: Dict[str, np.ndarray]) -> None:
+        """Validate + install an iterate and take the pass's zero state at
+        it (under the job lock) — the tail the wire's set_iterate and the
+        durable restore share: a tampered snapshot errors as cleanly as a
+        mis-shaped push, and both reopen the pass with statistics of the
+        INSTALLED iterate's shape."""
+        if not self.algorithm.iterative:
+            raise ValueError(
+                f"algo {self.algo!r} is single-pass; set_iterate not applicable"
+            )
+        with _DEVICE_LOCK:
+            self.algorithm.install_iterate(arrays)
+            self.state = self.algorithm.zero_state()
 
     def set_iterate(self, arrays: Dict[str, np.ndarray], iteration: int) -> None:
         """Install a driver-pushed iterate and open the given pass: reset
@@ -1616,8 +1269,6 @@ class _Job:
                 raise KeyError("job was finalized/dropped")
             self.touched = self._clock()
             self._install_iterate(arrays)
-            with _DEVICE_LOCK:
-                self.state = self._zero_state()
             # The cached pass outlives the boundary (a rewind of the pass
             # that was filling it does not); stages go.
             self._close_pass(opens=int(iteration))
@@ -1640,7 +1291,7 @@ class _Job:
             if self.dropped:
                 raise KeyError("job was finalized/dropped")
             self.touched = self._clock()
-            if self.algo not in ("kmeans", "logreg", "rf"):
+            if not self.algorithm.iterative:
                 raise ValueError(
                     f"algo {self.algo!r} is single-pass; step not applicable"
                 )
@@ -1663,117 +1314,142 @@ class _Job:
                     "step with no rows fed this pass (duplicate step retry, "
                     "or executors have not fed yet)"
                 )
-            if self.algo == "rf":
-                from spark_rapids_ml_tpu.models import random_forest as rf_mod
-
-                if self.rf_tables is None:
-                    raise ValueError(
-                        "step before the forest iterate is installed"
-                    )
+            self.algorithm.require_iterate("step")
+            # The boundary's span is the algorithm's to name; what it wraps
+            # is the job's: the wait for the pass's folds, the update and
+            # the next pass's zero state in ONE hold of the device lock
+            # (the zero state is of the iterate the update left), the
+            # fields' scalars to the host, the snapshot.
+            span = self.algorithm.boundary_span
+            with trace_span(span) if span else contextlib.nullcontext():
                 with _DEVICE_LOCK:
-                    grown = rf_mod.grow_level(
-                        self.rf_tables, self.state, self.rf_spec
-                    )
-                    # _zero_state answers () for a grown-out forest: no
-                    # doubled-frontier alloc (or capacity gate) for a
-                    # fit that will never scan again.
-                    self.state = self._zero_state()
+                    fields = self.algorithm.step(self.state, params)
+                    self.state = self.algorithm.zero_state()
+                self._close_pass()
                 self.iteration += 1
                 info = {
                     "iteration": self.iteration,
-                    "depth": grown["depth"],
-                    "open_nodes": grown["open_nodes"],
-                    "splits": grown["splits"],
+                    **{
+                        k: float(v) if isinstance(v, jax.Array) else v
+                        for k, v in fields.items()
+                    },
                     "pass_rows": self.pass_rows,
                 }
                 self.pass_rows = 0
                 self.touched = self._clock()  # exit stamp (see fold)
-                return self._cache_step(step_id, info)
-            if self.algo == "kmeans":
-                from spark_rapids_ml_tpu.models.kmeans import apply_lloyd_update
+                # Recorded for lost-ack replay. Also the per-pass
+                # durability point: the snapshot lands BEFORE the step ack
+                # (write-ahead), so a daemon that dies anywhere after here
+                # resurrects at this exact boundary.
+                self._maybe_snapshot()
+                self._last_step_id = None if step_id is None else str(step_id)
+                self._last_step_info = dict(info)
+                return info
 
-                # `lloyd.boundary`: what the device waits for between two
-                # passes — the wait for this pass's folds, the update, the
-                # two scalars to the host, the snapshot callback.
-                with trace_span("lloyd.boundary"):
-                    sums, counts, cost = self.state
-                    with _DEVICE_LOCK:
-                        self.centers, moved2 = apply_lloyd_update(
-                            sums, counts, self.centers
-                        )
-                        self.state = self._kmeans_zero_state()
-                    self._close_pass()
-                    self.iteration += 1
-                    info = {
-                        "iteration": self.iteration,
-                        "moved2": float(moved2),
-                        "cost": float(cost),
-                        "pass_rows": self.pass_rows,
-                    }
-                    self.pass_rows = 0
-                    self.touched = self._clock()  # exit stamp (see fold)
-                    return self._cache_step(step_id, info)
-            reg = float(params.get("reg", 0.0))
-            fit_intercept = bool(params.get("fit_intercept", True))
-            if getattr(self, "n_classes", 2) > 2:
-                from spark_rapids_ml_tpu.models.logistic_regression import (
-                    _stream_multinomial_step_fn,
-                    stream_softmax_objective,
-                )
-
-                gw, gb, hw, hwb, hbb, lsum, n = self.state
-                mm = _stream_multinomial_step_fn(reg, fit_intercept, self._accum.name)
-                with _DEVICE_LOCK:
-                    loss = stream_softmax_objective(lsum, n, reg, self.w)
-                    self.w, self.b, delta = mm(
-                        gw, gb, hw, hwb, hbb, n, self.w, self.b
-                    )
-                    self.state = self._logreg_zero_state()
-                self.iteration += 1
-                info = {
-                    "iteration": self.iteration,
-                    "delta": float(delta),
-                    "loss": loss,
-                    "pass_rows": self.pass_rows,
-                }
-                self.pass_rows = 0
-                self.touched = self._clock()  # exit stamp (see fold)
-                return self._cache_step(step_id, info)
-            from spark_rapids_ml_tpu.models.logistic_regression import (
-                _stream_newton_step_fn,
-                stream_objective,
-            )
-
-            gw, gb, hww, hwb, hbb, lsum, n = self.state
-            newton = _stream_newton_step_fn(reg, fit_intercept, self._accum.name)
+    def finalize(self, params: Dict[str, Any], drop: bool = False) -> Dict[str, np.ndarray]:
+        with self.lock:
+            self.algorithm.require_iterate("finalize")
             with _DEVICE_LOCK:
-                loss = stream_objective(lsum, n, reg, self.w)
-                self.w, self.b, delta = newton(
-                    gw, gb, hww, hwb, hbb, n, self.w, self.b
+                result = self.algorithm.finalize(
+                    self.state, params, self.rows, self.iteration
                 )
-                self.state = self._logreg_zero_state()
-            self.iteration += 1
-            info = {
-                "iteration": self.iteration,
-                "delta": float(delta),
-                "loss": loss,
-                "pass_rows": self.pass_rows,
-            }
-            self.pass_rows = 0
-            self.touched = self._clock()  # exit stamp (see fold)
-            return self._cache_step(step_id, info)
+            # The model is out: the fit scans no further pass.
+            self._free_cached_pass()
+            if drop:
+                # set under the same lock acquisition so a straggler feed
+                # blocked on it sees the flag and errors instead of folding
+                # rows into a model that was already returned
+                self.dropped = True
+            return result
 
-    def _cache_step(
-        self, step_id: Optional[str], info: Dict[str, Any]
-    ) -> Dict[str, Any]:
-        """Record the applied step for lost-ack replay (call under lock).
-        Also the per-pass durability point: the snapshot lands BEFORE the
-        step ack (write-ahead), so a daemon that dies anywhere after here
-        resurrects at this exact boundary."""
-        self._maybe_snapshot()
-        self._last_step_id = None if step_id is None else str(step_id)
-        self._last_step_info = dict(info)
-        return info
+
+class _RowsJob(_Job):
+    """A job whose algorithm is not `mergeable`: its state is the fed
+    rows themselves, host blocks in arrival order (knn — the model is the
+    database), not a device accumulator. `_Job`'s lock, stages,
+    `(partition, attempt)` exactly-once, fencing and replay memories apply
+    unchanged (a block only counts at commit); a block is neither padded
+    nor placed, a stage's blocks are kept by partition instead of added,
+    nothing merges across daemons (a multi-daemon fit builds a shard per
+    daemon), and finalize builds the index and consumes the job.
+    (ROADMAP D1: the one algorithm the daemon still knows.)"""
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.part_rows: Dict[int, list] = {}  # partition → row blocks
+
+    def _pad(self, x: np.ndarray, n: int):
+        return np.ascontiguousarray(x, dtype=np.float32), None
+
+    def _stage_zero(self):
+        return []
+
+    def _fold_batch(self, state, xb, mb, y, n, partition, offset):
+        return state + [xb], xb, None  # host rows: no device, no device lock
+
+    def _merge_stage(self, partition: int, state) -> None:
+        # Keyed by partition (not arrival order) so the finalize
+        # concatenation — and therefore the global row ids the index
+        # returns — is deterministic partition-major, however the
+        # concurrent commits interleaved.
+        self.part_rows[partition] = state
+
+    def export_state(self):
+        raise ValueError(
+            "knn job state is the dataset itself and does not "
+            "merge across daemons — multi-daemon knn fits instead "
+            "BUILD A SHARD per daemon (finalize with row_id_base; "
+            "docs/protocol.md 'Sharded index across daemons')"
+        )
+
+    def peek_pass_state(self):
+        raise ValueError(
+            "knn job state is the dataset itself and does not "
+            "reduce across daemons (build per-daemon shards "
+            "instead; docs/protocol.md)"
+        )
+
+    def merge_remote(self, arrays, rows, merge_id=None) -> int:
+        raise ValueError("knn jobs cannot merge remote state")
+
+    def sample_rows(self, n: int, seed: int = 0) -> np.ndarray:
+        """Seeded uniform sample of this knn job's COMMITTED rows
+        (read-only; the job keeps accumulating). The cross-daemon
+        quantizer-training op: a sharded IVF fit samples EVERY daemon's
+        shard in proportion to its rows, so the shared quantizer's
+        centroids cover the whole dataset instead of whichever slice
+        locality-sticky routing parked on the primary (ADVICE r5(b))."""
+        with self.lock:
+            if self.dropped:
+                raise KeyError("job was finalized/dropped")
+            self.touched = self._clock()
+            blocks = list(self.state)
+            for pid in sorted(self.part_rows):
+                blocks.extend(self.part_rows[pid])
+            total = sum(b.shape[0] for b in blocks)
+            if total == 0:
+                raise ValueError("sample_rows before any committed feed")
+            if int(n) <= 0:
+                raise ValueError(f"sample_rows n must be positive, got {n}")
+            n = min(int(n), total)
+            # shuffle=False: Floyd's O(n) sampling (same rationale as
+            # build_ivf_flat's training pick).
+            pick = np.sort(
+                np.random.default_rng(int(seed)).choice(
+                    total, n, replace=False, shuffle=False
+                )
+            )
+            out = np.empty((n, blocks[0].shape[1]), blocks[0].dtype)
+            base = 0
+            taken = 0
+            for b in blocks:
+                hi = base + b.shape[0]
+                j = np.searchsorted(pick, hi, side="left")
+                if j > taken:
+                    out[taken:j] = b[pick[taken:j] - base]
+                    taken = j
+                base = hi
+            return out
 
     def build_knn_model(
         self, params: Dict[str, Any],
@@ -1936,103 +1612,15 @@ class _Job:
             self.dropped = True  # rows are consumed by the built index
             return model, info, id_map
 
-    def finalize(self, params: Dict[str, Any], drop: bool = False) -> Dict[str, np.ndarray]:
-        with self.lock:
-            with _DEVICE_LOCK:
-                result = self._finalize_locked(params)
-            # The model is out: the fit scans no further pass.
-            self._free_cached_pass()
-            if drop:
-                # set under the same lock acquisition so a straggler feed
-                # blocked on it sees the flag and errors instead of folding
-                # rows into a model that was already returned
-                self.dropped = True
-            return result
 
-    def _finalize_locked(self, params: Dict[str, Any]) -> Dict[str, np.ndarray]:
-        if self.algo == "kmeans":
-            if self.centers is None:
-                raise ValueError("finalize before any feed: no centers")
-            _, _, cost = self.state
-            return {
-                "centers": np.asarray(jax.device_get(self.centers)),
-                "cost": np.asarray([float(cost)]),
-                "n_iter": np.asarray([self.iteration]),
-            }
-        if self.algo == "logreg":
-            w = np.asarray(jax.device_get(self.w))
-            b = np.asarray(jax.device_get(self.b))
-            if getattr(self, "n_classes", 2) > 2:
-                # Spark layout: (C, d) coefficientMatrix + (C,) intercepts.
-                w, b = w.T, b.reshape(-1)
-            else:
-                b = b.reshape(1)
-            return {
-                "coefficients": w,
-                "intercept": b,
-                "n_iter": np.asarray([self.iteration]),
-            }
-        if self.algo == "rf":
-            if self.rf_tables is None:
-                raise ValueError(
-                    "finalize before any feed: no forest iterate"
-                )
-            out = {
-                k: np.array(v) for k, v in self.rf_tables.items()
-                if k != "depth"
-            }
-            out["n_classes"] = np.asarray(
-                [self.rf_spec.n_classes], np.int64
-            )
-            out["n_iter"] = np.asarray([self.iteration])
-            return out
-        if self.algo == "pca" and params.get("raw_moments"):
-            # Raw accumulated moments, no eigensolve — a StandardScaler
-            # fit is a strict subset of the PCA statistics (count, Σx,
-            # diag XᵀX), so scaler fits ride the pca job protocol.
-            count, colsum, g = jax.device_get(self.state)
-            return {
-                "count": np.asarray([float(count)]),
-                "colsum": np.asarray(colsum),
-                "gram_diag": np.diagonal(np.asarray(g)).copy(),
-            }
-        if self.algo == "pca":
-            from spark_rapids_ml_tpu.models.pca import finalize_pca_stats
-
-            sol = finalize_pca_stats(
-                self.state,
-                k=int(params["k"]),
-                mean_center=bool(params.get("mean_center", True)),
-                mesh=self.mesh,
-                n_true=self.rows,
-                solver=params.get("solver"),
-            )
-            return {
-                "pc": sol.pc,
-                "explained_variance": sol.explained_variance,
-                "sigma": sol.sigma,
-                "mean": sol.mean,
-            }
-        from spark_rapids_ml_tpu.models.linear_regression import (
-            finalize_normal_eq_stats,
-        )
-
-        sol = finalize_normal_eq_stats(
-            self.state,
-            reg=float(params.get("reg", 0.0)),
-            elastic_net=float(params.get("elastic_net", 0.0)),
-            fit_intercept=bool(params.get("fit_intercept", True)),
-            max_iter=int(params.get("max_iter", 500)),
-            tol=float(params.get("tol", 1e-6)),
-            n_true=self.rows,
-        )
-        return {
-            "coefficients": sol.coefficients,
-            "intercept": np.asarray([sol.intercept]),
-            "rmse": np.asarray([sol.summary.rmse]),
-            "r2": np.asarray([sol.summary.r2]),
-        }
-
+def _new_job(
+    algo: str, n_cols: int, mesh,
+    params: Optional[Dict[str, Any]] = None, clock=time.monotonic,
+) -> _Job:
+    """The job for a request's ``algo``: `_Job` over device statistics,
+    `_RowsJob` where the algorithm keeps the rows themselves."""
+    cls = _Job if job_algorithm(algo).mergeable else _RowsJob
+    return cls(algo, n_cols, mesh, params, clock=clock)
 
 def _model_class(algo: str):
     """Wire algo → core model class for daemon-side reconstruction from
@@ -2965,9 +2553,7 @@ class DataPlaneDaemon:
         """Arm pass-boundary snapshots on an iterative job. Single-pass
         jobs (pca/linreg/knn) have no boundary before finalize — their
         recovery unit is the whole (re-runnable) scan, driver-side."""
-        if self._state_dir is None or job.algo not in (
-            "kmeans", "logreg", "rf",
-        ):
+        if self._state_dir is None or not job.algorithm.iterative:
             return
         job.snapshot_cb = lambda j, _n=name: self._save_job_state(_n, j)
 
@@ -2982,23 +2568,18 @@ class DataPlaneDaemon:
         if data is None:
             return None
         arrays, meta = data
-        job = _Job(
+        job = _new_job(
             str(meta["algo"]), int(meta["n_cols"]), self._mesh,
             meta.get("params") or {}, clock=self._clock,
         )
         with job.lock:
             if arrays:
-                # The same validate+install the wire set_iterate uses —
-                # a tampered/truncated snapshot errors cleanly here
-                # instead of crashing inside the next feed's update.
+                # The same validate+install+zero-state tail the wire
+                # set_iterate runs — a tampered/truncated snapshot errors
+                # cleanly here instead of crashing inside the next feed's
+                # update, and the pass reopens with statistics of the
+                # INSTALLED iterate's shape (a forest's frontier depth).
                 job._install_iterate(arrays)
-                if job.algo == "rf":
-                    # The restored forest reopens at its boundary with a
-                    # pass histogram of the INSTALLED depth's frontier
-                    # shape (the wire path gets this from set_iterate's
-                    # generic tail, which a restore never runs).
-                    with _DEVICE_LOCK:
-                        job.state = job._zero_state()
             job.iteration = int(meta["iteration"])
             job.rows = int(meta["rows"])
             job.touched = self._clock()
@@ -3688,7 +3269,9 @@ class DataPlaneDaemon:
         input_col = _opt(req, "input_col", "features")
         x = table_column_to_matrix(table, input_col, req.get("n_cols"))
         y = None
-        if str(_opt(req, "algo", "pca")) in ("linreg", "logreg", "rf"):
+        # (an unknown algo is refused where the job would be made)
+        algorithm = JOB_ALGORITHMS.get(str(_opt(req, "algo", "pca")))
+        if algorithm is not None and algorithm.needs_labels:
             label_col = _opt(req, "label_col", "label")
             if label_col not in table.column_names:
                 raise KeyError(f"label column {label_col!r} not in batch")
@@ -3730,54 +3313,19 @@ class DataPlaneDaemon:
         parked under the name forever."""
         name = str(req["job"])
         req_algo = str(_opt(req, "algo", "pca"))
-        # Single parse shared by label validation and the job-mismatch
-        # guard below, so the two can't disagree on the coercion rule.
-        req_classes = int((req.get("params") or {}).get("n_classes") or 2)
-        if req_algo in ("linreg", "logreg", "rf"):
+        req_params = req.get("params") or {}
+        # Asked of the algorithm's class, from the request alone (an
+        # unknown algo is refused below, where the job would be made).
+        algorithm = JOB_ALGORITHMS.get(req_algo)
+        if algorithm is not None and algorithm.needs_labels:
             if y is None:
                 raise ValueError(f"{req_algo} feed needs a label array")
-            if req_algo == "rf":
-                # rf params carry n_classes = 0 for regression (the
-                # shared req_classes parse's or-2 default is a logreg
-                # convention — re-read the raw value here); a
-                # classifier feed's labels validate like multinomial
-                # logreg (integers in [0, C)) BEFORE any job registers.
-                rf_classes = int(
-                    (req.get("params") or {}).get("n_classes") or 0
-                )
-                if rf_classes > 0:
-                    from spark_rapids_ml_tpu.models.logistic_regression import (
-                        validate_multiclass_labels,
-                    )
-
-                    validate_multiclass_labels(y, rf_classes)
-            if req_algo == "logreg":
-                if req_classes > 2:
-                    from spark_rapids_ml_tpu.models.logistic_regression import (
-                        validate_multiclass_labels,
-                    )
-
-                    validate_multiclass_labels(y, req_classes)
-                else:
-                    from spark_rapids_ml_tpu.models.logistic_regression import (
-                        validate_binary_labels,
-                    )
-
-                    validate_binary_labels(y)
+            algorithm.check_labels(req_params, y)
         # Registry first, then the durable-state restore: a feed naming a
         # job a crashed predecessor snapshotted resurrects it here.
         job = self._lookup_job(name)
-        if job is None and req_algo == "kmeans":
-            # Validate the seeding constraint BEFORE registering: a first
-            # batch smaller than k must not leave an orphan centerless job
-            # parked under the name (whose params later feeds would
-            # silently inherit).
-            k_req = int((req.get("params") or {}).get("k", 0))
-            if x.shape[0] < k_req:
-                raise ValueError(
-                    f"first kmeans batch has {x.shape[0]} rows < k={k_req}; "
-                    f"feed a larger first batch (it seeds the centers)"
-                )
+        if job is None and algorithm is not None:
+            algorithm.check_first_batch(req_params, x)
         part = req.get("partition")
         for retry in (False, True):
             created = False
@@ -3786,8 +3334,8 @@ class DataPlaneDaemon:
                     job = self._jobs.get(name)
                     created = job is None
                     if created:
-                        job = _Job(req_algo, x.shape[1], self._mesh,
-                                   req.get("params"), clock=self._clock)
+                        job = _new_job(req_algo, x.shape[1], self._mesh,
+                                       req.get("params"), clock=self._clock)
                         self._attach_durability(name, job)
                         self._jobs[name] = job
             if job.algo != req_algo:
@@ -3795,20 +3343,9 @@ class DataPlaneDaemon:
                     f"job {name!r} is algo {job.algo!r}; feed requested "
                     f"{req_algo!r}"
                 )
-            if req_algo == "logreg":
-                if req_classes != getattr(job, "n_classes", 2):
-                    raise ValueError(
-                        f"job {name!r} has n_classes={job.n_classes}; "
-                        f"feed carried n_classes={req_classes}"
-                    )
-            if req_algo == "rf":
-                want = int((req.get("params") or {}).get("n_classes") or 0)
-                if want != job.rf_spec.n_classes:
-                    raise ValueError(
-                        f"job {name!r} has n_classes="
-                        f"{job.rf_spec.n_classes}; feed carried "
-                        f"n_classes={want}"
-                    )
+            mismatch = job.algorithm.feed_mismatch(req_params)
+            if mismatch:
+                raise ValueError(f"job {name!r} {mismatch}")
             try:
                 job.fold(
                     x,
@@ -3919,8 +3456,8 @@ class DataPlaneDaemon:
             with self._jobs_lock:
                 job = self._jobs.get(name)
                 if job is None:
-                    job = _Job("kmeans", x.shape[1], self._mesh, params,
-                               clock=self._clock)
+                    job = _new_job("kmeans", x.shape[1], self._mesh, params,
+                                   clock=self._clock)
                     self._attach_durability(name, job)
                     self._jobs[name] = job
         job.seed_centers(x)
@@ -3948,7 +3485,7 @@ class DataPlaneDaemon:
             # payload (shape/count mismatch) must not leave an orphan
             # mis-shaped job parked under the name (the same invariant
             # the feed path keeps for rejected first feeds).
-            job = _Job(req_algo, int(n_cols), self._mesh, req.get("params"),
+            job = _new_job(req_algo, int(n_cols), self._mesh, req.get("params"),
                        clock=self._clock)
             self._attach_durability(name, job)
             rows = job.merge_remote(arrays, contrib, merge_id=merge_id)
@@ -4098,7 +3635,7 @@ class DataPlaneDaemon:
             # Every row may have been fed to peers: create the target
             # like merge_state does, shaped from the first peer's job.
             first = gathered[0][2]
-            job = _Job(
+            job = _new_job(
                 req_algo, first.n_cols, self._mesh, req.get("params"),
                 clock=self._clock,
             )
@@ -4172,7 +3709,7 @@ class DataPlaneDaemon:
             # leave the driver's membership untouched (the admit loop
             # registers nothing until this op acks).
             faults.checkpoint("daemon.join")
-            job = _Job(
+            job = _new_job(
                 str(_opt(req, "algo", "pca")), int(n_cols), self._mesh,
                 req.get("params"), clock=self._clock,
             )
@@ -4565,7 +4102,7 @@ class DataPlaneDaemon:
         extra = _recv_arrays_aligned(conn, req) if req.get("arrays") else {}
         job = self._get_job(req)
         params = _opt(req, "params", {})
-        if job.algo == "knn":
+        if isinstance(job, _RowsJob):
             # Build-and-serve: the index is registered daemon-side under
             # ``register_as``; only O(1) stats go back to the caller.
             name = str(params.get("register_as") or f"knn-{req.get('job')}")

@@ -870,6 +870,19 @@ class CallGraph:
                 return fn
         return None
 
+    def _derives(self, relpath: str, cls: str, root: str) -> bool:
+        """Whether ``cls`` is ``root`` or has it in its by-name MRO."""
+        if cls == root:
+            return True
+        for base in self.class_bases.get((relpath, cls), []):
+            imp = self.from_imports.get(relpath, {}).get(base)
+            rel, name = (imp[0], imp[1]) if imp is not None else (relpath, base)
+            if name == root or (
+                (rel, name) != (relpath, cls) and self._derives(rel, name, root)
+            ):
+                return True
+        return False
+
     def resolve_call(
         self, mod: Module, caller_fn: Optional[ast.AST], call: ast.Call
     ) -> List[FuncNode]:
@@ -909,6 +922,16 @@ class CallGraph:
         if src is not None:
             target = self.module_funcs.get(src, {}).get(name)
             return [target] if target else []
+        # a job's algorithm object → that member of the protocol's base
+        # class and of every class derived from it (models/*)
+        if recv_name == "algorithm":
+            return [
+                fn
+                for (_rel, _cls), methods in sorted(self.class_methods.items())
+                if self._derives(_rel, _cls, "JobAlgorithm")
+                for fn in [methods.get(name)]
+                if fn is not None
+            ]
         # by-name method dispatch over known classes (bounded)
         if name in _GENERIC_ATTR_SKIP:
             return []
@@ -1497,6 +1520,14 @@ class Project:
 _DEVICE_CALL_NAMES = frozenset(
     ("block_until_ready", "device_get", "device_put")
 )
+#: Members of the job-algorithm protocol (models/job_protocol.py, the
+#: ones its docstrings mark *dispatches*) — a daemon job reaches every
+#: algorithm's device programs through `self.algorithm.<member>(...)`, so
+#: such a call IS a dispatch wherever it stands.
+_ALGORITHM_DISPATCH_MEMBERS = frozenset((
+    "seed", "iterate_arrays", "install_iterate", "zero_state", "fold",
+    "fold_group", "step", "finalize",
+))
 #: Compile-path call targets: host work that must not hold _DEVICE_LOCK.
 _COMPILE_CALL_NAMES = frozenset(
     ("lower", "compile", "aot_prime", "cost_analysis")
@@ -1550,7 +1581,8 @@ class ModuleJitView:
 def _in_locked_helper(mod: Module, node: ast.AST) -> bool:
     """Whether the node sits in a ``*_locked``-suffixed function — the
     package convention for "the caller already holds the lock" (e.g.
-    ``_Job._finalize_locked`` runs under finalize()'s _DEVICE_LOCK)."""
+    ``DataPlaneDaemon._enforce_model_cap_locked`` runs under its callers'
+    ``_models_lock``)."""
     fn = _enclosing_function(mod, node)
     while fn is not None:
         if fn.name.endswith("_locked"):
@@ -1570,6 +1602,15 @@ def _is_dispatch_call(
         return None
     if name in _DEVICE_CALL_NAMES:
         return f"jax.{name} touches the device"
+    if (
+        name in _ALGORITHM_DISPATCH_MEMBERS
+        and isinstance(call.func, ast.Attribute)
+        and terminal_name(call.func.value) == "algorithm"
+    ):
+        return (
+            f"algorithm.{name}() is a job-algorithm protocol member that "
+            "dispatches the algorithm's device programs"
+        )
     resolved = view.resolve_call(call)
     if resolved is not None:
         return resolved[1] + " (dispatches a device program)"

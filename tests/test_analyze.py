@@ -109,6 +109,87 @@ class Job:
     assert found == []
 
 
+#: A job-algorithm protocol in miniature (models/job_protocol.py): the
+#: daemon reaches an algorithm's programs only through `self.algorithm`.
+PROTOCOL_FIXTURE = '''
+class JobAlgorithm:
+    def fold(self, state, xs, ms):
+        raise NotImplementedError
+    def require_iterate(self, op):
+        pass
+'''
+
+
+def test_device_lock_flags_protocol_dispatch_outside_lock():
+    """A call through the job's algorithm object dispatches (the members
+    the protocol marks so); one that cannot is left alone; under the lock
+    nothing is found."""
+    src = '''
+import threading
+_DEVICE_LOCK = threading.Lock()
+
+class Job:
+    def fold(self, state, xs, ms):
+        self.algorithm.require_iterate("feed")
+        %s
+        return state
+'''
+    files = {"models/job_protocol.py": PROTOCOL_FIXTURE}
+    bad = "state = self.algorithm.fold(state, xs, ms)"
+    good = "with _DEVICE_LOCK:\n            " + bad
+    _, found = run_rules({**files, "serve/daemon.py": src % bad}, "device-lock")
+    assert rule_ids(found) == ["device-lock"]
+    assert "algorithm.fold()" in found[0].message
+    _, found = run_rules({**files, "serve/daemon.py": src % good}, "device-lock")
+    assert found == []
+
+
+def test_blocking_under_device_lock_reaches_through_the_job_algorithm():
+    """`self.algorithm.step()` under _DEVICE_LOCK enters every class that
+    derives from the protocol's base — and no class that merely has a
+    method of that name: an algorithm that sleeps in `step` is found, a
+    client whose `step` sleeps is not an algorithm."""
+    files = {
+        "models/job_protocol.py": PROTOCOL_FIXTURE,
+        "models/slow.py": '''
+import time
+from spark_rapids_ml_tpu.models.job_protocol import JobAlgorithm
+
+class SlowJob(JobAlgorithm):
+    def step(self, state, params):
+        %s
+        return {}
+''',
+        "serve/client.py": '''
+import time
+
+class Client:
+    def step(self, params):
+        time.sleep(1.0)
+''',
+        "serve/daemon.py": '''
+import threading
+from spark_rapids_ml_tpu.models import slow
+from spark_rapids_ml_tpu.serve import client
+_DEVICE_LOCK = threading.Lock()
+
+class Job:
+    def step(self, params):
+        with _DEVICE_LOCK:
+            return self.algorithm.step(self.state, params)
+''',
+    }
+    sleepy = dict(files)
+    sleepy["models/slow.py"] %= "time.sleep(1.0)"
+    _, found = run_rules(sleepy, "blocking-under-device-lock")
+    assert rule_ids(found) == ["blocking-under-device-lock"]
+    assert "SlowJob.step" in found[0].message
+    quick = dict(files)
+    quick["models/slow.py"] %= "pass"
+    _, found = run_rules(quick, "blocking-under-device-lock")
+    assert found == []
+
+
 def test_device_lock_flags_block_until_ready_and_fn_handles():
     _, found = run_rules(_daemon('''
 import jax
@@ -1439,15 +1520,23 @@ def merge(parts):
     }
 
 
-def test_seeded_violation_in_scratch_daemon_is_caught():
+@pytest.mark.parametrize("planted", [
+    # through the job's algorithm object (models/job_protocol.py): the
+    # fold, and the zero state a stage or a boundary takes
+    "self.algorithm.fold(state, xs, ms)",
+    "self.algorithm.zero_state()",
+    # a transfer
+    "jax.device_put(xs, self.x_sharding)",
+])
+def test_seeded_violation_in_scratch_daemon_is_caught(planted):
     """The acceptance-criteria drill: splice a device dispatch outside
     _DEVICE_LOCK into a scratch copy of the REAL daemon.py and the gate
     must catch it."""
     files = Project.package_files()
-    files["serve/daemon.py"] += '''
+    files["serve/daemon.py"] += f'''
 
 def _scratch_unlocked_dispatch(self, state, xs, ms):
-    return self.update(state, xs, ms)
+    return {planted}
 '''
     project = Project(files=files)
     found = project.run(rules=["device-lock"], baseline=Baseline.load())

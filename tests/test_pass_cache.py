@@ -113,7 +113,7 @@ def test_a_cached_pass_against_a_partitioned_refed_pass_at_the_same_iterate(mesh
         with config.option("daemon_pass_cache_mb", 0):
             for xs, ms in cached._cache.batches:
                 per_batch.append([np.abs(np.asarray(a)) for a in jax.device_get(
-                    cached.update(cached._kmeans_zero_state(), cached.centers, xs, ms))])
+                    cached.algorithm.fold(cached.algorithm.zero_state(), xs, ms))])
         bound = [2 * (len(per_batch) - 1) * u * sum(b[i] for b in per_batch)
                  for i in range(3)]
         (s0, c0, k0), (s1, c1, k1) = _stats(fed), _stats(cached)

@@ -606,11 +606,11 @@ def test_knn_shard_build_failure_frees_all_shards(rng, mesh8, two_daemons,
     """If one shard's build fails, the fit must free the dataset-sized
     jobs AND any already-registered shard on every daemon — leaking them
     until TTL could OOM the corrected refit."""
-    from spark_rapids_ml_tpu.serve.daemon import _Job
+    from spark_rapids_ml_tpu.serve.daemon import _RowsJob
     from spark_rapids_ml_tpu.spark.estimator import SparkNearestNeighbors
 
     a, b = two_daemons
-    orig = _Job.build_knn_model
+    orig = _RowsJob.build_knn_model
     calls = {"n": 0}
 
     def flaky_build(self, params, extra_arrays=None):
@@ -619,7 +619,7 @@ def test_knn_shard_build_failure_frees_all_shards(rng, mesh8, two_daemons,
             raise ValueError("injected build failure")
         return orig(self, params, extra_arrays)
 
-    monkeypatch.setattr(_Job, "build_knn_model", flaky_build)
+    monkeypatch.setattr(_RowsJob, "build_knn_model", flaky_build)
     session, env_plan = _split_session(a, b)
     df = simdf_from_numpy(rng.normal(size=(200, 6)), n_partitions=4,
                           session=session, env_plan=env_plan)
