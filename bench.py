@@ -174,12 +174,14 @@ def main() -> None:
     # ingest-cast to the compute dtype as the bridge does at placement.
     x = jax.random.normal(jax.random.key(0), (BATCH_ROWS, D), dtype=jnp.float32)
     x = x.astype(jnp.bfloat16)
+    mask = jnp.ones((BATCH_ROWS,), jnp.float32)
     if n_chips > 1:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         x = jax.device_put(x, NamedSharding(mesh, P("data", None)))
+        mask = jax.device_put(mask, NamedSharding(mesh, P("data")))
 
-    update = gram_ops.streaming_update_rows(
+    update = gram_ops.streaming_update(
         mesh, compute_dtype="bfloat16", accum_dtype="float32"
     )
 
@@ -206,7 +208,7 @@ def main() -> None:
         state = gram_ops.init_stats(D, accum_dtype="float32")
         with trace_span("compute cov"):
             for _ in range(n_batches):
-                state = update(state, x, BATCH_ROWS)
+                state = update(state, x, mask)
             # Sync before the span closes: jitted updates dispatch async,
             # and without the block the fold's device time would land in
             # the NEXT span — the finalize blamed for fold regressions.
@@ -414,7 +416,7 @@ def multichip_bench() -> None:
             jax.random.key(0), (batch_rows, d), dtype=jnp.float32
         ).astype(jnp.dtype(cd))
         x = jax.device_put(x, NamedSharding(mesh, P(DATA_AXIS, None)))
-        update = gram_ops.streaming_update_rows(
+        update = gram_ops.streaming_update(
             mesh, compute_dtype=cd, accum_dtype=ad
         )
 
@@ -450,7 +452,7 @@ def multichip_bench() -> None:
         def pca_fit(batches: int):
             state = gram_ops.init_stats(d, accum_dtype=ad)
             for _ in range(batches):
-                state = update(state, x, batch_rows)
+                state = update(state, x, mask)
             jax.block_until_ready(state)
             return state
 

@@ -567,17 +567,31 @@ def stage_no_fallback(d: int, feed_rows: int) -> Dict[str, Any]:
           "no transform went through pca.project")
     cd, ad = config.get("compute_dtype"), config.get("accum_dtype")
     use_pallas = bool(config.get("use_pallas"))
+    from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, default_mesh
+    from spark_rapids_ml_tpu.utils import metrics
+
+    shard_rows = feed_rows // default_mesh().shape[DATA_AXIS]
+    fused = gram_ops._fused_fold_applicable((shard_rows, d), cd)
     pallas_gram = gram_ops._pallas_gram_applicable((feed_rows, d), cd, ad)
-    gram_path = "pallas.gram_pallas" if pallas_gram else f"xla dot_general[{cd}]"
+    gram_path = ("pallas.gram_colsum_pallas" if fused
+                 else "pallas.gram_pallas" if pallas_gram
+                 else f"xla dot_general[{cd}]")
+    counter = metrics.counter("srml_gram_fold_path_total")
+    fold_paths = {p: counter.value(path=p) for p in ("fused", "xla")}
     say(f"  profile: compute_dtype={cd} accum_dtype={ad} use_pallas="
         f"{use_pallas} finalize={config.get('finalize')} "
         f"solver={config.get('solver')}")
-    say(f"  Gram path of the daemon fold (gram.streaming_update) and of "
-        f"pca.fit: {gram_path}. The fused gram_colsum_pallas kernel that "
-        f"bench.py times is reached only through "
-        f"gram.streaming_update_rows, which no fit path calls.")
+    say(f"  Gram path of the daemon fold (gram.streaming_update) at "
+        f"{shard_rows} x {d} rows a shard: {gram_path}; "
+        f"srml_gram_fold_path_total {fold_paths}")
+    # Where the one-read kernel's gate holds for the feeds' bucket (the
+    # chip at a lane-aligned width) the daemon's folds must have taken it.
+    check(not fused or fold_paths["fused"] > 0,
+          f"the daemon's folds never counted path=fused where the gate holds: "
+          f"{fold_paths}")
     return {
         "gram_path": gram_path,
+        "fold_paths": fold_paths,
         "profile": {"compute_dtype": cd, "accum_dtype": ad,
                     "use_pallas": use_pallas},
         "ledger": {name: {"calls": a["calls"], "compiles": a["compiles"],
@@ -675,11 +689,11 @@ def _case_gram(mesh, d: int, n: int):
 
 
 def _case_gram_colsum(mesh, d: int, n: int, seeded: bool):
-    """gram_colsum_pallas ← ops.gram.streaming_update_rows: seeded (the
-    donated state folds inside the kernel) on a one-device mesh, unseeded
-    (+ psum + XLA add) on a mesh with more data devices. On one chip the
-    model-level function never picks the unseeded variant, so it is called
-    directly after its gate predicate said yes."""
+    """gram_colsum_pallas ← ops.gram.streaming_update on float32 rows (cast
+    in the kernel): seeded (the donated state folds inside the kernel) on
+    a one-device mesh, unseeded (+ psum + XLA add) on a mesh with more data
+    devices. On one chip the fold never picks the unseeded variant, so it
+    is called directly after the gate predicate said yes."""
     import jax
     import jax.numpy as jnp
 
@@ -688,34 +702,41 @@ def _case_gram_colsum(mesh, d: int, n: int, seeded: bool):
     from spark_rapids_ml_tpu.ops.pallas_kernels import gram_colsum_pallas
     from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
     from spark_rapids_ml_tpu.parallel.sharding import row_sharding
+    from spark_rapids_ml_tpu.utils import metrics
 
     cd = jnp.dtype(config.get("compute_dtype"))
     n_data = mesh.shape[DATA_AXIS]
     rows = n * n_data
     rng = np.random.default_rng(62 + seeded)
-    x = jnp.asarray(rng.standard_normal((rows, d), dtype=np.float32)).astype(cd)
+    x = jnp.asarray(rng.standard_normal((rows, d), dtype=np.float32))
     n_valid = rows - 300  # the boundary block pays the in-kernel mask
-    gate = gram_ops._pallas_rows_applicable((n, d), cd)
-    xf = x.astype(jnp.float32)[:n_valid]
+    gate = gram_ops._fused_fold_applicable((n, d), cd)
+    xf = x.astype(cd).astype(jnp.float32)[:n_valid]
     with jax.default_matmul_precision("highest"):
         want_g, want_s = jax.device_get((xf.T @ xf, xf.sum(0)))
     if seeded or n_data > 1:
-        update = gram_ops.streaming_update_rows(mesh)
+        counter = metrics.counter("srml_gram_fold_path_total")
+        before = counter.value(path="fused")
+        update = gram_ops.streaming_update(mesh)
         state = gram_ops.init_stats(d)
         xs = jax.device_put(x, row_sharding(mesh))
-        state = update(state, xs, n_valid)
-        state = update(state, xs, n_valid)  # second fold onto a live state
+        mask = jax.device_put(
+            (np.arange(rows) < n_valid).astype(np.float32), row_sharding(mesh, 1))
+        state = update(state, xs, mask)
+        state = update(state, xs, mask)  # second fold onto a live state
         count, colsum, gram = jax.device_get(state)
         folds = 2.0
+        # the fold took the kernel iff the gate said it would
+        gate = gate and counter.value(path="fused") - before == 2
     else:
-        gram, colsum, count = jax.device_get(
-            gram_colsum_pallas(x, jnp.asarray(n_valid, jnp.int32)))
+        gram, colsum, count = jax.device_get(gram_colsum_pallas(
+            x, jnp.asarray(n_valid, jnp.int32), compute_dtype=cd.name))
         folds = 1.0
     errs = {"gram": rel_to_max(gram, folds * want_g),
             "colsum": rel_to_max(colsum, folds * want_s),
             "count": abs(float(count) - folds * n_valid)}
-    # The operands are already bf16, so products are exact in float32 and
-    # only the accumulation order differs from the reference.
+    # The reference is of the rows after the cast, so products are exact in
+    # float32 and only the accumulation order differs.
     return gate, ["_gram_colsum_kernel"], errs, TOL_F32
 
 

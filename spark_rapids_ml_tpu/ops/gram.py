@@ -32,6 +32,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from spark_rapids_ml_tpu import config
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from spark_rapids_ml_tpu.parallel import mapreduce as mr
+from spark_rapids_ml_tpu.utils import metrics
 from spark_rapids_ml_tpu.utils.xprof import ledgered_jit
 
 Stats = Tuple[jax.Array, jax.Array, jax.Array]  # (count, colsum, gram)
@@ -306,17 +307,72 @@ def sharded_stats_ring(mesh: Mesh, compute_dtype=None, accum_dtype=None):
     return ledgered_jit("gram.sharded_stats_ring", f)
 
 
+_M_FOLD_PATH = metrics.counter(
+    "srml_gram_fold_path_total",
+    "Dispatches of the streaming Gram fold (gram.streaming_update) by the "
+    "body their program was built with: path=fused (one HBM read of the "
+    "batch through gram_colsum_pallas) or path=xla (CPU, float32 compute, "
+    "widths off the lane grid, a (d, d) accumulator over the kernel's VMEM "
+    "budget)",
+)
+
+
+def _fused_fold_applicable(shard_shape, cd, use_pallas: Optional[bool] = None) -> bool:
+    """`streaming_update`'s gate for the one-read kernel, by what the code
+    can observe: TPU backend, bfloat16 compute (float32 compute keeps
+    `local_stats`: `gram_pallas` or XLA's full-precision dot), lane-aligned
+    d, shard rows in multiples of 512 (the kernel picks its row block from
+    d and the rows), and a (d, d) float32 accumulator inside the kernel's
+    VMEM budget (constants imported from the kernel so the two cannot
+    drift)."""
+    if not _pallas_backend_ok(use_pallas):
+        return False
+    from spark_rapids_ml_tpu.ops.pallas_kernels import (
+        GRAM_COLSUM_ROW_MULTIPLE,
+        GRAM_COLSUM_VMEM_BUDGET,
+    )
+
+    m, d = shard_shape
+    return (
+        jnp.dtype(cd) == jnp.dtype(jnp.bfloat16)
+        and d % 128 == 0
+        and m % GRAM_COLSUM_ROW_MULTIPLE == 0
+        and d * d * 4 <= GRAM_COLSUM_VMEM_BUDGET
+    )
+
+
 def streaming_update(mesh: Mesh, compute_dtype=None, accum_dtype=None):
     """Jitted (state, x_batch, mask) -> state for out-of-HBM datasets.
 
     State (count, colsum, gram) lives replicated on device; host streams
     row-sharded batches in. Donation makes the accumulate in-place. This is
-    the path for BASELINE.json config #2 (100M×2048 ≫ HBM).
+    the path for BASELINE.json config #2 (100M×2048 ≫ HBM) — the one fold
+    of `fit_pca_stream`, the daemon's `PCAJob.fold` (PCA and the scaler
+    fits that ride it) and the benchmark's PCA cells.
+
+    Where `_fused_fold_applicable` holds for a shard's rows (TPU backend,
+    bfloat16 compute, lane-aligned d, whole blocks, the accumulator inside
+    VMEM) the batch is read from HBM ONCE:
+    :func:`~spark_rapids_ml_tpu.ops.pallas_kernels.gram_colsum_pallas`
+    casts each float32 tile to the compute dtype in VMEM and gives Gram,
+    column sums and row count from that one read, and on a mesh with one
+    data device and a float32 state the donated state is SEEDED into the
+    kernel, so ``state += stats`` is the same dispatch. Elsewhere
+    `local_stats`' XLA body runs: the only path a CPU, float32 compute or
+    an odd width can take, and the tests' twin.
+
+    **The mask contract.** The kernel takes the mask as a per-shard row
+    count, ``sum(mask)``: **each shard's valid rows must be a prefix of
+    the shard** (mask = ones, then zeros) — what `shard_rows`
+    (`fit_pca_stream`) and the daemon's bucket padding (`_Job.fold`)
+    produce: padding at the batch's tail, contiguous row sharding. Whole
+    blocks of padding then skip their product. A mask with a hole is
+    folded correctly by the XLA body only.
     """
     dcd, dad = _dtypes()
     cd = jnp.dtype(compute_dtype) if compute_dtype is not None else dcd
     ad = jnp.dtype(accum_dtype) if accum_dtype is not None else dad
-    # use_pallas is read by local_stats at trace time, so it must be part of
+    # use_pallas is read by the gates at trace time, so it must be part of
     # the cache key (same reason as _fit_fn's).
     return _streaming_update_cached(mesh, cd.name, ad.name, bool(config.get("use_pallas")))
 
@@ -326,11 +382,34 @@ def _streaming_update_cached(mesh: Mesh, compute_dtype, accum_dtype, use_pallas:
     # Cached per (mesh, dtypes, pallas flag): returning a fresh jitted
     # closure per call would force a full XLA recompile for every job in a
     # long-lived daemon (jit caches are keyed on the function object). The
-    # snapshot is threaded to the trace-time gate so a config flip between
+    # snapshot is threaded to the trace-time gates so a config flip between
     # builder call and first trace can't cache the wrong executable.
+    cd = jnp.dtype(compute_dtype)
+    ad = jnp.dtype(accum_dtype)
+    n_data = mesh.shape[DATA_AXIS]
+    # The seeded one-dispatch path folds the donated state INSIDE the
+    # kernel, which is only correct when no cross-shard psum sits between
+    # the partial and the state add — i.e. a single data device — and when
+    # the state dtype is the kernel's f32 accumulator dtype.
+    seed = n_data == 1 and ad == jnp.dtype(jnp.float32)
 
     def shard_update(count, colsum, gram, x, mask):
-        c, s, g = _stats_shard(x, mask, compute_dtype, accum_dtype, use_pallas)
+        if _fused_fold_applicable(x.shape, cd, use_pallas):
+            from spark_rapids_ml_tpu.ops.pallas_kernels import gram_colsum_pallas
+
+            n_valid = jnp.sum(mask.astype(jnp.int32))  # integer: exact past 2^24 rows
+            if seed:
+                g, s, c = gram_colsum_pallas(
+                    x, n_valid, compute_dtype=cd.name, state=(gram, colsum, count)
+                )
+                return c, s, g
+            g, s, _ = gram_colsum_pallas(x, n_valid, compute_dtype=cd.name)
+            c, s, g = n_valid.astype(ad), s.astype(ad), g.astype(ad)
+        else:
+            c, s, g = local_stats(x, mask, compute_dtype, accum_dtype, use_pallas)
+        c = mr.reduce_sum(c, DATA_AXIS)
+        s = mr.reduce_sum(s, DATA_AXIS)
+        g = mr.reduce_sum(g, DATA_AXIS)
         return count + c, colsum + s, gram + g
 
     f = jax.shard_map(
@@ -345,118 +424,14 @@ def _streaming_update_cached(mesh: Mesh, compute_dtype, accum_dtype, use_pallas:
     def update(state, x, mask):
         return f(state[0], state[1], state[2], x, mask)
 
-    return update
+    @functools.lru_cache(maxsize=None)
+    def path(shape) -> str:
+        fused = _fused_fold_applicable((shape[0] // n_data, shape[1]), cd, use_pallas)
+        return "fused" if fused else "xla"
 
-
-def _pallas_rows_applicable(shape, cd, use_pallas: Optional[bool] = None) -> bool:
-    """gram_colsum_pallas gate: TPU backend, lane-aligned d, block-divisible
-    rows, and a (d, d) f32 accumulator that fits the kernel's VMEM budget
-    (constants imported from the kernel so the two can't drift)."""
-    if not _pallas_backend_ok(use_pallas):
-        return False
-    from spark_rapids_ml_tpu.ops.pallas_kernels import (
-        GRAM_COLSUM_BLOCK_N,
-        GRAM_COLSUM_VMEM_BUDGET,
-    )
-
-    m, d = shape
-    return (
-        jnp.dtype(cd) in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
-        and d % 128 == 0
-        and m % GRAM_COLSUM_BLOCK_N == 0
-        and d * d * 4 <= GRAM_COLSUM_VMEM_BUDGET
-    )
-
-
-def streaming_update_rows(mesh: Mesh, compute_dtype=None, accum_dtype=None):
-    """Jitted (state, x_batch, n_valid) -> state — the fast streaming path.
-
-    Like :func:`streaming_update` but the padding mask is a single scalar:
-    rows ≥ ``n_valid`` (a *global* row count over the whole batch, rows laid
-    out contiguously across the ``data`` axis) are ignored. x arrives already
-    in the compute dtype — the ingest stage casts once at host→device
-    placement (halving transfer bytes for bfloat16) so the hot loop never
-    touches float32 row data. On TPU with ``use_pallas`` the per-shard stats
-    use the single-HBM-pass fused kernel
-    (:func:`~spark_rapids_ml_tpu.ops.pallas_kernels.gram_colsum_pallas`),
-    which emits count/colsum/gram together; on a single-data-device mesh
-    with float32 accumulation the donated streaming state is additionally
-    SEEDED into the kernel's VMEM accumulators, so the whole per-batch
-    ``state += batch_stats`` is one Pallas dispatch — the separate XLA add
-    that round-tripped the (d, d) state through HBM per batch is gone.
-    Elsewhere an iota-derived mask reuses the XLA path.
-    """
-    dcd, dad = _dtypes()
-    cd = jnp.dtype(compute_dtype) if compute_dtype is not None else dcd
-    ad = jnp.dtype(accum_dtype) if accum_dtype is not None else dad
-    return _streaming_update_rows_cached(
-        mesh, cd.name, ad.name, bool(config.get("use_pallas"))
-    )
-
-
-@functools.lru_cache(maxsize=32)
-def _streaming_update_rows_cached(
-    mesh: Mesh, compute_dtype, accum_dtype, use_pallas: bool
-):
-    # use_pallas is the snapshot taken when the builder was called — the gate
-    # must use it (not re-read config at trace time) or a config flip between
-    # builder call and first trace would cache the wrong executable forever.
-    cd = jnp.dtype(compute_dtype)
-    ad = jnp.dtype(accum_dtype)
-    # The seeded one-dispatch path folds the donated state INSIDE the
-    # kernel, which is only correct when no cross-shard psum sits between
-    # the partial and the state add — i.e. a single data device — and when
-    # the state dtype is the kernel's f32 accumulator dtype.
-    n_data = mesh.shape[DATA_AXIS]
-
-    def shard_update(count, colsum, gram, x, n_valid):
-        m = x.shape[0]
-        offset = jax.lax.axis_index(DATA_AXIS).astype(jnp.int32) * m
-        nv_local = jnp.clip(n_valid.astype(jnp.int32) - offset, 0, m)
-        xc = x.astype(cd)
-        if _pallas_rows_applicable(x.shape, cd, use_pallas):
-            from spark_rapids_ml_tpu.ops.pallas_kernels import gram_colsum_pallas
-
-            if n_data == 1 and ad == jnp.dtype(jnp.float32):
-                g, cs, c = gram_colsum_pallas(
-                    xc, nv_local, state=(gram, colsum, count)
-                )
-                return c, cs, g
-            g, cs, _ = gram_colsum_pallas(xc, nv_local)
-            g = g.astype(ad)
-            cs = cs.astype(ad)
-        else:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (m,), 0)
-            mask = (rows < nv_local).astype(cd)
-            _, cs, g = local_stats(
-                xc,
-                mask,
-                compute_dtype=compute_dtype,
-                accum_dtype=accum_dtype,
-                use_pallas=use_pallas,
-            )
-        c = mr.reduce_sum(nv_local.astype(ad), DATA_AXIS)
-        cs = mr.reduce_sum(cs, DATA_AXIS)
-        g = mr.reduce_sum(g, DATA_AXIS)
-        return count + c, colsum + cs, gram + g
-
-    f = jax.shard_map(
-        shard_update,
-        mesh=mesh,
-        in_specs=(P(), P(), P(), P(DATA_AXIS, None), P()),
-        out_specs=(P(), P(), P()),
-        # pallas_call outputs carry no VMA annotation; the post-psum values
-        # are replicated, which VMA inference can't prove (same as the 2-D
-        # variant above).
-        check_vma=False,
-    )
-
-    @functools.partial(
-        ledgered_jit, "gram.streaming_update_rows", donate_argnums=(0,)
-    )
-    def update(state, x, n_valid):
-        return f(state[0], state[1], state[2], x, jnp.asarray(n_valid, jnp.int32))
-
+    # One count a dispatch, by the body the program of that batch shape was
+    # built with: the gate's own predicate, asked once a shape.
+    update.on_dispatch = lambda state, x, mask: _M_FOLD_PATH.inc(path=path(x.shape))
     return update
 
 

@@ -5,8 +5,15 @@ cuBLAS GEMM calls (rapidsml_jni.cu). On TPU, XLA already fuses the
 mask-multiply + GEMM + accumulate chain well, so Pallas here targets the
 places hand-tiling pays:
 
-* ``gram_pallas`` / ``gram_colsum_pallas`` — tiled XᵀX with the mask (or
-  n_valid boundary) fused into the load, accumulators VMEM-resident.
+* ``gram_pallas`` — tiled XᵀX with the mask fused into the load (float32
+  compute: ``ops/gram.local_stats``).
+* ``gram_colsum_pallas`` — the PCA fold on the chip: count, column sums
+  and XᵀX from ONE read of the batch, the (d, d) accumulator VMEM-resident
+  and seedable from the donated state. Called by ``ops/gram.streaming_update``
+  where its gate holds (``fit_pca_stream``, the daemon's ``PCAJob.fold``,
+  the scaler fits that ride it); float32 rows are cast to the compute
+  dtype in VMEM. The mask is a row count: each shard's valid rows are a
+  prefix of the shard.
 * ``assign_min_dist_pallas`` / ``lloyd_step_pallas`` — KMeans assignment
   (+ fused centroid-sum update and cost): distance tile + argmin fused,
   never materializing the (m, k) distance matrix in HBM; the latter also
@@ -29,6 +36,7 @@ here (f32 min tile (8, 128); MXU 128×128).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -120,33 +128,65 @@ def gram_pallas(
 # ---------------------------------------------------------------------------
 
 
-# Defaults shared with the streaming-path applicability gate (ops/gram.py).
-GRAM_COLSUM_BLOCK_N = 512
+# Shared with the fold's gate (ops/gram.py `_fused_fold_applicable`), so the
+# two cannot drift.
 GRAM_COLSUM_VMEM_BUDGET = 64 * 2**20  # max (d, d) f32 resident accumulator
+GRAM_COLSUM_ROW_MULTIPLE = 512  # the fold's gate: shard rows in multiples of this
+GRAM_COLSUM_TILE_BYTES = 4 * 2**20  # a row block's float32 tile
+GRAM_COLSUM_MAX_BLOCK_N = 2048
 
 
-def _gram_colsum_kernel(nvalid_ref, x_ref, *refs, block_n, seeded):
+def gram_colsum_block_n(d: int, n: int) -> int:
+    """The kernel's row block for n rows of width d: the largest power of
+    two that divides n and whose (block, d) float32 tile is within 4 MiB,
+    at most 2,048 rows — 512 at d = 2048. Chosen on a v5e from a scratch
+    loop of 65,536-row folds onto a seeded state (host clock over 60 folds,
+    PERF.md §6, PR 31): at d = 2048 a fold took 2.923 ms in 512-row blocks,
+    2.935 at 256, 2.996 at 128 and 4.62 at 1,024 (XLA's dot and its
+    separate column sum: 3.954); at d = 4096 11.47 at 256 against 12.83 at
+    512; at d = 256 0.0958 at 2,048 against 0.118 at 512 and 0.0992 at
+    4,096. A call's first block is fetched with nothing to overlap it and
+    the (d, d) accumulator's read-modify-write is paid once a block, so
+    neither the smallest nor the largest block is the fastest; Mosaic
+    unrolls the block's product, so the build time grows with the block as
+    well."""
+    rows = max(8, min(GRAM_COLSUM_MAX_BLOCK_N, GRAM_COLSUM_TILE_BYTES // (4 * d)))
+    block = 1 << (rows.bit_length() - 1)
+    while n % block and block > 8:
+        block //= 2
+    return block
+
+
+def _gram_colsum_kernel(
+    nvalid_ref, x_ref, *refs, block_n, n_rows, seeded, compute_dtype
+):
     if seeded:
         g0_ref, cs0_ref, c0_ref, g_ref, cs_ref, c_ref = refs
     else:
         g_ref, cs_ref, c_ref = refs
 
+    row0 = pl.program_id(0) * block_n
+    nv = nvalid_ref[0]
+
     @pl.when(pl.program_id(0) == 0)
     def _init():
+        # The row count is one add a call, as the XLA body's `count + c`.
+        lane = jax.lax.broadcasted_iota(jnp.int32, c_ref.shape, 1)
+        rows = jnp.clip(nv, 0, n_rows).astype(jnp.float32)
+        rows = jnp.where(lane == 0, rows, 0.0)
         if seeded:
             # Accumulators start from the caller's streaming state, so the
             # whole per-batch update (state + batch stats) is ONE dispatch
             # with no separate add kernel reading the (d, d) state again.
-            g_ref[:] = g0_ref[:]
-            cs_ref[:] = cs0_ref[:]
-            c_ref[:] = c0_ref[:]
+            # The Gram comes straight from HBM into the output's buffer
+            # (its operand is aliased to the output): no second (d, d)
+            # block in VMEM.
+            pltpu.sync_copy(g0_ref, g_ref)
+            c_ref[:] = c0_ref[:] + rows
         else:
             g_ref[:] = jnp.zeros_like(g_ref)
-            cs_ref[:] = jnp.zeros_like(cs_ref)
-            c_ref[:] = jnp.zeros_like(c_ref)
-
-    row0 = pl.program_id(0) * block_n
-    nv = nvalid_ref[0]
+            c_ref[:] = rows
+        cs_ref[:] = jnp.zeros_like(cs_ref)
 
     # Blocks entirely past n_valid contribute nothing — skip their GEMM
     # (power-of-two bucketing can make half the blocks pure padding).
@@ -159,55 +199,76 @@ def _gram_colsum_kernel(nvalid_ref, x_ref, *refs, block_n, seeded):
             rows = jax.lax.broadcasted_iota(jnp.int32, x_ref.shape, 0) + row0
             x_ref[:] = jnp.where(rows < nv, x_ref[:], jnp.zeros_like(x_ref))
 
-        xb = x_ref[:]
+        # x may arrive wider than the compute dtype (a float32 batch under
+        # the bfloat16 profile): the tile is cast HERE, in VMEM, so the
+        # narrow copy of the batch is never written to HBM and read back.
+        xb = x_ref[:].astype(compute_dtype)
         g_ref[:] += jax.lax.dot_general(
             xb, xb, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
             precision=_dot_prec(xb.dtype),
         )
+        # float32 sums of the tile AFTER the cast: the Gram and the mean it
+        # is centred with are statistics of the same rounded rows.
         cs_ref[:] += jnp.sum(xb.astype(jnp.float32), axis=0, keepdims=True)
-        lane = jax.lax.broadcasted_iota(jnp.int32, c_ref.shape, 1)
-        valid = jnp.minimum(nv - row0, block_n).astype(jnp.float32)
-        c_ref[:] += jnp.where(lane == 0, valid, 0.0)
+
+    if seeded:
+        # The call's column sums meet the seed in ONE add, after the last
+        # block, as the XLA body's `colsum + s`: a block's partial sums are
+        # then not rounded at the magnitude of a fit's running total.
+        @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
+        def _seed_colsum():
+            cs_ref[:] += cs0_ref[:]
 
 
 @functools.partial(
-    ledgered_jit, "pallas.gram_colsum_pallas", static_argnames=("block_n", "interpret")
+    ledgered_jit, "pallas.gram_colsum_pallas",
+    static_argnames=("block_n", "compute_dtype", "interpret"),
 )
 def gram_colsum_pallas(
     x: jax.Array,
     n_valid: jax.Array,
-    block_n: int = GRAM_COLSUM_BLOCK_N,
+    block_n: Optional[int] = None,
     state=None,
+    compute_dtype=None,
     interpret: bool = False,
 ):
     """One-HBM-pass fused count + column sum + XᵀX of the first ``n_valid``
-    rows — the full streaming-moment statistic in a single kernel.
+    rows — the full streaming-moment statistic in a single kernel, and the
+    body of the PCA fold on the chip: ``ops/gram.streaming_update`` calls it
+    where its gate holds (``fit_pca_stream``, the daemon's ``PCAJob.fold``
+    and the scaler fits that ride it, the benchmark's three PCA cells).
 
-    x: (n, d) in the compute dtype (bfloat16 engages the MXU at full rate;
-    the GEMM accumulates in float32 either way). Rows ≥ n_valid are treated
-    as absent — this replaces the (n,) mask array of ``gram_pallas`` with a
-    scalar, so no mask ever touches HBM and only the boundary block pays
-    any select cost. The (d, d) accumulator lives in VMEM across the whole
+    x: (n, d) in ``compute_dtype`` OR wider (float32 rows under the
+    bfloat16 profile): each (block_n, d) tile is cast in VMEM, so a caller
+    never writes a narrow copy of x to HBM. ``compute_dtype`` None means
+    x's own. The GEMM is compute dtype x compute dtype with float32
+    accumulation; the column sums are float32 sums of the tile after the
+    cast. **The mask is a row count**: rows ≥ n_valid are treated as absent,
+    so the valid rows must be a prefix of x — no mask array touches HBM,
+    only the boundary block pays a select, and whole blocks of padding skip
+    their GEMM. The (d, d) accumulator lives in VMEM across the whole
     row-grid (grid is 1-D over row blocks), so X is read exactly once —
     the streaming equivalent of the reference's dgemmCov hot loop
     (rapidsml_jni.cu:109-127) with its mean-stats pass fused in.
 
     ``state``: optional ``(gram, colsum, count)`` f32 streaming state the
-    accumulators are SEEDED from (loaded into VMEM at the first grid step),
+    accumulators are SEEDED from (the Gram copied from HBM into the
+    accumulator at the first grid step, its buffer aliased to the output),
     so the per-batch ``state += batch_stats`` of the streaming fit is this
-    one dispatch — the separate XLA add that re-read and re-wrote the
-    (d, d) state per batch is gone (ops/gram.streaming_update_rows consumes
-    this under donation on single-data-device meshes).
+    one dispatch — no separate XLA add re-reads and re-writes the (d, d)
+    state per batch (``streaming_update`` does this under donation on
+    single-data-device meshes).
 
     Returns (gram (d, d) float32, colsum (d,) float32, count () float32 —
-    exact up to 2^24 rows per accumulator lifetime).
+    one float32 add of min(n_valid, n) a call, as exact as the XLA body's).
     """
     n, d = x.shape
-    bn = min(block_n, n)
+    bn = min(block_n or gram_colsum_block_n(d, n), n)
     if n % bn:
         raise ValueError(f"n={n} not divisible by block_n={bn}")
     if d * d * 4 > GRAM_COLSUM_VMEM_BUDGET:
         raise ValueError(f"d={d}: (d, d) f32 accumulator exceeds the VMEM budget")
+    cd = jnp.dtype(compute_dtype) if compute_dtype is not None else x.dtype
     nv = jnp.asarray(n_valid, jnp.int32).reshape((1,))
     seeded = state is not None
     extra_in = []
@@ -217,16 +278,20 @@ def gram_colsum_pallas(
         extra_in = [
             g0.astype(jnp.float32),
             cs0.astype(jnp.float32).reshape(1, d),
-            jnp.zeros((1, 128), jnp.float32)
-            .at[0, 0].set(jnp.asarray(c0, jnp.float32)),
+            # every lane holds the count; the kernel adds to lane 0, which
+            # is the one read back
+            jnp.broadcast_to(jnp.asarray(c0, jnp.float32).reshape(1, 1), (1, 128)),
         ]
         extra_specs = [
-            pl.BlockSpec((d, d), lambda i, nv: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, d), lambda i, nv: (0, 0)),
             pl.BlockSpec((1, 128), lambda i, nv: (0, 0)),
         ]
     gram, colsum, count = pl.pallas_call(
-        functools.partial(_gram_colsum_kernel, block_n=bn, seeded=seeded),
+        functools.partial(
+            _gram_colsum_kernel, block_n=bn, n_rows=n, seeded=seeded,
+            compute_dtype=cd,
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n // bn,),
@@ -242,6 +307,8 @@ def gram_colsum_pallas(
             jax.ShapeDtypeStruct((1, d), jnp.float32),
             jax.ShapeDtypeStruct((1, 128), jnp.float32),
         ],
+        # operand 2 (after n_valid and x) is the seed's Gram
+        input_output_aliases={2: 0} if seeded else {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # (d, d) f32 accumulator + double-buffered input blocks; the
@@ -1266,7 +1333,7 @@ def linreg_stats_pallas(
     x: jax.Array,
     y: jax.Array,
     mask: jax.Array,
-    block_n: int = GRAM_COLSUM_BLOCK_N,
+    block_n: int = 512,
     interpret: bool = False,
 ):
     """One-HBM-pass fused (XᵀX, Xᵀy, Σx, Σy, Σy², n) over masked rows —

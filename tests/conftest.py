@@ -75,6 +75,32 @@ def rng():
     return np.random.default_rng(42)
 
 
+@pytest.fixture
+def gram_fused_on_cpu(monkeypatch):
+    """Steer `gram.streaming_update` onto its one-read kernel here: the gate
+    is told the backend is a TPU and `gram_colsum_pallas` runs in interpret
+    mode. The program has no option for this (ROADMAP D5): the test does
+    it. Build the fold with `_streaming_update_cached(mesh, "bfloat16",
+    "float32", True)` — the `auto` profile on the chip."""
+    from spark_rapids_ml_tpu import config
+    from spark_rapids_ml_tpu.ops import gram as gram_ops
+    from spark_rapids_ml_tpu.ops import pallas_kernels as pk
+
+    calls = []
+    kernel = pk.gram_colsum_pallas
+
+    def spy(x, n_valid, **kw):
+        calls.append({"seeded": kw.get("state") is not None, "x_dtype": x.dtype,
+                      "compute_dtype": kw.get("compute_dtype")})
+        return kernel(x, n_valid, interpret=True, **kw)
+
+    monkeypatch.setattr(config, "backend_is_tpu", lambda: True)
+    monkeypatch.setattr(pk, "gram_colsum_pallas", spy)
+    gram_ops._streaming_update_cached.cache_clear()
+    yield calls
+    gram_ops._streaming_update_cached.cache_clear()
+
+
 # ---------------------------------------------------------------------------
 # Shared subprocess daemon workers (VERDICT carry #7: test wall clock).
 # The recovery/chaos/fleet/elastic flagships each need real OS-process

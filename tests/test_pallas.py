@@ -97,31 +97,89 @@ def test_gram_colsum_block_validation(rng):
         gram_colsum_pallas(x, 100, block_n=64, interpret=True)
 
 
-def test_streaming_update_rows_matches_mask_path(rng):
-    """streaming_update_rows (scalar n_valid) == streaming_update (mask array)
-    on a multi-device CPU mesh, including a partial boundary batch."""
-    import jax.numpy as jnp
+def _bf16_stats(x, n_valid):
+    """The plain statistic the fold is held to: rows rounded to bfloat16,
+    everything after that in float64."""
+    xv = np.asarray(jnp.asarray(x[:n_valid]).astype(jnp.bfloat16).astype(jnp.float32),
+                    np.float64)
+    return float(n_valid), xv.sum(axis=0), xv.T @ xv
 
+
+def _assert_stats(got, want, scale_rows):
+    """(count, colsum, gram) against `_bf16_stats`: the count exactly, the
+    sums to float32 accumulation order of the same bfloat16 operands."""
+    assert float(got[0]) == want[0]
+    np.testing.assert_allclose(np.asarray(got[1]), want[1], rtol=0,
+                               atol=2e-6 * max(scale_rows, 1) ** 0.5 * 8)
+    np.testing.assert_allclose(np.asarray(got[2]), want[2], rtol=0,
+                               atol=2e-6 * max(scale_rows, 1) * 4)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("seeded", [False, True], ids=["unseeded", "seeded"])
+@pytest.mark.parametrize("n_valid", [1024, 700, 512, 300, 0],
+                         ids=["all", "boundary", "edge", "padding_block", "none"])
+def test_gram_colsum_casts_float32_rows_in_the_kernel(rng, seeded, n_valid):
+    """float32 rows, bfloat16 compute: the tile is cast inside the kernel,
+    the Gram AND the column sums are of the rounded rows — all rows, a
+    boundary inside a block, on a block's edge, a whole block of padding,
+    no rows — onto zeros and onto a seeded state."""
+    from spark_rapids_ml_tpu.ops.pallas_kernels import gram_colsum_pallas
+
+    n, d = 1024, 128
+    x = (rng.normal(size=(n, d)) * 3 + 1).astype(np.float32)
+    x[n_valid:] = 7.0  # padding that would show in every statistic
+    c0, cs0, g0 = 37.0, rng.normal(size=(d,)).astype(np.float32), \
+        rng.normal(size=(d, d)).astype(np.float32)
+    state = (jnp.asarray(g0), jnp.asarray(cs0), jnp.asarray(c0, jnp.float32))
+    g, cs, cnt = gram_colsum_pallas(
+        jnp.asarray(x), n_valid, block_n=256, compute_dtype="bfloat16",
+        state=state if seeded else None, interpret=True)
+    want = _bf16_stats(x, n_valid)
+    if seeded:
+        want = (want[0] + c0, want[1] + cs0, want[2] + g0)
+    _assert_stats((cnt, cs, g), want, n_valid)
+    # ...and NOT of the unrounded rows: the cast happened
+    if n_valid >= 300:
+        xv = x[:n_valid].astype(np.float64)
+        assert np.abs(np.asarray(g) - (g0 if seeded else 0) - xv.T @ xv).max() > 0.05
+
+
+@pytest.mark.parametrize("n_dev", [1, 8], ids=["one_device", "eight_devices"])
+def test_streaming_update_fused_matches_its_xla_body(gram_fused_on_cpu, rng, devices, n_dev):
+    """`streaming_update` with the gate held (the kernel in interpret mode)
+    against its XLA body over several accumulating batches, the padding
+    straddling the last-but-one shard: seeded on one data device, unseeded
+    with the three psums across eight."""
     from spark_rapids_ml_tpu.ops import gram as gram_ops
     from spark_rapids_ml_tpu.parallel.mesh import make_mesh
 
-    mesh = make_mesh(model=1)
-    n_dev = mesh.shape["data"]
-    m, d = 16 * n_dev, 32
-    x = rng.normal(size=(m, d)).astype(np.float32)
-    n_valid = m - 5  # straddles the last shard
-
-    upd_rows = gram_ops.streaming_update_rows(mesh)
-    upd_mask = gram_ops.streaming_update(mesh)
+    mesh = make_mesh(data=n_dev, model=1, devices=devices[:n_dev])
+    m, d = 512 * max(n_dev, 3), 128
+    n_valid = m - 512 - 100  # a part block (shard), then a block (shard) of padding
     mask = (np.arange(m) < n_valid).astype(np.float32)
-
-    s_rows = gram_ops.init_stats(d)
-    s_mask = gram_ops.init_stats(d)
-    for _ in range(3):
-        s_rows = upd_rows(s_rows, jnp.asarray(x), n_valid)
-        s_mask = upd_mask(s_mask, jnp.asarray(x), jnp.asarray(mask))
-    for a, b in zip(s_rows, s_mask):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-4)
+    assert gram_ops._fused_fold_applicable((m // n_dev, d), "bfloat16", True)
+    fused = gram_ops._streaming_update_cached(mesh, "bfloat16", "float32", True)
+    xla = gram_ops._streaming_update_cached(mesh, "bfloat16", "float32", False)
+    s_f = gram_ops.init_stats(d, accum_dtype="float32")
+    s_x = gram_ops.init_stats(d, accum_dtype="float32")
+    want = np.zeros(()), np.zeros((d,)), np.zeros((d, d))
+    for i in range(3):
+        x = (rng.normal(size=(m, d)) + 0.5).astype(np.float32)
+        x[n_valid:] = 7.0
+        s_f = fused(s_f, jnp.asarray(x), jnp.asarray(mask))
+        s_x = xla(s_x, jnp.asarray(x), jnp.asarray(mask))
+        want = [a + b for a, b in zip(want, _bf16_stats(x, n_valid))]
+    # traced through the kernel (once for a fresh state, once for a folded one)
+    assert gram_fused_on_cpu and all(
+        c["seeded"] is (n_dev == 1) and c["x_dtype"] == np.float32  # cast in the kernel
+        for c in gram_fused_on_cpu)
+    assert s_f[2].dtype == jnp.float32
+    _assert_stats(s_f, want, 3 * n_valid)
+    # the XLA body (the CPU keeps the cast there and back) gives the same
+    np.testing.assert_array_equal(np.asarray(s_f[0]), np.asarray(s_x[0]))
+    np.testing.assert_allclose(np.asarray(s_f[1]), np.asarray(s_x[1]), rtol=0, atol=1e-3)
+    np.testing.assert_allclose(np.asarray(s_f[2]), np.asarray(s_x[2]), rtol=0, atol=2e-2)
 
 
 @pytest.mark.kernels
@@ -212,50 +270,26 @@ def test_dist_topk_bucket_boundary_dtype_ladder(rng, q):
 
 
 @pytest.mark.kernels
-def test_streaming_update_rows_seeded_kernel_matches_mask_path(rng):
-    """The donated one-dispatch streaming update (state seeded into the
-    kernel, single data device) must match the XLA mask path over several
-    accumulating batches — and the spy proves the seeded branch ran."""
-    import jax
-
+def test_streaming_update_seeds_the_donated_state_into_the_kernel(gram_fused_on_cpu, rng, mesh1):
+    """The donated one-dispatch fold on a single data device: the state
+    goes INTO the kernel (no separate add of the (d, d) state after it),
+    and the donated state's buffers are given up."""
     from spark_rapids_ml_tpu.ops import gram as gram_ops
-    from spark_rapids_ml_tpu.ops import pallas_kernels as pk
-    from spark_rapids_ml_tpu.parallel.mesh import make_mesh
 
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
     m, d = 512, 128
     x = rng.normal(size=(m, d)).astype(np.float32)
     n_valid = m - 100
-    ran = {"seeded": False}
-    orig_ok = gram_ops._pallas_rows_applicable
-    orig_kernel = pk.gram_colsum_pallas
-
-    def spy(xx, nv, block_n=pk.GRAM_COLSUM_BLOCK_N, state=None,
-            interpret=False):
-        ran["seeded"] |= state is not None
-        return orig_kernel(xx, nv, block_n=block_n, state=state,
-                           interpret=True)
-
-    gram_ops._pallas_rows_applicable = lambda shape, cd, use_pallas=None: True
-    pk.gram_colsum_pallas = spy
-    try:
-        gram_ops._streaming_update_rows_cached.cache_clear()
-        upd = gram_ops._streaming_update_rows_cached(
-            mesh, "float32", "float32", True
-        )
-        s = gram_ops.init_stats(d, accum_dtype="float32")
-        for _ in range(3):
-            s = upd(s, jnp.asarray(x), n_valid)
-        s = [np.asarray(v) for v in s]
-    finally:
-        gram_ops._pallas_rows_applicable = orig_ok
-        pk.gram_colsum_pallas = orig_kernel
-        gram_ops._streaming_update_rows_cached.cache_clear()
-    assert ran["seeded"], "the seeded one-dispatch branch never ran"
-    xv = x[:n_valid]
-    np.testing.assert_allclose(s[0], 3.0 * n_valid)
-    np.testing.assert_allclose(s[1], 3 * xv.sum(0), rtol=1e-5, atol=1e-3)
-    np.testing.assert_allclose(s[2], 3 * (xv.T @ xv), rtol=1e-5, atol=1e-2)
+    mask = (np.arange(m) < n_valid).astype(np.float32)
+    upd = gram_ops._streaming_update_cached(mesh1, "bfloat16", "float32", True)
+    s = gram_ops.init_stats(d, accum_dtype="float32")
+    for _ in range(3):
+        donated = s
+        s = upd(s, jnp.asarray(x), jnp.asarray(mask))
+    assert gram_fused_on_cpu and all(c["seeded"] for c in gram_fused_on_cpu), \
+        "the seeded one-dispatch branch never ran"
+    assert donated[2].is_deleted()
+    want = _bf16_stats(x, n_valid)
+    _assert_stats(s, [3 * w for w in want], 3 * n_valid)
 
 
 def test_assign_parity(rng):

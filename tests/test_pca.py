@@ -361,3 +361,122 @@ def test_legacy_model_resave_roundtrip(rng, mesh8, tmp_path):
     again = PCAModel.load(path)
     assert again.explainedVariance is None
     np.testing.assert_allclose(again.pc, model.pc)
+
+
+# ---------------------------------------------------------------------------
+# The fold's one-read kernel (ops/gram.streaming_update): gate, counter, and
+# the mask contract of the two fit paths that call it
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("tpu,use_pallas,cd,rows,d,want", [
+    (False, True, "bfloat16", 65536, 2048, False),  # the CPU, as every other test runs
+    (True, True, "bfloat16", 65536, 2048, True),    # the benchmark's fold on the chip
+    (True, True, "bfloat16", 16384, 2048, True),    # Spark's 10,000 rows in their bucket
+    (True, True, "bfloat16", 4096, 4096, True),     # the widest accumulator VMEM holds
+    (True, False, "bfloat16", 65536, 2048, False),  # use_pallas off
+    (True, True, "float32", 65536, 2048, False),    # float32 compute keeps gram_pallas / XLA
+    (True, True, "bfloat16", 65536, 200, False),    # d off the 128-lane grid
+    (True, True, "bfloat16", 65536, 8192, False),   # a (d, d) accumulator over VMEM
+    (True, True, "bfloat16", 1000, 2048, False),    # shard rows not in whole blocks
+], ids=["cpu", "chip", "chip_small_fold", "chip_d4096", "pallas_off", "float32_compute",
+        "odd_width", "d8192", "ragged_rows"])
+def test_fused_fold_gate(monkeypatch, tpu, use_pallas, cd, rows, d, want):
+    from spark_rapids_ml_tpu.ops import gram as gram_ops
+
+    monkeypatch.setattr(config, "backend_is_tpu", lambda: tpu)
+    assert gram_ops._fused_fold_applicable((rows, d), cd, use_pallas) is want
+
+
+def _fold_paths():
+    from spark_rapids_ml_tpu.utils import metrics
+
+    c = metrics.counter("srml_gram_fold_path_total")
+    return {p: c.value(path=p) for p in ("fused", "xla")}
+
+
+@pytest.mark.parametrize("d,path", [(128, "fused"), (72, "xla")])
+def test_fold_path_counter_counts_one_a_dispatch(gram_fused_on_cpu, rng, mesh1, d, path):
+    """`srml_gram_fold_path_total{path}`: one a dispatch of
+    `gram.streaming_update`, under the body the program was built with."""
+    import jax.numpy as jnp
+    from spark_rapids_ml_tpu.ops import gram as gram_ops
+
+    m = 1024
+    x = jnp.asarray(rng.normal(size=(m, d)).astype(np.float32))
+    mask = jnp.ones((m,), jnp.float32)
+    upd = gram_ops._streaming_update_cached(mesh1, "bfloat16", "float32", True)
+    s = gram_ops.init_stats(d, accum_dtype="float32")
+    before = _fold_paths()
+    s = upd(s, x, mask)
+    mid = _fold_paths()
+    s = upd(s, x, mask)
+    after = _fold_paths()
+    other = "xla" if path == "fused" else "fused"
+    assert mid[path] - before[path] == 1 and after[path] - mid[path] == 1
+    assert after[other] == before[other]
+    assert bool(gram_fused_on_cpu) is (path == "fused")
+    assert float(s[0]) == 2 * m
+
+
+def _assert_prefix_masks(seen, n_data):
+    """Every shard's mask is ones, then zeros: the fused body's contract."""
+    assert seen
+    for xs, ms in seen:
+        for shard in np.asarray(ms).reshape(n_data, -1):
+            assert set(np.unique(shard)) <= {0.0, 1.0}
+            assert np.all(np.diff(shard) <= 0), "a valid row after a padded one"
+
+
+def _recording(update, seen):
+    def call(state, xs, ms):
+        seen.append((xs, ms))
+        return update(state, xs, ms)
+    return call
+
+
+@pytest.mark.parametrize("path", ["job", "stream"])
+def test_fit_paths_keep_the_mask_contract(gram_fused_on_cpu, monkeypatch, rng, devices, path):
+    """The daemon's `PCAJob.fold` (bucket padding) and `fit_pca_stream`
+    (`shard_rows`) pad at the batch's tail, so every shard's valid rows are
+    a prefix of the shard, and the fold through the one-read kernel gives
+    the state (the model) its XLA body gives."""
+    from spark_rapids_ml_tpu.ops import gram as gram_ops
+
+    mesh = make_mesh(data=2, model=1, devices=devices[:2])
+    d, k = 128, 4
+    scales = np.logspace(0.5, -1.5, d)
+    batches = [(rng.normal(size=(n, d)) * scales + 0.25).astype(np.float32)
+               for n in (700, 1023, 1024)]  # padded to 1,024: 512 rows a shard
+    results = {}
+    for use_pallas in (True, False):
+        seen, kernel_calls = [], len(gram_fused_on_cpu)
+        real = gram_ops.streaming_update
+        monkeypatch.setattr(gram_ops, "streaming_update",
+                            lambda mesh, *a, **kw: _recording(real(mesh, *a, **kw), seen))
+        with config.option("use_pallas", use_pallas), \
+                config.option("compute_dtype", "bfloat16"), \
+                config.option("accum_dtype", "float32"):
+            if path == "job":
+                from spark_rapids_ml_tpu.serve.daemon import _Job
+
+                job = _Job("pca", d, mesh, {})
+                for b in batches:
+                    job.fold(b, None)
+                results[use_pallas] = [np.asarray(a, np.float64) for a in job.state]
+            else:
+                sol = fit_pca_stream(batches[1:], k=k, n_cols=d, mesh=mesh)
+                results[use_pallas] = [np.abs(sol.pc), sol.explained_variance, sol.mean]
+        monkeypatch.setattr(gram_ops, "streaming_update", real)
+        _assert_prefix_masks(seen, 2)
+        assert (len(gram_fused_on_cpu) > kernel_calls) is use_pallas
+    assert not any(c["seeded"] for c in gram_fused_on_cpu)  # two data devices: psum, then add
+    fused, xla = results[True], results[False]
+    if path == "job":
+        assert fused[0] == xla[0] == 700 + 1023 + 1024
+        np.testing.assert_allclose(fused[1], xla[1], rtol=0, atol=1e-3)
+        np.testing.assert_allclose(fused[2], xla[2], rtol=0, atol=2e-2)
+    else:
+        np.testing.assert_allclose(fused[0], xla[0], atol=2e-4)
+        np.testing.assert_allclose(fused[1], xla[1], atol=1e-6)
+        np.testing.assert_allclose(fused[2], xla[2], atol=1e-6)

@@ -367,13 +367,13 @@ def _record(value, steady_compiles=0):
         "value": value,
         "unit": "rows/s/chip",
         "xla": {
-            "warmup": {"gram.streaming_update_rows": {
+            "warmup": {"gram.streaming_update": {
                 "calls": 2, "compiles": 2, "compile_s": 1.2,
                 "cache_misses": 1, "execute_s": 0.0,
                 "flops": 1e9, "bytes": 1e8,
                 "flops_per_s": None, "bytes_per_s": None,
             }},
-            "steady": {"gram.streaming_update_rows": {
+            "steady": {"gram.streaming_update": {
                 "calls": 384, "compiles": steady_compiles,
                 "compile_s": 0.4 if steady_compiles else 0.0,
                 "cache_misses": 1, "execute_s": 0.0,
@@ -415,7 +415,7 @@ def test_perfcheck_fails_on_steady_state_compile_storm():
     # The exemption hatch names the fn explicitly.
     ok, _ = perfcheck.check(
         _record(21.9e6, steady_compiles=7), _HISTORY,
-        allow_compiles=("gram.streaming_update_rows",),
+        allow_compiles=("gram.streaming_update",),
     )
     assert ok
 
@@ -582,9 +582,9 @@ def test_analyze_throwaway_compile_not_booked_to_enclosing_entry():
 
 def test_traced_scalars_share_one_signature_static_values_do_not():
     """jit compiles ONE executable per traced-scalar type — the ledger
-    must mirror that key (gram.streaming_update_rows streams a varying
-    Python n_valid per ragged batch; value-keying fabricated a cache
-    miss and paid a full lower() per batch). Declared-static args keep
+    must mirror that key (a fold that takes a Python n_valid per
+    ragged batch; value-keying fabricated a cache miss and paid a full
+    lower() per batch). Declared-static args keep
     value keys: each value genuinely is its own compiled program."""
     import functools
 
