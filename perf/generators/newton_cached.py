@@ -1,0 +1,232 @@
+"""Binary logistic fits whose rows and labels stay on the chip: the
+daemon's own job object, every Newton pass after the first from its pass
+cache.
+
+The generator drives `serve/daemon.py` `_Job("logreg", d, mesh, {...})` in
+process — the object every wire op calls — and not the TCP wire, as
+`lloyd_cached` does and for its reason: 6.4 GB through a Python `recv`
+would put tens of seconds of host-clock noise into `setup_s`, and in the
+window no op carries a row (PERF.md §4).
+
+*Set-up.* The seeded rows and their labels are made on the device batch by
+batch, fetched, the labels validated as the daemon's `feed` op validates
+them, and fed through `_Job.fold` as partitioned feeds, then `commit`ted
+(the pad copy, the puts and the staging run as for a Spark task); the job
+keeps what its fold placed — rows, mask, label column — as its cached pass.
+The start iterate comes from the seed (`harness/logreg_data.start_iterate`)
+and is installed with `set_iterate`. One whole fit is the warm-up.
+
+*Window.* Fits back to back, closed loop at the device's pace. A fit =
+`set_iterate(start)` → `max_iter` × (`rescan` → `step`) → (w, b) read to the
+host; no further scan (the estimator does none). A `rescan` folds the
+cached batches a group a dispatch (`serve/daemon.py` `_RESCAN_GROUP`), batch
+by batch inside the program; a "fold" in this cell's `fold_device_ms` and
+`fold_roofline` is one such program, and `obs.fold_rows_per_chip` its rows.
+`obs.passes` gets `max_iter` entries a fit, each from before `rescan` until
+`step` has returned: the boundary and its solve are in `fold_rows_per_s`.
+
+*Outside the window.* The job is dropped (its cache freed), the same
+batches are made again on the device and the plain reference runs over them
+from the same start; `harness/agree_logreg.py` compares every fit.
+"""
+
+from __future__ import annotations
+
+import time
+
+import numpy as np
+
+from perf.harness import layout, trace
+
+#: the ledger's name of the program `rescan` dispatches
+#: (`models/logistic_regression.py` `_stream_grad_hess_group_fn`); the
+#: configuration's `fold_program` is its name in a trace
+FOLD_FN = "logreg.streaming_update_group"
+#: the leaves of a pass's state, in the program's order
+STATE = ("gw", "gb", "hww", "hwb", "hbb", "loss", "n")
+
+
+#: a pass is LATE when it took over this many times the window's median pass
+LATE = 1.5
+
+
+def _say_passes(passes, say) -> None:
+    """How long the window's passes took, and each LATE one by its two
+    calls: `rescan` (the dispatch) and `step` (the wait for the folds, the
+    solve, the zero state). What a run's rate spreads by (PERF.md §7)."""
+    took = sorted(pa["end"] - pa["start"] for pa in passes)
+    if not took:
+        return
+    at = lambda q: 1e3 * took[min(len(took) - 1, int(q * len(took)))]
+    median = took[len(took) // 2]
+    late = [pa for pa in passes if pa["end"] - pa["start"] > LATE * median]
+    extra = sum(pa["end"] - pa["start"] - median for pa in late)
+    say(f"a pass: p10 {at(0.1):.2f}, median {at(0.5):.2f}, p90 {at(0.9):.2f}, longest "
+        f"{1e3 * took[-1]:.2f} ms; {len(late)} of {len(took)} over {LATE:g} x the "
+        f"median, {extra:.3f} s beyond it in all ({100 * extra / sum(took):.3f}% of the "
+        "passes' seconds)")
+    for pa in late[:12]:
+        say(f"  late: fit {pa['fit']} pass {pa['pass']}: "
+            f"{1e3 * (pa['end'] - pa['start']):.1f} ms = rescan "
+            f"{1e3 * (pa['scanned'] - pa['start']):.1f} + step "
+            f"{1e3 * (pa['end'] - pa['scanned']):.1f}")
+
+
+def run(ctx):
+    # Before a byte of data is made: a program whose logistic job keeps no
+    # cached pass cannot run this cell, and says so at once.
+    try:
+        from spark_rapids_ml_tpu.models.jobs import job_algorithm
+
+        algorithm = job_algorithm("logreg")
+    except (ImportError, ValueError) as e:
+        raise RuntimeError(f"this program has no table of job algorithms with "
+                           f"'logreg' in it ({e}): the cell newton_cached cannot "
+                           "run on it") from e
+    if not getattr(algorithm, "cacheable", False):
+        raise RuntimeError(
+            "models/logistic_regression.py `LogisticRegressionJob` is not "
+            "`cacheable`: this program keeps no pass cache for the Newton job, "
+            "the cell newton_cached cannot run on it")
+
+    import jax
+
+    from spark_rapids_ml_tpu import config
+    from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, default_mesh, make_mesh
+    from spark_rapids_ml_tpu.serve.daemon import _Job
+
+    cfg, p, obs, say = ctx.config, ctx.params, ctx.obs, ctx.say
+    if cfg["algo"] != "logreg":
+        raise KeyError(f"newton_cached has no fit for algo {cfg['algo']!r}")
+    data = layout.load_module(ctx.root, "harness", "logreg_data")
+    agree = layout.load_module(ctx.root, "harness", "agree_logreg")
+    reference = layout.load_module(ctx.root, "reference", "logreg")
+
+    d, max_iter = cfg["n_cols"], cfg["max_iter"]
+    step_params = {"reg": cfg["reg"], "fit_intercept": cfg["fit_intercept"]}
+    rows, n_batches, parts = p["batch_rows"], p["cached_batches"], p["partitions"]
+    chips = ctx.cell["chips"]
+    if n_batches % parts:
+        raise ValueError("cached_batches must be a multiple of partitions")
+    mesh = (default_mesh() if len(jax.devices()) == chips
+            else make_mesh(devices=jax.devices()[:chips]))
+    if mesh.shape[DATA_AXIS] != chips:
+        raise RuntimeError(f"mesh {dict(mesh.shape)} does not put {chips} chips on 'data'")
+    cached_rows = n_batches * rows
+    say(f"mesh {dict(mesh.shape)}; a cached pass: {n_batches} batches x {rows} rows = "
+        f"{cached_rows} rows, {cached_rows * d * 4 / chips / 1e9:.2f} GB on each chip; "
+        f"a fit: {max_iter} Newton passes, reg {cfg['reg']:g}")
+
+    job_params = {"n_classes": 2}
+    with config.option("daemon_pass_cache_mb", int(cfg["daemon_pass_cache_mb"])):
+        job = _Job("logreg", d, mesh, job_params)  # reads its budget when made
+    planted = data.spec(ctx.seed, d)
+    start = data.start_iterate(ctx.seed, planted)
+    job.set_iterate(start, 0)
+    per_part = n_batches // parts
+    for i in range(n_batches):
+        x, y = (np.asarray(a) for a in data.device_rows(planted, ctx.seed, i, rows))
+        algorithm.check_labels(job_params, y)  # as `feed` does before the job sees them
+        job.fold(x, y, partition=i // per_part, pass_id=0)
+        if (i + 1) % per_part == 0:
+            job.commit(i // per_part, pass_id=0)
+    del x, y
+    ack = job.cache_ack()
+    if not ack.get("cached") or ack["cached_rows"] != cached_rows:
+        raise RuntimeError(f"the job did not keep the pass it was fed: {ack} "
+                           f"(budget {cfg['daemon_pass_cache_mb']} MiB a device)")
+    ctx.stage(f"{n_batches} batches and their labels made on the device, fetched, fed and "
+              f"committed in {parts} partitions; the job holds {job.pass_cache_bytes} "
+              "bytes a device")
+
+    def one_fit(index: int):
+        """→ (passes, model): the timed passes, and what the comparison
+        reads — device references until the fit is over, so that no pass
+        waits for a copy it does not need."""
+        passes, counted, first, infos = [], [], None, []
+        with ctx.span("set_iterate"):
+            job.set_iterate(start, job.iteration + 1)
+        for it in range(max_iter):
+            begin = time.monotonic()
+            with ctx.span("rescan"):
+                job.rescan(job.iteration)
+            state = job.peek_pass_state()[0]
+            scanned = time.monotonic()
+            with ctx.span("boundary"):
+                infos.append(job.step(step_params))
+            passes.append({"fit": index, "pass": it, "rows": cached_rows, "start": begin,
+                           "scanned": scanned, "end": time.monotonic()})
+            counted.append(state[STATE.index("n")])
+            first = state if first is None else first
+        with ctx.span("model_read"):
+            iterate = job.get_iterate()[0]
+            model = {
+                "w": np.asarray(iterate["w"]),
+                "b": float(np.asarray(iterate["b"]).reshape(-1)[0]),
+                "loss": float(infos[-1]["loss"]),
+                "pass_rows": [float(np.asarray(n)) for n in counted],
+                "pass0": {name: np.asarray(leaf) for name, leaf in zip(STATE, first)},
+                "delta": [float(info["delta"]) for info in infos],
+            }
+        return passes, model
+
+    _, warm = one_fit(-1)  # every program and every argument sharding a fit meets
+    obs.spans.clear()
+    ctx.stage("one whole fit as warm-up; the Newton step's length, by pass: " + ", ".join(
+        f"{i + 1}: {warm['delta'][i]:.3g}" for i in range(max_iter)))
+
+    tracer = trace.TraceWindow(ctx.trace, min(0.5, ctx.seconds / 4),
+                               min(p["trace_s"], ctx.seconds / 2), ctx.out_dir)
+    begin = ctx.begin_window()
+    deadline = obs.window[1]
+    ops_per_fit = 1 + 2 * max_iter + 1  # set_iterate, rescans and steps, the read
+    with tracer:
+        index = 0
+        while time.monotonic() < deadline:
+            passes, model = one_fit(index)
+            obs.attempted += ops_per_fit
+            obs.passes += passes
+            obs.fits.append({"fit": index, "rows": cached_rows * max_iter,
+                             "end": time.monotonic(), "model": model})
+            index += 1
+    ctx.end_window()
+    say(f"window closed after {time.monotonic() - begin:.2f} s: {len(obs.fits)} fits, "
+        f"{len(obs.passes)} passes")
+    _say_passes(obs.passes, say)
+    obs.trace = tracer.reduced(obs.spans)
+    # A fold program's rows, as the program counted them: `rescan` folds its
+    # cached batches a group a dispatch, and the group is the program's to choose.
+    folded = obs.counter_delta("srml_daemon_pass_rows_total", source="cache")
+    refed = obs.counter_delta("srml_daemon_pass_rows_total", source="wire")
+    dispatched = obs.counter_delta("srml_xla_calls_total", fn=FOLD_FN)
+    if dispatched > 0:
+        obs.fold_rows_per_chip = int(round(folded / dispatched)) // chips
+        say(f"a fold program folds {obs.fold_rows_per_chip * chips} rows "
+            f"({obs.fold_rows_per_chip * chips // rows} cached batches a dispatch)")
+    if folded + refed > 0:
+        say(f"rows folded in the window: {folded:.0f} from the cache, {refed:.0f} from "
+            f"the wire ({100.0 * folded / (folded + refed):.6g}% cached)")
+
+    # Outside the window: free the program's rows, make them again, and run
+    # the plain reference over them from the same start.
+    job.release()
+    del job
+    batches = [data.device_rows(planted, ctx.seed, i, rows) for i in range(n_batches)]
+    ref = reference.fit(batches, start, max_iter, cfg["tol"], cfg["reg"],
+                        cfg["fit_intercept"])
+    del batches
+    tol = cfg["tolerances"]
+    problems = agree.check_fits(obs.fits, ref, tol, cached_rows, say)
+    if refed:
+        problems.append(f"{refed:.0f} rows were fed again inside the window")
+    if not any(pa["end"] <= deadline for pa in obs.passes):
+        problems.append("no pass completed inside the window")
+    obs.compared = {**agree.compared(obs.fits, tol, cached_rows),
+                    "rows_refed_in_window": [float(refed), 0.0]}
+    for problem in problems[:20]:
+        say(f"  DISAGREES: {problem}")
+    if problems:
+        obs.correct = False
+    for fit in obs.fits:
+        fit.pop("model", None)
+    return obs

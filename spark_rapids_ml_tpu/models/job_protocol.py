@@ -42,6 +42,7 @@ class JobAlgorithm:
     #: is the rows themselves, on the host (the daemon's ``_RowsJob``).
     mergeable = True
     #: May keep its pass on the device for ``rescan``: gives ``fold_group``.
+    #: Ask ``cacheable_for(params)``: a class may say less for some params.
     cacheable = False
     #: Name of the span the job opens around a pass boundary, or None.
     boundary_span: Optional[str] = None
@@ -84,6 +85,13 @@ class JobAlgorithm:
     def check_first_batch(cls, params: Dict[str, Any], x) -> None:
         """Refuse the batch that would create the job."""
 
+    @classmethod
+    def cacheable_for(cls, params: Dict[str, Any]) -> bool:
+        """Whether a job of these params may keep its pass on the device:
+        the one answer, for the daemon's job and for the driver that
+        decides whether to ask the caches at all."""
+        return cls.cacheable
+
     def feed_mismatch(self, params: Dict[str, Any]) -> Optional[str]:
         """Why a feed's params are not this job's ("has …; feed carried …")."""
         return None
@@ -121,17 +129,28 @@ class JobAlgorithm:
         """A pass's empty statistics at the installed iterate (*dispatches*)."""
         raise NotImplementedError
 
-    def fold(self, state, xs, ms, y=None, n: int = 0,
-             partition: Optional[int] = None, offset: int = 0):
-        """Fold one placed batch (``xs`` padded rows, ``ms`` its mask, ``y``
-        the ``n`` host labels) against the iterate. ``offset``: the rows its
-        stage — the pass, for a direct feed — held before (*dispatches*)."""
+    def place_columns(self, target: int, y=None, n: int = 0,
+                      partition: Optional[int] = None, offset: int = 0) -> tuple:
+        """The per-row device columns this batch's fold reads beside
+        ``(xs, ms)`` — the ``n`` host labels ``y``, keys minted from
+        ``(partition, offset)`` (``offset``: the rows the batch's stage —
+        the pass, for a direct feed — held before) — each padded to
+        ``target`` rows and placed under the row sharding; ``()`` where the
+        fold reads none. With ``xs`` and ``ms`` they are the batch as the
+        pass cache keeps it (*dispatches*)."""
+        return ()
+
+    def fold(self, state, xs, ms, columns: tuple = (), n: int = 0):
+        """Fold one placed batch (``xs`` padded rows, ``ms`` its mask,
+        ``columns`` what ``place_columns`` gave for it, ``n`` its true
+        rows) against the iterate (*dispatches*)."""
         raise NotImplementedError
 
-    def fold_group(self, state, xs: tuple, ms: tuple):
-        """``fold`` over a run of placed batches of one shape in one program;
-        ``rescan`` calls it back to back under ONE hold of the device lock:
-        nothing else belongs here (*dispatches*)."""
+    def fold_group(self, state, xs: tuple, ms: tuple, columns: tuple = ()):
+        """``fold`` over a run of placed batches of one shape in one program
+        — ``columns`` holds, for each column ``place_columns`` gives, the
+        run's tuple of it; ``rescan`` calls it back to back under ONE hold
+        of the device lock: nothing else belongs here (*dispatches*)."""
         raise NotImplementedError
 
     def step(self, state, params: Dict[str, Any]) -> Dict[str, Any]:

@@ -639,10 +639,10 @@ class KMeansJob(JobAlgorithm):
     def zero_state(self):
         return stream_zero_state(self.k, self.n_cols, self.accum)
 
-    def fold(self, state, xs, ms, y=None, n=0, partition=None, offset=0):
+    def fold(self, state, xs, ms, columns=(), n=0):
         return self._update(state, self.centers, xs, ms)
 
-    def fold_group(self, state, xs, ms):
+    def fold_group(self, state, xs, ms, columns=()):
         return self._update_group(state, self.centers, xs, ms)
 
     def step(self, state, params):

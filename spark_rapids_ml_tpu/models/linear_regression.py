@@ -361,8 +361,11 @@ class LinearRegressionJob(JobAlgorithm):
     def zero_state(self):
         return init_normal_eq_stats(self.n_cols)
 
-    def fold(self, state, xs, ms, y=None, n=0, partition=None, offset=0):
-        ys = self._place_column(y, xs.shape[0], np.asarray(y).dtype)
+    def place_columns(self, target, y=None, n=0, partition=None, offset=0):
+        return (self._place_column(y, target, np.asarray(y).dtype),)
+
+    def fold(self, state, xs, ms, columns=(), n=0):
+        (ys,) = columns
         return self._update(state, xs, ys, ms)
 
     def finalize(self, state, params, rows, iteration):

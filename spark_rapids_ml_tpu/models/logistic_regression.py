@@ -454,10 +454,11 @@ def fit_logistic_regression(
 
 
 @functools.lru_cache(maxsize=32)
-def _stream_grad_hess_fn(mesh: Mesh, ad: str):
-    """Jitted donated accumulate of one batch's Newton statistics at fixed
-    (w, b): (state, w, b, x, y, mask) -> state with
+def _stream_grad_hess_shard_fn(mesh: Mesh, ad: str):
+    """One batch's Newton statistics at fixed (w, b), added to the running
+    ones under ``shard_map``: (*state, w, b, x, y, mask) -> state with
     state = (gw (d,), gb (), hww (d, d), hwb (d,), hbb (), loss (), n ()).
+    The one body of `_stream_grad_hess_fn` and `_stream_grad_hess_group_fn`.
 
     Raw sums — normalization by n and the L2 term are applied in the
     finalize step once the scan's true row count is known.
@@ -497,7 +498,7 @@ def _stream_grad_hess_fn(mesh: Mesh, ad: str):
                 n + mr.reduce_sum(bn, DATA_AXIS),
             )
 
-    f = jax.shard_map(
+    return jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(), P(), P(), P(),
@@ -505,11 +506,45 @@ def _stream_grad_hess_fn(mesh: Mesh, ad: str):
         out_specs=(P(),) * 7,
     )
 
+
+@functools.lru_cache(maxsize=32)
+def _stream_grad_hess_fn(mesh: Mesh, ad: str):
+    """Jitted donated accumulate of one batch's Newton statistics at fixed
+    (w, b): (state, w, b, x, y, mask) -> state
+    (`_stream_grad_hess_shard_fn`)."""
+    f = _stream_grad_hess_shard_fn(mesh, ad)
+
     @functools.partial(ledgered_jit, "logreg.streaming_update", donate_argnums=(0,))
     def update(state, w, b, x, y, mask):
         return f(*state, w, b, x, y, mask)
 
     return update
+
+
+@functools.lru_cache(maxsize=32)
+def _stream_grad_hess_group_fn(mesh: Mesh, ad: str):
+    """The same accumulate over a GROUP of device-resident batches in one
+    program: (state, w, b, xs, ys, masks) -> state, the three tuples of
+    equal length. Batch by batch, in order, through the one shard function
+    `_stream_grad_hess_fn` runs — the arithmetic of len(xs) calls of it,
+    for one dispatch (the daemon's cached pass, as kmeans'
+    `_stream_group_fn`). The barrier after each batch — over the state AND
+    the iterate the next batch reads — is what keeps it so: without it XLA
+    merges the batches' products over their shared operand `w` and their
+    accumulations, and a group of two is no longer bit-equal to two calls
+    (seen on the CPU, one device and eight). One compiled program per
+    (group length, batch shape)."""
+    f = _stream_grad_hess_shard_fn(mesh, ad)
+
+    @functools.partial(ledgered_jit, "logreg.streaming_update_group",
+                       donate_argnums=(0,))
+    def update_group(state, w, b, xs, ys, masks):
+        for x, y, mask in zip(xs, ys, masks):
+            state, w, b = jax.lax.optimization_barrier(
+                (f(*state, w, b, x, y, mask), w, b))
+        return state
+
+    return update_group
 
 
 @functools.lru_cache(maxsize=64)
@@ -1056,11 +1091,19 @@ class LogisticRegressionJob(JobAlgorithm):
     """Newton passes (binary) or MM-Newton passes (``n_classes`` > 2: the
     same feed/step/finalize op sequence over a per-class state). The
     iterate is (w, b), zero at creation; a pass's statistics are the
-    gradient and Hessian blocks, the loss sum and the row count at it."""
+    gradient and Hessian blocks, the loss sum and the row count at it.
+    A binary job may keep its pass on the device — rows, masks and the
+    label column its fold places — and fold it again by the group (the
+    multinomial one keeps none: ``cacheable_for`` its params is False and
+    every pass of such a fit is fed)."""
 
     name = "logreg"
     needs_labels = True
     iterative = True
+    cacheable = True
+    # what the device waits for between two passes: the wait for the pass's
+    # folds (the loss's read), the Newton solve, the zero state, the snapshot
+    boundary_span = "newton.boundary"
 
     def __init__(self, n_cols, mesh, params):
         super().__init__(n_cols, mesh, params)
@@ -1077,6 +1120,7 @@ class LogisticRegressionJob(JobAlgorithm):
             self.w = jnp.zeros((n_cols,), self.accum)
             self.b = jnp.zeros((), self.accum)
             self._update = _stream_grad_hess_fn(mesh, ad)
+            self._update_group = _stream_grad_hess_group_fn(mesh, ad)
             self._step_fn, self._objective = (
                 _stream_newton_step_fn, stream_objective)
 
@@ -1086,6 +1130,11 @@ class LogisticRegressionJob(JobAlgorithm):
         shared by label validation and the job-mismatch guard so the two
         cannot disagree on the coercion rule."""
         return int(params.get("n_classes") or 2)
+
+    @classmethod
+    def cacheable_for(cls, params) -> bool:
+        # no group program over the multinomial job's per-class state
+        return cls.cacheable and cls.feed_classes(params) <= 2
 
     @classmethod
     def check_labels(cls, params, y):
@@ -1136,17 +1185,29 @@ class LogisticRegressionJob(JobAlgorithm):
                 self.n_cols, self.n_classes, self.accum)
         return stream_zero_state(self.n_cols, self.accum)
 
-    def fold(self, state, xs, ms, y=None, n=0, partition=None, offset=0):
-        ys = self._place_column(y, xs.shape[0], np.float32)
+    def place_columns(self, target, y=None, n=0, partition=None, offset=0):
+        return (self._place_column(y, target, np.float32),)
+
+    def fold(self, state, xs, ms, columns=(), n=0):
+        (ys,) = columns
         return self._update(state, self.w, self.b, xs, ys, ms)
+
+    def fold_group(self, state, xs, ms, columns=()):
+        (ys,) = columns
+        return self._update_group(state, self.w, self.b, xs, ys, ms)
 
     def step(self, state, params):
         reg = float(params.get("reg", 0.0))
         fit_intercept = bool(params.get("fit_intercept", True))
         gw, gb, hww, hwb, hbb, lsum, n = state
         step_fn = self._step_fn(reg, fit_intercept, self.accum.name)
+        # the loss's read is the wait for the pass's folds: before the
+        # solve's span, so that the span holds the solve alone
         loss = self._objective(lsum, n, reg, self.w)
-        self.w, self.b, delta = step_fn(gw, gb, hww, hwb, hbb, n, self.w, self.b)
+        with trace_span("newton.solve"):
+            self.w, self.b, delta = step_fn(
+                gw, gb, hww, hwb, hbb, n, self.w, self.b)
+            delta = float(delta)
         return {"delta": delta, "loss": loss}
 
     def finalize(self, state, params, rows, iteration):

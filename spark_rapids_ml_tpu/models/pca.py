@@ -454,7 +454,7 @@ class PCAJob(JobAlgorithm):
     def zero_state(self):
         return gram_ops.init_stats(self.n_cols)
 
-    def fold(self, state, xs, ms, y=None, n=0, partition=None, offset=0):
+    def fold(self, state, xs, ms, columns=(), n=0):
         return self._update(state, xs, ms)
 
     def finalize(self, state, params, rows, iteration):

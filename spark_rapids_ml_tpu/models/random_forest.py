@@ -503,14 +503,17 @@ class RandomForestJob(JobAlgorithm):
             self.spec.max_bins, self.spec.n_stats, self.accum,
         )
 
-    def fold(self, state, xs, ms, y=None, n=0, partition=None, offset=0):
+    def place_columns(self, target, y=None, n=0, partition=None, offset=0):
         # Bootstrap-bag identity: the batch's rows are (partition,
         # offset..offset+n) — read before this fold, so replays of a
         # restarted stage mint identical keys.
-        target = xs.shape[0]
         ys = self._place_column(y, target, np.float64)
         ks = self._place_column(
             row_identity_keys(partition, offset, n), target, np.uint32)
+        return ys, ks
+
+    def fold(self, state, xs, ms, columns=(), n=0):
+        ys, ks = columns
         return accumulate_histogram(
             state, self.tables, xs, ys, ms, ks, self.spec, self.mesh,
             n_valid=n,

@@ -412,10 +412,11 @@ class _Stage:
         self.rows = rows
         self.nbytes = nbytes
         self.seen: set = set()
-        # Pass cache (off: stays empty): the (xs, ms) this stage's folds
-        # placed, kept until its commit moves them into the job's cached
-        # pass — a losing attempt's are freed with its stage — and their
-        # bytes per device (counted apart from `nbytes`: not back-pressure).
+        # Pass cache (off: stays empty): the batches this stage's folds
+        # placed — (xs, ms) and the algorithm's per-row columns — kept
+        # until its commit moves them into the job's cached pass — a
+        # losing attempt's are freed with its stage — and their bytes per
+        # device (counted apart from `nbytes`: not back-pressure).
         self.batches: list = []
         self.batch_bytes = 0
 
@@ -445,9 +446,12 @@ def _rescan_groups(batches):
 
 
 class _PassCache:
-    """One pass's batches as the fold placed them on the device — the
-    fold's operands ``(xs, ms)``: float32 rows padded to their bucket
-    under the job's row sharding, and the row mask — in commit order.
+    """One pass's batches as the fold placed them on the device, each a
+    tuple of the fold's operands ``(xs, ms, *columns)`` — float32 rows
+    padded to their bucket under the job's row sharding, the row mask,
+    and the per-row columns the algorithm placed beside them
+    (`JobAlgorithm.place_columns`: labels; none for kmeans) — in commit
+    order.
     ``filled_at`` is the pass that fed them; ``pass_rows`` is what that
     pass committed from the wire, set when the pass closed (None while it
     is open): a cached pass answers `rescan` only if it holds exactly
@@ -575,14 +579,15 @@ class _Job:
         self.algorithm = job_algorithm(algo)(n_cols, mesh, params)
         # Pass cache (docs/protocol.md "rescan"; docs/mesh.md "Capacity"):
         # the budget in bytes per device, 0 = off. Written over the fold's
-        # device operands (xs, ms); an algorithm that gives `fold_group`
-        # says `cacheable` and takes it. `_cache_ok` falls for the rest of
+        # device operands — (xs, ms) and the algorithm's per-row columns;
+        # an algorithm that gives `fold_group` for these params says
+        # `cacheable_for` them and takes it. `_cache_ok` falls for the rest of
         # the fit with the first batch that would pass the budget (all or
         # nothing); `_cache_bytes` counts the cached pass and the
         # uncommitted stages' batches together, apart from staged_bytes.
         self._cache_budget = (
             max(int(config.get("daemon_pass_cache_mb")), 0) << 20
-            if self.algorithm.cacheable else 0
+            if self.algorithm.cacheable_for(params) else 0
         )
         self._cache: Optional[_PassCache] = None
         self._cache_ok = self._cache_budget > 0
@@ -652,15 +657,17 @@ class _Job:
             return self.algorithm.zero_state()
 
     def _fold_batch(self, state, xb, mb, y, n, partition, offset):
-        """Place a padded batch and fold it: → (state, xs, ms), the last
-        two the fold's device operands (what the pass cache keeps)."""
+        """Place a padded batch and fold it: → (state, batch), `batch`
+        the fold's device operands ``(xs, ms, *columns)`` (what the pass
+        cache keeps)."""
         with _DEVICE_LOCK:
             xs = jax.device_put(xb, self.x_sharding)
             ms = jax.device_put(mb, self.v_sharding)
-            state = self.algorithm.fold(
-                state, xs, ms, y, n=n, partition=partition, offset=offset
+            columns = self.algorithm.place_columns(
+                xs.shape[0], y, n=n, partition=partition, offset=offset
             )
-        return state, xs, ms
+            state = self.algorithm.fold(state, xs, ms, columns, n=n)
+        return state, (xs, ms, *columns)
 
     def _check_pass(self, pass_id: Optional[int]) -> None:
         """Reject traffic from a zombie task of an earlier pass: its batch
@@ -800,13 +807,13 @@ class _Job:
         for stage in self.staged.values():
             stage.batches, stage.batch_bytes = [], 0
 
-    def _keep_batch(self, stage: Optional[_Stage], xs, ms, n: int) -> None:
+    def _keep_batch(self, stage: Optional[_Stage], batch: tuple, n: int) -> None:
         """Keep the operands a fold has just placed: in the job's cached
         pass (direct feed) or in the stage until its commit."""
         if not self._cache_ok:
             return
         cache = self._open_cache()
-        nbytes = (int(xs.nbytes) + int(ms.nbytes)) // self.n_data
+        nbytes = sum(int(a.nbytes) for a in batch) // self.n_data
         if self._cache_bytes + nbytes > self._cache_budget:
             self._drop_cache()  # this stage's too: fold has published it
             logger.warning(
@@ -817,11 +824,11 @@ class _Job:
             return
         self._cache_bytes += nbytes
         if stage is None:
-            cache.batches.append((xs, ms))
+            cache.batches.append(batch)
             cache.rows += n
             cache.nbytes += nbytes
         else:
-            stage.batches.append((xs, ms))
+            stage.batches.append(batch)
             stage.batch_bytes += nbytes
 
     def _commit_batches(self, partition: int, stage: _Stage) -> None:
@@ -901,11 +908,10 @@ class _Job:
             with trace_span("pass.rescan"):
                 with _DEVICE_LOCK:
                     for group in _rescan_groups(cache.batches):
-                        state = fold_group(
-                            state,
-                            tuple(xs for xs, _ in group),
-                            tuple(ms for _, ms in group),
-                        )
+                        # column-wise: the run's rows, its masks, and a
+                        # tuple a column the algorithm placed
+                        xs, ms, *columns = zip(*group)
+                        state = fold_group(state, xs, ms, tuple(columns))
             self.state = state
             self.committed = dict(cache.committed)
             self.rows += cache.rows
@@ -980,7 +986,7 @@ class _Job:
             # identity counts from it, so a restarted stage replays the
             # same identities.
             offset = stage.rows if stage is not None else self.pass_rows
-            state, xs, ms = self._fold_batch(
+            state, batch = self._fold_batch(
                 state, xb, mb, y, n, partition, offset
             )
             if partition is None:
@@ -1002,7 +1008,7 @@ class _Job:
             if self._cache_budget:
                 if partition is None:
                     self._wire_rows(n)
-                self._keep_batch(stage, xs, ms, n)
+                self._keep_batch(stage, batch, n)
             # Only now — after the device fold succeeded — is the feed_id
             # burned; an id recorded before a failing update would turn
             # the client's replay into a silent ack-without-fold.
@@ -1385,7 +1391,7 @@ class _RowsJob(_Job):
         return []
 
     def _fold_batch(self, state, xb, mb, y, n, partition, offset):
-        return state + [xb], xb, None  # host rows: no device, no device lock
+        return state + [xb], ()  # host rows: no device, no device lock
 
     def _merge_stage(self, partition: int, state) -> None:
         # Keyed by partition (not arrival order) so the finalize

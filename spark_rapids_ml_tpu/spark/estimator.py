@@ -885,12 +885,13 @@ class _SparkAdapter:
         grow = join_policy == "boundary"
         ledger_on = bool(rec_attempts) or elastic or grow
         # Pass cache (docs/protocol.md "rescan"): with a budget configured,
-        # the passes of a kmeans fit after the first are asked of the
-        # daemons' caches (`rescan`), and rows cross the wire once. 0 (the
-        # default) = off: not one op, ack column or branch more than before.
-        want_cache = (
-            algo == "kmeans" and daemon_session.pass_cache_mb(spark) > 0
-        )
+        # the passes after the first of a fit whose job algorithm says
+        # `cacheable_for` its params (models/jobs.py: kmeans, binary
+        # logreg) are asked of the daemons' caches (`rescan`), and rows
+        # cross the wire once. 0 (the default) = off: not one op, ack
+        # column, import or branch more than before. Decided below, once
+        # the fit's feed params are known.
+        want_cache = False
         # What the last FED pass left in the daemons' caches, as its task
         # acks saw it — {"per", "addr_of", "owner", "boots"} — when every
         # commit ack said `cached: true`; empty = the next pass is fed.
@@ -1094,6 +1095,12 @@ class _SparkAdapter:
                     ledger["arrays"], ledger["iteration"] = (
                         client.get_iterate(job)
                     )
+
+            if daemon_session.pass_cache_mb(spark) > 0:
+                from spark_rapids_ml_tpu.models.jobs import job_algorithm
+
+                # the one answer the daemon's job gives itself (`_Job`)
+                want_cache = job_algorithm(wire_algo).cacheable_for(feed_params)
 
             def run_pass(pass_id, merge=True, drop_peer=False):
                 """One executor scan; folds peer-daemon partials into the
@@ -1638,6 +1645,79 @@ class _SparkAdapter:
                         attempt += 1
                         recover(e)
 
+            def cached_pass(pass_id):
+                """One pass asked of the daemons' caches (`rescan`,
+                docs/protocol.md): every daemon that held rows in
+                the last fed pass folds its cached pass against its
+                current iterate, and the peers' partials are merged
+                as after a scan. Each must answer for exactly the
+                rows its tasks acked then, from the incarnation
+                that acked them. Returns the pass total, or None
+                when a daemon has no cached pass: the pass is then
+                fed (daemons that already folded theirs are rewound
+                to this pass's open boundary first)."""
+                nonlocal total_fed
+                view = dict(cache_view)
+                per, addr_of = view["per"], view["addr_of"]
+                asked = 0
+                try:
+                    with trace_span("rescan pass"):
+                        for did in sorted(d for d, c in per.items() if c > 0):
+                            c_ = (
+                                client if did == primary_id
+                                else peer_client(did, addr_of[did])
+                            )
+                            ack = c_.rescan(job, pass_id)
+                            asked += 1
+                            boot = ack.get("boot_id")
+                            seen = view["boots"].get(did) or set()
+                            if boot is not None and seen and str(boot) not in seen:
+                                raise _incarnation_change(
+                                    addr_of.get(did, did),
+                                    set(seen) | {str(boot)},
+                                )
+                            if int(ack["pass_rows"]) != per[did]:
+                                raise _split_brain(
+                                    f"rescan (pass {pass_id}) on "
+                                    f"{addr_of.get(did, did)}", per[did],
+                                    int(ack["pass_rows"]), _fed_detail(),
+                                )
+                except protocol.NoCachedPass as e:
+                    logger.info(
+                        "pass %s is re-fed: %s", pass_id, e
+                    )
+                    cache_view.clear()
+                    if asked:
+                        arrays, iteration = client.get_iterate(job)
+                        reseed(arrays, iteration)
+                    return None
+                with trace_span("merge peers"):
+                    if not _reduce_on_mesh(
+                        client, job, primary_id, per, addr_of,
+                        view["owner"], view["boots"], wire_algo,
+                        feed_params, False, mesh_cache,
+                    ):
+                        _merge_peer_daemons(
+                            client, job, primary_id, per, addr_of,
+                            view["owner"], peer_client, wire_algo,
+                            feed_params, drop_peer=False,
+                        )
+                n = sum(per.values())
+                for did, cnt in per.items():
+                    fed_by_daemon[did] = fed_by_daemon.get(did, 0) + cnt
+                total_fed += n
+                return n
+
+            def scan(pass_id):
+                """This pass's rows into the daemons' statistics:
+                from their caches when the last fed pass left every
+                row there, else over the wire (which refills them)."""
+                if cache_view:
+                    n = cached_pass(pass_id)
+                    if n is not None:
+                        return n
+                return run_pass(pass_id)
+
             if algo == "scaler":
 
                 def scaler_shot():
@@ -1720,79 +1800,6 @@ class _SparkAdapter:
             elif algo == "kmeans":
                 tol2 = core.getTol() ** 2
                 info = {"cost": float("nan"), "iteration": 0}
-
-                def cached_pass(pass_id):
-                    """One pass asked of the daemons' caches (`rescan`,
-                    docs/protocol.md): every daemon that held rows in
-                    the last fed pass folds its cached pass against its
-                    current iterate, and the peers' partials are merged
-                    as after a scan. Each must answer for exactly the
-                    rows its tasks acked then, from the incarnation
-                    that acked them. Returns the pass total, or None
-                    when a daemon has no cached pass: the pass is then
-                    fed (daemons that already folded theirs are rewound
-                    to this pass's open boundary first)."""
-                    nonlocal total_fed
-                    view = dict(cache_view)
-                    per, addr_of = view["per"], view["addr_of"]
-                    asked = 0
-                    try:
-                        with trace_span("rescan pass"):
-                            for did in sorted(d for d, c in per.items() if c > 0):
-                                c_ = (
-                                    client if did == primary_id
-                                    else peer_client(did, addr_of[did])
-                                )
-                                ack = c_.rescan(job, pass_id)
-                                asked += 1
-                                boot = ack.get("boot_id")
-                                seen = view["boots"].get(did) or set()
-                                if boot is not None and seen and str(boot) not in seen:
-                                    raise _incarnation_change(
-                                        addr_of.get(did, did),
-                                        set(seen) | {str(boot)},
-                                    )
-                                if int(ack["pass_rows"]) != per[did]:
-                                    raise _split_brain(
-                                        f"rescan (pass {pass_id}) on "
-                                        f"{addr_of.get(did, did)}", per[did],
-                                        int(ack["pass_rows"]), _fed_detail(),
-                                    )
-                    except protocol.NoCachedPass as e:
-                        logger.info(
-                            "pass %s is re-fed: %s", pass_id, e
-                        )
-                        cache_view.clear()
-                        if asked:
-                            arrays, iteration = client.get_iterate(job)
-                            reseed(arrays, iteration)
-                        return None
-                    with trace_span("merge peers"):
-                        if not _reduce_on_mesh(
-                            client, job, primary_id, per, addr_of,
-                            view["owner"], view["boots"], wire_algo,
-                            feed_params, False, mesh_cache,
-                        ):
-                            _merge_peer_daemons(
-                                client, job, primary_id, per, addr_of,
-                                view["owner"], peer_client, wire_algo,
-                                feed_params, drop_peer=False,
-                            )
-                    n = sum(per.values())
-                    for did, cnt in per.items():
-                        fed_by_daemon[did] = fed_by_daemon.get(did, 0) + cnt
-                    total_fed += n
-                    return n
-
-                def scan(pass_id):
-                    """This pass's rows into the daemons' statistics:
-                    from their caches when the last fed pass left every
-                    row there, else over the wire (which refills them)."""
-                    if cache_view:
-                        n = cached_pass(pass_id)
-                        if n is not None:
-                            return n
-                    return run_pass(pass_id)
 
                 def kmeans_pass(pass_id):
                     n = scan(pass_id)
@@ -1911,7 +1918,7 @@ class _SparkAdapter:
                 rows = 0
 
                 def logreg_pass(pass_id):
-                    n = run_pass(pass_id)
+                    n = scan(pass_id)
                     if n == 0:
                         raise ValueError("cannot fit on an empty DataFrame")
                     with trace_span("step"):

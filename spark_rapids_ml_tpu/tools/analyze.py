@@ -1525,8 +1525,8 @@ _DEVICE_CALL_NAMES = frozenset(
 #: algorithm's device programs through `self.algorithm.<member>(...)`, so
 #: such a call IS a dispatch wherever it stands.
 _ALGORITHM_DISPATCH_MEMBERS = frozenset((
-    "seed", "iterate_arrays", "install_iterate", "zero_state", "fold",
-    "fold_group", "step", "finalize",
+    "seed", "iterate_arrays", "install_iterate", "zero_state",
+    "place_columns", "fold", "fold_group", "step", "finalize",
 ))
 #: Compile-path call targets: host work that must not hold _DEVICE_LOCK.
 _COMPILE_CALL_NAMES = frozenset(

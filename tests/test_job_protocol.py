@@ -197,7 +197,7 @@ class ColumnSums(JobAlgorithm):
     def zero_state(self):
         return jnp.zeros((), self.accum), jnp.zeros((self.n_cols,), self.accum)
 
-    def fold(self, state, xs, ms, y=None, n=0, partition=None, offset=0):
+    def fold(self, state, xs, ms, columns=(), n=0):
         count, colsum = state
         masked = xs.astype(self.accum) * ms.astype(self.accum)[:, None]
         return count + ms.astype(self.accum).sum(), colsum + masked.sum(axis=0)
