@@ -839,6 +839,46 @@ def _case_newton(mesh, d: int, n: int):
     return gate, ["_newton_stats_kernel"], errs, 2.0**-5
 
 
+def _case_newton_fold(mesh, d: int, n: int):
+    """newton_fold_pallas ← models.logistic_regression.fit_logistic_stream:
+    a small streaming fit (two float32 batches, three Newton passes,
+    ``tol=0``) at the benchmark's width, off the lane grid, against the
+    same fit with ``use_pallas`` off; every fold of it counts under
+    ``srml_logreg_fold_path_total{path=fused}``."""
+    from spark_rapids_ml_tpu import config
+    from spark_rapids_ml_tpu.models import logistic_regression as lg
+    from spark_rapids_ml_tpu.utils import metrics
+
+    rng = np.random.default_rng(69)
+    x = rng.standard_normal((2 * n, d), dtype=np.float32)
+    w_true = rng.standard_normal((d,), dtype=np.float32) / np.sqrt(d)
+    y = (rng.uniform(size=2 * n) < 1.0 / (1.0 + np.exp(-(x @ w_true)))).astype(np.float32)
+    passes = 3
+
+    def fit():
+        return lg.fit_logistic_stream(
+            lambda: iter([(x[:n], y[:n]), (x[n:], y[n:])]), d, reg=1e-2,
+            max_iter=passes, tol=0.0, mesh=mesh)
+
+    counter = metrics.counter("srml_logreg_fold_path_total")
+    before = counter.value(path="fused")
+    got = fit()
+    folds = counter.value(path="fused") - before
+    with config.option("use_pallas", False):
+        want = fit()
+    # the fold took the kernel iff the gate said it would
+    gate = (lg._fused_newton_fold_applicable((n, d), np.float32, config.get("accum_dtype"))
+            and folds == 2 * passes)
+    errs = {"w": float(np.linalg.norm(got.coefficients - want.coefficients)
+                       / np.linalg.norm(want.coefficients)),
+            "b": abs(float(got.intercept) - float(want.intercept)),
+            "loss": abs(got.loss - want.loss) / abs(want.loss),
+            "rows": abs(got.n_rows - want.n_rows)}
+    # One arithmetic in two orders of addition: float32 gradient and loss,
+    # a bfloat16 Hessian product on both sides.
+    return gate, ["_newton_fold_kernel"], errs, TOL_F32
+
+
 def _case_softmax(mesh, d: int, n_classes: int, n: int):
     """softmax_curvature_pallas ← models.logistic_regression.
     _stream_softmax_stats_fn (one donated streaming update)."""
@@ -951,7 +991,8 @@ def _case_ivf(mesh, d: int, k: int, n: int, nlist: int, nprobe: int,
 
 
 def kernel_cases(mesh_one, mesh_all) -> List[Tuple[str, Callable[[], tuple]]]:
-    """The eleven cases at each estimator's BASELINE.json width."""
+    """The eleven cases at each estimator's BASELINE.json width (the
+    streaming Newton fold at the benchmark's, d = 3000)."""
     return [
         ("gram_pallas", lambda: _case_gram(mesh_one, 2048, 8192)),
         ("gram_colsum_pallas[seeded]",
@@ -964,6 +1005,7 @@ def kernel_cases(mesh_one, mesh_all) -> List[Tuple[str, Callable[[], tuple]]]:
          lambda: _case_lloyd(mesh_one, 256, 100, 16384, f32=True)),
         ("linreg_stats_pallas", lambda: _case_linreg(mesh_one, 1024, 8192)),
         ("newton_stats_pallas", lambda: _case_newton(mesh_one, 1024, 8192)),
+        ("newton_fold_pallas", lambda: _case_newton_fold(mesh_one, 3000, 4096)),
         ("softmax_curvature_pallas",
          lambda: _case_softmax(mesh_one, 1024, 32, 4096)),
         ("dist_topk_pallas",

@@ -48,6 +48,7 @@ from spark_rapids_ml_tpu.models.job_protocol import JobAlgorithm
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, default_mesh
 from spark_rapids_ml_tpu.parallel import mapreduce as mr
 from spark_rapids_ml_tpu.parallel.sharding import shard_rows
+from spark_rapids_ml_tpu.utils import metrics
 from spark_rapids_ml_tpu.utils.profiling import trace_span
 from spark_rapids_ml_tpu.utils.xprof import ledgered_jit
 
@@ -133,11 +134,15 @@ def _pcg_solve(h, g, x0, max_iter: Optional[int] = None, rtol: float = 1e-2):
 
 
 def _pallas_newton_applicable(shape, cd, ad, use_pallas: Optional[bool] = None) -> bool:
-    """Fused single-HBM-pass Newton step (ops/pallas_kernels.newton_stats_pallas):
-    TPU backend, bfloat16 compute (the speed mode the kernel exists for —
-    at float32 the fusion saves no wall-clock over XLA's lowering), f32
-    accumulate, lane-aligned d, block-divisible rows, VMEM-resident (d, d)
-    Hessian."""
+    """Fused single-HBM-pass Newton step (ops/pallas_kernels.newton_stats_pallas)
+    of the IN-MEMORY fit (`_newton_fn_cached`: the whole loop in one program
+    over rows cast to bfloat16 once, before it): TPU backend, bfloat16
+    compute (the speed mode the kernel exists for — at float32 the fusion
+    saves no wall-clock over XLA's lowering), f32 accumulate, lane-aligned d,
+    block-divisible rows, VMEM-resident (d, d) Hessian. The STREAMING fold
+    (`fit_logistic_stream`, the daemon's job) has its own kernel and gate:
+    `newton_fold_pallas` behind `_fused_newton_fold_applicable`, on float32
+    rows at any width."""
     from spark_rapids_ml_tpu.ops.gram import _pallas_backend_ok
     from spark_rapids_ml_tpu.ops.pallas_kernels import (
         NEWTON_STATS_BLOCK_N,
@@ -453,8 +458,79 @@ def fit_logistic_regression(
 # ---------------------------------------------------------------------------
 
 
+_M_FOLD_PATH = metrics.counter(
+    "srml_logreg_fold_path_total",
+    "Dispatches of the streaming Newton fold (logreg.streaming_update / "
+    "_group) by the body their program was built with: path=fused (one HBM "
+    "read of the batch through newton_fold_pallas) or path=xla (CPU, rows or "
+    "accumulator not float32, ragged shard rows, a width on the 128-lane "
+    "grid, a lane-padded (d, d) accumulator over the kernel's VMEM budget)",
+)
+
+
+def _fused_newton_fold_applicable(
+    shard_shape, x_dtype, ad, use_pallas: Optional[bool] = None
+) -> bool:
+    """`_stream_grad_hess_shard_fn`'s gate for the one-read kernel
+    (ops/pallas_kernels.newton_fold_pallas), by what the code can observe:
+    TPU backend, float32 rows and float32 accumulate (the kernel's gradient
+    and loss are float32 sums of float32 rows), shard rows in multiples of
+    512 (the kernel picks its row block from d and the rows), the
+    lane-padded (dp, dp) float32 Hessian inside the kernel's VMEM budget
+    (constants imported from the kernel so the two cannot drift) — and a
+    width the chip keeps ROWS MINOR. The TPU's default layout of an (m, d)
+    float32 array is the order that pads fewer bytes, row-major on a tie:
+    with m on the lane grid the rows are minor iff ceil(d / 8) · 8 <
+    ceil(d / 128) · 128 (asked of the v5e compiler at 28 shapes, PERF.md
+    §6, PR 33: d = 3000, 200, 1016, 3064 rows minor; 1024, 2048, 1020, 3068
+    row-major). There the kernel's `x.T` is a bitcast and the batch is read
+    as it lies; on the lane grid it would be a transposing copy of the
+    batch first, and the XLA body is as fast without it (eight 65,536-row
+    folds on a v5e: d = 1024 13.98 ms XLA, 14.99 so; d = 2048 38.38, 39.97;
+    but d = 3000 79.7 against 57.0 and d = 200 1.95 against 1.85)."""
+    from spark_rapids_ml_tpu.ops.gram import _pallas_backend_ok
+
+    if not _pallas_backend_ok(use_pallas):
+        return False
+    from spark_rapids_ml_tpu.ops.pallas_kernels import (
+        NEWTON_FOLD_ROW_MULTIPLE,
+        NEWTON_FOLD_VMEM_BUDGET,
+        _ceil_to,
+    )
+
+    m, d = shard_shape
+    dp = _ceil_to(d, 128)
+    return (
+        jnp.dtype(x_dtype) == jnp.dtype(jnp.float32)
+        and jnp.dtype(ad) == jnp.dtype(jnp.float32)
+        and m > 0
+        and m % NEWTON_FOLD_ROW_MULTIPLE == 0
+        and _ceil_to(d, 8) < dp
+        and dp * dp * 4 <= NEWTON_FOLD_VMEM_BUDGET
+    )
+
+
+def _seeded_hessian_width(mesh: Mesh, ad: str, use_pallas: bool, x) -> int:
+    """The lane-padded width dp the fused body keeps its running Hessian at
+    for batches like `x` — where the kernel is SEEDED with it: the gate
+    holds and the mesh has one data device, so no psum sits between a
+    batch's product and the state's add. 0 elsewhere."""
+    from spark_rapids_ml_tpu.ops.pallas_kernels import _ceil_to
+
+    n_data = mesh.shape[DATA_AXIS]
+    fused = _fused_newton_fold_applicable(
+        (x.shape[0] // n_data, x.shape[1]), x.dtype, ad, use_pallas)
+    return _ceil_to(x.shape[1], 128) if fused and n_data == 1 else 0
+
+
+def _pad_hessian(hww, dp: int):
+    """(d, d) -> (dp, dp), zeros past d: exact, and undone by `[:d, :d]`."""
+    d = hww.shape[0]
+    return hww if d == dp else jnp.pad(hww, ((0, dp - d), (0, dp - d)))
+
+
 @functools.lru_cache(maxsize=32)
-def _stream_grad_hess_shard_fn(mesh: Mesh, ad: str):
+def _stream_grad_hess_shard_fn(mesh: Mesh, ad: str, use_pallas: bool = False):
     """One batch's Newton statistics at fixed (w, b), added to the running
     ones under ``shard_map``: (*state, w, b, x, y, mask) -> state with
     state = (gw (d,), gb (), hww (d, d), hwb (d,), hbb (), loss (), n ()).
@@ -462,10 +538,52 @@ def _stream_grad_hess_shard_fn(mesh: Mesh, ad: str):
 
     Raw sums — normalization by n and the L2 term are applied in the
     finalize step once the scan's true row count is known.
+
+    Where `_fused_newton_fold_applicable` holds for a shard's rows (TPU
+    backend, float32 rows and accumulate, whole blocks, a width off the
+    lane grid — d = 3000 — whose lane-padded Hessian fits VMEM) the batch is
+    read from HBM ONCE: `newton_fold_pallas` takes logits, gradient, loss,
+    border and row count in float32 from each float32 tile and casts
+    `x·wgt` and `x` to bfloat16 in VMEM for the Hessian product — this
+    body's arithmetic in another order of additions. It reads the batch as
+    the chip keeps it at such a width, rows minor: `x.T` is a bitcast. On
+    one data device the running Hessian is seeded into the kernel,
+    lane-padded: `hww` may arrive at (d, d) or already at (dp, dp) (the
+    group program pads once) and leaves as it came. Elsewhere — the CPU,
+    the tests' float64 profile, ragged rows, a width on the lane grid
+    (row-major on the chip), d over the budget — the XLA body below runs,
+    unchanged: three reads of the batch. `use_pallas` is the builder-time snapshot of the
+    config flag (part of the cache key, never read inside the trace).
     """
     accum = jnp.dtype(ad)
+    # The kernel adds to the running Hessian itself only where no psum sits
+    # between a batch's product and the state's add: one data device.
+    seed = mesh.shape[DATA_AXIS] == 1
 
-    def shard(gw, gb, hww, hwb, hbb, loss, n, w, b, x, y, mask):
+    def fused_shard(gw, gb, hww, hwb, hbb, loss, n, w, b, x, y, mask):
+        from spark_rapids_ml_tpu.ops.pallas_kernels import _ceil_to, newton_fold_pallas
+
+        d = x.shape[1]
+        dp = _ceil_to(d, 128)
+        if seed:
+            bgw, bgb, h, bhwb, bhbb, bloss, bn = newton_fold_pallas(
+                x.T, y, mask, w, b, hww=_pad_hessian(hww, dp))
+            hww = h if hww.shape[0] == dp else h[:d, :d]
+        else:
+            bgw, bgb, h, bhwb, bhbb, bloss, bn = newton_fold_pallas(
+                x.T, y, mask, w, b)
+            hww = hww + mr.reduce_sum(h[:d, :d], DATA_AXIS)
+        return (
+            gw + mr.reduce_sum(bgw, DATA_AXIS),
+            gb + mr.reduce_sum(bgb, DATA_AXIS),
+            hww,
+            hwb + mr.reduce_sum(bhwb, DATA_AXIS),
+            hbb + mr.reduce_sum(bhbb, DATA_AXIS),
+            loss + mr.reduce_sum(bloss, DATA_AXIS),
+            n + mr.reduce_sum(bn, DATA_AXIS),
+        )
+
+    def xla_shard(gw, gb, hww, hwb, hbb, loss, n, w, b, x, y, mask):
         from spark_rapids_ml_tpu.ops.gram import mm_precision
 
         with mm_precision(accum):
@@ -498,30 +616,61 @@ def _stream_grad_hess_shard_fn(mesh: Mesh, ad: str):
                 n + mr.reduce_sum(bn, DATA_AXIS),
             )
 
+    def shard(gw, gb, hww, hwb, hbb, loss, n, w, b, x, y, mask):
+        fused = _fused_newton_fold_applicable(x.shape, x.dtype, ad, use_pallas)
+        return (fused_shard if fused else xla_shard)(
+            gw, gb, hww, hwb, hbb, loss, n, w, b, x, y, mask)
+
     return jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(), P(), P(), P(),
                   P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=(P(),) * 7,
+        check_vma=False,  # pallas_call out_shapes carry no vma annotation
     )
 
 
-@functools.lru_cache(maxsize=32)
+def _fold_path_counter(mesh: Mesh, ad: str, use_pallas: bool):
+    """`on_dispatch` hook of the fold's two programs: one increment of
+    `srml_logreg_fold_path_total` a dispatch, under the path the program of
+    that batch shape was built with — `_stream_grad_hess_shard_fn`'s own
+    predicate, asked once a shape."""
+
+    @functools.lru_cache(maxsize=None)
+    def path(shape, dtype) -> str:
+        fused = _fused_newton_fold_applicable(
+            (shape[0] // mesh.shape[DATA_AXIS], shape[1]), dtype, ad, use_pallas)
+        return "fused" if fused else "xla"
+
+    def count(state, w, b, x, y, mask):
+        first = x[0] if isinstance(x, tuple) else x  # a group is one shape
+        _M_FOLD_PATH.inc(path=path(first.shape, first.dtype))
+
+    return count
+
+
 def _stream_grad_hess_fn(mesh: Mesh, ad: str):
     """Jitted donated accumulate of one batch's Newton statistics at fixed
     (w, b): (state, w, b, x, y, mask) -> state
-    (`_stream_grad_hess_shard_fn`)."""
-    f = _stream_grad_hess_shard_fn(mesh, ad)
+    (`_stream_grad_hess_shard_fn`). `use_pallas` is read here, at build
+    time, so it keys the cached programs (the `_stream_softmax_stats_fn`
+    snapshot pattern)."""
+    return _stream_grad_hess_cached(mesh, ad, bool(config.get("use_pallas")))
+
+
+@functools.lru_cache(maxsize=32)
+def _stream_grad_hess_cached(mesh: Mesh, ad: str, use_pallas: bool):
+    f = _stream_grad_hess_shard_fn(mesh, ad, use_pallas)
 
     @functools.partial(ledgered_jit, "logreg.streaming_update", donate_argnums=(0,))
     def update(state, w, b, x, y, mask):
         return f(*state, w, b, x, y, mask)
 
+    update.on_dispatch = _fold_path_counter(mesh, ad, use_pallas)
     return update
 
 
-@functools.lru_cache(maxsize=32)
 def _stream_grad_hess_group_fn(mesh: Mesh, ad: str):
     """The same accumulate over a GROUP of device-resident batches in one
     program: (state, w, b, xs, ys, masks) -> state, the three tuples of
@@ -532,18 +681,33 @@ def _stream_grad_hess_group_fn(mesh: Mesh, ad: str):
     the iterate the next batch reads — is what keeps it so: without it XLA
     merges the batches' products over their shared operand `w` and their
     accumulations, and a group of two is no longer bit-equal to two calls
-    (seen on the CPU, one device and eight). One compiled program per
-    (group length, batch shape)."""
-    f = _stream_grad_hess_shard_fn(mesh, ad)
+    (seen on the CPU, one device and eight). Where the fused body seeds its
+    kernel with the running Hessian, that is lane-padded ONCE before the
+    first batch and sliced once after the last (a zero pad and its slice
+    are exact, so the group is still its calls bit for bit). One compiled
+    program per (group length, batch shape)."""
+    return _stream_grad_hess_group_cached(mesh, ad, bool(config.get("use_pallas")))
+
+
+@functools.lru_cache(maxsize=32)
+def _stream_grad_hess_group_cached(mesh: Mesh, ad: str, use_pallas: bool):
+    f = _stream_grad_hess_shard_fn(mesh, ad, use_pallas)
 
     @functools.partial(ledgered_jit, "logreg.streaming_update_group",
                        donate_argnums=(0,))
     def update_group(state, w, b, xs, ys, masks):
+        d = xs[0].shape[1]
+        dp = _seeded_hessian_width(mesh, ad, use_pallas, xs[0])
+        if dp:
+            state = (*state[:2], _pad_hessian(state[2], dp), *state[3:])
         for x, y, mask in zip(xs, ys, masks):
             state, w, b = jax.lax.optimization_barrier(
                 (f(*state, w, b, x, y, mask), w, b))
+        if dp:
+            state = (*state[:2], state[2][:d, :d], *state[3:])
         return state
 
+    update_group.on_dispatch = _fold_path_counter(mesh, ad, use_pallas)
     return update_group
 
 

@@ -20,7 +20,16 @@ places hand-tiling pays:
   casts float32 rows to the compute dtype in VMEM and serves the
   streaming fold (models/kmeans.py ``_stream_shard_fn``) as well as the
   in-memory fit.
-* ``newton_stats_pallas`` — one-HBM-pass binomial Newton statistics.
+* ``newton_stats_pallas`` — one-HBM-pass binomial Newton statistics of the
+  in-memory fit (bfloat16 rows, lane-aligned d).
+* ``newton_fold_pallas`` — the streaming Newton fold on the chip: one
+  batch's gradient, loss, border and row count in float32 and its
+  bfloat16 Hessian product from ONE read of the float32 rows, at any width
+  (the batch read transposed, as the chip keeps it; lane padding in VMEM),
+  the (dp, dp) accumulator VMEM-resident and seedable. Called by
+  ``models/logistic_regression.py`` ``_stream_grad_hess_shard_fn`` where
+  its gate holds (``fit_logistic_stream``, the daemon's
+  ``LogisticRegressionJob``).
 * ``ivf_scan_select_pallas`` — IVF bucketed scan: per-list residual GEMM
   + exact packed-key top-k selection, scores VMEM-resident (gated by
   ``config.ann_fused_scan``, not ``use_pallas``).
@@ -579,7 +588,13 @@ def newton_stats_pallas(
     block_n: int = NEWTON_STATS_BLOCK_N,
     interpret: bool = False,
 ):
-    """One binomial Newton-IRLS iteration's statistics in a single HBM pass.
+    """One binomial Newton-IRLS iteration's statistics in a single HBM pass
+    — the kernel of the IN-MEMORY fit (`models/logistic_regression.py`
+    `_newton_fn_cached` behind `_pallas_newton_applicable`): rows already
+    cast to bfloat16, so the gradient is a bfloat16 product as well, d on
+    the 128-lane grid, no loss, no row count, no running state. The
+    streaming fold (float32 rows, float32 gradient and loss, any width, a
+    seedable Hessian) is :func:`newton_fold_pallas`.
 
     The XLA lowering of the IRLS body reads x ~4× per iteration (z matvec,
     gradient GEMM, weighted copy x·wgt, Hessian GEMM) — at d=1024 the step
@@ -645,6 +660,217 @@ def newton_stats_pallas(
         wpad,
     )
     return gw[0], s[0, 0], h, gw[1], s[0, 1]
+
+
+# ---------------------------------------------------------------------------
+# The streaming Newton fold: one batch's statistics from one read of the
+# float32 rows, at any width up to the VMEM budget
+# ---------------------------------------------------------------------------
+
+
+# Shared with the fold's gate (models/logistic_regression.py
+# `_fused_newton_fold_applicable`), so the two cannot drift.
+NEWTON_FOLD_VMEM_BUDGET = 64 * 2**20  # max lane-padded (dp, dp) f32 accumulator
+NEWTON_FOLD_ROW_MULTIPLE = 512  # the fold's gate: shard rows in multiples of this
+
+
+def newton_fold_block_n(d: int, n: int) -> int:
+    """The kernel's row block for n rows of width d, by
+    `gram_colsum_block_n`'s rule on the lane-padded width: the largest power
+    of two that divides n and whose (block, dp) float32 tile is within 4 MiB,
+    at most 2,048 rows — 256 at d = 3000 (dp = 3072). Checked on a v5e at
+    that width (host clock over eight programs of eight seeded 65,536-row
+    folds, PERF.md §6, PR 33): a fold took 7.13 ms in 256-row blocks, 7.47
+    at 128 and 7.48 at 512 (the XLA body: 9.97; the product alone, with
+    nothing but the cast beside it: 6.67 at 256, 6.69 at 128, 6.92 at 512).
+    A block's logits must be whole before its weighted product can start,
+    so a smaller block pays that serial start and the (dp, dp)
+    accumulator's read-modify-write more often, and a larger one twice the
+    unrolled code."""
+    return gram_colsum_block_n(_ceil_to(d, 128), n)
+
+
+def _newton_fold_kernel(b_ref, xt_ref, y_ref, m_ref, w_ref, *refs, d, seeded):
+    if seeded:
+        h0_ref, gw_ref, hwb_ref, h_ref, s_ref = refs
+    else:
+        gw_ref, hwb_ref, h_ref, s_ref = refs
+
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        if seeded:
+            # The Hessian accumulator starts from the caller's running one,
+            # straight from HBM into the output's buffer (its operand is
+            # aliased to the output), as `_gram_colsum_kernel` seeds its
+            # Gram: no XLA add reads and writes the (dp, dp) state a batch.
+            pltpu.sync_copy(h0_ref, h_ref)
+        else:
+            h_ref[:] = jnp.zeros_like(h_ref)
+        gw_ref[:] = jnp.zeros_like(gw_ref)
+        hwb_ref[:] = jnp.zeros_like(hwb_ref)
+        s_ref[:] = jnp.zeros_like(s_ref)
+
+    dp, bn = xt_ref.shape
+    if d != dp:
+        # The block is taller than the array: what lies past feature d is
+        # unspecified, so those sublanes are zeroed before anything reads
+        # them. Zero features are exact: w's are zero too, and every sum
+        # and the product's live (d, d) block are those of the real ones.
+        d8 = (d // 8) * 8
+        feat = jax.lax.broadcasted_iota(jnp.int32, (dp - d8, bn), 0)
+        xt_ref[d8:, :] = jnp.where(feat < d - d8, xt_ref[d8:, :], 0.0)
+
+    xt = xt_ref[:]  # (dp, bn) float32: features on sublanes, rows on lanes
+    y = y_ref[:]  # (1, bn) f32
+    m = m_ref[:]  # (1, bn) f32
+    # Row-local quantities in float32 on the VPU/EUP, from the float32
+    # tile: a multiply and a sublane reduction for the logits, not the MXU
+    # — at `highest` a float32 matvec is six bfloat16 passes over the tile.
+    z = jnp.sum(xt * w_ref[:], axis=0, keepdims=True) + b_ref[0]  # (1, bn)
+    p = jax.nn.sigmoid(z)
+    r = (p - y) * m
+    wgt = jnp.maximum(p * (1.0 - p), 1e-10) * m
+    # The gradient Xᵀr and the border Σ x·wgt: float32 sums of float32
+    # rows, as the XLA body's `highest` products. The 128-lane chunks are
+    # added on the VPU into (dp, 128) accumulators; the one cross-lane
+    # reduction a call is the wrapper's.
+    xw = xt * wgt  # the weighting in float32, before the cast
+    xr = xt * r
+    gpart, hpart = xr[:, :128], xw[:, :128]
+    for j in range(1, bn // 128):
+        gpart = gpart + xr[:, j * 128:(j + 1) * 128]
+        hpart = hpart + xw[:, j * 128:(j + 1) * 128]
+    gw_ref[:] += gpart
+    hwb_ref[:] += hpart
+    # Both operands cast in VMEM, then the FULL product (x·wgt)ᵀ x — every
+    # one of its 2·bn·dp² operations, no triangle — single-pass on the MXU
+    # with float32 accumulation: the arithmetic of the XLA body's
+    # `dot_general(xw, xc, precision=DEFAULT)`.
+    h_ref[:] += jax.lax.dot_general(
+        xw.astype(jnp.bfloat16), xt.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=jax.lax.Precision.DEFAULT,
+    )
+    loss = jnp.sum((jax.nn.softplus(z) - y * z) * m)
+    slane = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape, 1)
+    s_ref[:] += (
+        jnp.where(slane == 0, jnp.sum(r), 0.0)
+        + jnp.where(slane == 1, jnp.sum(wgt), 0.0)
+        + jnp.where(slane == 2, loss, 0.0)
+        + jnp.where(slane == 3, jnp.sum(m), 0.0)
+    )
+
+
+@functools.partial(
+    ledgered_jit, "pallas.newton_fold_pallas", static_argnames=("block_n", "interpret")
+)
+def newton_fold_pallas(
+    xt: jax.Array,
+    y: jax.Array,
+    mask: jax.Array,
+    w: jax.Array,
+    b: jax.Array,
+    hww=None,
+    block_n: Optional[int] = None,
+    interpret: bool = False,
+):
+    """One batch's binomial Newton statistics at fixed (w, b) from ONE read
+    of the float32 rows — the body of the streaming fold on the chip:
+    `models/logistic_regression.py` `_stream_grad_hess_shard_fn` calls it
+    where `_fused_newton_fold_applicable` holds (`fit_logistic_stream`, the
+    daemon's `LogisticRegressionJob.fold` / `fold_group`, the benchmark's
+    `logreg_d3000.newton_cached`).
+
+    xt: **(d, n) float32, the batch TRANSPOSED** — features on sublanes,
+    rows on lanes. That is how the TPU keeps an (n, d) float32 batch whose
+    width is off the 128-lane grid: its default device layout puts the rows
+    minor (an (n, 3000) array costs 786 MB, not the 805 MB of lane-padded
+    rows), so `x.T` inside a jit is a bitcast there and the kernel reads the
+    cached batch as it lies (the gate sends widths ON the grid, which the
+    chip keeps row-major, to the XLA body: the kernel is right there too,
+    but its caller would pay a transposing copy). d is **any width** whose
+    lane-padded square fits the VMEM budget: a block is (dp, block_n),
+    dp = ceil(d / 128) · 128, laid over the d-feature array, and the
+    features past d are zeroed in VMEM — no padded copy of x is written to
+    HBM. y, mask: (n,) — the mask a general 0/1 array (it multiplies
+    residual and weight row by row), read in (1, block_n) blocks. w: (d,)
+    float32; b: scalar (prefetched to SMEM).
+
+    Per block, from the float32 tile: logits, sigmoid, residual, weight,
+    the gradient Xᵀr, the loss Σ(softplus(z) − y·z)·mask, the border
+    Σ x·wgt and the row count in float32 on the VPU; `x·wgt` and `x` cast
+    to bfloat16 in VMEM and the full Hessian product `(x·wgt)ᵀ x` single-pass
+    on the MXU with float32 accumulation into a VMEM-resident (dp, dp)
+    accumulator — the XLA body's arithmetic, term for term.
+
+    ``hww``: optional (dp, dp) float32 running Hessian, ALREADY lane-padded
+    (zero past d), the accumulator is SEEDED from — copied from HBM at the
+    first grid step, its buffer aliased to the output — so ``hww += batch``
+    is this one dispatch. The other statistics are the batch's own.
+
+    Returns (gw (d,), gb (), hww (dp, dp) — zero past d —, hwb (d,), hbb (),
+    loss (), n ()), all float32 raw sums of this call's rows.
+    """
+    d, n = xt.shape
+    dp = _ceil_to(d, 128)
+    bn = min(block_n or newton_fold_block_n(d, n), n)
+    if n % bn or bn % 128:
+        raise ValueError(f"n={n} not divisible by block_n={bn}, a multiple of 128")
+    if dp * dp * 4 > NEWTON_FOLD_VMEM_BUDGET:
+        raise ValueError(f"d={d}: (dp, dp) f32 Hessian exceeds the VMEM budget")
+    if xt.dtype != jnp.float32:
+        raise ValueError(f"xt must be float32 rows, got {xt.dtype}")
+    seeded = hww is not None
+    if seeded and hww.shape != (dp, dp):
+        raise ValueError(f"hww {hww.shape} is not lane-padded to ({dp}, {dp})")
+    bvec = jnp.asarray(b, jnp.float32).reshape((1,))
+    wcol = jnp.zeros((dp, 1), jnp.float32).at[:d, 0].set(w.astype(jnp.float32))
+    row = pl.BlockSpec((1, bn), lambda i, b: (0, i))
+    part = pl.BlockSpec((dp, 128), lambda i, b: (0, 0))
+    gw, hwb, h, s = pl.pallas_call(
+        functools.partial(_newton_fold_kernel, d=d, seeded=seeded),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n // bn,),
+            in_specs=[
+                pl.BlockSpec((dp, bn), lambda i, b: (0, i)),
+                row,
+                row,
+                pl.BlockSpec((dp, 1), lambda i, b: (0, 0)),
+            ]
+            + ([pl.BlockSpec(memory_space=pl.ANY)] if seeded else []),
+            out_specs=[
+                part,
+                part,
+                pl.BlockSpec((dp, dp), lambda i, b: (0, 0)),
+                pl.BlockSpec((1, 128), lambda i, b: (0, 0)),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((dp, 128), jnp.float32),
+            jax.ShapeDtypeStruct((dp, 128), jnp.float32),
+            jax.ShapeDtypeStruct((dp, dp), jnp.float32),
+            jax.ShapeDtypeStruct((1, 128), jnp.float32),
+        ],
+        # operand 5 (after b, xt, y, mask, w) is the seed's Hessian
+        input_output_aliases={5: 2} if seeded else {},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=100 * 2**20
+        )
+        if not interpret
+        else None,
+        interpret=interpret,
+    )(
+        bvec,
+        xt,
+        y.astype(jnp.float32).reshape(1, n),
+        mask.astype(jnp.float32).reshape(1, n),
+        wcol,
+        *([hww] if seeded else []),
+    )
+    return (
+        jnp.sum(gw, axis=1)[:d], s[0, 0], h, jnp.sum(hwb, axis=1)[:d],
+        s[0, 1], s[0, 2], s[0, 3],
+    )
 
 
 # ---------------------------------------------------------------------------
