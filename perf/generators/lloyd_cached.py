@@ -17,10 +17,13 @@ One whole fit is the warm-up.
 `set_iterate(start)` → `max_iter` × (`rescan` → `step`) → `rescan` for the
 cost → the centres and the cost read to the host. A `rescan` folds the cached
 batches a group a dispatch (`serve/daemon.py` `_RESCAN_GROUP`), batch by batch
-inside the program; a "fold" in this cell's `fold_device_ms` and
-`fold_roofline` is one such program, and `obs.fold_rows_per_chip` its rows. `obs.passes` gets
-`max_iter` + 1 entries a fit: a Lloyd pass from before `rescan` until
-`step` has returned, the cost scan until the cost is on the host.
+inside the program; a "fold" in this cell's `pass_fold_device_ms` and
+`pass_fold_roofline` is one such program, and `obs.fold_rows_per_chip` its
+rows. `obs.passes` gets `max_iter` + 1 entries a fit: a Lloyd pass from
+before `rescan` until `step` has returned, the cost scan until the cost is
+on the host; `scanned` is when `rescan` had returned. `pass_rows_per_s` is
+taken over all their seconds, `median_pass_rows_per_s` and `late_pass_share`
+(the run's `a pass:` and `late:` lines) stand beside it.
 
 *Outside the window.* The job is dropped (its cache freed), the same rows
 are made again on the device and the plain reference runs over them from
@@ -33,7 +36,7 @@ import time
 
 import numpy as np
 
-from perf.harness import layout, trace
+from perf.harness import layout, stats, trace
 
 #: the ledger's name of the program `rescan` dispatches (`models/kmeans.py`
 #: `_stream_group_fn`); the configuration's `fold_program` is its name in a trace
@@ -108,20 +111,22 @@ def run(ctx):
             with ctx.span("rescan"):
                 job.rescan(job.iteration)
             state = job.peek_pass_state()[0]
+            scanned = time.monotonic()
             with ctx.span("boundary"):
                 moved.append(job.step({})["moved2"])
-            passes.append({"fit": index, "pass": it, "rows": cached_rows,
-                           "start": begin, "end": time.monotonic()})
+            passes.append({"fit": index, "pass": it, "rows": cached_rows, "start": begin,
+                           "scanned": scanned, "end": time.monotonic()})
             counts.append(state[1])
             first = state if first is None else first
         begin = time.monotonic()
         with ctx.span("rescan"):
             job.rescan(job.iteration)
+        scanned = time.monotonic()
         with ctx.span("cost_read"):
             state = job.peek_pass_state()[0]
             cost = float(np.asarray(state[2]))
-        passes.append({"fit": index, "pass": max_iter, "rows": cached_rows,
-                       "start": begin, "end": time.monotonic()})
+        passes.append({"fit": index, "pass": max_iter, "rows": cached_rows, "start": begin,
+                       "scanned": scanned, "end": time.monotonic()})
         with ctx.span("model_read"):
             centers = np.asarray(job.get_iterate()[0]["centers"])
             counts.append(state[1])
@@ -139,8 +144,12 @@ def run(ctx):
     ctx.stage("one whole fit as warm-up; the farthest a centre moved, by pass: " + ", ".join(
         f"{i + 1}: {warm['moved2'][i] ** 0.5:.3g}" for i in sorted({0, max_iter // 2 - 1, max_iter - 1})))
 
-    tracer = trace.TraceWindow(ctx.trace, min(0.5, ctx.seconds / 4),
-                               min(p["trace_s"], ctx.seconds / 2), ctx.out_dir)
+    # The traced part is the window's LAST seconds: stopping the profiler
+    # takes the host seconds (against this loop, over 30 of them), and so
+    # runs on after the window has closed instead of inside it.
+    trace_s = min(p["trace_s"], ctx.seconds / 2)
+    tracer = trace.TraceWindow(ctx.trace, max(0.0, ctx.seconds - trace_s - 0.5), trace_s,
+                               ctx.out_dir)
     begin = ctx.begin_window()
     deadline = obs.window[1]
     ops_per_fit = 1 + 2 * max_iter + 2  # set_iterate, rescans and steps, the read
@@ -157,6 +166,7 @@ def run(ctx):
     say(f"window closed after {time.monotonic() - begin:.2f} s: {len(obs.fits)} fits, "
         f"{len(obs.passes)} passes")
     obs.trace = tracer.reduced(obs.spans)
+    stats.say_passes(obs.passes, deadline, say, obs.trace)
     # A fold program's rows, as the program counted them: `rescan` folds its
     # cached batches a group a dispatch, and the group is the program's to choose.
     folded = obs.counter_delta("srml_daemon_pass_rows_total", source="cache")

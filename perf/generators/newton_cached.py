@@ -20,10 +20,14 @@ and is installed with `set_iterate`. One whole fit is the warm-up.
 `set_iterate(start)` → `max_iter` × (`rescan` → `step`) → (w, b) read to the
 host; no further scan (the estimator does none). A `rescan` folds the
 cached batches a group a dispatch (`serve/daemon.py` `_RESCAN_GROUP`), batch
-by batch inside the program; a "fold" in this cell's `fold_device_ms` and
-`fold_roofline` is one such program, and `obs.fold_rows_per_chip` its rows.
+by batch inside the program; a "fold" in this cell's `pass_fold_device_ms`
+and `pass_fold_roofline` is one such program, and `obs.fold_rows_per_chip`
+its rows.
 `obs.passes` gets `max_iter` entries a fit, each from before `rescan` until
-`step` has returned: the boundary and its solve are in `fold_rows_per_s`.
+`step` has returned (`scanned`: `rescan` had returned): the boundary and
+its solve are in `pass_rows_per_s`, which is taken over all the passes'
+seconds; `median_pass_rows_per_s` and `late_pass_share` (the run's `a pass:`
+and `late:` lines) stand beside it.
 
 *Outside the window.* The job is dropped (its cache freed), the same
 batches are made again on the device and the plain reference runs over them
@@ -36,7 +40,7 @@ import time
 
 import numpy as np
 
-from perf.harness import layout, trace
+from perf.harness import layout, stats, trace
 
 #: the ledger's name of the program `rescan` dispatches
 #: (`models/logistic_regression.py` `_stream_grad_hess_group_fn`); the
@@ -44,32 +48,6 @@ from perf.harness import layout, trace
 FOLD_FN = "logreg.streaming_update_group"
 #: the leaves of a pass's state, in the program's order
 STATE = ("gw", "gb", "hww", "hwb", "hbb", "loss", "n")
-
-
-#: a pass is LATE when it took over this many times the window's median pass
-LATE = 1.5
-
-
-def _say_passes(passes, say) -> None:
-    """How long the window's passes took, and each LATE one by its two
-    calls: `rescan` (the dispatch) and `step` (the wait for the folds, the
-    solve, the zero state). What a run's rate spreads by (PERF.md §7)."""
-    took = sorted(pa["end"] - pa["start"] for pa in passes)
-    if not took:
-        return
-    at = lambda q: 1e3 * took[min(len(took) - 1, int(q * len(took)))]
-    median = took[len(took) // 2]
-    late = [pa for pa in passes if pa["end"] - pa["start"] > LATE * median]
-    extra = sum(pa["end"] - pa["start"] - median for pa in late)
-    say(f"a pass: p10 {at(0.1):.2f}, median {at(0.5):.2f}, p90 {at(0.9):.2f}, longest "
-        f"{1e3 * took[-1]:.2f} ms; {len(late)} of {len(took)} over {LATE:g} x the "
-        f"median, {extra:.3f} s beyond it in all ({100 * extra / sum(took):.3f}% of the "
-        "passes' seconds)")
-    for pa in late[:12]:
-        say(f"  late: fit {pa['fit']} pass {pa['pass']}: "
-            f"{1e3 * (pa['end'] - pa['start']):.1f} ms = rescan "
-            f"{1e3 * (pa['scanned'] - pa['start']):.1f} + step "
-            f"{1e3 * (pa['end'] - pa['scanned']):.1f}")
 
 
 def run(ctx):
@@ -175,8 +153,12 @@ def run(ctx):
     ctx.stage("one whole fit as warm-up; the Newton step's length, by pass: " + ", ".join(
         f"{i + 1}: {warm['delta'][i]:.3g}" for i in range(max_iter)))
 
-    tracer = trace.TraceWindow(ctx.trace, min(0.5, ctx.seconds / 4),
-                               min(p["trace_s"], ctx.seconds / 2), ctx.out_dir)
+    # The traced part is the window's LAST seconds: stopping the profiler
+    # takes the host seconds (against this loop, over 30 of them), and so
+    # runs on after the window has closed instead of inside it.
+    trace_s = min(p["trace_s"], ctx.seconds / 2)
+    tracer = trace.TraceWindow(ctx.trace, max(0.0, ctx.seconds - trace_s - 0.5), trace_s,
+                               ctx.out_dir)
     begin = ctx.begin_window()
     deadline = obs.window[1]
     ops_per_fit = 1 + 2 * max_iter + 1  # set_iterate, rescans and steps, the read
@@ -192,8 +174,8 @@ def run(ctx):
     ctx.end_window()
     say(f"window closed after {time.monotonic() - begin:.2f} s: {len(obs.fits)} fits, "
         f"{len(obs.passes)} passes")
-    _say_passes(obs.passes, say)
     obs.trace = tracer.reduced(obs.spans)
+    stats.say_passes(obs.passes, deadline, say, obs.trace)
     # A fold program's rows, as the program counted them: `rescan` folds its
     # cached batches a group a dispatch, and the group is the program's to choose.
     folded = obs.counter_delta("srml_daemon_pass_rows_total", source="cache")

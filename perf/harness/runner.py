@@ -72,6 +72,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool, *
         os.makedirs(out_dir, exist_ok=True)
         with open(os.path.join(out_dir, f"{workload}.seed{seed}.trace{int(trace)}.json"),
                   "w", encoding="utf-8") as f:
-            json.dump({"result": result, "notes": obs.notes, "passes": obs.passes,
-                       "fits": obs.fits, "setup_s": obs.setup_s}, f, default=str)
+            json.dump({"result": result, "notes": obs.notes, "window": obs.window,
+                       "passes": obs.passes, "fits": obs.fits, "setup_s": obs.setup_s},
+                      f, default=str)
     return result

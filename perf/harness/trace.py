@@ -255,6 +255,11 @@ class TraceWindow:
         #: called: the device is traced between the two (stopping takes a
         #: second or more, and the profile's own stop time is after it)
         self.traced: Optional[Interval] = None
+        #: monotonic clock before start_trace was called and after stop_trace
+        #: had returned: what the host does in between carries the profiler
+        #: (a pass of 11 ms takes 18, and stopping takes seconds), so the
+        #: readers of host-clock pass times leave that interval out
+        self.profiled: Optional[Interval] = None
         self.error: Optional[BaseException] = None
         self._thread: Optional[threading.Thread] = None
         #: wall clock minus monotonic clock, to lay the benchmark's spans
@@ -264,6 +269,7 @@ class TraceWindow:
     def _run(self) -> None:
         try:
             time.sleep(self.lead_s)
+            before = time.monotonic()
             start(self.logdir)
             began = time.time()
             try:
@@ -271,6 +277,7 @@ class TraceWindow:
             finally:
                 self.traced = (began, time.time())
                 stop()
+                self.profiled = (before, time.monotonic())
         except BaseException as e:  # noqa: BLE001 - surfaced by reduced()
             self.error = e
 
@@ -305,6 +312,7 @@ class TraceWindow:
             out = reduce_trace(
                 raw, window,
                 [(name, a + shift, b + shift) for name, a, b in spans])
+            out["profiled"] = self.profiled
             if self.out_dir:
                 os.makedirs(self.out_dir, exist_ok=True)
                 shutil.copy(path, os.path.join(

@@ -5,7 +5,7 @@ program spends in the jit ledger's wrapper (`utils/xprof.py`
 `{fn=kmeans.streaming_update_group}` (`models/kmeans.py` `_stream_group_fn`:
 the fold `kmeans.streaming_update` runs, over a group of cached batches in
 one program), across the window: what `fold_dispatch_ms` is for the Gram
-fold. Far under `fold_device_ms` it is what a dispatch costs the host; near
+fold. Far under `pass_fold_device_ms` it is what a dispatch costs the host; near
 it the runtime's queue is full. Nothing to read when no such program was
 called, or no second was counted."""
 

@@ -5,7 +5,7 @@ program spends in the jit ledger's wrapper (`utils/xprof.py`
 `{fn=logreg.streaming_update_group}` (`models/logistic_regression.py`
 `_stream_grad_hess_group_fn`: the fold `logreg.streaming_update` runs, over
 a group of cached batches in one program), across the window: what
-`lloyd_fold_dispatch_ms` is for the KMeans fold. Far under `fold_device_ms`
+`lloyd_fold_dispatch_ms` is for the KMeans fold. Far under `pass_fold_device_ms`
 it is what a dispatch costs the host; near it the runtime's queue is full.
 Nothing to read when no such program was called, or no second was
 counted."""

@@ -39,14 +39,33 @@ ACCEPTED_PER_LAYER = {
                                "lower"),
     "collective_exposed_share": ("device_trace", "collectives", "fold_rows_per_s", "%",
                                  "lower"),
-    "finalize_eig_ms": ("program_span", "finalize", "finalize_s", "ms", "lower"),
-    "device_idle_share": ("device_trace", "device", "finalize_s", "%", "lower"),
+    # what the finalize moves end to end is the whole fit (PR 35: `finalize_s`
+    # itself, which no bound of 10% holds from run to run, stands per layer)
+    "finalize_eig_ms": ("program_span", "finalize", "fit_rows_per_s", "ms", "lower"),
+    "finalize_s": ("host_clock", "finalize", "fit_rows_per_s", "s", "lower"),
+    "device_idle_share": ("device_trace", "device", "fit_rows_per_s", "%", "lower"),
     "compiles_in_window": ("program_counter", "model_programs", "setup_s", "programs",
                            "lower"),
-    **{name: ("program_span", "finalize", "finalize_s", "ms", "lower")
+    **{name: ("program_span", "finalize", "fit_rows_per_s", "ms", "lower")
        for name in SPLIT - {"fold_dispatch_ms"}},
     "fold_dispatch_ms": ("program_counter", "model_programs", "fold_rows_per_s", "ms",
                          "lower"),
+    # the cells on a daemon job's cached pass (PRs 28, 32), as PR 35 left
+    # them: their end-to-end rate is `pass_rows_per_s`
+    "pass_cached_share": ("program_counter", "daemon", "pass_rows_per_s", "%", "higher"),
+    "rescan_dispatch_ms": ("program_span", "daemon", "pass_rows_per_s", "ms", "lower"),
+    "lloyd_boundary_ms": ("program_span", "daemon", "pass_rows_per_s", "ms", "lower"),
+    "lloyd_fold_dispatch_ms": ("program_counter", "model_programs", "pass_rows_per_s",
+                               "ms", "lower"),
+    "newton_boundary_ms": ("program_span", "daemon", "pass_rows_per_s", "ms", "lower"),
+    "newton_solve_ms": ("program_span", "model_programs", "pass_rows_per_s", "ms", "lower"),
+    "newton_fold_dispatch_ms": ("program_counter", "model_programs", "pass_rows_per_s",
+                                "ms", "lower"),
+    "pass_fold_device_ms": ("device_trace", "kernels", "pass_rows_per_s", "ms", "lower"),
+    "pass_fold_roofline": ("device_trace", "kernels", "pass_rows_per_s", "%", "higher"),
+    "median_pass_rows_per_s": ("host_clock", "daemon", "pass_rows_per_s", "rows/s",
+                               "higher"),
+    "late_pass_share": ("host_clock", "daemon", "pass_rows_per_s", "%", "lower"),
 }
 
 

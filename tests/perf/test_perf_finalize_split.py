@@ -117,7 +117,7 @@ def test_the_new_entries_belong_to_the_one_chip_cell_only_and_are_appended(tmp_p
         m.setdefault("workloads", []).append("a_later.cell")  # lists may grow
     bench["per_layer"].append({"name": "a_later_metric", "unit": "ms", "better": "lower",
                                "source": "program_span", "layer": "a later layer",
-                               "moves": "finalize_s", "workloads": ["a_later.cell"]})
+                               "moves": "fit_rows_per_s", "workloads": ["a_later.cell"]})
     held(write(bench))
     dispatch = next(m for m in bench["per_layer"] if m["name"] == "fold_dispatch_ms")
     dispatch["moves"] = "setup_s"  # a field of an accepted entry may not change

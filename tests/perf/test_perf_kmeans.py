@@ -17,6 +17,10 @@ BENCH = layout.load_benchmark(ROOT)
 CELL = "kmeans_d256_k100.lloyd_cached"
 NEW_PER_LAYER = {"pass_cached_share", "rescan_dispatch_ms", "lloyd_boundary_ms",
                  "lloyd_fold_dispatch_ms"}
+#: PR 35: the cached cells' rate under a name and a bound of its own, and
+#: what stands beside it
+PASS_PER_LAYER = {"pass_fold_device_ms", "pass_fold_roofline", "median_pass_rows_per_s",
+                  "late_pass_share"}
 
 
 @pytest.fixture(scope="module")
@@ -47,14 +51,22 @@ def test_the_cell_is_the_sources_deployment_cut_to_one_chips_eighth(config):
     assert 0.25 * 2**34 <= held <= cfg["daemon_pass_cache_mb"] << 20 < 16e9
     reported = {kind: {m["name"] for m in layout.metric_entries(BENCH, kind, CELL)}
                 for kind in ("end_to_end", "per_layer")}
-    assert reported == {"end_to_end": {"fold_rows_per_s", "setup_s"},
-                        "per_layer": {"fold_device_ms", "fold_roofline", "compiles_in_window"}
-                        | NEW_PER_LAYER}
+    # by name, not by `==`: a later cached cell lists these by addition
+    assert reported["end_to_end"] == {"pass_rows_per_s", "setup_s"}
+    assert reported["per_layer"] >= {"compiles_in_window"} | NEW_PER_LAYER | PASS_PER_LAYER
+    # a per-layer metric names the one end-to-end metric its cells report
+    assert not reported["per_layer"] & {"fold_device_ms", "fold_roofline"}
     for m in BENCH["per_layer"]:
-        if m["name"] in NEW_PER_LAYER:
-            assert (m["workloads"], m["moves"]) == ([CELL], "fold_rows_per_s")
+        if m["name"] in NEW_PER_LAYER | PASS_PER_LAYER:
+            assert CELL in m["workloads"] and m["moves"] == "pass_rows_per_s"
     assert {m["layer"] for m in BENCH["per_layer"] if m["name"] in NEW_PER_LAYER} == {
         "daemon", "model_programs"}
+    assert {m["name"]: (m["layer"], m["source"], m["unit"], m["better"])
+            for m in BENCH["per_layer"] if m["name"] in PASS_PER_LAYER} == {
+        "pass_fold_device_ms": ("kernels", "device_trace", "ms", "lower"),
+        "pass_fold_roofline": ("kernels", "device_trace", "%", "higher"),
+        "median_pass_rows_per_s": ("daemon", "host_clock", "rows/s", "higher"),
+        "late_pass_share": ("daemon", "host_clock", "%", "lower")}
     assert set(cfg["tolerances"]) == {"pass0_stats_rel", "centers_rel", "cost_rel"}
 
 
@@ -140,14 +152,18 @@ def test_the_tiny_cell_runs_end_to_end_and_traced(root, trace):
     assert result["compared"]["rows_refed_in_window"] == [0.0, 0.0]
     got = {name: m["value"] for name, m in result["metrics"].items()}
     if trace:
-        assert NEW_PER_LAYER | {"compiles_in_window"} == set(got)
+        assert NEW_PER_LAYER | {"compiles_in_window", "median_pass_rows_per_s",
+                                "late_pass_share"} <= set(got)
         assert got["pass_cached_share"] == 100.0 and got["compiles_in_window"] == 0
         assert got["rescan_dispatch_ms"] > 0 and got["lloyd_boundary_ms"] > 0
         assert 0 < got["lloyd_fold_dispatch_ms"] < got["rescan_dispatch_ms"]
-        for name in ("fold_device_ms", "fold_roofline"):
+        assert got["median_pass_rows_per_s"] > 0 and 0 <= got["late_pass_share"] < 100
+        for name in ("pass_fold_device_ms", "pass_fold_roofline"):
             assert f"metric {name}: nothing to read, left out" in text
     else:
-        assert {"fold_rows_per_s", "setup_s"} == set(got) and got["fold_rows_per_s"] > 0
+        assert {"pass_rows_per_s", "setup_s"} == set(got) and got["pass_rows_per_s"] > 0
+    # the run says its passes, and the share the reader reads, on a line of its own
+    assert "a pass: p10 " in text and "x the median, " in text
 
 
 def test_a_program_without_the_cache_fails_at_once_and_makes_no_data(root, monkeypatch):

@@ -17,6 +17,10 @@ ROOT = layout.REPO_ROOT
 BENCH = layout.load_benchmark(ROOT)
 CELL = "logreg_d3000.newton_cached"
 NEW_PER_LAYER = {"newton_boundary_ms", "newton_solve_ms", "newton_fold_dispatch_ms"}
+#: what every cell on a cached pass reports (PR 35; the daemon's two since
+#: this cell may list them: `tests/perf/test_perf_kmeans.py` un-pinned)
+CACHED_PER_LAYER = {"pass_cached_share", "rescan_dispatch_ms", "pass_fold_device_ms",
+                    "pass_fold_roofline", "median_pass_rows_per_s", "late_pass_share"}
 COMPARED = {"rows_not_folded", "pass0_grad_rel", "pass0_hess_rel", "coef_rel", "loss_rel",
             "rows_refed_in_window", "compiles_in_window"}
 
@@ -65,12 +69,12 @@ def test_the_cell_is_the_deployment_cut_to_one_chips_rows(config):
     assert 0.25 * 16e9 <= held <= cfg["daemon_pass_cache_mb"] << 20 < 16e9
     reported = {kind: {m["name"] for m in layout.metric_entries(BENCH, kind, CELL)}
                 for kind in ("end_to_end", "per_layer")}
-    assert reported["end_to_end"] == {"fold_rows_per_s", "setup_s"}
-    assert reported["per_layer"] >= {"fold_device_ms", "fold_roofline",
-                                     "compiles_in_window"} | NEW_PER_LAYER
+    assert reported["end_to_end"] == {"pass_rows_per_s", "setup_s"}
+    assert reported["per_layer"] >= {"compiles_in_window"} | NEW_PER_LAYER | CACHED_PER_LAYER
+    assert not reported["per_layer"] & {"fold_device_ms", "fold_roofline"}
     for m in BENCH["per_layer"]:
-        if m["name"] in NEW_PER_LAYER:
-            assert CELL in m["workloads"] and m["moves"] == "fold_rows_per_s"
+        if m["name"] in NEW_PER_LAYER | CACHED_PER_LAYER:
+            assert CELL in m["workloads"] and m["moves"] == "pass_rows_per_s"
     assert {m["name"]: m["layer"] for m in BENCH["per_layer"]
             if m["name"] in NEW_PER_LAYER} == {
         "newton_boundary_ms": "daemon", "newton_solve_ms": "model_programs",
@@ -191,14 +195,19 @@ def test_the_tiny_cell_runs_end_to_end_and_traced(root, trace):
     assert result["compared"]["rows_refed_in_window"] == [0.0, 0.0]
     got = {name: m["value"] for name, m in result["metrics"].items()}
     if trace:
-        assert NEW_PER_LAYER | {"compiles_in_window"} == set(got)
+        off_the_chip = CACHED_PER_LAYER - {"pass_fold_device_ms", "pass_fold_roofline"}
+        assert NEW_PER_LAYER | off_the_chip | {"compiles_in_window"} == set(got)
         assert got["compiles_in_window"] == 0
         assert 0 < got["newton_solve_ms"] < got["newton_boundary_ms"]
         assert got["newton_fold_dispatch_ms"] > 0
-        for name in ("fold_device_ms", "fold_roofline"):
+        # the daemon's two, listed for this cell too since PR 35
+        assert got["pass_cached_share"] == 100.0 and got["rescan_dispatch_ms"] > 0
+        assert got["median_pass_rows_per_s"] > 0 and 0 <= got["late_pass_share"] < 100
+        for name in ("pass_fold_device_ms", "pass_fold_roofline"):
             assert f"metric {name}: nothing to read, left out" in text
     else:
-        assert {"fold_rows_per_s", "setup_s"} == set(got) and got["fold_rows_per_s"] > 0
+        assert {"pass_rows_per_s", "setup_s"} == set(got) and got["pass_rows_per_s"] > 0
+    assert "a pass: p10 " in text and "x the median, " in text
 
 
 def test_a_program_whose_newton_job_keeps_no_pass_fails_at_once_and_makes_no_data(

@@ -250,7 +250,7 @@ def test_an_addition_may_not_replace_a_file_that_is_there(next_pr):
     (lambda b: b["workloads"].pop(), "workloads: 1 entries went"),
     (lambda b: b["end_to_end"][0]["workloads"].insert(0, "a.cell"),
      "end_to_end[0].workloads[0]"),  # not at the end
-    (lambda b: b["end_to_end"][1].update(bound=0.1), "end_to_end[1].bound"),
+    (lambda b: b["end_to_end"][0].update(bound=0.1), "end_to_end[0].bound"),
     (lambda b: b["per_layer"][6].update(workloads=[]), "per_layer[6]: keys ['workloads']"),
 ], ids=["a_field_edited", "an_entry_taken_out", "a_cell_taken_out", "a_cell_put_in_front",
         "a_bound_loosened", "a_key_added"])

@@ -20,6 +20,101 @@ def test_rows_per_s_counts_only_passes_that_completed_in_the_window():
     assert stats.rows_per_s(passes, deadline=1.0) is None
 
 
+def _passes(seconds, rows=1000, start=0.0):
+    """Passes back to back from `start`, one for each of `seconds` (and of
+    `rows`, where that is a list)."""
+    rows = rows if isinstance(rows, list) else [rows] * len(seconds)
+    out, at = [], start
+    for took, n in zip(seconds, rows):
+        out.append({"fit": 0, "pass": len(out), "rows": n, "start": at,
+                    "scanned": at + took / 4, "end": at + took})
+        at += took
+    return out
+
+
+@pytest.mark.parametrize("seconds,rows,deadline,mean,median,late", [
+    # equal passes: the two rates are one, nothing is late
+    ([2.0] * 5, 1000, 100.0, 500.0, 500.0, 0.0),
+    # unequal rows: rows over (count x the median seconds), not the median pass's own rows
+    ([2.0, 2.0, 2.0, 2.0], [1000, 3000, 1000, 3000], 100.0, 1000.0, 1000.0, 0.0),
+    # one pass ten times late: the median pass ignores it, the rate over all
+    # the seconds does not, and the share reads exactly its excess, 18 of 28 s
+    ([2.0, 2.0, 20.0, 2.0, 2.0], 1000, 100.0, 5000 / 28.0, 500.0, 100 * 18.0 / 28.0),
+    # a pass just under 1.5 x the median is not late, one just over is: 0.02 of 8.02 s
+    ([2.0, 2.98, 2.0, 2.0], 1000, 100.0, 4000 / 8.98, 500.0, 0.0),
+    ([2.0, 3.02, 2.0, 1.0], 1000, 100.0, 4000 / 8.02, 500.0, 100 * 1.02 / 8.02),
+    # passes past the deadline are left out: the late one ends at 26 s
+    ([2.0, 2.0, 2.0, 20.0, 2.0], 1000, 10.0, 500.0, 500.0, 0.0),
+], ids=["equal", "unequal_rows", "one_ten_times_late", "under_late", "over_late",
+        "past_the_deadline"])
+def test_the_two_rates_of_a_windows_passes_and_the_share_of_its_late_ones(
+        seconds, rows, deadline, mean, median, late):
+    passes = _passes(seconds, rows)
+    assert stats.rows_per_s(passes, deadline) == pytest.approx(mean)
+    assert stats.median_pass_rows_per_s(passes, deadline) == pytest.approx(median)
+    assert stats.late_pass_share(passes, deadline) == pytest.approx(late, abs=1e-12)
+
+
+def test_no_completed_pass_is_nothing_to_read_for_either_rate_or_the_share():
+    passes = _passes([2.0, 2.0], start=5.0)
+    for read in (stats.rows_per_s, stats.median_pass_rows_per_s, stats.late_pass_share):
+        assert read(passes, 6.0) is None and read([], 6.0) is None
+    assert stats.late_pass_share(passes, 9.0) == 0.0  # passes, none late: 0.0, not None
+
+
+def test_the_pass_readers_are_the_harness_arithmetic_over_the_windows_passes():
+    from perf.end_to_end import fold_rows_per_s, pass_rows_per_s
+    from perf.layer_metrics import late_pass_share, median_pass_rows_per_s
+
+    obs = _observation(passes=_passes([1.0, 1.0, 4.0, 1.0, 1.0, 9.0], start=100.0))
+    # the last pass ends at 117 s, after the deadline of 110 s
+    assert pass_rows_per_s.read(obs) == fold_rows_per_s.read(obs) == pytest.approx(5000 / 8.0)
+    assert median_pass_rows_per_s.read(obs) == pytest.approx(1000.0)
+    assert late_pass_share.read(obs) == pytest.approx(100 * 3.0 / 8.0)
+    empty = _observation()
+    assert [r.read(empty) for r in (pass_rows_per_s, median_pass_rows_per_s,
+                                    late_pass_share)] == [None, None, None]
+
+
+def test_a_traced_runs_pass_readers_leave_out_the_profilers_interval():
+    from perf.layer_metrics import late_pass_share, median_pass_rows_per_s
+
+    # ten passes of 1 s from 100 s; the profiler was on from 102.5 to 105.2 s
+    # and the four passes that touch that interval took 3 s under it
+    passes = _passes([1.0, 1.0, 3.0, 3.0, 3.0, 3.0, 1.0, 1.0, 1.0, 1.0], start=100.0)
+    obs = _observation(passes=passes)
+    obs.window = (100.0, 130.0)
+    assert late_pass_share.read(obs) == pytest.approx(100 * 8.0 / 18.0)  # untraced: all count
+    obs.trace = {"devices": {}, "window_s": 2.0, "profiled": (102.5, 112.2)}
+    assert [p["pass"] for p in stats.unprofiled(passes, obs.trace)] == [0, 1, 6, 7, 8, 9]
+    assert late_pass_share.read(obs) == 0.0
+    assert median_pass_rows_per_s.read(obs) == pytest.approx(1000.0)
+    said = []
+    stats.say_passes(passes, 130.0, said.append, obs.trace)
+    assert said[0].startswith("the profiler was on for 9.70 s (it traced 2.00 s of them)")
+    assert "0 of 6 over 1.5 x the median" in said[1]
+    # nothing traced, a trace that kept no interval, or one that covers every pass: all count
+    assert stats.unprofiled(passes, {"profiled": (99.0, 131.0)}) == passes
+    assert stats.unprofiled(passes, None) == passes == stats.unprofiled(passes, {"devices": {}})
+
+
+def test_say_passes_prints_the_share_the_reader_reads_and_each_late_pass_by_its_calls():
+    said = []
+    passes = _passes([2.0, 2.0, 20.0, 2.0, 2.0, 50.0])
+    stats.say_passes(passes, 30.0, said.append)
+    assert len(said) == 2
+    assert "1 of 5 over 1.5 x the median" in said[0] and "64.286% of the passes'" in said[0]
+    assert "rows/s over all their seconds 178.571, of the median pass 500" in said[0]
+    assert said[1] == "  late: fit 0 pass 2: 20000.0 ms = rescan 5000.0 + step 15000.0"
+    # a generator that keeps no `scanned` gets the line without the split
+    said.clear()
+    stats.say_passes([{k: v for k, v in p.items() if k != "scanned"} for p in passes],
+                     30.0, said.append)
+    assert said[1] == "  late: fit 0 pass 2: 20000.0 ms"
+    stats.say_passes([], 30.0, said.append)
+    assert len(said) == 2  # no completed pass: nothing said
+
+
 def _snapshot(eig_sum, eig_count, folds):
     return {
         "srml_phase_duration_seconds": {"type": "histogram", "samples": [
@@ -51,28 +146,64 @@ def _observation(**fields):
 
 
 def test_the_finalize_readers_take_fits_and_spans_of_the_window_only():
-    from perf.end_to_end import finalize_s, fold_rows_per_s
-    from perf.layer_metrics import finalize_eig_ms
+    from perf.end_to_end import fit_rows_per_s, fold_rows_per_s
+    from perf.layer_metrics import finalize_eig_ms, finalize_s
 
     fits = [{"finalize_s": 1.0, "end": 103.0}, {"finalize_s": 1.2, "end": 106.0},
             {"finalize_s": 1.1, "end": 109.0},
             {"finalize_s": 9.0, "end": 111.5}]  # its model arrived after the window
-    passes = [{"rows": 500, "start": 100.0, "end": 102.0},
-              {"rows": 500, "start": 103.0, "end": 105.0},
-              {"rows": 500, "start": 109.5, "end": 110.5}]  # ended after the deadline
+    for i, fit in enumerate(fits):
+        fit.update(fit=i, rows=500)
+    passes = [{"fit": 0, "rows": 500, "start": 100.0, "end": 102.0},
+              {"fit": 1, "rows": 500, "start": 103.0, "end": 105.0},
+              {"fit": 3, "rows": 500, "start": 109.5, "end": 110.5}]  # ended after the deadline
     obs = _observation(fits=fits, passes=passes,
                        before={"metrics": _snapshot(2.0, 4, 0.0)},
                        after={"metrics": _snapshot(5.3, 7, 0.0)})
     assert finalize_s.read(obs) == pytest.approx(1.1)
     assert fold_rows_per_s.read(obs) == pytest.approx(1000 / 4.0)
+    # the whole fit: folds and finalize of the fits whose model arrived in the
+    # window and whose passes were kept (fit 2 has none here, fit 3 ended late)
+    assert fit_rows_per_s.read(obs) == pytest.approx(1000 / (2.0 + 1.0 + 2.0 + 1.2))
     assert finalize_eig_ms.read(obs) == pytest.approx(1100.0)
     empty = _observation(before={"metrics": {}}, after={"metrics": {}})
     assert finalize_s.read(empty) is None and fold_rows_per_s.read(empty) is None
+    assert fit_rows_per_s.read(empty) is None
     assert finalize_eig_ms.read(empty) is None  # nothing to read: left out of the line
 
 
-def test_spread_is_the_quartile_distance_over_the_median():
-    assert stats.spread([90, 95, 100, 105, 110]) == pytest.approx(0.10)
+def test_spread_is_the_quartile_distance_over_the_median_as_the_driver_takes_it():
+    # statistics.quantiles' quartiles (92.5, 107.5), not numpy's (95, 105)
+    assert stats.spread([90, 95, 100, 105, 110]) == pytest.approx(0.15)
+    # without the run farthest from the median: 150 goes, (92.5, 103) over 100
+    assert stats.spread_without_farthest([90, 95, 100, 105, 150, 101]) == pytest.approx(0.105)
+
+
+def test_the_spread_command_reads_a_set_of_runs_and_both_estimators(tmp_path):
+    import json
+
+    from perf import spread
+
+    for i, late in enumerate([0.0, 0.0, 0.3, 0.0, 0.9, 0.1]):
+        passes = _passes([0.01] * 50 + [0.01 + late] + [0.01] * 49, rows=100, start=5.0)
+        end = passes[-1]["end"]
+        passes += _passes([0.01] * 3, rows=100, start=end + 1.0)  # after the deadline
+        mean = stats.rows_per_s(passes, end)
+        run = {"result": {"correct": True, "metrics": {
+            "pass_rows_per_s": {"value": mean, "unit": "rows/s"}}},
+            "window": [5.0, end], "passes": passes}
+        (tmp_path / f"a.cell.seed{i}.trace0.json").write_text(json.dumps(run))
+    said = []
+    spread.say_set(str(tmp_path), said.append)
+    text = "\n".join(said)
+    assert "a.cell, 6 runs, 0 not correct" in said[0]
+    # the rate over all the seconds spreads with the late passes, the median pass's does not
+    assert "rows/s of the median pass: median 10000, spread 0.000%" in text
+    mean_line = [line for line in said if "over all the passes' seconds" in line][0]
+    assert "median 9" in mean_line and "spread 0.000%" not in mean_line
+    assert said[2] == said[1].replace("pass_rows_per_s", "rows/s over all the passes' seconds")
+    assert "seed4.trace0.json: 100 passes, median 10.000 ms, 1 late" in text
+    assert spread.main([]) == 2
 
 
 # -- intervals and the reduction of a trace ------------------------------------
