@@ -17,7 +17,12 @@ config #2: d=2048, k=32) under the shipped ``auto`` profile:
   6. kernels    every Pallas kernel ``use_pallas=auto`` / ``ann_fused_scan=
                 auto`` turns on, through the model-level function that gates
                 it, against the plain-jnp path
-  7. summary    two JSON lines close stdout: the full summary (per-stage
+  7. forest     one RandomForestRegressor fit at the upstream suite's widths
+                (3,000 float32 columns, 128 bins, depth 6; a few trees, two
+                65,536-row batches) through the daemon's cached job — every
+                depth a ``rescan`` — against the benchmark's plain reference
+                (``perf/reference/rf.py``) under the deployment's tolerances
+  8. summary    two JSON lines close stdout: the full summary (per-stage
                 seconds, compiles, cache hits, kernel verdicts, ``"claim":
                 null``), then — the last line, which the driver parses —
                 exactly ``{"ok": true, "device": {"platform", "kind",
@@ -1060,6 +1065,49 @@ def stage_kernels(cases: List[Tuple[str, Callable[[], tuple]]],
 # ---------------------------------------------------------------------------
 
 
+#: The forest stage: `perf/configs/rf_reg_d3000.json` with fewer trees over
+#: two of its six batches (every width as stated: a fit and its reference
+#: then take seconds, and every program is the deployment's shape but for
+#: the tree axis).
+FOREST_TREES = 4
+FOREST_PARAMS = {"batch_rows": 65536, "cached_batches": 2, "partitions": 2,
+                 "compare_trees": 2}
+
+
+def stage_forest(sizes: Optional[Dict[str, int]] = None,
+                 params: Optional[Dict[str, int]] = None,
+                 seed: int = 3000) -> Dict[str, Any]:
+    """One forest-regressor fit through `serve/daemon.py` `_Job("rf", ...)`
+    with its pass cached — driven as the benchmark's generator drives it —
+    and the comparison a benchmark run makes of it."""
+    from perf.harness import layout
+    from spark_rapids_ml_tpu.utils import metrics
+
+    bench = layout.load_benchmark(REPO)
+    cfg = {**layout.load_config(REPO, bench, "rf_reg_d3000"),
+           "num_trees": FOREST_TREES, **(sizes or {})}
+    generator = layout.load_module(REPO, "generators", "levels_cached")
+    agree = layout.load_module(REPO, "harness", "agree_rf")
+    reference = layout.load_module(REPO, "reference", "rf")
+
+    def cached_passes() -> float:
+        return sum(s["value"] for s in metrics.snapshot().get(
+            "srml_daemon_passes_total", {}).get("samples", [])
+            if s["labels"].get("source") == "cache")
+
+    before = cached_passes()
+    forest = generator.CachedForest(REPO, cfg, params or FOREST_PARAMS, seed, 1, say)
+    passes, captured = forest.captured_fit()
+    check(cached_passes() - before == len(passes) == cfg["max_depth"],
+          f"{cached_passes() - before} of the fit's {len(passes)} level passes came "
+          "from the pass cache")
+    compared = forest.compared(captured, [], agree, reference, say)
+    problems = agree.problems(compared)
+    check(not problems, f"the forest fit disagrees with perf/reference/rf.py: {problems}")
+    return {"compared": compared,
+            "level_seconds": [round(p["end"] - p["start"], 3) for p in passes]}
+
+
 def run_main_path(
     watch: CompileWatch,
     record: Callable[[str, Callable[[], Any]], Any],
@@ -1142,6 +1190,7 @@ def main() -> int:
         detail = run_main_path(watch, record)
         detail["kernels"] = record("kernels", lambda: stage_kernels(
             kernel_cases(make_mesh(devices=jax.devices()[:1]), default_mesh())))
+        detail["forest"] = record("forest", stage_forest)
     except Exception as e:  # noqa: BLE001 - report, then exit non-zero
         import traceback
 

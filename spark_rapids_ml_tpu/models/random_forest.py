@@ -90,10 +90,11 @@ _M_TRANSFORM_ROWS = metrics_mod.counter(
 #: node-table (and the deepest frontier histogram) stays addressable.
 MAX_MAX_DEPTH = 16
 
-#: In-memory fit row chunk: bounds the fused accumulate's transient
-#: one-hot expansion (O(chunk · d · bins · stats)) the way streaming
-#: fits bound their batches; the last partial chunk pads to the data
-#: axis, so chunking never changes the (additive) histograms.
+#: In-memory fit row chunk: what one placed batch of the fit holds, the
+#: way streaming fits bound their batches (the fold bounds its own
+#: transient: ops/histogram.py FOLD_CHUNK_ROWS); the last partial chunk
+#: pads to the data axis, so chunking never changes the (additive)
+#: histograms.
 FIT_CHUNK_ROWS = 8192
 
 
@@ -320,18 +321,22 @@ def row_identity_keys(partition: Optional[int], offset: int, n: int) -> np.ndarr
 
 
 def accumulate_histogram(
-    hist, tables: Dict[str, np.ndarray], x, y, mask, row_key,
+    hist, tables: Dict[str, np.ndarray], xs, ys, masks, row_keys,
     spec: ForestSpec, mesh: Mesh, n_valid: int,
 ):
-    """Fold one placed batch into the frontier histogram — the ONE entry
-    both the in-memory fit and the daemon job use (drift would break the
+    """Fold a run of placed batches — four tuples of equal length, a
+    single batch a run of one — into the frontier histogram in ONE
+    program: the ONE entry the in-memory fit, the daemon job's ``fold``
+    and its ``fold_group`` use (drift would break the
     single-daemon-oracle bitwise contract). Inputs are already padded +
     row-sharded; replicated table arrays upload per call (tiny next to
-    the batch). ``n_valid`` is the unpadded row count (booking only)."""
+    the batch). ``n_valid`` is the run's unpadded row count (booking
+    only)."""
     depth = int(tables["depth"][0])
-    update = hist_ops.hist_update_fn(
+    update = hist_ops.hist_update_group_fn(
         mesh, spec.num_trees, spec.max_bins, depth, spec.n_classes,
         spec.bootstrap, spec.seed, config.get("accum_dtype"),
+        config.get("compute_dtype"),
     )
     _M_HIST_ROWS.inc(int(n_valid), role=spec.role())
     # Edges upload in the accumulation dtype EXPLICITLY: on a non-x64
@@ -344,7 +349,7 @@ def accumulate_histogram(
         jnp.asarray(tables["bin_edges"], accum),
         jnp.asarray(tables["feature"]),
         jnp.asarray(tables["threshold"]),
-        x, y, mask, row_key,
+        tuple(xs), tuple(ys), tuple(masks), tuple(row_keys),
     )
 
 
@@ -364,9 +369,15 @@ def grow_level(
         spec.num_trees, depth, spec.n_classes, spec.subset_m, spec.seed,
         spec.min_instances, config.get("accum_dtype"),
     )
-    score, bf, bb, left, right, tot = (
-        np.asarray(jax.device_get(a)) for a in scorer(hist)
+    mask = hist_ops.feature_subset_mask(
+        spec.num_trees, W, depth, hist.shape[2], spec.subset_m, spec.seed
     )
+    # the scorer's dispatch and the read of its result: the wait for the
+    # pass's folds is in it (the histogram is their output)
+    with trace_span("forest.score"):
+        score, bf, bb, left, right, tot = (
+            np.asarray(jax.device_get(a)) for a in scorer(hist, mask)
+        )
     score = np.where(np.isfinite(score), score, -np.inf)
     feat, thr, val = tables["feature"], tables["threshold"], tables["value"]
     fl = feat[:, base: base + W]  # basic slices: views, writes stick
@@ -428,6 +439,14 @@ class RandomForestJob(JobAlgorithm):
     name = "rf"
     needs_labels = True
     iterative = True
+    # The cached batch is everything the fold placed: rows, mask, the
+    # label column and the bag keys (`place_columns`), so a cached pass
+    # weighs every row as the fed one did, whatever the params.
+    cacheable = True
+    # what the device waits for between two depths: the scorer (the wait
+    # for the pass's folds is in its read), the host's table update, the
+    # next depth's zero histogram, the snapshot
+    boundary_span = "forest.boundary"
     no_iterate = {
         # The kmeans-seed contract: a peer daemon the driver never
         # configured fails its tasks loudly instead of binning differently.
@@ -485,7 +504,13 @@ class RandomForestJob(JobAlgorithm):
         return {k: np.array(v) for k, v in self.tables.items()}
 
     def install_iterate(self, arrays):
-        self.tables = validate_forest_arrays(arrays, self.spec, self.n_cols)
+        # Copies: `grow_level` writes the tables in place, and an in-process
+        # caller's arrays (the wire's are its own already) must stay the
+        # iterate it installed.
+        self.tables = {
+            k: np.array(v) for k, v in validate_forest_arrays(
+                arrays, self.spec, self.n_cols).items()
+        }
 
     def zero_state(self):
         if self.tables is None:
@@ -514,6 +539,17 @@ class RandomForestJob(JobAlgorithm):
 
     def fold(self, state, xs, ms, columns=(), n=0):
         ys, ks = columns
+        return accumulate_histogram(
+            state, self.tables, (xs,), (ys,), (ms,), (ks,), self.spec,
+            self.mesh, n_valid=n,
+        )
+
+    def fold_group(self, state, xs, ms, columns=()):
+        ys, ks = columns
+        # The run's true rows, for the booking: what its masks count (a
+        # cached batch's mask was placed before this pass began; the read
+        # waits for no program).
+        n = sum(int(np.count_nonzero(np.asarray(m))) for m in ms)
         return accumulate_histogram(
             state, self.tables, xs, ys, ms, ks, self.spec, self.mesh,
             n_valid=n,
@@ -622,10 +658,8 @@ def _fit_forest(
     keys = row_identity_keys(None, 0, n)
     mask = np.ones((n,), np.float32)
     n_passes = 0
-    # Row-chunked passes: the fused accumulate's one-hot expansion is a
-    # transient O(chunk·d·bins·stats) — chunking bounds it the way the
-    # streaming fits bound their batches (the daemon path is naturally
-    # chunked by feed batches). Numerically free: histograms are sums.
+    # Row-chunked passes, as the daemon path is chunked by feed batches.
+    # Numerically free: histograms are sums.
     chunk = FIT_CHUNK_ROWS
     placed = [
         _place_batch(
@@ -644,7 +678,7 @@ def _fit_forest(
             )
             for (xs, ys, ms, ks), i in zip(placed, range(0, n, chunk)):
                 hist = accumulate_histogram(
-                    hist, tables, xs, ys, ms, ks, spec, mesh,
+                    hist, tables, (xs,), (ys,), (ms,), (ks,), spec, mesh,
                     n_valid=min(chunk, n - i),
                 )
             grow_level(tables, hist, spec)

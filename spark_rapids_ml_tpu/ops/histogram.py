@@ -13,11 +13,13 @@ CPU tree library:
   program; a sort-based exact split search is shape-dynamic and hostile
   to XLA).
 
-* **Fused per-node histogram builder** (:func:`hist_update_fn`): one
-  jitted, donated dispatch per batch does bin → descend-to-frontier →
-  scatter into the ``(tree, node, feature, bin, stat)`` tensor. The
-  scatter is formulated as a one-hot × stats contraction (an einsum over
-  the row axis) instead of a gather/scatter loop — MXU-shaped, and the
+* **Fused per-node histogram builder** (:func:`hist_update_group_fn`):
+  one jitted, donated dispatch per run of batches does bin →
+  descend-to-frontier → scatter into the ``(tree, node, feature, bin,
+  stat)`` tensor, walking each batch in row chunks inside the program (a
+  65,536 x 3,000 batch is never expanded whole). The scatter is
+  formulated as a one-hot × stats contraction (an einsum over the row
+  axis) instead of a gather/scatter loop — MXU-shaped, and the
   per-shard partials reduce with ``parallel.mapreduce.reduce_sum``
   (DrJAX psum; PAPERS.md 2403.07128) like every other sufficient
   statistic in the package. Histograms are ADDITIVE, so the tensor rides
@@ -27,7 +29,8 @@ CPU tree library:
   sums along the bin axis give every (feature, threshold) candidate's
   left/right statistics at once; Gini (classification) and variance
   (regression) gains reduce to the shared ``Σg²/n`` form, scored and
-  arg-maxed for ALL frontier nodes of ALL trees in one device program.
+  arg-maxed for ALL frontier nodes of ALL trees in one device program,
+  tree by tree (no second tensor the size of the frontier's).
 
 Stat layout (the ``S`` axis): classification keeps per-class counts
 (``S = n_classes``; the count is their sum), regression keeps
@@ -89,7 +92,10 @@ def quantile_bin_edges(sample: np.ndarray, max_bins: int) -> np.ndarray:
             f"max_bins = {max_bins} out of range [2, 256] (bin ids are uint8)"
         )
     qs = np.linspace(0.0, 1.0, int(max_bins) + 1)[1:-1]
-    edges = np.quantile(sample, qs, axis=0).T  # (d, B-1)
+    # feature-major first: a column's values lie together for its
+    # partition (the same numbers as over axis 0, in half the time at
+    # 65,536 x 3,000)
+    edges = np.quantile(np.ascontiguousarray(sample.T), qs, axis=1).T  # (d, B-1)
     return np.ascontiguousarray(edges, dtype=np.float64)
 
 
@@ -102,16 +108,17 @@ def bin_matrix(x, edges):
     )
 
 
-def _hash_u32(h):
+def _hash_u32(h, xp=jnp):
     """splitmix-style avalanche on uint32 lanes (counter-based RNG: the
     weight of a row must be a pure function of its identity, never of
-    batch boundaries or arrival order)."""
-    h = jnp.asarray(h, jnp.uint32)
-    h = h ^ (h >> 16)
-    h = h * jnp.uint32(0x7FEB352D)
-    h = h ^ (h >> 15)
-    h = h * jnp.uint32(0x846CA68B)
-    return h ^ (h >> 16)
+    batch boundaries or arrival order). ``xp``: ``jnp`` on the device,
+    ``np`` for what is a function of static numbers alone."""
+    h = xp.asarray(h, xp.uint32)
+    h = h ^ (h >> xp.uint32(16))
+    h = h * xp.uint32(0x7FEB352D)
+    h = h ^ (h >> xp.uint32(15))
+    h = h * xp.uint32(0x846CA68B)
+    return h ^ (h >> xp.uint32(16))
 
 
 def bootstrap_weights(row_key, n_trees: int, seed: int):
@@ -161,28 +168,115 @@ def descend_to_frontier(bins, feature, threshold, depth: int):
     return idx, alive
 
 
+#: Rows of a shard the fold walks at a time inside its one program. The
+#: batch is never expanded whole: a chunk's bin one-hot is
+#: ``chunk * d * B`` elements, built a feature block at a time
+#: (`_ONEHOT_BLOCK_BYTES`), and the frontier accumulator is read and
+#: written once a chunk — so once that tensor is large the chunk is long
+#: enough that the contraction, not that traffic, is what a chunk costs ...
+FOLD_CHUNK_ROWS = 16384
+#: ... and short while it is under `_SMALL_FRONTIER_BYTES` (the first
+#: depths: there the contraction is a few hundred rows tall and the chip
+#: runs short chunks of it faster — 0.36 s against 0.67 s for 131,072 rows
+#: of 3,000 columns at depth 0; PERF.md §6, PR 36).
+_SHORT_CHUNK_ROWS = 4096
+_SMALL_FRONTIER_BYTES = 512 << 20
+#: From this many rows of the contraction's left operand (trees x nodes x
+#: channels) on, a feature block's one-hot is written out before the
+#: contraction instead of being generated inside it: the product then runs
+#: at 85% of the MXU's bfloat16 peak instead of 61%, which is worth the
+#: one-hot's trip through HBM once the product is tall (PERF.md §6, PR 36).
+_MATERIALIZE_FROM_ROWS = 2048
+#: A chunk's bin one-hot is built in feature blocks of at most this size ...
+_ONEHOT_BLOCK_BYTES = 512 << 20
+#: ... and no larger than leaves the block's product — every tree's and
+#: node's, before it joins the accumulator — at most this size.
+_PRODUCT_BLOCK_BYTES = 384 << 20
+#: The scorer takes a frontier tensor of at most this size whole (its
+#: transient is a few times that), a larger one tree by tree.
+_SCORE_BLOCK_BYTES = 640 << 20
+#: Digits a label statistic travels in when the compute dtype holds fewer
+#: mantissa bits than the accumulation dtype (bfloat16 under float32: the
+#: MXU's narrow operands). The contraction's other operand is 0/1, so a
+#: term may travel as whole-number digits and the products accumulate
+#: exactly: a chunk's terms are scaled by one power of two to 22 bits and
+#: a sign, cut into three balanced base-256 digits — int8, the MXU's
+#: fastest operand — multiplied into int32 and put together again in the
+#: accumulation dtype. What is dropped is under 2^-22 of the chunk's
+#: largest term (whole-number labels up to 2^22 travel exactly): the order
+#: of a float32 accumulator's own rounding of such a sum.
+_DIGITS = 3
+_DIGIT_BITS = 8 * _DIGITS - 2
+
+
+def _digits(v):
+    """``v`` (float) → (``_DIGITS`` int8 arrays, least significant first,
+    the float32 factor that takes ``sum(digit_k * 256**k)`` back to ``v``)."""
+    _, e = jnp.frexp(jnp.max(jnp.abs(v)))  # max|v| <= 2**e
+    q = jnp.round(jnp.ldexp(v, _DIGIT_BITS - e)).astype(jnp.int32)
+    out = []
+    for _ in range(_DIGITS - 1):
+        digit = ((q + 128) & 255) - 128  # balanced: in [-128, 127]
+        out.append(digit.astype(jnp.int8))
+        q = (q - digit) >> 8
+    out.append(q.astype(jnp.int8))
+    return out, jnp.ldexp(jnp.float32(1.0), e - _DIGIT_BITS)
+
+
 @functools.lru_cache(maxsize=64)
-def hist_update_fn(
+def hist_update_group_fn(
     mesh, n_trees: int, max_bins: int, depth: int,
-    n_classes: int, bootstrap: bool, seed: int, ad: str,
+    n_classes: int, bootstrap: bool, seed: int, ad: str, cd: str,
 ):
     """Build the fused per-depth histogram accumulate for one mesh:
-    ``(hist, edges, feature, threshold, x, y, mask, row_key) -> hist``
-    with ``hist`` donated. One device dispatch does bin → descend →
-    weight → one-hot contraction → cross-shard ``reduce_sum``; the
-    returned (T, W, d, B, S) tensor is replicated (it is the pass's
-    sufficient statistic, exactly like a Gram block).
+    ``(hist, edges, feature, threshold, xs, ys, masks, row_keys) -> hist``
+    with ``hist`` donated and the other four TUPLES of equal length — a
+    run of placed batches folded in order in ONE program (a feed's batch
+    is a run of one; the daemon's cached pass a run of
+    ``serve/daemon.py`` ``_RESCAN_GROUP``). One device dispatch does, for
+    each batch and each `FOLD_CHUNK_ROWS` rows of it: bin → descend →
+    weight → one-hot contraction, the accumulator carried from chunk to
+    chunk and from batch to batch; the per-shard partials meet in one
+    ``reduce_sum`` a batch. The returned (T, W, d, B, S) tensor is
+    replicated (it is the pass's sufficient statistic, exactly like a
+    Gram block).
+
+    The "scatter" is a contraction over the row axis — MXU-shaped: the
+    ``(T * W * channels, rows)`` matrix of node one-hots times bag weight
+    times statistic against a feature block's ``(rows, features * B)``
+    bin one-hot, the product accumulated in ``ad``. Where the compute
+    dtype ``cd`` holds what ``ad`` holds (the CPU profiles) the operands
+    are of it and a statistic is one channel. Where it is narrower
+    (bfloat16 under float32, the chip) the operands are int8 — a count
+    channel's factors (0/1, a bag weight up to 6) are whole numbers, and a
+    label statistic travels as `_DIGITS` whole-number digits (`_digits`) —
+    so nothing is rounded that a float32 accumulation would keep. Nothing
+    of size rows x d x B x S, nor a second frontier tensor, ever exists:
+    the transient is one feature block's one-hot and its product.
 
     ``n_classes = 0`` selects the regression stat layout (count, Σy,
-    Σy²); otherwise per-class counts. ``ad`` is the accumulation dtype
-    (config ``accum_dtype``) — all one-hot factors are exact small
-    integers in it, so fold order cannot perturb classification
-    histograms and integer-labeled regression is bitwise-reproducible."""
-    accum = jnp.dtype(ad)
+    Σy²); otherwise per-class counts. All one-hot factors are exact small
+    integers, so fold order — chunking and grouping included — cannot
+    perturb the count channel, classification histograms or
+    integer-labeled regression."""
+    accum, compute = jnp.dtype(ad), jnp.dtype(cd)
     W = 1 << depth
+    n_stats = n_classes if n_classes > 0 else 3
+    narrow = jnp.finfo(compute).nmant < jnp.finfo(accum).nmant
+    operand, product = (jnp.int8, jnp.int32) if narrow else (compute, accum)
+    digits = _DIGITS if narrow else 1
+    # channel -> the statistic it is a part of
+    stat_of = (
+        tuple(range(n_stats)) if n_classes > 0
+        else (0,) + (1,) * digits + (2,) * digits
+    )
+    n_ch = len(stat_of)
+    one_device = mesh.shape[DATA_AXIS] == 1
 
-    def shard(hist, edges, feature, threshold, x, y, mask, row_key):
-        n = x.shape[0]
+    def chunk_operand(edges, feature, threshold, x, y, mask, row_key):
+        """One chunk's rows → (bins (c, d) int32, lhs (T * W * n_ch, c),
+        scales (n_ch,): what a channel's product is multiplied by)."""
+        c = x.shape[0]
         bins = bin_matrix(x.astype(edges.dtype), edges)
         idx, alive = descend_to_frontier(bins, feature, threshold, depth)
         node_f = jnp.take_along_axis(feature, idx, axis=1)
@@ -194,24 +288,131 @@ def hist_update_fn(
         if bootstrap:
             w = w * bootstrap_weights(row_key, n_trees, seed).astype(accum)
         pos = jnp.clip(idx - (W - 1), 0, W - 1)
-        node_oh = (
-            jax.nn.one_hot(pos, W, dtype=accum) * w[:, :, None]
-        )  # (T, n, W)
-        bin_oh = jax.nn.one_hot(bins, max_bins, dtype=accum)  # (n, d, B)
+        node_w = jnp.swapaxes(
+            jax.nn.one_hot(pos, W, dtype=accum) * w[:, :, None], 1, 2
+        )  # (T, W, c)
+        ones = jnp.ones((), accum)
         if n_classes > 0:
             stat = jax.nn.one_hot(
                 jnp.clip(y.astype(jnp.int32), 0, n_classes - 1),
                 n_classes, dtype=accum,
-            )  # (n, C)
+            ).T  # (C, c): 0/1, whole numbers in any dtype
+            channels = [
+                (node_w * stat[s][None, None, :]).astype(operand)
+                for s in range(n_classes)
+            ]
+            scales = [ones] * n_classes
         else:
             ya = y.astype(accum)
-            stat = jnp.stack(
-                [jnp.ones((n,), accum), ya, ya * ya], axis=1
-            )  # (n, 3)
-        # (n, d, B, S) per-row terms, then T batched GEMM-shaped
-        # contractions over the row axis — the "scatter" as a matmul.
-        sb = bin_oh[:, :, :, None] * stat[:, None, None, :]
-        h = jnp.einsum("tnw,ndbs->twdbs", node_oh, sb)
+            channels, scales = [node_w.astype(operand)], [ones]
+            for label in (ya, ya * ya):
+                v = node_w * label[None, None, :]
+                if narrow:
+                    parts, unit = _digits(v)
+                    channels += parts
+                    scales += [
+                        (unit * 256.0 ** k).astype(accum)
+                        for k in range(digits)
+                    ]
+                else:
+                    channels.append(v.astype(operand))
+                    scales.append(ones)
+        lhs = jnp.stack(channels, axis=2)  # (T, W, n_ch, c)
+        return bins, lhs.reshape(n_trees * W * n_ch, c), jnp.stack(scales)
+
+    def fold_chunk(acc, bins, lhs, scales):
+        """acc (T, W, S, d, B) += the chunk's histogram, a feature block at
+        a time: every tree's and node's channels against the block's bin
+        one-hot in one contraction, joined to the accumulator in place."""
+        c, d = bins.shape
+        db = max(1, min(
+            d,
+            _ONEHOT_BLOCK_BYTES
+            // (c * max_bins * jnp.dtype(operand).itemsize),
+            _PRODUCT_BLOCK_BYTES
+            // (n_trees * W * n_ch * max_bins * accum.itemsize),
+        ))
+        n_blocks = -(-d // db)
+        db = -(-d // n_blocks)  # equal blocks; the last may reach back
+
+        def block(i, acc):
+            # Block i covers features [i * db, (i + 1) * db); where that
+            # would pass d it starts at d - db instead and the columns it
+            # shares with the block before are blanked (bin id -1: an
+            # all-zero one-hot), so one loop of one shape covers any d.
+            f0 = jnp.minimum(i * db, d - db).astype(jnp.int32)
+            cols = jax.lax.dynamic_slice_in_dim(bins, f0, db, axis=1)
+            cols = jnp.where(
+                (jnp.arange(db, dtype=jnp.int32) < i * db - f0)[None, :],
+                -1, cols,
+            )
+            bin_oh = jax.nn.one_hot(cols, max_bins, dtype=operand)
+            if lhs.shape[0] >= _MATERIALIZE_FROM_ROWS:
+                # written out, then a plain matrix product
+                h = jnp.matmul(
+                    lhs,
+                    jax.lax.optimization_barrier(
+                        bin_oh.reshape(c, db * max_bins)),
+                    preferred_element_type=product,
+                )
+            else:
+                # generated inside the contraction's own fusion
+                h = jnp.einsum(
+                    "mn,ndb->mdb", lhs, bin_oh,
+                    preferred_element_type=product,
+                )
+            h = h.reshape(n_trees, W, n_ch, db, max_bins)
+            # the parts of one statistic join smallest first
+            h = jnp.stack(
+                [
+                    functools.reduce(
+                        jnp.add,
+                        [h[:, :, k].astype(accum) * scales[k]
+                         for k in range(n_ch) if stat_of[k] == s],
+                    )
+                    for s in range(n_stats)
+                ],
+                axis=2,
+            )  # (T, W, S, db, B)
+            zero = jnp.zeros((), jnp.int32)
+            at = (zero, zero, zero, f0, zero)
+            old = jax.lax.dynamic_slice(acc, at, h.shape)
+            return jax.lax.dynamic_update_slice(acc, old + h, at)
+
+        return jax.lax.fori_loop(0, n_blocks, block, acc)
+
+    def fold_batch(acc, edges, feature, threshold, x, y, mask, row_key):
+        n = x.shape[0]
+        small = acc.size * accum.itemsize < _SMALL_FRONTIER_BYTES
+        c = min(n, _SHORT_CHUNK_ROWS if small else FOLD_CHUNK_ROWS)
+        n_chunks = -(-n // c)
+        pad = n_chunks * c - n
+        if pad:  # a ragged tail folds as masked rows
+            x = jnp.pad(x, ((0, pad), (0, 0)))
+            y, mask, row_key = (jnp.pad(a, (0, pad)) for a in (y, mask, row_key))
+
+        def chunk(i, acc):
+            rows = [
+                jax.lax.dynamic_slice_in_dim(a, i * c, c, axis=0)
+                for a in (x, y, mask, row_key)
+            ]
+            return fold_chunk(
+                acc, *chunk_operand(edges, feature, threshold, *rows)
+            )
+
+        return jax.lax.fori_loop(0, n_chunks, chunk, acc)
+
+    def shard(hist, edges, feature, threshold, x, y, mask, row_key):
+        if one_device:
+            # no partial to reduce: the batch joins the frontier tensor
+            # itself, and no second tensor of its size exists
+            return fold_batch(
+                hist, edges, feature, threshold, x, y, mask, row_key
+            )
+        h = fold_batch(
+            jnp.zeros_like(hist), edges, feature, threshold, x, y, mask,
+            row_key,
+        )
         return hist + mr.reduce_sum(h, DATA_AXIS)
 
     f = mr.map_fn(
@@ -222,10 +423,33 @@ def hist_update_fn(
             P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
         ),
         out_specs=P(),
+        # the accumulator is carried through the chunk loop, replicated on
+        # the way in and (on several devices) a per-shard partial inside
+        check_vma=False,
     )
-    # One ledger name pools every depth's accounting (per-depth programs
-    # are distinct shape-signatures under it — the ledger's own keying).
-    return ledgered_jit("histogram.update", f, donate_argnums=(0,))
+
+    def hist_update_group(hist, edges, feature, threshold, xs, ys, masks,
+                          row_keys):
+        # Inside the program the statistic axis stands before the feature
+        # axis, (T, W, S, d, B): the order the contraction writes, and the
+        # order the chip keeps a (T, W, d, B, S) array in anyway (bins
+        # minor, the statistic axis of 3 never a padded tile) — there the
+        # two moves are no copy, and no second frontier tensor exists.
+        acc = jnp.moveaxis(hist, 4, 2)
+        for x, y, mask, row_key in zip(xs, ys, masks, row_keys):
+            # the barrier keeps a run its calls bit for bit (XLA may not
+            # merge two batches' loops or reorder their additions)
+            acc = jax.lax.optimization_barrier(
+                f(acc, edges, feature, threshold, x, y, mask, row_key)
+            )
+        return jnp.moveaxis(acc, 2, 4)
+
+    # One ledger name pools every depth's and every run length's
+    # accounting (distinct shape-signatures under it — the ledger's own
+    # keying); in a device trace the program is `jit_hist_update_group`.
+    return ledgered_jit(
+        "histogram.update_group", hist_update_group, donate_argnums=(0,)
+    )
 
 
 def zero_hist(n_trees: int, depth: int, n_cols: int, max_bins: int,
@@ -236,30 +460,36 @@ def zero_hist(n_trees: int, depth: int, n_cols: int, max_bins: int,
     )
 
 
+@functools.lru_cache(maxsize=16)
 def feature_subset_mask(n_trees: int, width: int, depth: int, n_cols: int,
-                        m: int, seed: int):
+                        m: int, seed: int) -> np.ndarray:
     """Deterministic per-node feature subset (featureSubsetStrategy):
     ``(T, W, d)`` bool with exactly ``min(m, d)`` True per (tree, node),
     chosen by ranking counter-based hashes of (seed, tree, global node
-    id, feature) — no RNG state to thread through replays."""
+    id, feature) — no RNG state to thread through replays. A function of
+    static numbers alone, so it is taken on the host (numpy; a device
+    sort of 3,000 keys a node costs the scorer's program ten seconds of
+    compiling) and handed to the scorer as an operand."""
     if m >= n_cols:
-        return jnp.ones((n_trees, width, n_cols), jnp.bool_)
-    t = jnp.arange(n_trees, dtype=jnp.uint32)[:, None, None]
+        return np.ones((n_trees, width, n_cols), np.bool_)
+    t = np.arange(n_trees, dtype=np.uint32)[:, None, None]
     node = (
-        jnp.uint32(width - 1)
-        + jnp.arange(width, dtype=jnp.uint32)[None, :, None]
+        np.uint32(width - 1)
+        + np.arange(width, dtype=np.uint32)[None, :, None]
     )
-    f = jnp.arange(n_cols, dtype=jnp.uint32)[None, None, :]
+    f = np.arange(n_cols, dtype=np.uint32)[None, None, :]
     r = _hash_u32(
         f
-        ^ _hash_u32(node * jnp.uint32(0x85EBCA6B))
+        ^ _hash_u32(node * np.uint32(0x85EBCA6B), np)
         ^ _hash_u32(
-            t * jnp.uint32(0xC2B2AE35)
-            + jnp.uint32(np.uint32(seed & 0xFFFFFFFF))
-        )
+            t * np.uint32(0xC2B2AE35)
+            + np.uint32(np.uint32(seed & 0xFFFFFFFF)),
+            np,
+        ),
+        np,
     )
-    order = jnp.argsort(r, axis=-1)
-    rank = jnp.argsort(order, axis=-1)
+    order = np.argsort(r, axis=-1, kind="stable")
+    rank = np.argsort(order, axis=-1, kind="stable")
     return rank < m
 
 
@@ -268,8 +498,9 @@ def best_splits_fn(
     n_trees: int, depth: int, n_classes: int, subset_m: int, seed: int,
     min_instances: int, ad: str,
 ):
-    """Vectorized split scorer for one frontier:
-    ``hist (T, W, d, B, S) -> (score, feature, bin, left, right, total)``
+    """Vectorized split scorer for one frontier: ``(hist (T, W, d, B, S),
+    mask (T, W, d)) -> (score, feature, bin, left, right, total)`` —
+    ``mask`` the nodes' feature subsets (:func:`feature_subset_mask`) —
     with ``score (T, W)`` the best impurity-improvement over every
     (feature, threshold-bin) candidate in the node's feature subset,
     ``left``/``right``/``total (T, W, S)`` the chosen split's child and
@@ -283,22 +514,26 @@ def best_splits_fn(
     outside the node's subset, duplicate-edge empty bins) score -inf."""
     accum = jnp.dtype(ad)
 
-    def scorer(hist):
-        T, W, d, B, S = hist.shape
+    def score_tree(args):
+        """One tree's frontier: hist (W, d, B, S), mask (W, d)."""
+        hist, mask = args
+        # The statistic axis before the feature axis, as the chip keeps
+        # the tensor (`hist_update_group_fn`): each statistic a whole slab.
+        hist = jnp.moveaxis(hist, 3, 1)
+        W, S, d, B = hist.shape
         cum = jnp.cumsum(hist, axis=3)
-        tot = cum[:, :, 0, B - 1, :]  # (T, W, S) — identical per feature
-        left = cum[:, :, :, : B - 1, :]  # (T, W, d, B-1, S)
-        right = tot[:, :, None, None, :] - left
+        tot = cum[:, :, 0, B - 1]  # (W, S) — identical per feature
+        left = cum[:, :, :, : B - 1]  # (W, S, d, B-1)
+        right = tot[:, :, None, None] - left
         if n_classes > 0:
-            n_l = jnp.sum(left, axis=-1)
-            n_r = jnp.sum(right, axis=-1)
-            g_l = jnp.sum(left * left, axis=-1)
-            g_r = jnp.sum(right * right, axis=-1)
+            n_l = jnp.sum(left, axis=1)
+            n_r = jnp.sum(right, axis=1)
+            g_l = jnp.sum(left * left, axis=1)
+            g_r = jnp.sum(right * right, axis=1)
         else:
-            n_l, n_r = left[..., 0], right[..., 0]
-            g_l = left[..., 1] * left[..., 1]
-            g_r = right[..., 1] * right[..., 1]
-        n_tot = n_l + n_r
+            n_l, n_r = left[:, 0], right[:, 0]
+            g_l = left[:, 1] * left[:, 1]
+            g_r = right[:, 1] * right[:, 1]
         score = (
             g_l / jnp.maximum(n_l, 1) + g_r / jnp.maximum(n_r, 1)
         )
@@ -307,25 +542,35 @@ def best_splits_fn(
             g_t = jnp.sum(tot * tot, axis=-1)
             n_t = jnp.sum(tot, axis=-1)
         else:
-            g_t = tot[..., 1] * tot[..., 1]
-            n_t = tot[..., 0]
-        score = score - (g_t / jnp.maximum(n_t, 1))[:, :, None, None]
+            g_t = tot[:, 1] * tot[:, 1]
+            n_t = tot[:, 0]
+        score = score - (g_t / jnp.maximum(n_t, 1))[:, None, None]
         mi = jnp.asarray(float(min_instances), accum)
-        valid = (n_l >= mi) & (n_r >= mi)
-        mask = feature_subset_mask(T, W, depth, d, subset_m, seed)
-        valid = valid & mask[:, :, :, None]
-        score = jnp.where(valid, score, -jnp.inf)
-        flat = score.reshape(T, W, d * (B - 1))
-        best = jnp.argmax(flat, axis=-1)
-        best_score = jnp.take_along_axis(flat, best[:, :, None], -1)[..., 0]
-        best_f = (best // (B - 1)).astype(jnp.int32)
-        best_b = (best % (B - 1)).astype(jnp.int32)
-        pick = lambda a: jnp.take_along_axis(  # noqa: E731 - local gather
+        valid = (n_l >= mi) & (n_r >= mi) & mask[:, :, None]
+        score = jnp.where(valid, score, -jnp.inf)  # (W, d, B-1)
+        # The first maximum in (feature, bin) order, in two steps: the
+        # first best bin of every feature, then the first best feature.
+        bin_of = jnp.argmax(score, axis=-1)  # (W, d)
+        best_f = jnp.argmax(jnp.max(score, axis=-1), axis=-1).astype(jnp.int32)
+        best_b = jnp.take_along_axis(
+            bin_of, best_f[:, None], axis=1)[:, 0].astype(jnp.int32)
+        pick = lambda a, lead: jnp.take_along_axis(  # noqa: E731 - local gather
             jnp.take_along_axis(
-                a, best_f[:, :, None, None, None], axis=2
-            ),
-            best_b[:, :, None, None, None], axis=3,
-        )[:, :, 0, 0, :]
-        return best_score, best_f, best_b, pick(left), pick(right), tot
+                a, best_f.reshape((W,) + (1,) * (a.ndim - 1)), axis=lead),
+            best_b.reshape((W,) + (1,) * (a.ndim - 1)), axis=lead + 1,
+        )
+        best_score = pick(score, 1)[:, 0, 0]
+        return (best_score, best_f, best_b, pick(left, 2)[:, :, 0, 0],
+                pick(right, 2)[:, :, 0, 0], tot)
+
+    def scorer(hist, mask):
+        # Tree by tree once the frontier is wide or deep: the cumulative
+        # sums, both children's statistics
+        # and the scores are each the size of what they are taken from, and
+        # a frontier tensor may fill a third of the device (one tree's
+        # share of it is the transient, not the whole).
+        if hist.size * accum.itemsize <= _SCORE_BLOCK_BYTES:
+            return jax.vmap(score_tree)((hist, mask))
+        return jax.lax.map(score_tree, (hist, mask))
 
     return ledgered_jit("histogram.best_splits", scorer)

@@ -887,7 +887,7 @@ class _SparkAdapter:
         # Pass cache (docs/protocol.md "rescan"): with a budget configured,
         # the passes after the first of a fit whose job algorithm says
         # `cacheable_for` its params (models/jobs.py: kmeans, binary
-        # logreg) are asked of the daemons' caches (`rescan`), and rows
+        # logreg, rf) are asked of the daemons' caches (`rescan`), and rows
         # cross the wire once. 0 (the default) = off: not one op, ack
         # column, import or branch more than before. Decided below, once
         # the fit's feed params are known.
@@ -1867,7 +1867,9 @@ class _SparkAdapter:
                 rows = 0
 
                 def rf_pass(pass_id):
-                    n = run_pass(pass_id)
+                    # every depth after the first from the daemons' caches
+                    # where the last fed pass left every row there
+                    n = scan(pass_id)
                     if n == 0:
                         raise ValueError("cannot fit on an empty DataFrame")
                     with trace_span("step"):

@@ -39,6 +39,21 @@ def test_main_path_stages_on_the_cpu_mesh():
     json.dumps(out)  # the summary must serialize
 
 
+def test_the_forest_stage_on_the_cpu_mesh():
+    """Stage 7 small (d=64, 16 bins, depth 3) through the function ``main``
+    runs at the deployment's widths: every level from the job's pass cache,
+    the fit inside the deployment's tolerances of the plain reference."""
+    out = chip_smoke.stage_forest(
+        sizes={"n_cols": 64, "num_trees": 3, "max_bins": 16, "max_depth": 3,
+               "daemon_pass_cache_mb": 4, "forest_hist_budget_mb": 4},
+        params={"batch_rows": 512, "cached_batches": 2, "partitions": 2,
+                "compare_trees": 2})
+    assert len(out["level_seconds"]) == 3
+    assert out["compared"]["count_mismatch"] == [0.0, 0.0]
+    assert out["compared"]["split_equal_share"][0] == 1.0
+    json.dumps(out)
+
+
 def test_result_line_is_exactly_the_drivers_contract():
     line = chip_smoke.result_line(
         {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})

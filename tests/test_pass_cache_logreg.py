@@ -67,7 +67,9 @@ def _phase_count(phase):
 
 
 def test_the_table_says_which_algorithms_may_keep_their_pass(mesh8):
-    assert {a for a, cls in JOB_ALGORITHMS.items() if cls.cacheable} == {"kmeans", "logreg"}
+    # the forest joined them in PR 36 (tests/test_pass_cache_forest.py)
+    assert {a for a, cls in JOB_ALGORITHMS.items() if cls.cacheable} == {
+        "kmeans", "logreg", "rf"}
     binary = job_algorithm("logreg")(D, mesh8, {})
     assert binary.cacheable_for({}) and binary.boundary_span == "newton.boundary"
     # the multinomial job has no group program: the class says so for its
