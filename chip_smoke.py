@@ -21,7 +21,10 @@ config #2: d=2048, k=32) under the shipped ``auto`` profile:
                 (3,000 float32 columns, 128 bins, depth 6; a few trees, two
                 65,536-row batches) through the daemon's cached job — every
                 depth a ``rescan`` — against the benchmark's plain reference
-                (``perf/reference/rf.py``) under the deployment's tolerances
+                (``perf/reference/rf.py``) under the deployment's tolerances;
+                and its depth-2 level folded both ways — the whole frontier,
+                and one child of every pair with the sibling taken from the
+                parent's histogram — the count channel equal cell for cell
   8. summary    two JSON lines close stdout: the full summary (per-stage
                 seconds, compiles, cache hits, kernel verdicts, ``"claim":
                 null``), then — the last line, which the driver parses —
@@ -1090,22 +1093,77 @@ def stage_forest(sizes: Optional[Dict[str, int]] = None,
     agree = layout.load_module(REPO, "harness", "agree_rf")
     reference = layout.load_module(REPO, "reference", "rf")
 
-    def cached_passes() -> float:
-        return sum(s["value"] for s in metrics.snapshot().get(
-            "srml_daemon_passes_total", {}).get("samples", [])
-            if s["labels"].get("source") == "cache")
+    def counter(name: str, **labels: str) -> float:
+        return sum(s["value"] for s in metrics.snapshot().get(name, {}).get("samples", [])
+                   if all(s["labels"].get(k) == v for k, v in labels.items()))
 
-    before = cached_passes()
+    def cached_passes() -> float:
+        return counter("srml_daemon_passes_total", source="cache")
+
+    def frontier_nodes() -> Dict[str, float]:
+        return {how: counter("srml_forest_frontier_nodes_total", how=how)
+                for how in ("folded", "derived")}
+
+    before, nodes_before = cached_passes(), frontier_nodes()
     forest = generator.CachedForest(REPO, cfg, params or FOREST_PARAMS, seed, 1, say)
     passes, captured = forest.captured_fit()
     check(cached_passes() - before == len(passes) == cfg["max_depth"],
           f"{cached_passes() - before} of the fit's {len(passes)} level passes came "
           "from the pass cache")
+    nodes = {how: n - nodes_before[how] for how, n in frontier_nodes().items()}
+    check(nodes["derived"] > 0,
+          f"the fit derived no node's histogram from its parent's ({nodes}): every level "
+          "after the first should fold one child of a split and subtract for the other")
+    both_ways = forest_level_both_ways(forest.job, captured["levels"], depth=2)
+    check(both_ways["derived_nodes"] > 0 and both_ways["count_cells_differ"] == 0,
+          "the depth-2 level folded whole and folded by halves (one child of a pair "
+          f"contracted, its sibling the parent less it) differ: {both_ways}")
     compared = forest.compared(captured, [], agree, reference, say)
     problems = agree.problems(compared)
     check(not problems, f"the forest fit disagrees with perf/reference/rf.py: {problems}")
-    return {"compared": compared,
+    return {"compared": compared, "both_ways": both_ways, "frontier_nodes": nodes,
+            "derived_share": round(100.0 * nodes["derived"] / sum(nodes.values()), 2),
             "level_seconds": [round(p["end"] - p["start"], 3) for p in passes]}
+
+
+def forest_level_both_ways(job, levels, depth: int) -> Dict[str, Any]:
+    """One level of the fit the job has just made, folded both ways from
+    its cached batches under the fit's own tables: the whole frontier
+    contracted, and (ISSUE 37) a state seeded from the complete histogram
+    one depth up with one child of every pair contracted. The count channel
+    is whole numbers either way, so it must agree cell for cell — on the
+    chip, where a cast XLA elides has been seen only there (PR 36)."""
+    import jax.numpy as jnp
+
+    from spark_rapids_ml_tpu.models import random_forest as forest
+    from spark_rapids_ml_tpu.ops import histogram
+
+    algo = job.algorithm
+    spec, d = algo.spec, algo.n_cols
+    xs, ms, ys, ks = zip(*job._cache.batches)
+
+    def fold(tables, state, signs=None):
+        return forest.accumulate_histogram(
+            state, tables, xs, ys, ms, ks, spec, job.mesh, n_valid=job._cache.rows,
+            signs=signs)
+
+    def zeros(at):
+        return histogram.zero_hist(
+            spec.num_trees, at, d, spec.max_bins, spec.n_stats, algo.accum)
+
+    parent = fold(levels[depth - 1], zeros(depth - 1))
+    whole = fold(levels[depth], zeros(depth))
+    state, signs = forest.open_pass(levels[depth], spec, d, parent=parent)
+    halved = fold(levels[depth], state, signs)
+    label_rel = [
+        float(jnp.linalg.norm(halved[..., s] - whole[..., s])
+              / jnp.maximum(jnp.linalg.norm(whole[..., s]), 1e-30))
+        for s in range(1, spec.n_stats)]
+    return {"depth": depth, "derived_nodes": int((signs < 0).sum()),
+            "folded_nodes": int((signs > 0).sum()),
+            "count_cells_differ": int(jnp.sum(halved[..., 0] != whole[..., 0])),
+            "count_total": float(jnp.sum(whole[..., 0])),
+            "label_sums_rel": label_rel}
 
 
 def run_main_path(

@@ -129,6 +129,18 @@ class JobAlgorithm:
         """A pass's empty statistics at the installed iterate (*dispatches*)."""
         raise NotImplementedError
 
+    def next_pass_state(self):
+        """The job's state for the pass a ``step`` has just opened, asked
+        right after it in the same hold of the device lock. A stage's state
+        is always ``zero_state()``; an algorithm whose finished pass says
+        something of the next one's statistics starts the JOB's from that
+        (*dispatches*)."""
+        return self.zero_state()
+
+    def state_merged(self) -> None:
+        """The job's state has just taken another daemon's (``merge_state``
+        / ``reduce_mesh``): it is no longer this job's rows alone."""
+
     def place_columns(self, target: int, y=None, n: int = 0,
                       partition: Optional[int] = None, offset: int = 0) -> tuple:
         """The per-row device columns this batch's fold reads beside

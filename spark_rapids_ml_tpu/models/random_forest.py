@@ -76,6 +76,12 @@ _M_NODES_SPLIT = metrics_mod.counter(
     "srml_forest_nodes_split_total",
     "Frontier nodes split into children across all trees, by role",
 )
+_M_FRONTIER_NODES = metrics_mod.counter(
+    "srml_forest_frontier_nodes_total",
+    "Open frontier nodes whose histogram a level pass built, by how: "
+    "folded (its rows contracted) or derived (the parent's histogram less "
+    "its folded sibling's)",
+)
 _M_HIST_ROWS = metrics_mod.counter(
     "srml_forest_hist_rows_total",
     "Rows folded into per-node split histograms (each dataset pass "
@@ -308,6 +314,56 @@ def open_frontier_nodes(feature: np.ndarray, depth: int) -> int:
     return int(np.sum(feature[:, base: base + W] == OPEN))
 
 
+def pair_signs(tables: Dict[str, np.ndarray], spec: ForestSpec) -> np.ndarray:
+    """Which child of every frontier PAIR the next pass folds and which it
+    derives from the parent's histogram — ``(T, W/2, 2)`` of +1 (folded),
+    -1 (derived) and 0 (closed), from the iterate alone (``depth >= 1``):
+    where both children of a split are OPEN, the one with the smaller count
+    in the ``value`` table folds (ties: the left) and its sibling — the
+    larger, so its relative error the smaller — is derived; where one is
+    OPEN it folds and nothing is derived; a parent that did not split has
+    no open child. An operand of the halved fold beside ``feature`` and
+    ``threshold`` (ops/histogram.py `hist_update_group_fn`)."""
+    depth = int(tables["depth"][0])
+    W = 1 << depth
+    level = slice(W - 1, 2 * W - 1)
+    T = spec.num_trees
+    is_open = (tables["feature"][:, level] == OPEN).reshape(T, W // 2, 2)
+    value = tables["value"][:, level]
+    count = (value.sum(-1) if spec.n_classes > 0 else value[..., 0]).reshape(
+        T, W // 2, 2)
+    right_folds = np.where(
+        is_open.all(-1), count[..., 1] < count[..., 0], is_open[..., 1])
+    folded = is_open & (np.arange(2) == right_folds[..., None])
+    derived = is_open & ~folded
+    return folded.astype(np.int8) - derived.astype(np.int8)
+
+
+def open_pass(tables: Dict[str, np.ndarray], spec: ForestSpec, n_cols: int,
+              parent=None):
+    """A pass's state before its first fold, at the installed depth:
+    ``(state, signs)``. ``()`` where no node is open (no scan will fold
+    there: no frontier is allocated and the capacity gate, which a
+    histogram nobody builds must not trip, is not asked); else the gate,
+    then zeros — ``signs`` None: every fold of the pass contracts the whole
+    frontier — or, given ``parent`` (the COMPLETE histogram of the same
+    rows one depth up, consumed here), what is already known of the new
+    frontier (`hist_ops.seed_hist`) and the ``signs`` (:func:`pair_signs`)
+    every fold of the pass then takes: the one opening of a pass for the
+    in-memory fit and the daemon job. Dispatches."""
+    depth = int(tables["depth"][0])
+    if open_frontier_nodes(tables["feature"], depth) == 0:
+        return (), None
+    require_hist_capacity(spec, depth, n_cols)
+    accum = jnp.dtype(config.get("accum_dtype"))
+    if parent is None:
+        return hist_ops.zero_hist(
+            spec.num_trees, depth, n_cols, spec.max_bins, spec.n_stats, accum
+        ), None
+    signs = pair_signs(tables, spec)
+    return hist_ops.seed_hist(parent, jnp.asarray(signs)), signs
+
+
 def row_identity_keys(partition: Optional[int], offset: int, n: int) -> np.ndarray:
     """uint32 bootstrap-bag identity keys for ``n`` rows starting at
     partition-relative ``offset`` — a pure function of (partition,
@@ -322,7 +378,7 @@ def row_identity_keys(partition: Optional[int], offset: int, n: int) -> np.ndarr
 
 def accumulate_histogram(
     hist, tables: Dict[str, np.ndarray], xs, ys, masks, row_keys,
-    spec: ForestSpec, mesh: Mesh, n_valid: int,
+    spec: ForestSpec, mesh: Mesh, n_valid: int, signs=None,
 ):
     """Fold a run of placed batches — four tuples of equal length, a
     single batch a run of one — into the frontier histogram in ONE
@@ -331,12 +387,14 @@ def accumulate_histogram(
     single-daemon-oracle bitwise contract). Inputs are already padded +
     row-sharded; replicated table arrays upload per call (tiny next to
     the batch). ``n_valid`` is the run's unpadded row count (booking
-    only)."""
+    only). ``signs``: what :func:`open_pass` gave with the pass's state —
+    None folds the whole frontier, a table the halved fold of a state
+    seeded from the parent."""
     depth = int(tables["depth"][0])
     update = hist_ops.hist_update_group_fn(
         mesh, spec.num_trees, spec.max_bins, depth, spec.n_classes,
         spec.bootstrap, spec.seed, config.get("accum_dtype"),
-        config.get("compute_dtype"),
+        config.get("compute_dtype"), halved=signs is not None,
     )
     _M_HIST_ROWS.inc(int(n_valid), role=spec.role())
     # Edges upload in the accumulation dtype EXPLICITLY: on a non-x64
@@ -344,11 +402,15 @@ def accumulate_histogram(
     # per batch); naming the dtype keeps fit and predict binning in the
     # same precision on every profile (f64 under the parity tests).
     accum = jnp.dtype(config.get("accum_dtype"))
-    return update(
-        hist,
+    operands = (
         jnp.asarray(tables["bin_edges"], accum),
         jnp.asarray(tables["feature"]),
         jnp.asarray(tables["threshold"]),
+    )
+    if signs is not None:
+        operands += (jnp.asarray(signs, accum),)
+    return update(
+        hist, operands,
         tuple(xs), tuple(ys), tuple(masks), tuple(row_keys),
     )
 
@@ -427,6 +489,19 @@ def grow_level(
     return {"open_nodes": opened, "splits": n_split, "depth": depth + 1}
 
 
+def close_pass(
+    tables: Dict[str, np.ndarray], hist, spec: ForestSpec, signs=None,
+) -> Dict[str, int]:
+    """The end of a pass :func:`open_pass` opened (``signs``: what it
+    gave): books how the level's open nodes — the ones :func:`grow_level`
+    is about to score — came by their histograms, then grows the level."""
+    n_open = open_frontier_nodes(tables["feature"], int(tables["depth"][0]))
+    n_derived = 0 if signs is None else int((signs < 0).sum())
+    _M_FRONTIER_NODES.inc(n_open - n_derived, how="folded")
+    _M_FRONTIER_NODES.inc(n_derived, how="derived")
+    return grow_level(tables, hist, spec)
+
+
 class RandomForestJob(JobAlgorithm):
     """Histogram tree ensembles as a daemon job (docs/protocol.md "The
     `rf` job algo"): one pass per tree depth. The iterate is the (bin
@@ -434,7 +509,19 @@ class RandomForestJob(JobAlgorithm):
     BEFORE the first scan (a peer daemon not pre-seeded rejects its feeds
     loudly); a pass's state is ONE additive (tree, node, feature, bin,
     stat) histogram of the installed depth's frontier, so the cross-daemon
-    merge plane carries it like any other."""
+    merge plane carries it like any other.
+
+    A job that stepped its own pass keeps that pass's histogram across the
+    boundary: the next pass's state starts from it (`open_pass`) and its
+    folds — direct, staged, cached — contract one child of every split, the
+    sibling being the parent less that child. Decided by what the job
+    holds, never by an option: an installed iterate (`set_iterate`, a
+    restore, a peer daemon) comes with no parent, and a state that took a
+    merge holds other daemons' rows beside this one's — the parent must be
+    of exactly the rows the next pass folds HERE — so from its first merge
+    until an iterate is installed again the job hands no parent on (a peer
+    that once held rows may hold none in one pass and some in the next):
+    those passes fold the whole frontier."""
 
     name = "rf"
     needs_labels = True
@@ -445,7 +532,7 @@ class RandomForestJob(JobAlgorithm):
     cacheable = True
     # what the device waits for between two depths: the scorer (the wait
     # for the pass's folds is in its read), the host's table update, the
-    # next depth's zero histogram, the snapshot
+    # next depth's opening state (zeros, or the parent's seed), the snapshot
     boundary_span = "forest.boundary"
     no_iterate = {
         # The kmeans-seed contract: a peer daemon the driver never
@@ -469,6 +556,13 @@ class RandomForestJob(JobAlgorithm):
         # a clean first-feed error, never a mid-pass OOM.
         require_hist_capacity(self.spec, 0, n_cols)
         self.tables = None
+        # Of the pass being folded: its signs (None: the whole frontier) ...
+        self._signs = None
+        # ... whether a state of this job has taken another daemon's since
+        # the iterate was installed, and — between `step` and
+        # `next_pass_state` alone — the histogram `step` was handed.
+        self._merged = False
+        self._parent = None
 
     @staticmethod
     def feed_classes(params) -> int:
@@ -511,22 +605,23 @@ class RandomForestJob(JobAlgorithm):
             k: np.array(v) for k, v in validate_forest_arrays(
                 arrays, self.spec, self.n_cols).items()
         }
+        # an installed iterate comes with no histogram: its pass folds whole
+        self._signs = self._parent = None
+        self._merged = False
 
     def zero_state(self):
         if self.tables is None:
             return ()  # no iterate yet — feeds are rejected anyway
-        depth = int(self.tables["depth"][0])
-        if open_frontier_nodes(self.tables["feature"], depth) == 0:
-            # Grown out (or this depth is fully closed): no scan will ever
-            # fold here — skip the frontier alloc AND its capacity gate
-            # (the final boundary's peer sync must not trip on a
-            # histogram nobody will build).
-            return ()
-        require_hist_capacity(self.spec, depth, self.n_cols)
-        return hist_ops.zero_hist(
-            self.spec.num_trees, depth, self.n_cols,
-            self.spec.max_bins, self.spec.n_stats, self.accum,
-        )
+        return open_pass(self.tables, self.spec, self.n_cols)[0]
+
+    def next_pass_state(self):
+        parent, self._parent = self._parent, None
+        state, self._signs = open_pass(
+            self.tables, self.spec, self.n_cols, parent)
+        return state
+
+    def state_merged(self):
+        self._merged = True
 
     def place_columns(self, target, y=None, n=0, partition=None, offset=0):
         # Bootstrap-bag identity: the batch's rows are (partition,
@@ -541,7 +636,7 @@ class RandomForestJob(JobAlgorithm):
         ys, ks = columns
         return accumulate_histogram(
             state, self.tables, (xs,), (ys,), (ms,), (ks,), self.spec,
-            self.mesh, n_valid=n,
+            self.mesh, n_valid=n, signs=self._signs,
         )
 
     def fold_group(self, state, xs, ms, columns=()):
@@ -552,13 +647,15 @@ class RandomForestJob(JobAlgorithm):
         n = sum(int(np.count_nonzero(np.asarray(m))) for m in ms)
         return accumulate_histogram(
             state, self.tables, xs, ys, ms, ks, self.spec, self.mesh,
-            n_valid=n,
+            n_valid=n, signs=self._signs,
         )
 
     def step(self, state, params):
-        # the tables grow first: the job's zero_state() that follows, in
-        # the same hold of the device lock, is of the NEW depth
-        grown = grow_level(self.tables, state, self.spec)
+        # the tables grow first: the job's next_pass_state() that follows,
+        # in the same hold of the device lock, is of the NEW depth — and
+        # starts from this histogram where it is this job's rows alone
+        grown = close_pass(self.tables, state, self.spec, self._signs)
+        self._parent = None if self._merged else state
         return {k: grown[k] for k in ("depth", "open_nodes", "splits")}
 
     def finalize(self, state, params, rows, iteration):
@@ -651,7 +748,6 @@ def _fit_forest(
         cap = int(config.get("forest_seed_sample_rows"))
         edges = hist_ops.quantile_bin_edges(x[:cap], spec.max_bins)
     tables = init_forest_arrays(spec, edges)
-    ad = config.get("accum_dtype")
     # Row identity for bootstrap bags: the whole matrix is "partition 0",
     # offset = row index — the daemon's (partition, offset) keying with
     # one partition, so a one-partition daemon fit reproduces this fit.
@@ -669,19 +765,19 @@ def _fit_forest(
         for i in range(0, n, chunk)
     ]
     with trace_span("forest grow"):
+        hist = None
         for depth in range(spec.max_depth + 1):
             if open_frontier_nodes(tables["feature"], depth) == 0:
                 break
-            require_hist_capacity(spec, depth, d)
-            hist = hist_ops.zero_hist(
-                spec.num_trees, depth, d, spec.max_bins, spec.n_stats, ad
-            )
+            # every pass but the first opens from the one before (the
+            # single-daemon job's path: `step`, then `next_pass_state`)
+            hist, signs = open_pass(tables, spec, d, parent=hist)
             for (xs, ys, ms, ks), i in zip(placed, range(0, n, chunk)):
                 hist = accumulate_histogram(
                     hist, tables, (xs,), (ys,), (ms,), (ks,), spec, mesh,
-                    n_valid=min(chunk, n - i),
+                    n_valid=min(chunk, n - i), signs=signs,
                 )
-            grow_level(tables, hist, spec)
+            close_pass(tables, hist, spec, signs)
             n_passes += 1
     arrays = dict(tables)
     arrays.pop("depth")

@@ -24,6 +24,12 @@ CPU tree library:
   (DrJAX psum; PAPERS.md 2403.07128) like every other sufficient
   statistic in the package. Histograms are ADDITIVE, so the tensor rides
   the daemon's cross-daemon merge/reduce_mesh plane completely unchanged.
+  The contraction spends a row of its left operand on every frontier node
+  though a data row stands on ONE of them, so from depth 1 on a pass that
+  holds the complete histogram one depth up (:func:`seed_hist`) folds one
+  child of every split only — the one with fewer rows — at half the height,
+  and its sibling is the parent less it, subtracted as the product joins
+  the accumulator (LightGBM's and XGBoost-hist's histogram subtraction).
 
 * **Vectorized best-split scoring** (:func:`best_splits_fn`): cumulative
   sums along the bin axis give every (feature, threshold) candidate's
@@ -168,25 +174,71 @@ def descend_to_frontier(bins, feature, threshold, depth: int):
     return idx, alive
 
 
+def route_to_frontier(bins, feature, threshold, depth: int, dtype):
+    """The fold's own descent: every row's position ON the frontier of
+    ``depth`` (0 .. 2^depth - 1) in every tree, ``(pos (T, n) int32, alive
+    (T, n) bool)`` — :func:`descend_to_frontier`'s routing to the bit
+    (``pos = idx - (2^depth - 1)`` where ``alive``), made of dense products
+    and selects: a level's "bin at the node's split feature" is ONE small
+    product of the rows' bin ids with the level's split features one-hot,
+    ``(T * nodes, d) x (d, n)``, and a node's threshold, feature and
+    state reach a row through its node one-hot. The chip runs a gather of
+    (tree, row) pairs at tens of nanoseconds an element — 0.06-0.09 s a
+    level and 65,536-row batch, a fifth of a deep level's fold (PERF.md
+    §6, PR 37) — and this in a few milliseconds. ``dtype``: the product's
+    operands (bin ids are whole numbers under 256: exact from bfloat16 up,
+    accumulated in float32)."""
+    T = feature.shape[0]
+    n, d = bins.shape
+    pos = jnp.zeros((T, n), jnp.int32)
+    alive = jnp.ones((T, n), jnp.bool_)
+    ids = bins.astype(dtype)
+    for level in range(depth):
+        width = 1 << level
+        nodes = slice(width - 1, 2 * width - 1)
+        feat, thr = feature[:, nodes], threshold[:, nodes]  # (T, width)
+        splits_on = jax.nn.one_hot(jnp.clip(feat, 0, d - 1), d, dtype=dtype)
+        bin_at = jnp.einsum(
+            "twd,nd->twn", splits_on, ids,
+            preferred_element_type=jnp.float32,
+        )
+        here = pos[:, None, :] == jnp.arange(width, dtype=jnp.int32)[None, :, None]
+        right = jnp.any(
+            here & (bin_at > thr[:, :, None].astype(jnp.float32)), axis=1)
+        internal = jnp.any(here & (feat >= 0)[:, :, None], axis=1)
+        pos = jnp.where(internal, 2 * pos + right.astype(jnp.int32), pos)
+        alive = alive & internal
+    return pos, alive
+
+
 #: Rows of a shard the fold walks at a time inside its one program. The
 #: batch is never expanded whole: a chunk's bin one-hot is
 #: ``chunk * d * B`` elements, built a feature block at a time
 #: (`_ONEHOT_BLOCK_BYTES`), and the frontier accumulator is read and
-#: written once a chunk — so once that tensor is large the chunk is long
-#: enough that the contraction, not that traffic, is what a chunk costs ...
+#: written once a chunk — a long chunk, so that the contraction and not
+#: that traffic is what a chunk costs ...
 FOLD_CHUNK_ROWS = 16384
-#: ... and short while it is under `_SMALL_FRONTIER_BYTES` (the first
-#: depths: there the contraction is a few hundred rows tall and the chip
-#: runs short chunks of it faster — 0.36 s against 0.67 s for 131,072 rows
-#: of 3,000 columns at depth 0; PERF.md §6, PR 36).
+#: ... and a short one while the contraction's left operand (trees x slots
+#: x channels; slots: the frontier's nodes, or its pairs in a halved fold)
+#: is under `_SHORT_CHUNKS_BELOW_ROWS` rows tall. The operand's height
+#: decides, not the frontier tensor's bytes, and alike for both folds: for
+#: 131,072 rows of 3,000 columns 210 rows read 0.32 s short and 0.65 long
+#: (depth 0; the halved depth 1: 0.34 / 0.65), 420 rows 0.42 / 0.37 (depth
+#: 1) and 0.48 / 0.38 (the halved depth 2), 840 rows 0.79 / 0.58 (PERF.md
+#: §6, PR 37; PR 36's descent by gathers had put the line at 840).
 _SHORT_CHUNK_ROWS = 4096
-_SMALL_FRONTIER_BYTES = 512 << 20
-#: From this many rows of the contraction's left operand (trees x nodes x
-#: channels) on, a feature block's one-hot is written out before the
-#: contraction instead of being generated inside it: the product then runs
-#: at 85% of the MXU's bfloat16 peak instead of 61%, which is worth the
-#: one-hot's trip through HBM once the product is tall (PERF.md §6, PR 36).
+_SHORT_CHUNKS_BELOW_ROWS = 320
+#: From this many rows of the contraction's left operand on, a feature
+#: block's one-hot is written out before the contraction instead of being
+#: generated inside it: the product then runs at 85% of the MXU's bfloat16
+#: peak instead of 61%, which is worth the one-hot's trip through HBM once
+#: the product is tall — 3,360 rows 1.83 s written out against 2.25 (depth
+#: 4; the halved depth 5: 1.94 / 2.35), 1,680 rows 1.30 / 1.16 (depth 3;
+#: the halved depth 4: 1.35 / 1.21) (PERF.md §6, PRs 36 and 37).
 _MATERIALIZE_FROM_ROWS = 2048
+#: Rows of a float32 tile on the chip: the grain of the accumulator's
+#: feature axis (its bins are the 128 lanes).
+_FEATURE_TILE = 8
 #: A chunk's bin one-hot is built in feature blocks of at most this size ...
 _ONEHOT_BLOCK_BYTES = 512 << 20
 #: ... and no larger than leaves the block's product — every tree's and
@@ -227,19 +279,37 @@ def _digits(v):
 def hist_update_group_fn(
     mesh, n_trees: int, max_bins: int, depth: int,
     n_classes: int, bootstrap: bool, seed: int, ad: str, cd: str,
+    halved: bool = False,
 ):
     """Build the fused per-depth histogram accumulate for one mesh:
-    ``(hist, edges, feature, threshold, xs, ys, masks, row_keys) -> hist``
-    with ``hist`` donated and the other four TUPLES of equal length — a
-    run of placed batches folded in order in ONE program (a feed's batch
-    is a run of one; the daemon's cached pass a run of
-    ``serve/daemon.py`` ``_RESCAN_GROUP``). One device dispatch does, for
-    each batch and each `FOLD_CHUNK_ROWS` rows of it: bin → descend →
-    weight → one-hot contraction, the accumulator carried from chunk to
-    chunk and from batch to batch; the per-shard partials meet in one
-    ``reduce_sum`` a batch. The returned (T, W, d, B, S) tensor is
-    replicated (it is the pass's sufficient statistic, exactly like a
-    Gram block).
+    ``(hist, tables, xs, ys, masks, row_keys) -> hist`` with ``hist``
+    donated, ``tables = (edges, feature, threshold)`` the replicated
+    iterate and the other four TUPLES of equal length — a run of placed
+    batches folded in order in ONE program (a feed's batch is a run of
+    one; the daemon's cached pass a run of ``serve/daemon.py``
+    ``_RESCAN_GROUP``). One device dispatch does, for each batch and each
+    chunk of its rows: bin → descend → weight → one-hot contraction, the
+    accumulator carried from chunk to chunk and from batch to batch; the
+    per-shard partials meet in one ``reduce_sum`` a batch. The returned
+    (T, W, d, B, S) tensor is replicated (it is the pass's sufficient
+    statistic, exactly like a Gram block).
+
+    ``halved`` (``depth >= 1``) is the fold of a pass whose state was
+    seeded from the parent's histogram (:func:`seed_hist`): ``tables``
+    then ends in ``signs`` (T, W/2, 2), the children of every frontier
+    PAIR marked +1 (folded), -1 (derived: its slot holds the parent's
+    histogram) or 0 (closed). The node one-hot runs over the W/2 pairs and
+    weighs only the rows that stand on a pair's folded child, so the
+    contraction is half as tall — depth ``d``'s costs what depth ``d - 1``'s
+    did; the product joins the folded child's slot of the accumulator as it
+    is and a derived sibling's negated: parent − child, what is left of the
+    parent's rows. The fold stays a sum of per-row terms, so stages, runs
+    of batches and the shards' ``reduce_sum`` (of a partial half as tall)
+    add up as they do for the whole frontier, and once every row of the
+    parent's pass is folded the state IS the frontier's histogram. A count
+    channel's difference is exact (whole numbers under 2^24 in float32); a
+    label statistic's carries one more rounding of the accumulation dtype a
+    chunk, against the parent's cell.
 
     The "scatter" is a contraction over the row axis — MXU-shaped: the
     ``(T * W * channels, rows)`` matrix of node one-hots times bag weight
@@ -265,6 +335,7 @@ def hist_update_group_fn(
     narrow = jnp.finfo(compute).nmant < jnp.finfo(accum).nmant
     operand, product = (jnp.int8, jnp.int32) if narrow else (compute, accum)
     digits = _DIGITS if narrow else 1
+    route_dtype = compute if narrow else jnp.float32
     # channel -> the statistic it is a part of
     stat_of = (
         tuple(range(n_stats)) if n_classes > 0
@@ -272,25 +343,40 @@ def hist_update_group_fn(
     )
     n_ch = len(stat_of)
     one_device = mesh.shape[DATA_AXIS] == 1
+    if halved and depth < 1:
+        raise ValueError("a halved fold needs a parent: depth >= 1")
+    # what the node one-hot runs over: the frontier's nodes, or its pairs
+    slots = W // 2 if halved else W
 
-    def chunk_operand(edges, feature, threshold, x, y, mask, row_key):
-        """One chunk's rows → (bins (c, d) int32, lhs (T * W * n_ch, c),
-        scales (n_ch,): what a channel's product is multiplied by)."""
+    def signed(h, signs):
+        """A pairs' histogram (T, W/2, ...) → (T, W/2, 2, ...): what it
+        adds to each child's slot (+h folded, -h derived, 0 closed)."""
+        return signs.reshape(signs.shape + (1,) * (h.ndim - 2)) * h[:, :, None]
+
+    def chunk_operand(tables, x, y, mask, row_key):
+        """One chunk's rows → (bins (c, d) int32, lhs (T * slots * n_ch,
+        c), scales (n_ch,): what a channel's product is multiplied by)."""
+        edges, feature, threshold = tables[:3]
         c = x.shape[0]
         bins = bin_matrix(x.astype(edges.dtype), edges)
-        idx, alive = descend_to_frontier(bins, feature, threshold, depth)
-        node_f = jnp.take_along_axis(feature, idx, axis=1)
-        # Contributing rows: unpadded, not settled at a shallower leaf,
-        # and standing on a node that is actually OPEN this pass.
-        w = (
-            alive & (node_f == OPEN) & (mask > 0)[None, :]
-        ).astype(accum)
+        pos, alive = route_to_frontier(
+            bins, feature, threshold, depth, route_dtype)
+        # Contributing rows: unpadded, not settled at a shallower leaf ...
+        w = (alive & (mask > 0)[None, :]).astype(accum)
         if bootstrap:
             w = w * bootstrap_weights(row_key, n_trees, seed).astype(accum)
-        pos = jnp.clip(idx - (W - 1), 0, W - 1)
-        node_w = jnp.swapaxes(
-            jax.nn.one_hot(pos, W, dtype=accum) * w[:, :, None], 1, 2
-        )  # (T, W, c)
+        # ... and standing on a node that is actually OPEN this pass — of
+        # a halved pass: on a pair's folded child, and under the pair.
+        weighs = (
+            tables[3].reshape(n_trees, W) > 0 if halved
+            else feature[:, W - 1: 2 * W - 1] == OPEN
+        )
+        node = (
+            pos[:, None, :] == jnp.arange(W, dtype=jnp.int32)[None, :, None]
+        ) & weighs[:, :, None]  # (T, W, c)
+        if halved:
+            node = jnp.any(node.reshape(n_trees, slots, 2, c), axis=2)
+        node_w = node.astype(accum) * w[:, None, :]  # (T, slots, c)
         ones = jnp.ones((), accum)
         if n_classes > 0:
             stat = jax.nn.one_hot(
@@ -317,33 +403,46 @@ def hist_update_group_fn(
                 else:
                     channels.append(v.astype(operand))
                     scales.append(ones)
-        lhs = jnp.stack(channels, axis=2)  # (T, W, n_ch, c)
-        return bins, lhs.reshape(n_trees * W * n_ch, c), jnp.stack(scales)
+        lhs = jnp.stack(channels, axis=2)  # (T, slots, n_ch, c)
+        return bins, lhs.reshape(n_trees * slots * n_ch, c), jnp.stack(scales)
 
-    def fold_chunk(acc, bins, lhs, scales):
-        """acc (T, W, S, d, B) += the chunk's histogram, a feature block at
-        a time: every tree's and node's channels against the block's bin
-        one-hot in one contraction, joined to the accumulator in place."""
+    def fold_chunk(acc, bins, lhs, scales, signs=None):
+        """acc (T, slots, S, d, B) += the chunk's histogram, a feature
+        block at a time: every tree's and slot's channels against the
+        block's bin one-hot in one contraction, joined to the accumulator
+        in place. With ``signs`` the accumulator is the whole frontier's,
+        seen by pairs — (T, W/2, 2, S, d, B) — and a pair's product joins
+        both of its children's slots, each under its sign."""
         c, d = bins.shape
         db = max(1, min(
             d,
             _ONEHOT_BLOCK_BYTES
             // (c * max_bins * jnp.dtype(operand).itemsize),
             _PRODUCT_BLOCK_BYTES
-            // (n_trees * W * n_ch * max_bins * accum.itemsize),
+            // (n_trees * slots * n_ch * max_bins * accum.itemsize),
         ))
         n_blocks = -(-d // db)
         db = -(-d // n_blocks)  # equal blocks; the last may reach back
+        # In whole tiles of the accumulator's feature axis where d allows
+        # it, so that a block joins at an aligned offset (with the index
+        # unsigned — no wrap-around select — the chip makes the join one
+        # fused add-and-update: 0.065 s a batch at the halved depth 5
+        # where an update after an add took 0.24; PERF.md §6, PR 37).
+        tile = _FEATURE_TILE if d % _FEATURE_TILE == 0 else 1
+        db = min(d, -(-db // tile) * tile)
+        n_blocks = -(-d // db)
 
         def block(i, acc):
             # Block i covers features [i * db, (i + 1) * db); where that
             # would pass d it starts at d - db instead and the columns it
             # shares with the block before are blanked (bin id -1: an
             # all-zero one-hot), so one loop of one shape covers any d.
-            f0 = jnp.minimum(i * db, d - db).astype(jnp.int32)
+            f0 = tile * jnp.minimum(
+                i * (db // tile), (d - db) // tile).astype(jnp.uint32)
             cols = jax.lax.dynamic_slice_in_dim(bins, f0, db, axis=1)
             cols = jnp.where(
-                (jnp.arange(db, dtype=jnp.int32) < i * db - f0)[None, :],
+                (jnp.arange(db, dtype=jnp.int32)
+                 < i * db - f0.astype(jnp.int32))[None, :],
                 -1, cols,
             )
             bin_oh = jax.nn.one_hot(cols, max_bins, dtype=operand)
@@ -361,7 +460,7 @@ def hist_update_group_fn(
                     "mn,ndb->mdb", lhs, bin_oh,
                     preferred_element_type=product,
                 )
-            h = h.reshape(n_trees, W, n_ch, db, max_bins)
+            h = h.reshape(n_trees, slots, n_ch, db, max_bins)
             # the parts of one statistic join smallest first
             h = jnp.stack(
                 [
@@ -373,18 +472,20 @@ def hist_update_group_fn(
                     for s in range(n_stats)
                 ],
                 axis=2,
-            )  # (T, W, S, db, B)
-            zero = jnp.zeros((), jnp.int32)
-            at = (zero, zero, zero, f0, zero)
+            )  # (T, slots, S, db, B)
+            if signs is not None:
+                h = signed(h, signs)
+            zero = jnp.zeros((), jnp.uint32)
+            at = (zero,) * (h.ndim - 2) + (f0, zero)
             old = jax.lax.dynamic_slice(acc, at, h.shape)
             return jax.lax.dynamic_update_slice(acc, old + h, at)
 
         return jax.lax.fori_loop(0, n_blocks, block, acc)
 
-    def fold_batch(acc, edges, feature, threshold, x, y, mask, row_key):
+    def fold_batch(acc, tables, x, y, mask, row_key, signs=None):
         n = x.shape[0]
-        small = acc.size * accum.itemsize < _SMALL_FRONTIER_BYTES
-        c = min(n, _SHORT_CHUNK_ROWS if small else FOLD_CHUNK_ROWS)
+        short = n_trees * slots * n_ch < _SHORT_CHUNKS_BELOW_ROWS
+        c = min(n, _SHORT_CHUNK_ROWS if short else FOLD_CHUNK_ROWS)
         n_chunks = -(-n // c)
         pad = n_chunks * c - n
         if pad:  # a ragged tail folds as masked rows
@@ -397,29 +498,31 @@ def hist_update_group_fn(
                 for a in (x, y, mask, row_key)
             ]
             return fold_chunk(
-                acc, *chunk_operand(edges, feature, threshold, *rows)
+                acc, *chunk_operand(tables, *rows), signs=signs
             )
 
         return jax.lax.fori_loop(0, n_chunks, chunk, acc)
 
-    def shard(hist, edges, feature, threshold, x, y, mask, row_key):
+    def shard(hist, tables, x, y, mask, row_key):
+        signs = tables[3] if halved else None
         if one_device:
             # no partial to reduce: the batch joins the frontier tensor
             # itself, and no second tensor of its size exists
-            return fold_batch(
-                hist, edges, feature, threshold, x, y, mask, row_key
-            )
+            return fold_batch(hist, tables, x, y, mask, row_key, signs)
+        # the shards' partial is of the contraction's height: the
+        # frontier's nodes, or (halved) its pairs, signed after the sum
         h = fold_batch(
-            jnp.zeros_like(hist), edges, feature, threshold, x, y, mask,
-            row_key,
+            jnp.zeros((n_trees, slots) + hist.shape[-3:], accum),
+            tables, x, y, mask, row_key,
         )
-        return hist + mr.reduce_sum(h, DATA_AXIS)
+        h = mr.reduce_sum(h, DATA_AXIS)
+        return hist + (signed(h, signs) if halved else h)
 
     f = mr.map_fn(
         shard,
         mesh=mesh,
         in_specs=(
-            P(), P(), P(), P(),
+            P(), P(),
             P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
         ),
         out_specs=P(),
@@ -428,20 +531,24 @@ def hist_update_group_fn(
         check_vma=False,
     )
 
-    def hist_update_group(hist, edges, feature, threshold, xs, ys, masks,
-                          row_keys):
+    def hist_update_group(hist, tables, xs, ys, masks, row_keys):
         # Inside the program the statistic axis stands before the feature
         # axis, (T, W, S, d, B): the order the contraction writes, and the
         # order the chip keeps a (T, W, d, B, S) array in anyway (bins
         # minor, the statistic axis of 3 never a padded tile) — there the
-        # two moves are no copy, and no second frontier tensor exists.
+        # two moves are no copy, and no second frontier tensor exists. A
+        # halved fold sees the node axis by pairs, (T, W/2, 2, ...).
         acc = jnp.moveaxis(hist, 4, 2)
+        if halved:
+            acc = acc.reshape((n_trees, slots, 2) + acc.shape[2:])
         for x, y, mask, row_key in zip(xs, ys, masks, row_keys):
             # the barrier keeps a run its calls bit for bit (XLA may not
             # merge two batches' loops or reorder their additions)
             acc = jax.lax.optimization_barrier(
-                f(acc, edges, feature, threshold, x, y, mask, row_key)
+                f(acc, tables, x, y, mask, row_key)
             )
+        if halved:
+            acc = acc.reshape((n_trees, W) + acc.shape[3:])
         return jnp.moveaxis(acc, 2, 4)
 
     # One ledger name pools every depth's and every run length's
@@ -458,6 +565,20 @@ def zero_hist(n_trees: int, depth: int, n_cols: int, max_bins: int,
     return jnp.zeros(
         (n_trees, 1 << depth, n_cols, max_bins, n_stats), jnp.dtype(ad)
     )
+
+
+@ledgered_jit("histogram.seed_frontier")
+def seed_hist(parent, signs):
+    """What a pass already knows of its frontier before a row is folded:
+    the parent's complete histogram ``(T, W/2, d, B, S)`` in the slots of
+    the children that will be DERIVED from it (``signs (T, W/2, 2) < 0``),
+    zeros elsewhere → ``(T, W, d, B, S)``, the state the halved fold of
+    :func:`hist_update_group_fn` brings to the whole frontier's."""
+    kids = jnp.where(
+        (signs < 0)[:, :, :, None, None, None], parent[:, :, None], 0
+    )
+    T, pairs = parent.shape[:2]
+    return kids.reshape((T, 2 * pairs) + parent.shape[2:])
 
 
 @functools.lru_cache(maxsize=16)

@@ -610,9 +610,12 @@ class _Job:
     def durable_arrays(self) -> Dict[str, np.ndarray]:
         """The iterate arrays a pass-boundary snapshot stores (call under
         the job lock). Pass-local accumulator state is deliberately
-        excluded: at a boundary it is zero by construction, so the
-        snapshot is O(iterate) — the cheap-persistence property
-        core/checkpoint.py already proved for the O(d²) case."""
+        excluded: at a boundary it is zero, or what the algorithm's
+        `next_pass_state` carried over from the finished pass to save the
+        next one work (the forest's parent histogram) — which a restored
+        job does without: its pass opens from `zero_state()` and folds in
+        full. So the snapshot is O(iterate) — the cheap-persistence
+        property core/checkpoint.py already proved for the O(d²) case."""
         if not (self.algorithm.iterative and self.algorithm.installed):
             return {}
         with _DEVICE_LOCK:
@@ -1171,6 +1174,7 @@ class _Job:
                 for ol in peer_leaves:
                     leaves = [a + b for a, b in zip(leaves, ol)]
             self.state = jax.tree_util.tree_unflatten(treedef, leaves)
+            self.algorithm.state_merged()
             for _pid, _state, rows in contributions:
                 self.rows += int(rows)
                 self.pass_rows += int(rows)
@@ -1223,6 +1227,7 @@ class _Job:
                         )
                     merged.append(leaf + jnp.asarray(inc, leaf.dtype))
             self.state = jax.tree_util.tree_unflatten(treedef, merged)
+            self.algorithm.state_merged()
             self.rows += int(rows)
             self.pass_rows += int(rows)
             if merge_id is not None:
@@ -1323,14 +1328,15 @@ class _Job:
             self.algorithm.require_iterate("step")
             # The boundary's span is the algorithm's to name; what it wraps
             # is the job's: the wait for the pass's folds, the update and
-            # the next pass's zero state in ONE hold of the device lock
-            # (the zero state is of the iterate the update left), the
+            # the next pass's opening state in ONE hold of the device lock
+            # (it is of the iterate the update left: zeros, unless the
+            # algorithm starts it from the pass just finished), the
             # fields' scalars to the host, the snapshot.
             span = self.algorithm.boundary_span
             with trace_span(span) if span else contextlib.nullcontext():
                 with _DEVICE_LOCK:
                     fields = self.algorithm.step(self.state, params)
-                    self.state = self.algorithm.zero_state()
+                    self.state = self.algorithm.next_pass_state()
                 self._close_pass()
                 self.iteration += 1
                 info = {

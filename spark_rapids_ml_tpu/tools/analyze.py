@@ -1526,7 +1526,8 @@ _DEVICE_CALL_NAMES = frozenset(
 #: such a call IS a dispatch wherever it stands.
 _ALGORITHM_DISPATCH_MEMBERS = frozenset((
     "seed", "iterate_arrays", "install_iterate", "zero_state",
-    "place_columns", "fold", "fold_group", "step", "finalize",
+    "next_pass_state", "place_columns", "fold", "fold_group", "step",
+    "finalize",
 ))
 #: Compile-path call targets: host work that must not hold _DEVICE_LOCK.
 _COMPILE_CALL_NAMES = frozenset(

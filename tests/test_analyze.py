@@ -1525,6 +1525,8 @@ def merge(parts):
     # fold, and the zero state a stage or a boundary takes
     "self.algorithm.fold(state, xs, ms)",
     "self.algorithm.zero_state()",
+    # ... and the state a job opens its next pass with (ISSUE 37)
+    "self.algorithm.next_pass_state()",
     # a transfer
     "jax.device_put(xs, self.x_sharding)",
 ])

@@ -51,6 +51,11 @@ def test_the_forest_stage_on_the_cpu_mesh():
     assert len(out["level_seconds"]) == 3
     assert out["compared"]["count_mismatch"] == [0.0, 0.0]
     assert out["compared"]["split_equal_share"][0] == 1.0
+    # the depth-2 level folded whole and by halves: the same counts
+    ways = out["both_ways"]
+    assert ways["count_cells_differ"] == 0 and ways["count_total"] > 0
+    assert ways["derived_nodes"] > 0 and ways["folded_nodes"] >= ways["derived_nodes"]
+    assert 0 < out["derived_share"] < 50 and out["frontier_nodes"]["folded"] >= 3
     json.dumps(out)
 
 

@@ -235,6 +235,42 @@ def test_a_toy_algorithm_runs_through_a_real_job_with_the_daemon_unedited(
         _new_job("colsums", D, mesh8, {})
 
 
+@pytest.mark.parametrize("algo", ITERATIVE)
+def test_a_job_asks_its_algorithm_for_the_next_passs_state_and_tells_it_of_a_merge(
+        algo, mesh8, rng):
+    """The two hooks of ISSUE 37, through a real job: both merges say
+    `state_merged` once the add applied — not for a replay — and `step`
+    takes the next pass's state from `next_pass_state`: by default
+    `zero_state()`, zeros of the state's own shapes."""
+    x, labels = _rows(rng)
+    y = labels.get(algo)
+    here, there = (_new_job(algo, D, mesh8, PARAMS.get(algo)) for _ in range(2))
+    calls = []
+    for name in ("next_pass_state", "state_merged"):
+        real = getattr(here.algorithm, name)
+        setattr(here.algorithm, name,
+                lambda real=real, name=name: calls.append(name) or real())
+    start = _start(algo, x)
+    for job, rows in ((here, slice(0, 101)), (there, slice(101, N))):
+        if start is not None:
+            job.set_iterate(start, 0)
+        job.fold(x[rows], None if y is None else y[rows], pass_id=0)
+    arrays, meta = there.export_state()
+    here.merge_remote(arrays, meta["rows"], merge_id="m")
+    here.merge_remote(arrays, meta["rows"], merge_id="m")  # replayed: not applied
+    assert calls == ["state_merged"]
+    state, rows, _, _ = there.peek_pass_state()
+    here.merge_mesh([("peer", state, rows)], reduce_id="r")
+    here.merge_mesh([("peer", state, rows)], reduce_id="r")
+    assert calls == ["state_merged"] * 2
+    here.step({})
+    assert calls == ["state_merged"] * 2 + ["next_pass_state"]
+    zeros = jax.tree_util.tree_leaves(here.algorithm.zero_state())
+    assert len(zeros) == len(_leaves(here))
+    for got, zero in zip(_leaves(here), zeros):  # a merged forest hands no parent on
+        np.testing.assert_array_equal(got, np.asarray(zero))
+
+
 # -- (c) the structure that keeps it so -------------------------------------
 
 

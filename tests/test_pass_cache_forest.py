@@ -275,7 +275,7 @@ def test_the_chunked_fold_equals_the_whole_batch_contraction(
         monkeypatch.setattr(hist_ops, "_SHORT_CHUNK_ROWS", chunk)
     else:
         monkeypatch.setattr(hist_ops, "FOLD_CHUNK_ROWS", chunk)
-        monkeypatch.setattr(hist_ops, "_SMALL_FRONTIER_BYTES", 0)
+        monkeypatch.setattr(hist_ops, "_SHORT_CHUNKS_BELOW_ROWS", 0)
         monkeypatch.setattr(hist_ops, "_MATERIALIZE_FROM_ROWS", 0)
     hist_ops.hist_update_group_fn.cache_clear()
     try:
@@ -413,6 +413,331 @@ def test_a_restored_forest_job_has_no_cached_pass(mesh8):
     restored.commit(0, pass_id=1)
     assert job.rescan(1)["pass_rows"] == ROWS
     np.testing.assert_array_equal(_hist(job), _hist(restored))
+
+
+# ---- ISSUE 37: one child of every split is folded, its sibling is the parent less it ----
+
+
+def _signs(job):
+    return job.algorithm._signs
+
+
+def _frontier_nodes(how):
+    return _counter("srml_forest_frontier_nodes_total", how=how)
+
+
+@pytest.mark.parametrize("params", [REG, CLF], ids=["regressor", "classifier"])
+@pytest.mark.parametrize("transport", ["direct", "staged", "cached"])
+def test_a_seeded_pass_holds_the_direct_folds_histogram_at_every_depth(
+        mesh8, params, transport):
+    """`whole` is handed its iterate before every pass (so it holds no
+    parent and folds the whole frontier, as the job did before ISSUE 37);
+    `halved` steps on its own: from depth 1 on its state starts from the
+    parent's histogram and its folds — direct feeds, stages merged at their
+    commit, a rescan's runs, the last batch ragged — contract one child of
+    every pair. Whole-number rows: before every `step` the two states are
+    the same bits, and so are the tables after it."""
+    params = dict(params, max_depth=4)
+    x, y = _rows(61, classes=params["n_classes"])
+    whole, halved = _job(mesh8, 0, params), _job(mesh8, 16, params)
+    for job in (whole, halved):
+        job.set_iterate(_start(params, x), 0)
+    folded = derived = 0
+
+    def feed(job, it):
+        # a row's bag is keyed by its (partition, offset): both jobs feed alike
+        for i, (lo, hi) in enumerate(BATCHES):
+            job.fold(x[lo:hi], y[lo:hi], pass_id=it,
+                     partition=i % 2 if transport == "staged" else None)
+        if transport == "staged":
+            for part in (1, 0):
+                job.commit(part, pass_id=it)
+
+    for it in range(params["max_depth"] + 1):
+        feed(whole, it)
+        if transport == "cached" and it > 0:
+            halved.rescan(it)
+        else:
+            feed(halved, it)
+        signs = _signs(halved)
+        assert _signs(whole) is None and (signs is None) == (it == 0)
+        if it:
+            assert signs.shape == (params["num_trees"], 1 << (it - 1), 2)
+            folded += int((signs > 0).sum())
+            derived += int((signs < 0).sum())
+        np.testing.assert_array_equal(_hist(halved), _hist(whole))
+        info = halved.step({})
+        assert whole.step({}) == info
+        got, want = halved.get_iterate()[0], whole.get_iterate()[0]
+        for key in want:
+            np.testing.assert_array_equal(got[key], want[key], err_msg=key)
+        if info["open_nodes"] == 0:
+            break
+        whole.set_iterate(want, it + 1)  # an installed iterate: no parent
+    assert it >= 3 and derived > 0 and folded >= derived
+
+
+def _doctored(params, x, y, mesh):
+    """Tables at depth 2 with every case of a pair in them, and the
+    complete depth-1 histogram they descend from: tree 0 has a parent that
+    did not split (closed, its children closed) beside a split whose
+    children tie in their counts; tree 1 a pair with one OPEN child."""
+    spec, tables, placed, keys = _grown(params, x, y, mesh, depth=1)
+    xs, ys, ms, ks = placed
+    parent = rf.accumulate_histogram(
+        hist_ops.zero_hist(spec.num_trees, 1, x.shape[1], spec.max_bins, spec.n_stats,
+                           config.get("accum_dtype")),
+        tables, (xs,), (ys,), (ms,), (ks,), spec, mesh, n_valid=len(x))
+    rf.grow_level(tables, parent, spec)
+    feat, val = tables["feature"], tables["value"]
+    assert (feat[:2, 1:3] >= 0).all() and (feat[:2, 3:7] == OPEN).all()
+    feat[0, 1], feat[0, 3:5] = rf.LEAF, rf.LEAF  # the parent that did not split
+    val[0, 5] = val[0, 6]  # a tie: the left child folds
+    feat[1, 4] = rf.LEAF  # one OPEN child: it folds, nothing is derived
+    return spec, tables, placed, parent
+
+
+@pytest.mark.parametrize("params", [REG, CLF], ids=["regressor", "classifier"])
+def test_which_child_folds_and_the_halved_fold_over_every_case_of_a_pair(mesh8, params):
+    x, y = _rows(67, classes=params["n_classes"])
+    spec, tables, placed, parent = _doctored(params, x, y, mesh8)
+    signs = rf.pair_signs(tables, spec)
+    assert signs.shape == (spec.num_trees, 2, 2) and signs.dtype == np.int8
+    np.testing.assert_array_equal(signs[0], [[0, 0], [1, -1]])
+    np.testing.assert_array_equal(signs[1, 0], [1, 0])
+    count = tables["value"][:, 3:7].sum(-1) if spec.n_classes else tables["value"][:, 3:7, 0]
+    for t in range(2, spec.num_trees):
+        for pair in range(2):
+            left, right = count[t, 2 * pair], count[t, 2 * pair + 1]
+            assert list(signs[t, pair]) == ([-1, 1] if right < left else [1, -1])
+    xs, ys, ms, ks = placed
+    accum = config.get("accum_dtype")
+    want = np.asarray(rf.accumulate_histogram(
+        hist_ops.zero_hist(spec.num_trees, 2, D, spec.max_bins, spec.n_stats, accum),
+        tables, (xs,), (ys,), (ms,), (ks,), spec, mesh8, n_valid=len(x)))
+    state, got_signs = rf.open_pass(tables, spec, D, parent=parent)
+    np.testing.assert_array_equal(got_signs, signs)
+    seeded = np.asarray(state)
+    # the seed: the parent's histogram where a child will be derived, zeros elsewhere
+    np.testing.assert_array_equal(seeded[0, 3], np.asarray(parent)[0, 1])
+    assert not seeded[0, :3].any() and not seeded[1, :2].any()
+    got = np.asarray(rf.accumulate_histogram(
+        state, tables, (xs,), (ys,), (ms,), (ks,), spec, mesh8, n_valid=len(x), signs=signs))
+    assert want[0, 2:].any() and want[1, 0].any() and not want[0, :2].any()
+    np.testing.assert_array_equal(got, want)
+    # without a parent, at depth 0 or where nothing is open the pass opens as it did
+    assert rf.open_pass(tables, spec, D)[1] is None
+    tables["feature"][:, 3:7] = rf.LEAF
+    assert rf.open_pass(tables, spec, D, parent=parent) == ((), None)
+
+
+@pytest.mark.parametrize("d,block", [(40, 12), (7, 4)], ids=["whole_tiles", "ragged"])
+def test_a_chunk_folded_in_feature_blocks_joins_each_block_where_it_belongs(
+        mesh8, monkeypatch, d, block):
+    """Several feature blocks a chunk, the last reaching back over the one
+    before it — at a width in whole tiles of eight (blocks of 16 at offsets
+    0, 16, 24) and at one that is not (blocks of 4 at 0, 3): the whole
+    frontier's fold and the halved one, against the whole-batch oracle."""
+    x, y = _rows(97, n=1600, d=d)
+    monkeypatch.setattr(hist_ops, "_ONEHOT_BLOCK_BYTES", 200 * REG["max_bins"] * 8 * block)
+    hist_ops.hist_update_group_fn.cache_clear()
+    try:
+        spec, tables, placed, keys = _grown(REG, x, y, mesh8, depth=1)
+        xs, ys, ms, ks = placed
+        accum = jnp.dtype(config.get("accum_dtype"))
+
+        def fold(state, signs=None):
+            return rf.accumulate_histogram(
+                state, tables, (xs,), (ys,), (ms,), (ks,), spec, mesh8, n_valid=len(x),
+                signs=signs)
+
+        parent = fold(hist_ops.zero_hist(spec.num_trees, 1, d, spec.max_bins, 3, accum))
+        rf.grow_level(tables, parent, spec)
+        want = np.asarray(_whole_batch_histogram(
+            jnp.asarray(tables["bin_edges"], accum), jnp.asarray(tables["feature"]),
+            jnp.asarray(tables["threshold"]), x, y, np.ones(len(x)), keys, spec, depth=2))
+        assert want[..., 0].sum() > 0
+        whole = fold(hist_ops.zero_hist(spec.num_trees, 2, d, spec.max_bins, 3, accum))
+        np.testing.assert_array_equal(np.asarray(whole), want)
+        state, signs = rf.open_pass(tables, spec, d, parent=parent)
+        assert (signs < 0).any()
+        np.testing.assert_array_equal(np.asarray(fold(state, signs)), want)
+    finally:
+        hist_ops.hist_update_group_fn.cache_clear()
+
+
+def test_fold_group_over_a_run_is_fold_batch_by_batch_in_a_seeded_pass(mesh8):
+    """Real-valued labels, as the depth-0 test above: the run's program
+    adds — and subtracts — in the calls' order."""
+    x, _ = _rows(71)
+    y = np.random.default_rng(71).normal(size=ROWS)
+    job = _job(mesh8, 16, REG)
+    job.set_iterate(_start(REG, x), 0)
+    for lo, hi in BATCHES:
+        job.fold(x[lo:hi], y[lo:hi], pass_id=0)
+    parent = job.peek_pass_state()[0]
+    job.step({})
+    assert _signs(job) is not None
+    for lo, hi in BATCHES[:4]:
+        job.fold(x[lo:hi], y[lo:hi], pass_id=1)
+    one_by_one = _hist(job)
+    algo = job.algorithm
+    xs, ms, ys, ks = zip(*job._cache.batches[:4])
+    state, _ = rf.open_pass(algo.tables, algo.spec, D, parent=parent)
+    grouped = np.asarray(algo.fold_group(state, xs, ms, (ys, ks)))
+    np.testing.assert_array_equal(grouped, one_by_one)
+
+
+def test_a_restored_job_folds_one_level_whole_and_hands_its_parent_on_again(mesh8):
+    """A snapshot holds the iterate, not the parent: the restored job's
+    first pass folds the whole frontier, its `step` keeps that histogram,
+    and the fit ends in the uninterrupted fit's tables."""
+    x, y = _rows(73)
+    params = dict(REG, max_depth=4)
+    job = _job(mesh8, 16, params)
+    job.set_iterate(_start(params, x), 0)
+    for lo, hi in BATCHES:
+        job.fold(x[lo:hi], y[lo:hi], pass_id=0)
+    for it in (1, 2):
+        job.step({})
+        job.rescan(it)
+    assert _signs(job) is not None
+    restored = _job(mesh8, 16, params)
+    restored.set_iterate(job.durable_arrays(), 2)  # what a boundary snapshot holds
+    modes = []
+    for it in range(2, 5):
+        for lo, hi in BATCHES:
+            restored.fold(x[lo:hi], y[lo:hi], pass_id=it)
+        modes.append(_signs(restored) is not None)
+        np.testing.assert_array_equal(_hist(restored), _hist(job))
+        info = restored.step({})
+        assert job.step({}) == info
+        if info["open_nodes"] == 0:
+            break
+        job.rescan(it + 1)
+    assert modes[:2] == [False, True]
+    got, want = restored.get_iterate()[0], job.get_iterate()[0]
+    for key in want:
+        np.testing.assert_array_equal(got[key], want[key], err_msg=key)
+
+
+@pytest.mark.parametrize("merge", ["merge_remote", "merge_mesh"])
+def test_a_job_whose_state_took_a_merge_hands_no_parent_on(mesh8, merge):
+    """The parent must be of exactly the rows the next pass folds here: a
+    state that took another daemon's is not, and a peer that held rows once
+    may hold none in one pass and some in the next — so from the first
+    merge until an iterate is installed the job folds whole frontiers."""
+    x, y = _rows(79)
+    params = dict(REG, max_depth=6)
+    primary, peer, alone = (_job(mesh8, 0, params) for _ in range(3))
+    start = _start(params, x)
+    for job in (primary, peer, alone):
+        job.set_iterate(start, 0)
+    for it in range(3):
+        primary.fold(x[:700], y[:700], partition=0, pass_id=it)
+        primary.commit(0, pass_id=it)
+        for part, rows in enumerate((slice(0, 700), slice(700, None))):
+            alone.fold(x[rows], y[rows], partition=part, pass_id=it)
+            alone.commit(part, pass_id=it)
+        if it < 2:  # in the last pass the peer holds no row: nothing is merged
+            peer.fold(x[700:], y[700:], partition=1, pass_id=it)
+            peer.commit(1, pass_id=it)
+            if merge == "merge_remote":
+                arrays, meta = peer.export_state()
+                primary.merge_remote(arrays, meta["pass_rows"], merge_id=f"m{it}")
+            else:
+                state, rows, _, _ = peer.peek_pass_state()
+                primary.merge_mesh([("peer", state, rows)], reduce_id=f"r{it}")
+        else:
+            primary.fold(x[700:], y[700:], partition=1, pass_id=it)
+            primary.commit(1, pass_id=it)
+        assert _signs(primary) is None and (_signs(alone) is None) == (it == 0)
+        np.testing.assert_array_equal(_hist(primary), _hist(alone))
+        assert primary.step({}) == alone.step({})
+        peer.set_iterate(primary.get_iterate()[0], it + 1)
+    # an installed iterate opens a new account: the next own step hands a parent on
+    primary.set_iterate(primary.get_iterate()[0], 3)
+    for it in (3, 4):
+        for job in (primary, alone):
+            for part, rows in enumerate((slice(0, 700), slice(700, None))):
+                job.fold(x[rows], y[rows], partition=part, pass_id=it)
+                job.commit(part, pass_id=it)
+        assert (_signs(primary) is None) == (it == 3)
+        np.testing.assert_array_equal(_hist(primary), _hist(alone))
+        if it == 3:
+            assert primary.step({}) == alone.step({})
+
+
+@pytest.mark.parametrize("params", [REG, CLF], ids=["regressor", "classifier"])
+def test_folded_and_derived_nodes_add_to_the_open_nodes_of_every_level(mesh8, params):
+    x, y = _rows(83, classes=params["n_classes"])
+    before = {how: _frontier_nodes(how) for how in ("folded", "derived")}
+    job = _job(mesh8, 16, params)
+    job.set_iterate(_start(params, x), 0)
+    job.fold(x, y, pass_id=0)
+    open_nodes, derived = params["num_trees"], 0  # the roots
+    it = 0
+    while True:
+        info = job.step({})
+        if info["open_nodes"] == 0:
+            break
+        it += 1
+        open_nodes += info["open_nodes"]
+        derived += int((_signs(job) < 0).sum())
+        job.rescan(it)
+    got = {how: _frontier_nodes(how) - before[how] for how in before}
+    assert derived > 0 and got["derived"] == derived
+    assert got["folded"] + got["derived"] == open_nodes
+    # the in-memory fit takes the same path: the same nodes, the same way
+    before = {how: _frontier_nodes(how) for how in before}
+    with config.option("forest_seed_sample_rows", ROWS):
+        fit = (rf.fit_random_forest_classifier if params["n_classes"]
+               else rf.fit_random_forest_regressor)
+        fit(x, y, **{k: v for k, v in params.items() if k != "n_classes"}, mesh=mesh8)
+    assert {how: _frontier_nodes(how) - before[how] for how in before} == got
+
+
+def test_derived_label_sums_stay_within_float32s_rounding_of_a_float64_histogram(mesh8):
+    """Real labels in float32, four levels deep: a derived child's Σy and
+    Σy² are the parent's less the folded child's — one more rounding a
+    chunk and level — and stay within 1e-6 of a float64 histogram of the
+    same rows (what the chip's `hist_rel` is predicted from); the count
+    channel is exact."""
+    params = dict(REG, max_depth=5, num_trees=3, bootstrap=False)
+    rng = np.random.default_rng(89)
+    n = 4096
+    x = rng.normal(size=(n, D)).astype(np.float32)
+    y = (3.0 * x[:, 0] - 2.0 * x[:, 1] + x[:, 2] * x[:, 3] + 0.3 * rng.normal(size=n) + 5.0)
+    with config.option("accum_dtype", "float32"), config.option("compute_dtype", "float32"):
+        job = _job(mesh8, 16, params)
+        job.set_iterate(_start(params, x), 0)
+        job.fold(x, y, pass_id=0)
+        for it in range(1, 5):
+            job.step({})
+            job.rescan(it)
+        signs, got = _signs(job), _hist(job)
+        tables = job.get_iterate()[0]
+    assert got.dtype == np.float32 and signs.shape == (3, 8, 2) and (signs < 0).sum() >= 8
+    # float64 on the host: bin ids by the stated rule, every row walked to depth 4
+    edges = tables["bin_edges"].astype(np.float32)
+    bins = (x[:, :, None] > edges[None]).sum(-1)
+    want = np.zeros(got.shape, np.float64)
+    for t in range(3):
+        node = np.zeros(n, np.int64)
+        for _ in range(4):
+            f = tables["feature"][t, node]
+            right = bins[np.arange(n), np.clip(f, 0, D - 1)] > tables["threshold"][t, node]
+            node = np.where(f >= 0, 2 * node + 1 + right, -1 - n)
+        ok = node >= 0
+        ok[ok] = tables["feature"][t, node[ok]] == OPEN
+        for f in range(D):
+            for s, v in enumerate((np.ones(n), y, y * y)):
+                np.add.at(want[t, :, f, :, s], (node[ok] - 15, bins[ok, f]), v[ok])
+    np.testing.assert_array_equal(got[..., 0], want[..., 0])
+    is_derived = (signs < 0).reshape(3, 16)
+    for s in (1, 2):
+        a, b = got[..., s][is_derived], want[..., s][is_derived]
+        assert b.any() and np.linalg.norm(a - b) <= 1e-6 * np.linalg.norm(b), s
 
 
 # ---------------- through a real daemon, client and spark/estimator.py -------
