@@ -1332,19 +1332,35 @@ class _Job:
             # (it is of the iterate the update left: zeros, unless the
             # algorithm starts it from the pass just finished), the
             # fields' scalars to the host, the snapshot.
+            #
+            # Its four parts are children `<span>.<part>`, named here for
+            # every algorithm (docs/observability.md "Phases"): `.update`
+            # and `.state` DISPATCH — nothing in them waits unless the
+            # algorithm reads a result itself (logreg's loss, the forest's
+            # scorer) — `.read` is where the job waits for the device, and
+            # `.snapshot` the durability point. What stands outside a
+            # child is bookkeeping: the parent's self time reads near 0.
             span = self.algorithm.boundary_span
+
+            def part(name: str):
+                return trace_span(f"{span}.{name}") if span else contextlib.nullcontext()
+
             with trace_span(span) if span else contextlib.nullcontext():
                 with _DEVICE_LOCK:
-                    fields = self.algorithm.step(self.state, params)
-                    self.state = self.algorithm.next_pass_state()
+                    with part("update"):
+                        fields = self.algorithm.step(self.state, params)
+                    with part("state"):
+                        self.state = self.algorithm.next_pass_state()
                 self._close_pass()
                 self.iteration += 1
-                info = {
-                    "iteration": self.iteration,
-                    **{
+                with part("read"):
+                    scalars = {
                         k: float(v) if isinstance(v, jax.Array) else v
                         for k, v in fields.items()
-                    },
+                    }
+                info = {
+                    "iteration": self.iteration,
+                    **scalars,
                     "pass_rows": self.pass_rows,
                 }
                 self.pass_rows = 0
@@ -1353,7 +1369,8 @@ class _Job:
                 # durability point: the snapshot lands BEFORE the step ack
                 # (write-ahead), so a daemon that dies anywhere after here
                 # resurrects at this exact boundary.
-                self._maybe_snapshot()
+                with part("snapshot"):
+                    self._maybe_snapshot()
                 self._last_step_id = None if step_id is None else str(step_id)
                 self._last_step_info = dict(info)
                 return info

@@ -101,12 +101,18 @@ _ring_arms = 0
 _ring_cap = 0
 
 
+_config = None  # the config module, once `_path` has imported it
+
+
 def _path() -> Optional[str]:
+    global _config
     if _broken:
         return None
-    from spark_rapids_ml_tpu import config
+    if _config is None:
+        from spark_rapids_ml_tpu import config
 
-    p = config.peek("run_journal")
+        _config = config
+    p = _config.peek("run_journal")
     return str(p) if p else None
 
 

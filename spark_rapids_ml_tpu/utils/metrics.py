@@ -59,15 +59,23 @@ DEFAULT_BUCKETS = (
 )
 
 
+_config = None  # the config module, once the first gate has imported it
+
+
 def _enabled() -> bool:
     # Lazy import: config pulls utils.logging; importing it at module load
     # from here would make the utils package order-sensitive. config.peek
     # is a lock-free dict read — this gate sits on the daemon's per-frame
     # hot path, and the disabled state must truly be an early return (no
-    # process-wide lock), as the module docstring promises.
-    from spark_rapids_ml_tpu import config
+    # process-wide lock), as the module docstring promises. The module is
+    # kept after the first call: an import statement a metric operation is
+    # a sixth of what one costs (PR 38).
+    global _config
+    if _config is None:
+        from spark_rapids_ml_tpu import config
 
-    return bool(config.peek("metrics"))
+        _config = config
+    return bool(_config.peek("metrics"))
 
 
 def _exemplar_window() -> float:
@@ -120,6 +128,9 @@ def _label_key(labels: Dict[str, Any]) -> Tuple[Tuple[str, str], ...]:
     ``inc(op="feed")`` and ``inc(**{"op": "feed"})`` land in one series."""
     if not labels:
         return ()
+    if len(labels) == 1:  # most series have one label: nothing to sort
+        ((k, v),) = labels.items()
+        return ((k, str(v)),)
     return tuple(sorted((k, str(v)) for k, v in labels.items()))
 
 
@@ -197,6 +208,10 @@ class Histogram(_Metric):
                 f"ascending, got {buckets!r}"
             )
         self.buckets = uppers
+        #: the bounds as a scrape prints them, formatted once: a snapshot
+        #: cumulates every series of every histogram (PR 38 added eight
+        #: series a cached job; the benchmark takes a snapshot between fits)
+        self._les = tuple(_fmt_float(b) for b in uppers)
         #: (series key, bucket idx) → (value, ts, trace dict): the worst
         #: sample of the current exemplar window, per bucket.
         self._exemplars: Dict[Tuple[Any, int], Tuple[float, float, Dict[str, str]]] = {}
@@ -207,11 +222,13 @@ class Histogram(_Metric):
             self._exemplars.clear()
 
     def _le(self, idx: int) -> str:
-        return _fmt_float(self.buckets[idx]) if idx < len(self.buckets) else "+Inf"
+        return self._les[idx] if idx < len(self._les) else "+Inf"
 
     def exemplars(self, **labels: Any) -> Dict[str, Dict[str, Any]]:
         """Fresh (within-window) exemplars of one series, keyed by the
         bucket's ``le`` bound: ``{le: {"value", "ts", …trace fields}}``."""
+        if not self._exemplars:  # nothing to filter: no lock, no clock
+            return {}
         key = _label_key(labels)
         now = time.time()
         window = _exemplar_window()
@@ -285,9 +302,9 @@ class Histogram(_Metric):
     def _cumulate(self, counts: List[int]) -> Dict[str, int]:
         out: Dict[str, int] = {}
         running = 0
-        for upper, c in zip(self.buckets, counts):
+        for le, c in zip(self._les, counts):
             running += c
-            out[_fmt_float(upper)] = running
+            out[le] = running
         out["+Inf"] = running + counts[-1]
         return out
 

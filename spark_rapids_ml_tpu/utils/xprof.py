@@ -91,6 +91,13 @@ _M_DISPATCH_SECONDS = metrics_mod.counter(
     "(signature found -> dispatch returned: first-call analysis and "
     "compile, jit dispatch, any wait on a full runtime queue), by fn",
 )
+_M_DISPATCH_DURATION = metrics_mod.histogram(
+    "srml_xla_dispatch_duration_seconds",
+    "The same clock as srml_xla_dispatch_seconds_total, one observation a "
+    "top-level call (its sum and count are that counter's and "
+    "srml_xla_calls_total's): the distribution a dispatch's p99 is read "
+    "from, by fn",
+)
 _M_PCACHE_HITS = metrics_mod.counter(
     "srml_xla_persistent_cache_hits_total",
     "XLA programs served from the persistent compilation cache "
@@ -577,6 +584,7 @@ class LedgeredJit:
         _M_CALLS.inc(fn=entry.name)
         # In the timing mode dt holds the blocked execution too.
         _M_DISPATCH_SECONDS.inc(dt, fn=entry.name)
+        _M_DISPATCH_DURATION.observe(dt, fn=entry.name)
         if timing and not compiled_now:
             _M_EXEC_SECONDS.observe(dt, fn=entry.name)
         if self.on_dispatch is not None:

@@ -132,6 +132,53 @@ def test_a_call_inlined_into_another_trace_books_no_dispatch():
     assert _xla_counter("srml_xla_dispatch_seconds_total", "test.dispatch_inner") == 0.0
 
 
+def _dispatch_hist(fn):
+    """(bucket counts, sum, count) of the dispatch histogram's `fn` series,
+    zeros while it has none."""
+    for s in metrics.snapshot().get(
+            "srml_xla_dispatch_duration_seconds", {}).get("samples", []):
+        if s["labels"] == {"fn": fn}:
+            return s["buckets"], s["sum"], s["count"]
+    return {}, 0.0, 0
+
+
+def test_every_top_level_call_is_one_observation_of_the_dispatch_histogram():
+    """`srml_xla_dispatch_duration_seconds{fn}` is the counter pair as a
+    distribution: the same `dt` of the same calls, so its sum and count ARE
+    `srml_xla_dispatch_seconds_total` and `srml_xla_calls_total`."""
+    f = xprof.ledgered_jit("test.dispatch_hist", lambda x: x * 2 + 1)
+    x = jnp.ones((8,), jnp.float32)
+    f(x)
+    _, sum0, count0 = _dispatch_hist("test.dispatch_hist")
+    seconds0 = _xla_counter("srml_xla_dispatch_seconds_total", "test.dispatch_hist")
+    assert count0 == 1 and sum0 == pytest.approx(seconds0, rel=1e-12)
+    for _ in range(5):
+        f(x)
+    buckets, total, count = _dispatch_hist("test.dispatch_hist")
+    assert count - count0 == 5 == (
+        _xla_counter("srml_xla_calls_total", "test.dispatch_hist") - 1)
+    assert total == pytest.approx(
+        _xla_counter("srml_xla_dispatch_seconds_total", "test.dispatch_hist"),
+        rel=1e-12)
+    # the registry's default buckets: 0.5 ms … 60 s, cumulative
+    assert list(buckets)[0] == "0.0005" and list(buckets)[-2:] == ["60", "+Inf"]
+    assert "0.05" in buckets and buckets["+Inf"] == count
+
+
+@pytest.mark.parametrize("how", ["inlined", "ledger_off"])
+def test_the_dispatch_histogram_takes_nothing_from_a_call_the_ledger_leaves_out(how):
+    inner = xprof.ledgered_jit(f"test.dispatch_hist_{how}", lambda x: x * 3)
+    if how == "inlined":
+        outer = xprof.ledgered_jit("test.dispatch_hist_outer", lambda x: inner(x) + 1)
+        outer(jnp.ones((4,)))
+        assert _dispatch_hist("test.dispatch_hist_outer")[2] == 1
+    else:
+        with config.option("metrics", False):
+            inner(jnp.ones((4,)))
+            inner(jnp.ones((4,)))
+    assert _dispatch_hist(f"test.dispatch_hist_{how}") == ({}, 0.0, 0)
+
+
 def test_the_registry_holds_no_cost_analysis_counters():
     """`srml_xla_executed_{flops,bytes}_total` (cost-analysis × calls, blind
     inside a custom call, read by nothing) left the fold's hot path."""
