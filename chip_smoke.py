@@ -24,7 +24,10 @@ config #2: d=2048, k=32) under the shipped ``auto`` profile:
                 (``perf/reference/rf.py``) under the deployment's tolerances;
                 and its depth-2 level folded both ways — the whole frontier,
                 and one child of every pair with the sibling taken from the
-                parent's histogram — the count channel equal cell for cell
+                parent's histogram — the count channel equal cell for cell;
+                every fold of the fit through the kernel that makes the bin
+                one-hot in VMEM (``srml_forest_fold_path_total{path=fused}``),
+                and that level once more through the XLA body: the same counts
   8. summary    two JSON lines close stdout: the full summary (per-stage
                 seconds, compiles, cache hits, kernel verdicts, ``"claim":
                 null``), then — the last line, which the driver parses —
@@ -1084,6 +1087,7 @@ def stage_forest(sizes: Optional[Dict[str, int]] = None,
     with its pass cached — driven as the benchmark's generator drives it —
     and the comparison a benchmark run makes of it."""
     from perf.harness import layout
+    from spark_rapids_ml_tpu import config
     from spark_rapids_ml_tpu.utils import metrics
 
     bench = layout.load_benchmark(REPO)
@@ -1104,7 +1108,11 @@ def stage_forest(sizes: Optional[Dict[str, int]] = None,
         return {how: counter("srml_forest_frontier_nodes_total", how=how)
                 for how in ("folded", "derived")}
 
-    before, nodes_before = cached_passes(), frontier_nodes()
+    def fold_paths() -> Dict[str, float]:
+        return {path: counter("srml_forest_fold_path_total", path=path)
+                for path in ("fused", "xla")}
+
+    before, nodes_before, paths_before = cached_passes(), frontier_nodes(), fold_paths()
     forest = generator.CachedForest(REPO, cfg, params or FOREST_PARAMS, seed, 1, say)
     passes, captured = forest.captured_fit()
     check(cached_passes() - before == len(passes) == cfg["max_depth"],
@@ -1114,14 +1122,28 @@ def stage_forest(sizes: Optional[Dict[str, int]] = None,
     check(nodes["derived"] > 0,
           f"the fit derived no node's histogram from its parent's ({nodes}): every level "
           "after the first should fold one child of a split and subtract for the other")
+    # every fold of the fit, before the stage folds a level through the XLA body itself
+    paths = {path: n - paths_before[path] for path, n in fold_paths().items()}
+    fused_share = round(100.0 * paths["fused"] / max(sum(paths.values()), 1.0), 2)
+    say(f"forest: srml_forest_fold_path_total {paths}: {fused_share}% of the fold's "
+        "dispatches made their bin one-hot in VMEM (hist_onehot_matmul_pallas)")
+    if config.backend_is_tpu():
+        check(paths["fused"] > 0 and paths["xla"] == 0,
+              f"not every dispatch of histogram.update_group took the fused body ({paths}): "
+              "on the chip the `auto` profile's int8 operands at 128 bins and 65,536-row "
+              "batches pass `_fused_hist_fold_applicable`")
     both_ways = forest_level_both_ways(forest.job, captured["levels"], depth=2)
     check(both_ways["derived_nodes"] > 0 and both_ways["count_cells_differ"] == 0,
           "the depth-2 level folded whole and folded by halves (one child of a pair "
           f"contracted, its sibling the parent less it) differ: {both_ways}")
+    check(both_ways["xla_count_cells_differ"] == 0,
+          "the depth-2 level folded through the kernel and through the XLA body "
+          f"(`use_pallas` off) differ in the count channel: {both_ways}")
     compared = forest.compared(captured, [], agree, reference, say)
     problems = agree.problems(compared)
     check(not problems, f"the forest fit disagrees with perf/reference/rf.py: {problems}")
     return {"compared": compared, "both_ways": both_ways, "frontier_nodes": nodes,
+            "fold_paths": paths, "fused_share": fused_share,
             "derived_share": round(100.0 * nodes["derived"] / sum(nodes.values()), 2),
             "level_seconds": [round(p["end"] - p["start"], 3) for p in passes]}
 
@@ -1132,9 +1154,15 @@ def forest_level_both_ways(job, levels, depth: int) -> Dict[str, Any]:
     contracted, and (ISSUE 37) a state seeded from the complete histogram
     one depth up with one child of every pair contracted. The count channel
     is whole numbers either way, so it must agree cell for cell — on the
-    chip, where a cast XLA elides has been seen only there (PR 36)."""
+    chip, where a cast XLA elides has been seen only there (PR 36). And
+    (ISSUE 39) the whole frontier once more with `use_pallas` off: the XLA
+    body — `jax.nn.one_hot` and XLA's product in 16,384-row chunks — against
+    the body the fit took (on the chip the kernel that makes the one-hot in
+    VMEM, a 65,536-row chunk): the same counts cell for cell, the label
+    sums to the digits' rounding."""
     import jax.numpy as jnp
 
+    from spark_rapids_ml_tpu import config
     from spark_rapids_ml_tpu.models import random_forest as forest
     from spark_rapids_ml_tpu.ops import histogram
 
@@ -1155,15 +1183,22 @@ def forest_level_both_ways(job, levels, depth: int) -> Dict[str, Any]:
     whole = fold(levels[depth], zeros(depth))
     state, signs = forest.open_pass(levels[depth], spec, d, parent=parent)
     halved = fold(levels[depth], state, signs)
-    label_rel = [
-        float(jnp.linalg.norm(halved[..., s] - whole[..., s])
-              / jnp.maximum(jnp.linalg.norm(whole[..., s]), 1e-30))
-        for s in range(1, spec.n_stats)]
+    with config.option("use_pallas", False):
+        xla = fold(levels[depth], zeros(depth))
+
+    def label_rel(other):
+        return [
+            float(jnp.linalg.norm(other[..., s] - whole[..., s])
+                  / jnp.maximum(jnp.linalg.norm(whole[..., s]), 1e-30))
+            for s in range(1, spec.n_stats)]
+
     return {"depth": depth, "derived_nodes": int((signs < 0).sum()),
             "folded_nodes": int((signs > 0).sum()),
             "count_cells_differ": int(jnp.sum(halved[..., 0] != whole[..., 0])),
             "count_total": float(jnp.sum(whole[..., 0])),
-            "label_sums_rel": label_rel}
+            "label_sums_rel": label_rel(halved),
+            "xla_count_cells_differ": int(jnp.sum(xla[..., 0] != whole[..., 0])),
+            "xla_label_sums_rel": label_rel(xla)}
 
 
 def run_main_path(

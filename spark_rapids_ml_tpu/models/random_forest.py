@@ -395,6 +395,7 @@ def accumulate_histogram(
         mesh, spec.num_trees, spec.max_bins, depth, spec.n_classes,
         spec.bootstrap, spec.seed, config.get("accum_dtype"),
         config.get("compute_dtype"), halved=signs is not None,
+        use_pallas=bool(config.get("use_pallas")),
     )
     _M_HIST_ROWS.inc(int(n_valid), role=spec.role())
     # Edges upload in the accumulation dtype EXPLICITLY: on a non-x64

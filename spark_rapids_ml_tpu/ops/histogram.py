@@ -19,7 +19,9 @@ CPU tree library:
   stat)`` tensor, walking each batch in row chunks inside the program (a
   65,536 x 3,000 batch is never expanded whole). The scatter is
   formulated as a one-hot × stats contraction (an einsum over the row
-  axis) instead of a gather/scatter loop — MXU-shaped, and the
+  axis) instead of a gather/scatter loop — MXU-shaped; on the chip its
+  bin one-hot exists only tile by tile in VMEM
+  (``pallas_kernels.hist_onehot_matmul_pallas``); the
   per-shard partials reduce with ``parallel.mapreduce.reduce_sum``
   (DrJAX psum; PAPERS.md 2403.07128) like every other sufficient
   statistic in the package. Histograms are ADDITIVE, so the tensor rides
@@ -58,6 +60,7 @@ import numpy as np
 
 from spark_rapids_ml_tpu.parallel import mapreduce as mr
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
+from spark_rapids_ml_tpu.utils import metrics
 from spark_rapids_ml_tpu.utils.xprof import ledgered_jit
 from jax.sharding import PartitionSpec as P
 
@@ -211,9 +214,10 @@ def route_to_frontier(bins, feature, threshold, depth: int, dtype):
     return pos, alive
 
 
-#: Rows of a shard the fold walks at a time inside its one program. The
-#: batch is never expanded whole: a chunk's bin one-hot is
-#: ``chunk * d * B`` elements, built a feature block at a time
+#: Rows of a shard the fold's XLA body walks at a time inside its one
+#: program (the fused body, which builds no one-hot block:
+#: `_FUSED_CHUNK_ROWS`). The batch is never expanded whole: a chunk's bin
+#: one-hot is ``chunk * d * B`` elements, built a feature block at a time
 #: (`_ONEHOT_BLOCK_BYTES`), and the frontier accumulator is read and
 #: written once a chunk — a long chunk, so that the contraction and not
 #: that traffic is what a chunk costs ...
@@ -228,9 +232,9 @@ FOLD_CHUNK_ROWS = 16384
 #: §6, PR 37; PR 36's descent by gathers had put the line at 840).
 _SHORT_CHUNK_ROWS = 4096
 _SHORT_CHUNKS_BELOW_ROWS = 320
-#: From this many rows of the contraction's left operand on, a feature
-#: block's one-hot is written out before the contraction instead of being
-#: generated inside it: the product then runs at 85% of the MXU's bfloat16
+#: From this many rows of the contraction's left operand on, the XLA body
+#: writes a feature block's one-hot out before the contraction instead of
+#: generating it inside it: the product then runs at 85% of the MXU's bfloat16
 #: peak instead of 61%, which is worth the one-hot's trip through HBM once
 #: the product is tall — 3,360 rows 1.83 s written out against 2.25 (depth
 #: 4; the halved depth 5: 1.94 / 2.35), 1,680 rows 1.30 / 1.16 (depth 3;
@@ -239,10 +243,12 @@ _MATERIALIZE_FROM_ROWS = 2048
 #: Rows of a float32 tile on the chip: the grain of the accumulator's
 #: feature axis (its bins are the 128 lanes).
 _FEATURE_TILE = 8
-#: A chunk's bin one-hot is built in feature blocks of at most this size ...
+#: The XLA body builds a chunk's bin one-hot in feature blocks of at most
+#: this size ...
 _ONEHOT_BLOCK_BYTES = 512 << 20
-#: ... and no larger than leaves the block's product — every tree's and
-#: node's, before it joins the accumulator — at most this size.
+#: ... and either body takes a feature block no larger than leaves its
+#: product — every tree's and node's, before it joins the accumulator — at
+#: most this size.
 _PRODUCT_BLOCK_BYTES = 384 << 20
 #: The scorer takes a frontier tensor of at most this size whole (its
 #: transient is a few times that), a larger one tree by tree.
@@ -259,6 +265,52 @@ _SCORE_BLOCK_BYTES = 640 << 20
 #: of a float32 accumulator's own rounding of such a sum.
 _DIGITS = 3
 _DIGIT_BITS = 8 * _DIGITS - 2
+#: Rows of a shard the fold walks at a time where the bin one-hot is made
+#: in VMEM (`_fused_hist_fold_applicable`): no block of it exists whose
+#: bytes would bound the chunk, so a chunk is as long as a digit's int32
+#: sums stay whole numbers of the accumulation dtype — 65,536 rows of
+#: digits up to 128: under 2^24 — and the frontier accumulator is read and
+#: written once a 65,536-row batch, not four times.
+_FUSED_CHUNK_ROWS = 65536
+
+_M_FOLD_PATH = metrics.counter(
+    "srml_forest_fold_path_total",
+    "Dispatches of the forest's histogram fold (histogram.update_group) by "
+    "the body their program was built with: path=fused (the bin one-hot made "
+    "in VMEM by hist_onehot_matmul_pallas) or path=xla (CPU, float32/float64 "
+    "compute, a bin count off the 128-lane grid, chunk rows no multiple of "
+    "512: jax.nn.one_hot and XLA's product)",
+)
+
+
+def _fused_hist_fold_applicable(
+    shard_rows: int, operand, max_bins: int, use_pallas=None
+) -> bool:
+    """`hist_update_group_fn`'s gate for the kernel that makes the bin
+    one-hot in VMEM (ops/pallas_kernels.hist_onehot_matmul_pallas), by what
+    the code can observe: TPU backend, the narrow operand case (int8:
+    bfloat16 compute under float32 accumulate, the chip's `auto` profile —
+    a float32 or float64 operand keeps XLA's product in its own precision),
+    a bin count on the 128-lane grid (a feature's bins are whole lane tiles
+    of the product; Spark's default `maxBins` 32 keeps the XLA body), and a
+    chunk — the shard's rows, at most `_FUSED_CHUNK_ROWS` — of whole row
+    tiles (the constant imported from the kernel so the two cannot drift).
+    Any height of the left operand: the kernel walks a tall one in parts
+    of its VMEM budget, and was the faster at every height the cell has
+    (PERF.md §6, PR 39)."""
+    from spark_rapids_ml_tpu.ops.gram import _pallas_backend_ok
+
+    if not _pallas_backend_ok(use_pallas):
+        return False
+    from spark_rapids_ml_tpu.ops.pallas_kernels import HIST_ONEHOT_ROW_MULTIPLE
+
+    c = min(int(shard_rows), _FUSED_CHUNK_ROWS)
+    return (
+        jnp.dtype(operand) == jnp.dtype(jnp.int8)
+        and max_bins % 128 == 0
+        and c > 0
+        and c % HIST_ONEHOT_ROW_MULTIPLE == 0
+    )
 
 
 def _digits(v):
@@ -279,7 +331,7 @@ def _digits(v):
 def hist_update_group_fn(
     mesh, n_trees: int, max_bins: int, depth: int,
     n_classes: int, bootstrap: bool, seed: int, ad: str, cd: str,
-    halved: bool = False,
+    halved: bool = False, use_pallas: bool = False,
 ):
     """Build the fused per-depth histogram accumulate for one mesh:
     ``(hist, tables, xs, ys, masks, row_keys) -> hist`` with ``hist``
@@ -323,6 +375,15 @@ def hist_update_group_fn(
     so nothing is rounded that a float32 accumulation would keep. Nothing
     of size rows x d x B x S, nor a second frontier tensor, ever exists:
     the transient is one feature block's one-hot and its product.
+
+    ``use_pallas`` (the caller's snapshot of the config key, so that it
+    keys the cached programs): where `_fused_hist_fold_applicable` holds
+    for a batch's shard — the chip's int8 operands at a bin count on the
+    lane grid — not even that one-hot exists: a block's product is
+    `hist_onehot_matmul_pallas`, which makes the one-hot tile by tile in
+    VMEM, and a chunk is `_FUSED_CHUNK_ROWS` long. The products are the
+    same whole numbers either way; the XLA body below is what runs
+    everywhere else, and the kernel's oracle.
 
     ``n_classes = 0`` selects the regression stat layout (count, Σy,
     Σy²); otherwise per-class counts. All one-hot factors are exact small
@@ -406,20 +467,23 @@ def hist_update_group_fn(
         lhs = jnp.stack(channels, axis=2)  # (T, slots, n_ch, c)
         return bins, lhs.reshape(n_trees * slots * n_ch, c), jnp.stack(scales)
 
-    def fold_chunk(acc, bins, lhs, scales, signs=None):
+    def fold_chunk(acc, bins, lhs, scales, signs=None, fused=False):
         """acc (T, slots, S, d, B) += the chunk's histogram, a feature
         block at a time: every tree's and slot's channels against the
         block's bin one-hot in one contraction, joined to the accumulator
         in place. With ``signs`` the accumulator is the whole frontier's,
         seen by pairs — (T, W/2, 2, S, d, B) — and a pair's product joins
-        both of its children's slots, each under its sign."""
+        both of its children's slots, each under its sign. ``fused``: the
+        contraction is the kernel's, which takes the bin ids themselves —
+        rows along the lanes, as the chip keeps what is computed from an
+        (n, 3000) batch — and no one-hot block bounds the feature block."""
         c, d = bins.shape
         db = max(1, min(
             d,
-            _ONEHOT_BLOCK_BYTES
-            // (c * max_bins * jnp.dtype(operand).itemsize),
             _PRODUCT_BLOCK_BYTES
             // (n_trees * slots * n_ch * max_bins * accum.itemsize),
+            d if fused else _ONEHOT_BLOCK_BYTES
+            // (c * max_bins * jnp.dtype(operand).itemsize),
         ))
         n_blocks = -(-d // db)
         db = -(-d // n_blocks)  # equal blocks; the last may reach back
@@ -431,6 +495,10 @@ def hist_update_group_fn(
         tile = _FEATURE_TILE if d % _FEATURE_TILE == 0 else 1
         db = min(d, -(-db // tile) * tile)
         n_blocks = -(-d // db)
+        if fused:
+            from spark_rapids_ml_tpu.ops import pallas_kernels
+
+            bins = bins.T  # (d, c)
 
         def block(i, acc):
             # Block i covers features [i * db, (i + 1) * db); where that
@@ -439,12 +507,16 @@ def hist_update_group_fn(
             # all-zero one-hot), so one loop of one shape covers any d.
             f0 = tile * jnp.minimum(
                 i * (db // tile), (d - db) // tile).astype(jnp.uint32)
-            cols = jax.lax.dynamic_slice_in_dim(bins, f0, db, axis=1)
+            shared = (
+                jnp.arange(db, dtype=jnp.int32) < i * db - f0.astype(jnp.int32))
+            cols = jax.lax.dynamic_slice_in_dim(
+                bins, f0, db, axis=0 if fused else 1)
             cols = jnp.where(
-                (jnp.arange(db, dtype=jnp.int32)
-                 < i * db - f0.astype(jnp.int32))[None, :],
-                -1, cols,
-            )
+                shared[:, None] if fused else shared[None, :], -1, cols)
+            if fused:
+                h = pallas_kernels.hist_onehot_matmul_pallas(
+                    lhs, cols, n_bins=max_bins)
+                return join(acc, h, f0)
             bin_oh = jax.nn.one_hot(cols, max_bins, dtype=operand)
             if lhs.shape[0] >= _MATERIALIZE_FROM_ROWS:
                 # written out, then a plain matrix product
@@ -460,6 +532,11 @@ def hist_update_group_fn(
                     "mn,ndb->mdb", lhs, bin_oh,
                     preferred_element_type=product,
                 )
+            return join(acc, h, f0)
+
+        def join(acc, h, f0):
+            """A block's product (T * slots * n_ch, db * B) → its
+            statistics, added to the accumulator's features from f0."""
             h = h.reshape(n_trees, slots, n_ch, db, max_bins)
             # the parts of one statistic join smallest first
             h = jnp.stack(
@@ -484,8 +561,10 @@ def hist_update_group_fn(
 
     def fold_batch(acc, tables, x, y, mask, row_key, signs=None):
         n = x.shape[0]
+        fused = _fused_hist_fold_applicable(n, operand, max_bins, use_pallas)
         short = n_trees * slots * n_ch < _SHORT_CHUNKS_BELOW_ROWS
-        c = min(n, _SHORT_CHUNK_ROWS if short else FOLD_CHUNK_ROWS)
+        c = min(n, _FUSED_CHUNK_ROWS if fused
+                else _SHORT_CHUNK_ROWS if short else FOLD_CHUNK_ROWS)
         n_chunks = -(-n // c)
         pad = n_chunks * c - n
         if pad:  # a ragged tail folds as masked rows
@@ -498,7 +577,7 @@ def hist_update_group_fn(
                 for a in (x, y, mask, row_key)
             ]
             return fold_chunk(
-                acc, *chunk_operand(tables, *rows), signs=signs
+                acc, *chunk_operand(tables, *rows), signs=signs, fused=fused
             )
 
         return jax.lax.fori_loop(0, n_chunks, chunk, acc)
@@ -541,11 +620,17 @@ def hist_update_group_fn(
         acc = jnp.moveaxis(hist, 4, 2)
         if halved:
             acc = acc.reshape((n_trees, slots, 2) + acc.shape[2:])
-        for x, y, mask, row_key in zip(xs, ys, masks, row_keys):
+        pending = tuple(zip(xs, ys, masks, row_keys))
+        while pending:
+            batch, pending = pending[0], pending[1:]
             # the barrier keeps a run its calls bit for bit (XLA may not
-            # merge two batches' loops or reorder their additions)
-            acc = jax.lax.optimization_barrier(
-                f(acc, tables, x, y, mask, row_key)
+            # merge two batches' loops or reorder their additions) — and,
+            # over the batches still to come, one batch's temporaries at a
+            # time: nothing of a later batch (its bin ids, its operand: 1.8
+            # GB where a chunk is a whole batch) is made before this one
+            # has joined the accumulator
+            acc, pending = jax.lax.optimization_barrier(
+                (f(acc, tables, *batch), pending)
             )
         if halved:
             acc = acc.reshape((n_trees, W) + acc.shape[3:])
@@ -554,9 +639,22 @@ def hist_update_group_fn(
     # One ledger name pools every depth's and every run length's
     # accounting (distinct shape-signatures under it — the ledger's own
     # keying); in a device trace the program is `jit_hist_update_group`.
-    return ledgered_jit(
+    update = ledgered_jit(
         "histogram.update_group", hist_update_group, donate_argnums=(0,)
     )
+
+    @functools.lru_cache(maxsize=None)
+    def path(rows: int) -> str:
+        fused = _fused_hist_fold_applicable(
+            rows // mesh.shape[DATA_AXIS], operand, max_bins, use_pallas)
+        return "fused" if fused else "xla"
+
+    # One count a dispatch, by the body the program of that batch shape was
+    # built with: the gate's own predicate, asked once a shape (a run is
+    # one shape).
+    update.on_dispatch = lambda hist, tables, xs, *cols: _M_FOLD_PATH.inc(
+        path=path(xs[0].shape[0]))
+    return update
 
 
 def zero_hist(n_trees: int, depth: int, n_cols: int, max_bins: int,

@@ -30,6 +30,12 @@ places hand-tiling pays:
   ``models/logistic_regression.py`` ``_stream_grad_hess_shard_fn`` where
   its gate holds (``fit_logistic_stream``, the daemon's
   ``LogisticRegressionJob``).
+* ``hist_onehot_matmul_pallas`` — the forests' histogram contraction on
+  the chip: a chunk's int8 operand times the bin one-hot of a feature
+  block, the one-hot made tile by tile in VMEM from the bin ids (never in
+  HBM, nor inside an XLA fusion), int8 into int32 at the MXU's int8 rate.
+  Called by ``ops/histogram.py`` ``hist_update_group_fn`` where its gate
+  holds (both forests' in-memory fit, the daemon's ``RandomForestJob``).
 * ``ivf_scan_select_pallas`` — IVF bucketed scan: per-list residual GEMM
   + exact packed-key top-k selection, scores VMEM-resident (gated by
   ``config.ann_fused_scan``, not ``use_pallas``).
@@ -871,6 +877,156 @@ def newton_fold_pallas(
         jnp.sum(gw, axis=1)[:d], s[0, 0], h, jnp.sum(hwb, axis=1)[:d],
         s[0, 1], s[0, 2], s[0, 3],
     )
+
+
+# ---------------------------------------------------------------------------
+# The forest's histogram product: lhs × one_hot(bins), the one-hot made in
+# VMEM tile by tile and never in HBM
+# ---------------------------------------------------------------------------
+
+
+# Shared with the fold's gate (ops/histogram.py
+# `_fused_hist_fold_applicable`), so the two cannot drift.
+HIST_ONEHOT_ROW_MULTIPLE = 512  # the fold's gate: chunk rows in multiples of this
+HIST_ONEHOT_MAX_ROW_TILE = 2048
+HIST_ONEHOT_FEATURES = 8  # features a grid step folds: one int32 tile of bin ids
+HIST_ONEHOT_OUT_TILE_BYTES = 16 * 2**20  # a VMEM-resident (rows, 8 · bins) int32 tile
+
+
+def hist_onehot_tiles(m: int, c: int, n_bins: int) -> tuple[int, int]:
+    """(tm, tk): the rows of the left operand a grid step holds — all of
+    them, padded to int8's 32-sublane tile, or equal parts whose (tm, 8 ·
+    n_bins) int32 output tile is within 16 MiB (4,096 rows at 128 bins) —
+    and the rows of the chunk it contracts: the largest power of two up to
+    2,048 that divides c. Chosen on a v5e from the product alone at the
+    forest cell's heights against a 16,384-row chunk and 232 features (host
+    clock over 18 products, PERF.md §6, PR 39): 3,360 rows 8.55 ms at
+    2,048 (382 T/s of the chip's 393 int8), 8.72 at 1,024, 9.00 at 512,
+    and 10.34 with 16 features a step; 210 rows 0.752 at 2,048 and 4,096,
+    0.795 at 1,024, 0.878 at 512 — a longer tile pays the output tile's
+    read-modify-write and a grid step's start less often."""
+    mp = _ceil_to(m, 32)
+    cap = HIST_ONEHOT_OUT_TILE_BYTES // (4 * HIST_ONEHOT_FEATURES * n_bins) // 32 * 32
+    parts = -(-mp // cap)
+    tm = _ceil_to(-(-mp // parts), 32)
+    tk = HIST_ONEHOT_MAX_ROW_TILE
+    while c % tk and tk > 128:
+        tk //= 2
+    return tm, tk
+
+
+def hist_onehot_vmem_limit(tm: int, tk: int, n_bins: int) -> int:
+    """The scoped VMEM the kernel is compiled under: what its tiles take —
+    the output tile and both operands' double-buffered, a feature's product
+    and one-hot — and a quarter over, at least Mosaic's default 16 MiB and
+    at most 66 MiB (4,096 rows), NOT a flat 100 MiB. XLA keeps small arrays
+    of the surrounding program in VMEM — a 28-row operand made in the
+    fold's program among them — and a kernel that claims 96 MiB or more of
+    the chip's 128 overwrites them (seen on a v5e: the product all zeros at
+    4 trees under 96, 100 and 112 MiB, right under 80 and less; PERF.md §6,
+    PR 39)."""
+    feats = HIST_ONEHOT_FEATURES
+    tiles = (2 * (tm * feats * n_bins * 4 + tm * tk + feats * tk * 4)
+             + tm * n_bins * 4 + 5 * n_bins * tk)
+    return max(16 * 2**20, tiles + tiles // 4 + 2 * 2**20)
+
+
+def _hist_onehot_kernel(lhs_ref, bins_ref, o_ref, *, n_bins):
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        o_ref[:] = jnp.zeros_like(o_ref)
+
+    lhs = lhs_ref[:]  # (tm, tk) int8: channels on sublanes, rows on lanes
+    tk = lhs.shape[1]
+    # A feature's one-hot, bins on sublanes and rows on lanes: its row of
+    # bin ids broadcast down the sublanes against the bins' own numbers. A
+    # blanked column (id -1) and a padded one equal no bin: all zeros.
+    bin_of = jax.lax.broadcasted_iota(jnp.int32, (n_bins, tk), 0)
+    for f in range(bins_ref.shape[0]):
+        one_hot = (bins_ref[f:f + 1, :] == bin_of).astype(jnp.int8)
+        # both operands contract their lanes (q·kᵀ's form): int8 into int32
+        o_ref[:, f * n_bins:(f + 1) * n_bins] += jax.lax.dot_general(
+            lhs, one_hot, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )
+
+
+@functools.partial(
+    ledgered_jit, "pallas.hist_onehot_matmul_pallas",
+    static_argnames=("n_bins", "row_tile", "interpret"),
+)
+def hist_onehot_matmul_pallas(
+    lhs: jax.Array,
+    bins_t: jax.Array,
+    n_bins: int,
+    row_tile: Optional[int] = None,
+    interpret: bool = False,
+):
+    """A feature block's histogram product ``h (M, db · n_bins) int32 =
+    lhs (M, c) int8 × one_hot(bins (c, db), n_bins)`` with the one-hot made
+    tile by tile in VMEM — the contraction of the forest's fold on the chip:
+    `ops/histogram.py` `hist_update_group_fn` calls it where
+    `_fused_hist_fold_applicable` holds (the forests' in-memory fit, the
+    daemon's `RandomForestJob.fold` / `fold_group`, the benchmark's
+    `rf_reg_d3000.levels_cached`), in place of `jax.nn.one_hot` and a
+    matrix product whose right operand XLA has to write to HBM (4.6 MB a
+    row of the block at 3,000 columns) or generate inside the product's
+    fusion at a third of the MXU's int8 rate.
+
+    lhs: (M, c) int8 — the chunk's node one-hots times bag weight times a
+    statistic's whole-number digit, any M (padded here to int8's 32-row
+    tile; over 4,096 rows at 128 bins it is walked in equal parts, each
+    generating the one-hot again). bins_t: **(db, c) int32, the block's bin
+    ids TRANSPOSED** — rows along the lanes, as the chip keeps an (n, 3000)
+    batch and what is computed from it; an id outside [0, n_bins) — the
+    fold's blanked columns carry -1 — is an all-zero one-hot. ``n_bins`` a
+    multiple of 128: a feature's bins are whole lane tiles of the output.
+    c a multiple of the row tile (`hist_onehot_tiles`; 512 divides it).
+
+    Grid (part of M, 8 features, row tile), the last the contraction: an
+    output tile (tm, 8 · n_bins) int32 stays in VMEM across a chunk's row
+    tiles and is written once; a step loads (tm, tk) of lhs and (8, tk) bin
+    ids, and for each feature compares its ids with an iota over the bins —
+    (n_bins, tk), a sublane broadcast — and multiplies on the MXU, int8 x
+    int8 into int32: whole numbers, the XLA product's to the bit in any
+    order."""
+    m, c = lhs.shape
+    db = bins_t.shape[0]
+    if lhs.dtype != jnp.int8:
+        raise ValueError(f"lhs must be int8, got {lhs.dtype}")
+    if bins_t.shape != (db, c) or bins_t.dtype != jnp.int32:
+        raise ValueError(
+            f"bins_t must be (db, {c}) int32, got {bins_t.shape} {bins_t.dtype}")
+    if n_bins % 128 or n_bins <= 0:
+        raise ValueError(f"n_bins={n_bins} is not a multiple of 128")
+    tm, tk = hist_onehot_tiles(m, c, n_bins)
+    tk = min(row_tile or tk, c)
+    if c % tk or tk % 128:
+        raise ValueError(f"c={c} not divisible by row_tile={tk}, a multiple of 128")
+    feats = HIST_ONEHOT_FEATURES
+    mp, dbp = _ceil_to(m, tm), _ceil_to(db, feats)
+    if mp != m:
+        lhs = jnp.pad(lhs, ((0, mp - m), (0, 0)))
+    if dbp != db:
+        bins_t = jnp.pad(bins_t, ((0, dbp - db), (0, 0)), constant_values=-1)
+    h = pl.pallas_call(
+        functools.partial(_hist_onehot_kernel, n_bins=n_bins),
+        grid=(mp // tm, dbp // feats, c // tk),
+        in_specs=[
+            pl.BlockSpec((tm, tk), lambda i, j, k: (i, k)),
+            pl.BlockSpec((feats, tk), lambda i, j, k: (j, k)),
+        ],
+        out_specs=pl.BlockSpec((tm, feats * n_bins), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((mp, dbp * n_bins), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=hist_onehot_vmem_limit(tm, tk, n_bins),
+        )
+        if not interpret
+        else None,
+        interpret=interpret,
+    )(lhs, bins_t)
+    return h[:m, :db * n_bins]
 
 
 # ---------------------------------------------------------------------------

@@ -56,6 +56,10 @@ def test_the_forest_stage_on_the_cpu_mesh():
     assert ways["count_cells_differ"] == 0 and ways["count_total"] > 0
     assert ways["derived_nodes"] > 0 and ways["folded_nodes"] >= ways["derived_nodes"]
     assert 0 < out["derived_share"] < 50 and out["frontier_nodes"]["folded"] >= 3
+    # here every fold is the XLA body's (on the chip the stage fails unless all are fused)
+    # (the two feeds' folds and the three levels' rescans)
+    assert out["fold_paths"] == {"fused": 0.0, "xla": 5.0} and out["fused_share"] == 0.0
+    assert ways["xla_count_cells_differ"] == 0 and max(ways["xla_label_sums_rel"]) < 1e-6
     json.dumps(out)
 
 

@@ -642,3 +642,98 @@ def test_softmax_stats_fn_kernel_matches_xla(rng, mesh8):
         np.testing.assert_allclose(
             np.asarray(va), np.asarray(vb), rtol=1e-4, atol=1e-2
         )
+
+
+def _hist_product(lhs, bins, n_bins):
+    """The XLA body's product: the block's one-hot, then a matrix product."""
+    c, db = bins.shape
+    one_hot = jax.nn.one_hot(jnp.asarray(bins), n_bins, dtype=jnp.int8)
+    return np.asarray(jnp.matmul(
+        jnp.asarray(lhs), one_hot.reshape(c, db * n_bins),
+        preferred_element_type=jnp.int32))
+
+
+@pytest.mark.parametrize("m,c,db,n_bins,row_tile,out_tile_rows", [
+    (7, 512, 8, 128, None, None),       # under int8's 32-row tile: padded, sliced
+    (32, 512, 8, 128, None, None),      # one whole tile: no pad
+    (210, 1024, 11, 128, 512, None),    # the root's height; 11 features: three padded to 16
+    (420, 2048, 16, 128, None, None),   # one 2,048-row tile, two feature steps
+    (63, 1536, 5, 256, None, None),     # two lane tiles of bins; 1,536 rows: 512-row tiles
+    (64, 512, 8, 128, None, 64),        # the output tile's cap: exactly one part
+    (65, 512, 8, 128, None, 64),        # one row over it: two parts of 64, the one-hot made twice
+    (200, 1024, 9, 128, 512, 64),       # four parts (ceil(224 / 64)), ragged features, two row tiles
+])
+def test_hist_onehot_matmul_parity_bit_for_bit(rng, monkeypatch, m, c, db, n_bins,
+                                               row_tile, out_tile_rows):
+    """lhs x one_hot(bins) with the one-hot made in VMEM is XLA's product
+    of the written-out one-hot to the bit (int8 into int32: whole numbers),
+    on both sides of every line the kernel draws — the 32-row pad of the
+    left operand, the 8-feature step, the output tile's cap on the rows a
+    grid step holds, the row tile — with what the fold hands it: blanked
+    columns (bin id -1: the ragged last feature block's), masked rows
+    (an all-zero column of lhs), bin ids at 0 and at n_bins - 1."""
+    from spark_rapids_ml_tpu.ops import pallas_kernels as pk
+
+    if out_tile_rows:
+        monkeypatch.setattr(
+            pk, "HIST_ONEHOT_OUT_TILE_BYTES",
+            4 * pk.HIST_ONEHOT_FEATURES * n_bins * out_tile_rows)
+        assert pk.hist_onehot_tiles(m, c, n_bins)[0] <= out_tile_rows
+    lhs = rng.integers(-128, 128, size=(m, c)).astype(np.int8)
+    lhs[:, rng.random(c) < 0.3] = 0  # masked rows weigh nothing in any channel
+    bins = rng.integers(0, n_bins, size=(c, db)).astype(np.int32)
+    bins[::7, 0], bins[1::7, 0] = 0, n_bins - 1
+    bins[:, -2:] = -1  # blanked columns: an all-zero one-hot
+    got = np.asarray(pk.hist_onehot_matmul_pallas.__wrapped__(
+        jnp.asarray(lhs), jnp.asarray(bins.T.copy()), n_bins=n_bins,
+        row_tile=row_tile, interpret=True))
+    want = _hist_product(lhs, bins, n_bins)
+    assert got.dtype == np.int32 and got.shape == want.shape == (m, db * n_bins)
+    np.testing.assert_array_equal(got, want)
+    assert not got[:, -2 * n_bins:].any() and got[:, :n_bins].any()
+
+
+@pytest.mark.parametrize("what,kw,match", [
+    ("lhs", {"lhs": np.zeros((32, 512), np.float32)}, "int8"),
+    ("bins", {"bins_t": np.zeros((8, 512), np.int8)}, "int32"),
+    ("bins", {"bins_t": np.zeros((8, 256), np.int32)}, "int32"),
+    ("n_bins", {"n_bins": 32}, "multiple of 128"),
+    ("row_tile", {"row_tile": 384}, "not divisible"),
+])
+def test_hist_onehot_matmul_refuses_what_it_cannot_multiply(what, kw, match):
+    from spark_rapids_ml_tpu.ops.pallas_kernels import hist_onehot_matmul_pallas
+
+    args = {"lhs": np.zeros((32, 512), np.int8), "bins_t": np.zeros((8, 512), np.int32),
+            "n_bins": 128, **kw}
+    with pytest.raises(ValueError, match=match):
+        hist_onehot_matmul_pallas(
+            jnp.asarray(args.pop("lhs")), jnp.asarray(args.pop("bins_t")),
+            interpret=True, **args)
+
+
+@pytest.mark.parametrize("m,c,n_bins,want", [
+    (210, 65536, 128, (224, 2048)),    # the cell's root
+    (3360, 65536, 128, (3360, 2048)),  # its halved depth 5: 105 whole 32-row tiles
+    (3360, 1536, 128, (3360, 512)),    # the largest power of two that divides the chunk
+    (6720, 65536, 128, (3360, 2048)),  # over 4,096 rows: two equal parts
+    (3360, 65536, 256, (1696, 2048)),  # 256 bins: 2,048 rows a part
+])
+def test_hist_onehot_tiles_from_the_height_the_chunk_and_the_bins(m, c, n_bins, want):
+    from spark_rapids_ml_tpu.ops.pallas_kernels import hist_onehot_tiles, hist_onehot_vmem_limit
+
+    assert hist_onehot_tiles(m, c, n_bins) == want
+    # the kernel claims what its tiles take, never the 96 MiB and over under
+    # which XLA's own VMEM-resident arrays were overwritten on the chip
+    limit = hist_onehot_vmem_limit(*want, n_bins)
+    assert 16 * 2**20 <= limit <= 72 * 2**20
+    assert limit == 16 * 2**20 or limit >= 1.25 * want[0] * (2 * 8 * n_bins * 4 + 2 * want[1])
+
+
+def test_hist_onehot_vmem_limit_at_the_largest_tiles_the_chooser_gives():
+    from spark_rapids_ml_tpu.ops.pallas_kernels import hist_onehot_tiles, hist_onehot_vmem_limit
+
+    for n_bins in (128, 256):
+        worst = max(
+            hist_onehot_vmem_limit(*hist_onehot_tiles(m, 65536, n_bins), n_bins)
+            for m in range(32, 20000, 32))
+        assert worst <= 72 * 2**20
