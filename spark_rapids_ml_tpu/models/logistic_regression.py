@@ -631,23 +631,27 @@ def _stream_grad_hess_shard_fn(mesh: Mesh, ad: str, use_pallas: bool = False):
     )
 
 
-def _fold_path_counter(mesh: Mesh, ad: str, use_pallas: bool):
-    """`on_dispatch` hook of the fold's two programs: one increment of
-    `srml_logreg_fold_path_total` a dispatch, under the path the program of
-    that batch shape was built with — `_stream_grad_hess_shard_fn`'s own
-    predicate, asked once a shape."""
+def _fold_path_counter(mesh: Mesh, counter, fused):
+    """`on_dispatch` hook of a streaming fold's two programs (one batch, a
+    group): one increment of `counter` a dispatch, under the path the
+    program of that batch shape was built with — `fused(shard_shape,
+    dtype)`, the shard body's own predicate, asked once a shape."""
 
     @functools.lru_cache(maxsize=None)
     def path(shape, dtype) -> str:
-        fused = _fused_newton_fold_applicable(
-            (shape[0] // mesh.shape[DATA_AXIS], shape[1]), dtype, ad, use_pallas)
-        return "fused" if fused else "xla"
+        shard = (shape[0] // mesh.shape[DATA_AXIS], shape[1])
+        return "fused" if fused(shard, dtype) else "xla"
 
     def count(state, w, b, x, y, mask):
         first = x[0] if isinstance(x, tuple) else x  # a group is one shape
-        _M_FOLD_PATH.inc(path=path(first.shape, first.dtype))
+        counter.inc(path=path(first.shape, first.dtype))
 
     return count
+
+
+def _newton_fold_path_counter(mesh: Mesh, ad: str, use_pallas: bool):
+    return _fold_path_counter(mesh, _M_FOLD_PATH, lambda shard, dtype: (
+        _fused_newton_fold_applicable(shard, dtype, ad, use_pallas)))
 
 
 def _stream_grad_hess_fn(mesh: Mesh, ad: str):
@@ -667,7 +671,7 @@ def _stream_grad_hess_cached(mesh: Mesh, ad: str, use_pallas: bool):
     def update(state, w, b, x, y, mask):
         return f(*state, w, b, x, y, mask)
 
-    update.on_dispatch = _fold_path_counter(mesh, ad, use_pallas)
+    update.on_dispatch = _newton_fold_path_counter(mesh, ad, use_pallas)
     return update
 
 
@@ -707,7 +711,7 @@ def _stream_grad_hess_group_cached(mesh: Mesh, ad: str, use_pallas: bool):
             state = (*state[:2], state[2][:d, :d], *state[3:])
         return state
 
-    update_group.on_dispatch = _fold_path_counter(mesh, ad, use_pallas)
+    update_group.on_dispatch = _newton_fold_path_counter(mesh, ad, use_pallas)
     return update_group
 
 
@@ -735,24 +739,50 @@ def _stream_newton_step_fn(reg: float, fit_intercept: bool, ad: str):
     return ledgered_jit("logreg.newton_step", step)
 
 
-def _stream_softmax_stats_fn(mesh: Mesh, n_classes: int, ad: str):
-    # compute_dtype / use_pallas are read at build time so they participate
-    # in the cache key (the _newton_fn snapshot pattern): a config flip
-    # between fits must not silently reuse a stale-curvature-dtype closure.
-    return _stream_softmax_stats_cached(
-        mesh, n_classes, ad, jnp.dtype(config.get("compute_dtype")).name,
-        bool(config.get("use_pallas")),
+_M_SOFTMAX_FOLD_PATH = metrics.counter(
+    "srml_logreg_softmax_fold_path_total",
+    "Dispatches of the streaming multinomial fold "
+    "(logreg.softmax_streaming_update / _group) by the body their program "
+    "was built with: path=fused (the per-class curvature blocks through "
+    "softmax_curvature_pallas) or path=xla (CPU, accumulator not float32, "
+    "shard rows off the kernel's block, a width off the 128-lane grid, one "
+    "class's (d, d) block over the kernel's VMEM budget)",
+)
+
+
+def _softmax_curv_kernel_applicable(shard_shape, ad, use_pallas: bool) -> bool:
+    """The multinomial fold's gate for the shared-tile Pallas curvature
+    (ops/pallas_kernels.softmax_curvature_pallas): TPU backend + float32
+    accumulate + block-divisible shard shapes (the streaming path's
+    power-of-two row buckets satisfy it from the block size up, smaller
+    buckets take the XLA loop, which is fine at that size) + even ONE
+    class's (d, d) accumulator inside the VMEM budget (past that the XLA
+    loop handles d, not a trace-time raise)."""
+    from spark_rapids_ml_tpu.ops.gram import _pallas_backend_ok
+    from spark_rapids_ml_tpu.ops.pallas_kernels import (
+        SOFTMAX_CURV_BLOCK_N,
+        SOFTMAX_CURV_VMEM_BUDGET,
+    )
+
+    n, d = shard_shape
+    return (
+        _pallas_backend_ok(use_pallas)
+        and jnp.dtype(ad) == jnp.dtype(jnp.float32)
+        and n % SOFTMAX_CURV_BLOCK_N == 0
+        and d % 128 == 0
+        and 4 * d * d <= SOFTMAX_CURV_VMEM_BUDGET
     )
 
 
-@functools.lru_cache(maxsize=32)
-def _stream_softmax_stats_cached(
-    mesh: Mesh, n_classes: int, ad: str, cd: str, use_pallas: bool = False
+def _stream_softmax_shard_fn(
+    mesh: Mesh, n_classes: int, ad: str, cd: str, use_pallas: bool
 ):
-    """Jitted donated accumulate of one batch's multinomial statistics at
-    fixed (W, b): (state, W, b, x, y, mask) -> state with
-    state = (gw (d, C), gb (C), hw (C, d, d), hwb (C, d), hbb (C),
-    loss (), n ()).
+    """One batch's multinomial statistics at fixed (W, b), added to the
+    running ones under ``shard_map``: (*state, W, b, x, y, mask) -> state
+    with state = (gw (d, C), gb (C), hw (C, d, d), hwb (C, d), hbb (C),
+    loss (), n ()). The one body of `_stream_softmax_stats_fn` and
+    `_stream_softmax_stats_group_fn`; the curvature kernel is looked up
+    here, when a program is built.
 
     The per-class curvature blocks are the MM/upper-bound Hessian
     Xᵀdiag(p_c)X: the softmax Hessian's class-coupling matrix satisfies
@@ -776,29 +806,9 @@ def _stream_softmax_stats_cached(
     )
 
     from spark_rapids_ml_tpu.ops.pallas_kernels import (
-        SOFTMAX_CURV_BLOCK_N,
-        SOFTMAX_CURV_VMEM_BUDGET,
         softmax_curv_block_c,
         softmax_curvature_pallas,
     )
-
-    def _curv_kernel_ok(n: int, d: int) -> bool:
-        """Shared-tile Pallas curvature: TPU backend + f32 accumulate +
-        block-divisible shapes (the n check runs per traced shape — the
-        streaming path's power-of-two row buckets satisfy it from the
-        block size up, smaller buckets take the XLA loop, which is fine
-        at that size) + even ONE class's (d, d) accumulator inside the
-        VMEM budget (past that the XLA loop handles d, not a trace-time
-        raise)."""
-        from spark_rapids_ml_tpu.ops.gram import _pallas_backend_ok
-
-        return (
-            _pallas_backend_ok(use_pallas)
-            and accum == jnp.float32
-            and n % SOFTMAX_CURV_BLOCK_N == 0
-            and d % 128 == 0
-            and 4 * d * d <= SOFTMAX_CURV_VMEM_BUDGET
-        )
 
     def shard(gw, gb, hw, hwb, hbb, loss, n, W, b, x, y, mask):
         from spark_rapids_ml_tpu.ops.gram import mm_precision
@@ -820,7 +830,7 @@ def _stream_softmax_stats_cached(
 
             xh = xc.astype(hd)
 
-            if _curv_kernel_ok(*x.shape):
+            if _softmax_curv_kernel_applicable(x.shape, ad, use_pallas):
                 # Shared-tile kernel: each VMEM-resident x tile feeds a
                 # class GROUP's GEMMs, dividing the C× HBM re-read of x —
                 # the cost that capped this pass at 0.85× (see
@@ -868,7 +878,7 @@ def _stream_softmax_stats_cached(
                 n + mr.reduce_sum(bn, DATA_AXIS),
             )
 
-    f = jax.shard_map(
+    return jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(), P(), P(), P(),
@@ -877,11 +887,70 @@ def _stream_softmax_stats_cached(
         check_vma=False,  # pallas_call out_shapes carry no vma annotation
     )
 
+
+def _softmax_fold_path_counter(mesh: Mesh, ad: str, use_pallas: bool):
+    return _fold_path_counter(mesh, _M_SOFTMAX_FOLD_PATH, lambda shard, _dtype: (
+        _softmax_curv_kernel_applicable(shard, ad, use_pallas)))
+
+
+def _stream_softmax_stats_fn(mesh: Mesh, n_classes: int, ad: str):
+    # compute_dtype / use_pallas are read at build time so they participate
+    # in the cache key (the _newton_fn snapshot pattern): a config flip
+    # between fits must not silently reuse a stale-curvature-dtype closure.
+    return _stream_softmax_stats_cached(
+        mesh, n_classes, ad, jnp.dtype(config.get("compute_dtype")).name,
+        bool(config.get("use_pallas")),
+    )
+
+
+@functools.lru_cache(maxsize=32)
+def _stream_softmax_stats_cached(
+    mesh: Mesh, n_classes: int, ad: str, cd: str, use_pallas: bool = False
+):
+    """Jitted donated accumulate of one batch's multinomial statistics at
+    fixed (W, b): (state, W, b, x, y, mask) -> state
+    (`_stream_softmax_shard_fn`)."""
+    f = _stream_softmax_shard_fn(mesh, n_classes, ad, cd, use_pallas)
+
     @functools.partial(ledgered_jit, "logreg.softmax_streaming_update", donate_argnums=(0,))
     def update(state, W, b, x, y, mask):
         return f(*state, W, b, x, y, mask)
 
+    update.on_dispatch = _softmax_fold_path_counter(mesh, ad, use_pallas)
     return update
+
+
+def _stream_softmax_stats_group_fn(mesh: Mesh, n_classes: int, ad: str):
+    """The same accumulate over a GROUP of device-resident batches in one
+    program: (state, W, b, xs, ys, masks) -> state, the three tuples of
+    equal length — the daemon's cached multinomial pass, as
+    `_stream_grad_hess_group_fn` is the binary one's. Batch by batch, in
+    order, through the one shard body `_stream_softmax_stats_fn` runs, with
+    an optimization barrier after each over the state AND the iterate the
+    next batch reads, so that a group is bit-equal to len(xs) calls of it.
+    One compiled program per (group length, batch shape)."""
+    return _stream_softmax_stats_group_cached(
+        mesh, n_classes, ad, jnp.dtype(config.get("compute_dtype")).name,
+        bool(config.get("use_pallas")),
+    )
+
+
+@functools.lru_cache(maxsize=32)
+def _stream_softmax_stats_group_cached(
+    mesh: Mesh, n_classes: int, ad: str, cd: str, use_pallas: bool
+):
+    f = _stream_softmax_shard_fn(mesh, n_classes, ad, cd, use_pallas)
+
+    @functools.partial(ledgered_jit, "logreg.softmax_streaming_update_group",
+                       donate_argnums=(0,))
+    def softmax_update_group(state, W, b, xs, ys, masks):
+        for x, y, mask in zip(xs, ys, masks):
+            state, W, b = jax.lax.optimization_barrier(
+                (f(*state, W, b, x, y, mask), W, b))
+        return state
+
+    softmax_update_group.on_dispatch = _softmax_fold_path_counter(mesh, ad, use_pallas)
+    return softmax_update_group
 
 
 @functools.lru_cache(maxsize=64)
@@ -1256,30 +1325,32 @@ class LogisticRegressionJob(JobAlgorithm):
     same feed/step/finalize op sequence over a per-class state). The
     iterate is (w, b), zero at creation; a pass's statistics are the
     gradient and Hessian blocks, the loss sum and the row count at it.
-    A binary job may keep its pass on the device — rows, masks and the
-    label column its fold places — and fold it again by the group (the
-    multinomial one keeps none: ``cacheable_for`` its params is False and
-    every pass of such a fit is fed)."""
+    Either job may keep its pass on the device — rows, masks and the
+    label column its fold places — and fold it again by the group, with
+    the group program of its own state."""
 
     name = "logreg"
     needs_labels = True
     iterative = True
     cacheable = True
-    # what the device waits for between two passes: the wait for the pass's
-    # folds (the loss's read), the Newton solve, the zero state, the snapshot
-    boundary_span = "newton.boundary"
 
     def __init__(self, n_cols, mesh, params):
         super().__init__(n_cols, mesh, params)
         self._require_gram_capacity()
         self.n_classes = self.feed_classes(params)
         ad = config.get("accum_dtype")
+        # The boundary's span names what the device waits for between two
+        # passes: the wait for the pass's folds (the loss's read), the
+        # solve (its own span), the zero state, the snapshot.
         if self.n_classes > 2:
             self.w = jnp.zeros((n_cols, self.n_classes), self.accum)
             self.b = jnp.zeros((self.n_classes,), self.accum)
             self._update = _stream_softmax_stats_fn(mesh, self.n_classes, ad)
+            self._update_group = _stream_softmax_stats_group_fn(
+                mesh, self.n_classes, ad)
             self._step_fn, self._objective = (
                 _stream_multinomial_step_fn, stream_softmax_objective)
+            self.boundary_span, self.solve_span = "softmax.boundary", "softmax.solve"
         else:
             self.w = jnp.zeros((n_cols,), self.accum)
             self.b = jnp.zeros((), self.accum)
@@ -1287,6 +1358,7 @@ class LogisticRegressionJob(JobAlgorithm):
             self._update_group = _stream_grad_hess_group_fn(mesh, ad)
             self._step_fn, self._objective = (
                 _stream_newton_step_fn, stream_objective)
+            self.boundary_span, self.solve_span = "newton.boundary", "newton.solve"
 
     @staticmethod
     def feed_classes(params) -> int:
@@ -1294,11 +1366,6 @@ class LogisticRegressionJob(JobAlgorithm):
         shared by label validation and the job-mismatch guard so the two
         cannot disagree on the coercion rule."""
         return int(params.get("n_classes") or 2)
-
-    @classmethod
-    def cacheable_for(cls, params) -> bool:
-        # no group program over the multinomial job's per-class state
-        return cls.cacheable and cls.feed_classes(params) <= 2
 
     @classmethod
     def check_labels(cls, params, y):
@@ -1368,7 +1435,7 @@ class LogisticRegressionJob(JobAlgorithm):
         # the loss's read is the wait for the pass's folds: before the
         # solve's span, so that the span holds the solve alone
         loss = self._objective(lsum, n, reg, self.w)
-        with trace_span("newton.solve"):
+        with trace_span(self.solve_span):
             self.w, self.b, delta = step_fn(
                 gw, gb, hww, hwb, hbb, n, self.w, self.b)
             delta = float(delta)

@@ -886,9 +886,9 @@ class _SparkAdapter:
         ledger_on = bool(rec_attempts) or elastic or grow
         # Pass cache (docs/protocol.md "rescan"): with a budget configured,
         # the passes after the first of a fit whose job algorithm says
-        # `cacheable_for` its params (models/jobs.py: kmeans, binary
-        # logreg, rf) are asked of the daemons' caches (`rescan`), and rows
-        # cross the wire once. 0 (the default) = off: not one op, ack
+        # `cacheable_for` its params (models/jobs.py: kmeans, logreg of
+        # any class count, rf) are asked of the daemons' caches (`rescan`),
+        # and rows cross the wire once. 0 (the default) = off: not one op, ack
         # column, import or branch more than before. Decided below, once
         # the fit's feed params are known.
         want_cache = False

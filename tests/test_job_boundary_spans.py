@@ -46,6 +46,14 @@ def _logreg(mesh):
     return job, {"reg": 1e-3, "fit_intercept": True}, {"delta", "loss"}
 
 
+def _logreg_mn(mesh):
+    x, y = _rows(5)
+    job = _Job("logreg", D, mesh, {"n_classes": 3})
+    job.set_iterate({"w": np.full((D, 3), 0.01), "b": np.asarray([0.05, 0.0, -0.05])}, 0)
+    job.fold(x / 20.0, np.digitize(y, [-10.0, 10.0]).astype(np.float64), pass_id=0)
+    return job, {"reg": 1e-3, "fit_intercept": True}, {"delta", "loss"}
+
+
 def _forest(mesh):
     x, y = _rows(7)
     with config.option("daemon_pass_cache_mb", 16):
@@ -59,6 +67,7 @@ def _forest(mesh):
 
 JOBS = {"kmeans": (_kmeans, "lloyd.boundary", []),
         "logreg": (_logreg, "newton.boundary", ["newton.solve"]),
+        "logreg_mn": (_logreg_mn, "softmax.boundary", ["softmax.solve"]),
         "rf": (_forest, "forest.boundary", ["forest.score"])}
 
 

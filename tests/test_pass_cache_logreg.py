@@ -1,7 +1,8 @@
-"""The pass cache for the Newton job (ISSUE 32; docs/protocol.md "rescan"):
-a binary logistic job keeps the batches its fold placed on the device —
-rows, mask AND the label column — and the Newton passes after the first
-are folded from there.
+"""The pass cache for the Newton job (docs/protocol.md "rescan"): a
+logistic job — binary Newton, or multinomial MM-Newton with its group
+program over the per-class state — keeps the batches its fold placed on
+the device — rows, mask AND the label column — and the passes after the
+first are folded from there.
 
 The invariant is `tests/test_pass_cache.py`'s: **the cache changes the
 transport of a pass, never its result.** Against a re-fed pass that folds
@@ -44,6 +45,21 @@ def _rows(seed, n, d=D):
     return x.astype(np.float32), y
 
 
+def _rows_mn(seed, n, d=D, classes=3):
+    """Overlapping classes: each class's logits of standard deviation ~1.5,
+    labels drawn from their softmax."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(n, d))
+    w = rng.normal(size=(d, classes)) * 1.5 / np.sqrt(d)
+    y = np.argmax(x @ w + rng.gumbel(size=(n, classes)), axis=1).astype(np.float64)
+    return x.astype(np.float32), y
+
+
+def _start_mn(seed, d=D, classes=3):
+    rng = np.random.default_rng([seed, 1])
+    return {"w": rng.normal(size=(d, classes)) * 0.1, "b": rng.normal(size=classes) * 0.05}
+
+
 def _start(seed, d=D):
     rng = np.random.default_rng([seed, 1])
     return {"w": rng.normal(size=d) * 0.1, "b": np.asarray([0.05])}
@@ -72,21 +88,21 @@ def test_the_table_says_which_algorithms_may_keep_their_pass(mesh8):
         "kmeans", "logreg", "rf"}
     binary = job_algorithm("logreg")(D, mesh8, {})
     assert binary.cacheable_for({}) and binary.boundary_span == "newton.boundary"
-    # the multinomial job has no group program: the class says so for its
-    # params — the one answer the driver and the daemon's job both ask —
-    # and the job that holds one is given no budget
+    # the multinomial job folds its cached pass with a group program of its
+    # own: the class says so for any class count — the one answer the driver
+    # and the daemon's job both ask — and the job that holds one has a budget
     logreg = job_algorithm("logreg")
-    assert logreg.cacheable_for({"n_classes": 2}) and not logreg.cacheable_for({"n_classes": 3})
+    assert logreg.cacheable_for({"n_classes": 2}) and logreg.cacheable_for({"n_classes": 3})
     assert job_algorithm("kmeans").cacheable_for({"k": 3})
     assert not job_algorithm("pca").cacheable_for({})
     multi = _job(mesh8, 16, params={"n_classes": 3})
-    assert multi._cache_budget == 0
-    assert multi.cache_ack() == {}
+    assert multi._cache_budget == 16 << 20
+    assert multi.algorithm.boundary_span == "softmax.boundary"
     x, _ = _rows(1, 300)
     multi.fold(x, np.arange(300) % 3, pass_id=0)
+    assert multi.cache_ack() == {"cached": True, "cached_rows": 300}
     multi.step(STEP)
-    with pytest.raises(protocol.NoCachedPass, match="keeps none"):
-        multi.rescan(1)
+    assert multi.rescan(1) == {"pass_rows": 300, "cached_rows": 300, "cached_batches": 1}
 
 
 @pytest.mark.parametrize("seed", [3, 2147483659])
@@ -119,6 +135,72 @@ def test_a_cached_newton_pass_is_bit_equal_to_a_refed_pass_of_direct_feeds(mesh8
     for key in ("w", "b"):
         np.testing.assert_array_equal(
             fed.get_iterate()[0][key], cached.get_iterate()[0][key])
+
+
+@pytest.mark.parametrize("seed", [3, 2147483659])
+def test_a_cached_multinomial_pass_is_bit_equal_to_a_refed_pass_of_direct_feeds(mesh8, seed):
+    """The multinomial job's cached pass, folded by its group program, is the
+    re-fed pass of direct feeds bit for bit, in every leaf of the per-class
+    state, pass after pass of MM-Newton."""
+    x, y = _rows_mn(seed, 4217)
+    params = {"n_classes": 3}
+    fed, cached = _job(mesh8, 0, params=params), _job(mesh8, 16, params=params)
+    for job in (fed, cached):
+        job.set_iterate(_start_mn(seed), 0)
+    for it in range(4):
+        for lo, hi in BATCHES:
+            fed.fold(x[lo:hi], y[lo:hi], pass_id=it)
+        if it == 0:
+            for lo, hi in BATCHES:
+                cached.fold(x[lo:hi], y[lo:hi], pass_id=it)
+            assert cached.cache_ack() == {"cached": True, "cached_rows": 4217}
+            xs, ms, ys = cached._cache.batches[4]
+            assert (xs.shape, ms.shape, ys.shape) == ((256, D), (256,), (256,))
+            np.testing.assert_array_equal(np.asarray(ys)[:217], y[4000:4217])
+        else:
+            ack = cached.rescan(it)
+            assert ack == {"pass_rows": 4217, "cached_rows": 4217, "cached_batches": 5}
+        got_fed, got_cached = _stats(fed), _stats(cached)
+        assert [a.shape for a in got_cached] == [
+            (D, 3), (3,), (3, D, D), (3, D), (3,), (), ()]
+        for a, b in zip(got_fed, got_cached):
+            np.testing.assert_array_equal(a, b)  # every leaf of the state: bit-equal
+        assert got_cached[-1] == 4217
+        assert fed.step(STEP) == cached.step(STEP)
+    for key in ("w", "b"):
+        np.testing.assert_array_equal(
+            fed.get_iterate()[0][key], cached.get_iterate()[0][key])
+
+
+@pytest.mark.parametrize("mesh", ["mesh1", "mesh8"])
+def test_the_multinomial_group_program_is_its_batches_fed_singly(request, mesh):
+    """`_stream_softmax_stats_group_fn` over a run of batches equals
+    `_stream_softmax_stats_fn` called on each in order, bit for bit: one
+    shard body, a barrier after each batch."""
+    from spark_rapids_ml_tpu.models import logistic_regression as lg
+    from spark_rapids_ml_tpu.parallel.sharding import shard_rows
+
+    mesh = request.getfixturevalue(mesh)
+    ad = config.get("accum_dtype")
+    x, y = _rows_mn(41, 3 * 512)
+    start = _start_mn(41)
+    w = jax.numpy.asarray(start["w"], ad)
+    b = jax.numpy.asarray(start["b"], ad)
+    placed = []
+    for i in range(3):
+        xs, ms, _ = shard_rows(x[i * 512:(i + 1) * 512], mesh, dtype=np.float32)
+        ys, _, _ = shard_rows(y[i * 512:(i + 1) * 512].astype(np.float32), mesh)
+        placed.append((xs, ys, ms))
+    single = lg._stream_softmax_stats_fn(mesh, 3, ad)
+    state = lg.stream_softmax_zero_state(D, 3, ad)
+    for xs, ys, ms in placed:
+        state = single(state, w, b, xs, ys, ms)
+    group = lg._stream_softmax_stats_group_fn(mesh, 3, ad)
+    xs, ys, ms = zip(*placed)
+    grouped = group(lg.stream_softmax_zero_state(D, 3, ad), w, b, xs, ys, ms)
+    for a, g in zip(jax.device_get(state), jax.device_get(grouped)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(g))
+    assert float(np.asarray(grouped[-1])) == 3 * 512
 
 
 @pytest.mark.parametrize("seed", [5, 3000000019])
@@ -273,6 +355,44 @@ def test_the_boundary_and_its_solve_are_spans_of_the_phase_histogram(mesh8):
     assert "logreg.newton_step" in xprof.snapshot()
 
 
+PHASES = ("newton.boundary", "newton.solve", "softmax.boundary", "softmax.solve")
+PATHS = ("srml_logreg_fold_path_total", "srml_logreg_softmax_fold_path_total")
+
+
+@pytest.mark.parametrize("classes,spans,counter,programs", [
+    (2, ("newton.boundary", "newton.solve"), "srml_logreg_fold_path_total",
+     ("logreg.streaming_update", "logreg.streaming_update_group", "logreg.newton_step")),
+    (3, ("softmax.boundary", "softmax.solve"), "srml_logreg_softmax_fold_path_total",
+     ("logreg.softmax_streaming_update", "logreg.softmax_streaming_update_group",
+      "logreg.softmax_newton_step")),
+], ids=["binary", "multinomial"])
+def test_each_job_counts_its_own_spans_programs_and_fold_paths(
+        mesh8, classes, spans, counter, programs):
+    """A boundary is one sample of each of its job's two spans and of no
+    other; a fold program's dispatch is one count of its job's path counter
+    (`xla` off the chip) and of no other; the binary job's span, ledger and
+    counter names are what they were before the multinomial job had its own."""
+    x, _ = _rows(37, 600)
+    y = (np.arange(600) % classes).astype(np.float64)
+    job = _job(mesh8, 16, params={"n_classes": classes})
+    assert (job.algorithm.boundary_span, job.algorithm.solve_span) == spans
+    phases = {p: _phase_count(p) for p in PHASES}
+    paths = {c: _counter(c) for c in PATHS}
+    xla = _counter(counter, path="xla")
+    calls = {name: xprof.snapshot().get(name, {"calls": 0})["calls"] for name in programs}
+    job.fold(x[:300], y[:300], pass_id=0)
+    job.fold(x[300:], y[300:], pass_id=0)
+    job.step(STEP)
+    job.rescan(1)  # both cached batches: one group dispatch
+    job.step(STEP)
+    assert {p: _phase_count(p) - n for p, n in phases.items()} == {
+        p: 2 if p in spans else 0 for p in PHASES}
+    assert {c: _counter(c) - n for c, n in paths.items()} == {
+        c: 3 if c == counter else 0 for c in PATHS}
+    assert _counter(counter, path="xla") - xla == 3  # off the chip: the XLA body
+    assert [xprof.snapshot()[name]["calls"] - calls[name] for name in programs] == [2, 1, 2]
+
+
 # ---------------- through a real daemon, client and spark/estimator.py -------
 
 
@@ -321,10 +441,10 @@ def test_spark_logistic_rows_and_labels_cross_the_wire_once_and_the_model_is_the
 
 def test_spark_multinomial_logistic_asks_no_cache_and_feeds_every_pass(
         mesh8, monkeypatch):
-    """The table says `logreg` may cache, but not for three classes
-    (`cacheable_for`): the driver never takes the cached-pass branch, the
-    daemon's job is given no budget, and the fit feeds every pass: a clean
-    refusal, not a wrong answer."""
+    """The multinomial fit keeps its pass since its job has a group program:
+    `cacheable_for` three classes is true, the driver takes the cached-pass
+    branch, its rows and labels cross the wire once, the two later MM-Newton
+    passes come from the daemon's cache, and the model is the key-off model."""
     from sparksim import SimDataFrame
     from spark_rapids_ml_tpu.spark import estimator as spark_est
 
@@ -335,12 +455,21 @@ def test_spark_multinomial_logistic_asks_no_cache_and_feeds_every_pass(
     with DataPlaneDaemon(host="127.0.0.1", port=0, mesh=mesh8) as daemon:
         monkeypatch.setenv("SRML_DAEMON_ADDRESS", "%s:%d" % daemon.address)
         monkeypatch.delenv("SRML_DAEMON_PASS_CACHE_MB", raising=False)
+        metrics_mod.reset()
         off = _fit(x, y, max_iter=3)
+        assert _counter("srml_daemon_requests_total", op="rescan") == 0
         monkeypatch.setenv("SRML_DAEMON_PASS_CACHE_MB", "16")
         metrics_mod.reset()
         with config.option("daemon_pass_cache_mb", 16):
             on = _fit(x, y, max_iter=3)
-    assert _counter("srml_daemon_requests_total", op="rescan") == 0
-    assert _counter("srml_daemon_pass_rows_total", source="cache") == 0
+    assert _counter("srml_daemon_pass_rows_total", source="wire") == len(x)
+    assert _counter("srml_daemon_pass_rows_total", source="cache") == 2 * len(x)
+    assert _counter("srml_daemon_passes_total", source="wire") == 1
+    assert _counter("srml_daemon_passes_total", source="cache") == 2
+    assert _counter("srml_daemon_requests_total", op="rescan") >= 2
+    assert on.summary.numIter == off.summary.numIter == 3
     assert on.coefficients.shape == off.coefficients.shape == (3, 5)
-    np.testing.assert_array_equal(on.coefficients, off.coefficients)
+    # the sums differ by the order of the accumulator's additions only
+    np.testing.assert_allclose(on.coefficients, off.coefficients, rtol=0, atol=1e-10)
+    np.testing.assert_allclose(np.asarray(on.intercept), np.asarray(off.intercept),
+                               rtol=0, atol=1e-10)
