@@ -930,6 +930,57 @@ def _case_softmax(mesh, d: int, n_classes: int, n: int):
     return gate, ["_softmax_curv_kernel"], errs, 2.0**-6
 
 
+def _case_softmax_fold(mesh, d: int, n_classes: int, n: int):
+    """softmax_fold_pallas ← models.logistic_regression.
+    _stream_softmax_stats_group_fn: the daemon's cached multinomial pass (two
+    float32 batches, one dispatch) at the benchmark's width, off the lane
+    grid, against the float32 XLA body; the dispatch counts under
+    ``srml_logreg_softmax_fold_path_total{path=fused}``."""
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_ml_tpu import config
+    from spark_rapids_ml_tpu.models import logistic_regression as lg
+    from spark_rapids_ml_tpu.parallel.sharding import row_sharding
+    from spark_rapids_ml_tpu.utils import metrics
+
+    rng = np.random.default_rng(70)
+    W = jnp.asarray(rng.standard_normal((d, n_classes), dtype=np.float32) / np.sqrt(d))
+    b = jnp.asarray(0.2 * rng.standard_normal((n_classes,), dtype=np.float32))
+    xs, ys, ms = [], [], []
+    for part in range(2):
+        mask = (rng.uniform(size=n) < 0.9).astype(np.float32)  # holes, not a prefix
+        xs.append(jax.device_put(rng.standard_normal((n, d), dtype=np.float32),
+                                 row_sharding(mesh)))
+        ys.append(jax.device_put(rng.integers(0, n_classes, size=n).astype(np.float32),
+                                 row_sharding(mesh, 1)))
+        ms.append(jax.device_put(mask, row_sharding(mesh, 1)))
+    ad = config.get("accum_dtype")
+    counter = metrics.counter("srml_logreg_softmax_fold_path_total")
+    before = counter.value(path="fused")
+    got = jax.device_get(lg._stream_softmax_stats_group_fn(mesh, n_classes, ad)(
+        lg.stream_softmax_zero_state(d, n_classes, ad), W, b,
+        tuple(xs), tuple(ys), tuple(ms)))
+    folds = counter.value(path="fused") - before
+    twin = lg._stream_softmax_stats_group_cached(
+        mesh, n_classes, "float32", "float32", False)
+    want = jax.device_get(twin(
+        lg.stream_softmax_zero_state(d, n_classes, "float32"), W, b,
+        tuple(xs), tuple(ys), tuple(ms)))
+    # the fold took the kernel iff the gate said it would
+    gate = (lg._fused_softmax_fold_applicable(
+        (n, d), np.float32, ad, n_classes) and folds == 1)
+    names = ("gw", "gb", "hw", "hwb", "hbb", "loss", "n")
+    errs = {name: rel_to_max(g, w) for name, g, w in zip(names, got, want)}
+    # One arithmetic in two orders of addition: float32 logits, gradient,
+    # loss and borders; the curvature a bfloat16 product on both sides.
+    # (Where the gate says no, the XLA body under a bfloat16 compute dtype
+    # weights the rows in bfloat16 before the border's sum.)
+    return gate, ["_softmax_fold_kernel"], errs, {
+        **dict.fromkeys(names, TOL_F32), "hw": TOL_BF16,
+        "hwb": TOL_F32 if folds else TOL_BF16}
+
+
 def _neighbor_errors(db: np.ndarray, queries: np.ndarray, source: np.ndarray,
                      d2: np.ndarray, ids: np.ndarray, k: int) -> Dict[str, float]:
     """Neighbour answers against exact float64 distances: the planted
@@ -1002,8 +1053,9 @@ def _case_ivf(mesh, d: int, k: int, n: int, nlist: int, nprobe: int,
 
 
 def kernel_cases(mesh_one, mesh_all) -> List[Tuple[str, Callable[[], tuple]]]:
-    """The eleven cases at each estimator's BASELINE.json width (the
-    streaming Newton fold at the benchmark's, d = 3000)."""
+    """The twelve cases at each estimator's BASELINE.json width (the
+    streaming Newton folds, binary and multinomial, at the benchmarks',
+    d = 3000)."""
     return [
         ("gram_pallas", lambda: _case_gram(mesh_one, 2048, 8192)),
         ("gram_colsum_pallas[seeded]",
@@ -1019,6 +1071,7 @@ def kernel_cases(mesh_one, mesh_all) -> List[Tuple[str, Callable[[], tuple]]]:
         ("newton_fold_pallas", lambda: _case_newton_fold(mesh_one, 3000, 4096)),
         ("softmax_curvature_pallas",
          lambda: _case_softmax(mesh_one, 1024, 32, 4096)),
+        ("softmax_fold_pallas", lambda: _case_softmax_fold(mesh_one, 3000, 3, 4096)),
         ("dist_topk_pallas",
          lambda: _case_exact_knn(mesh_one, 768, 10, 16384, 256)),
         ("ivf_scan_select_pallas+probe_select_pallas",

@@ -510,23 +510,26 @@ def _fused_newton_fold_applicable(
     )
 
 
-def _seeded_hessian_width(mesh: Mesh, ad: str, use_pallas: bool, x) -> int:
-    """The lane-padded width dp the fused body keeps its running Hessian at
-    for batches like `x` — where the kernel is SEEDED with it: the gate
-    holds and the mesh has one data device, so no psum sits between a
-    batch's product and the state's add. 0 elsewhere."""
+def _seeded_hessian_width(mesh: Mesh, fused, x) -> int:
+    """The lane-padded width dp a fused body keeps its running (..., d, d)
+    curvature at for batches like `x` — where its kernel is SEEDED with it:
+    the gate `fused(shard_shape, dtype)` holds and the mesh has one data
+    device, so no psum sits between a batch's product and the state's add.
+    0 elsewhere."""
     from spark_rapids_ml_tpu.ops.pallas_kernels import _ceil_to
 
     n_data = mesh.shape[DATA_AXIS]
-    fused = _fused_newton_fold_applicable(
-        (x.shape[0] // n_data, x.shape[1]), x.dtype, ad, use_pallas)
-    return _ceil_to(x.shape[1], 128) if fused and n_data == 1 else 0
+    seeded = n_data == 1 and fused((x.shape[0] // n_data, x.shape[1]), x.dtype)
+    return _ceil_to(x.shape[1], 128) if seeded else 0
 
 
 def _pad_hessian(hww, dp: int):
-    """(d, d) -> (dp, dp), zeros past d: exact, and undone by `[:d, :d]`."""
-    d = hww.shape[0]
-    return hww if d == dp else jnp.pad(hww, ((0, dp - d), (0, dp - d)))
+    """(..., d, d) -> (..., dp, dp), zeros past d: exact, and undone by
+    `[..., :d, :d]`."""
+    d = hww.shape[-1]
+    if d == dp:
+        return hww
+    return jnp.pad(hww, ((0, 0),) * (hww.ndim - 2) + ((0, dp - d),) * 2)
 
 
 @functools.lru_cache(maxsize=32)
@@ -701,7 +704,8 @@ def _stream_grad_hess_group_cached(mesh: Mesh, ad: str, use_pallas: bool):
                        donate_argnums=(0,))
     def update_group(state, w, b, xs, ys, masks):
         d = xs[0].shape[1]
-        dp = _seeded_hessian_width(mesh, ad, use_pallas, xs[0])
+        dp = _seeded_hessian_width(mesh, lambda shard, dtype: (
+            _fused_newton_fold_applicable(shard, dtype, ad, use_pallas)), xs[0])
         if dp:
             state = (*state[:2], _pad_hessian(state[2], dp), *state[3:])
         for x, y, mask in zip(xs, ys, masks):
@@ -743,11 +747,51 @@ _M_SOFTMAX_FOLD_PATH = metrics.counter(
     "srml_logreg_softmax_fold_path_total",
     "Dispatches of the streaming multinomial fold "
     "(logreg.softmax_streaming_update / _group) by the body their program "
-    "was built with: path=fused (the per-class curvature blocks through "
-    "softmax_curvature_pallas) or path=xla (CPU, accumulator not float32, "
-    "shard rows off the kernel's block, a width off the 128-lane grid, one "
-    "class's (d, d) block over the kernel's VMEM budget)",
+    "was built with: path=fused (a width the chip keeps rows minor, float32 "
+    "rows: the whole batch's statistics through softmax_fold_pallas, gate "
+    "_fused_softmax_fold_applicable; a width on the 128-lane grid: the "
+    "per-class curvature blocks through softmax_curvature_pallas) or "
+    "path=xla (CPU, rows or accumulator not float32, shard rows off the "
+    "kernel's block, a width on neither, a kernel's VMEM budget exceeded)",
 )
+
+
+def _fused_softmax_fold_applicable(
+    shard_shape, x_dtype, ad, n_classes: int, use_pallas: Optional[bool] = None
+) -> bool:
+    """`_stream_softmax_shard_fn`'s gate for the one-kernel fold
+    (ops/pallas_kernels.softmax_fold_pallas), by what the code can observe,
+    as `_fused_newton_fold_applicable` decides the binary one: TPU backend
+    and `use_pallas`, float32 rows and float32 accumulate, shard rows in
+    multiples of 512, a width the chip keeps ROWS MINOR (ceil(d / 8) · 8 <
+    ceil(d / 128) · 128 — there the kernel's `x.T` is a bitcast), and what
+    the kernel holds in VMEM — one lane-padded (dp, dp) class block, the
+    shard's logits — within its budget (constants and the VMEM reckoning
+    imported from the kernel so the two cannot drift). Widths on the lane
+    grid keep `softmax_curvature_pallas` (`_softmax_curv_kernel_applicable`);
+    everything else the XLA body."""
+    from spark_rapids_ml_tpu.ops.gram import _pallas_backend_ok
+
+    if not _pallas_backend_ok(use_pallas):
+        return False
+    from spark_rapids_ml_tpu.ops.pallas_kernels import (
+        SOFTMAX_FOLD_ROW_MULTIPLE,
+        SOFTMAX_FOLD_VMEM_BUDGET,
+        _ceil_to,
+        newton_fold_block_n,
+        softmax_fold_vmem_limit,
+    )
+
+    m, d = shard_shape
+    return (
+        jnp.dtype(x_dtype) == jnp.dtype(jnp.float32)
+        and jnp.dtype(ad) == jnp.dtype(jnp.float32)
+        and m > 0
+        and m % SOFTMAX_FOLD_ROW_MULTIPLE == 0
+        and _ceil_to(d, 8) < _ceil_to(d, 128)
+        and softmax_fold_vmem_limit(d, m, n_classes, newton_fold_block_n(d, m))
+        <= SOFTMAX_FOLD_VMEM_BUDGET
+    )
 
 
 def _softmax_curv_kernel_applicable(shard_shape, ad, use_pallas: bool) -> bool:
@@ -790,9 +834,21 @@ def _stream_softmax_shard_fn(
     EXACT gradient is a majorize-minimize Newton step — monotone descent
     with no line search, O(C·d²) state, one scan per iteration (the same
     streaming contract as the binary path; full-softmax coupling would
-    need a (C·d)² Hessian that cannot stream)."""
+    need a (C·d)² Hessian that cannot stream).
+
+    Where `_fused_softmax_fold_applicable` holds for a shard's rows (TPU,
+    float32 rows and accumulate, whole blocks, a width the chip keeps rows
+    minor — d = 3000) the whole batch goes through ONE kernel,
+    `softmax_fold_pallas`: it reads the batch as it lies (`x.T`, a
+    bitcast), takes every class's logits in float32 once, and folds one
+    class's curvature block at a time — this body's arithmetic in another
+    order of additions. On one data device the running blocks are seeded
+    into it, lane-padded: `hw` may arrive at (C, d, d) or already at
+    (C, dp, dp) (the group program pads once) and leaves as it came; on
+    several, the batch's blocks meet the state after the psum."""
     accum = jnp.dtype(ad)
     C = n_classes
+    seed = mesh.shape[DATA_AXIS] == 1
     # Curvature blocks set only the MM step DIRECTION (the fixed point is
     # pinned by the exact full-precision gradient below), so their GEMM
     # operands stream at the compute dtype: on the TPU bf16 profile that
@@ -810,9 +866,33 @@ def _stream_softmax_shard_fn(
         softmax_curvature_pallas,
     )
 
+    def fused_shard(gw, gb, hw, hwb, hbb, loss, n, W, b, x, y, mask):
+        from spark_rapids_ml_tpu.ops.pallas_kernels import _ceil_to, softmax_fold_pallas
+
+        d = x.shape[1]
+        dp = _ceil_to(d, 128)
+        if seed:
+            bgw, bgb, h, bhwb, bhbb, bloss, bn = softmax_fold_pallas(
+                x.T, y, mask, W, b, hw=_pad_hessian(hw, dp))
+            hw = h if hw.shape[-1] == dp else h[:, :d, :d]
+        else:
+            bgw, bgb, h, bhwb, bhbb, bloss, bn = softmax_fold_pallas(x.T, y, mask, W, b)
+            hw = hw + mr.reduce_sum(h[:, :d, :d], DATA_AXIS)
+        return (
+            gw + mr.reduce_sum(bgw, DATA_AXIS),
+            gb + mr.reduce_sum(bgb, DATA_AXIS),
+            hw,
+            hwb + mr.reduce_sum(bhwb, DATA_AXIS),
+            hbb + mr.reduce_sum(bhbb, DATA_AXIS),
+            loss + mr.reduce_sum(bloss, DATA_AXIS),
+            n + mr.reduce_sum(bn, DATA_AXIS),
+        )
+
     def shard(gw, gb, hw, hwb, hbb, loss, n, W, b, x, y, mask):
         from spark_rapids_ml_tpu.ops.gram import mm_precision
 
+        if _fused_softmax_fold_applicable(x.shape, x.dtype, ad, C, use_pallas):
+            return fused_shard(gw, gb, hw, hwb, hbb, loss, n, W, b, x, y, mask)
         with mm_precision(accum):
             xc = x.astype(accum)
             maskc = mask.astype(accum)
@@ -888,9 +968,10 @@ def _stream_softmax_shard_fn(
     )
 
 
-def _softmax_fold_path_counter(mesh: Mesh, ad: str, use_pallas: bool):
-    return _fold_path_counter(mesh, _M_SOFTMAX_FOLD_PATH, lambda shard, _dtype: (
-        _softmax_curv_kernel_applicable(shard, ad, use_pallas)))
+def _softmax_fold_path_counter(mesh: Mesh, n_classes: int, ad: str, use_pallas: bool):
+    return _fold_path_counter(mesh, _M_SOFTMAX_FOLD_PATH, lambda shard, dtype: (
+        _fused_softmax_fold_applicable(shard, dtype, ad, n_classes, use_pallas)
+        or _softmax_curv_kernel_applicable(shard, ad, use_pallas)))
 
 
 def _stream_softmax_stats_fn(mesh: Mesh, n_classes: int, ad: str):
@@ -916,7 +997,7 @@ def _stream_softmax_stats_cached(
     def update(state, W, b, x, y, mask):
         return f(*state, W, b, x, y, mask)
 
-    update.on_dispatch = _softmax_fold_path_counter(mesh, ad, use_pallas)
+    update.on_dispatch = _softmax_fold_path_counter(mesh, n_classes, ad, use_pallas)
     return update
 
 
@@ -928,7 +1009,9 @@ def _stream_softmax_stats_group_fn(mesh: Mesh, n_classes: int, ad: str):
     order, through the one shard body `_stream_softmax_stats_fn` runs, with
     an optimization barrier after each over the state AND the iterate the
     next batch reads, so that a group is bit-equal to len(xs) calls of it.
-    One compiled program per (group length, batch shape)."""
+    Where the fused body seeds its kernel with the running curvature, that
+    is lane-padded ONCE before the first batch and sliced once after the
+    last. One compiled program per (group length, batch shape)."""
     return _stream_softmax_stats_group_cached(
         mesh, n_classes, ad, jnp.dtype(config.get("compute_dtype")).name,
         bool(config.get("use_pallas")),
@@ -944,12 +1027,20 @@ def _stream_softmax_stats_group_cached(
     @functools.partial(ledgered_jit, "logreg.softmax_streaming_update_group",
                        donate_argnums=(0,))
     def softmax_update_group(state, W, b, xs, ys, masks):
+        d = xs[0].shape[1]
+        dp = _seeded_hessian_width(mesh, lambda shard, dtype: (
+            _fused_softmax_fold_applicable(shard, dtype, ad, n_classes, use_pallas)), xs[0])
+        if dp:
+            state = (*state[:2], _pad_hessian(state[2], dp), *state[3:])
         for x, y, mask in zip(xs, ys, masks):
             state, W, b = jax.lax.optimization_barrier(
                 (f(*state, W, b, x, y, mask), W, b))
+        if dp:
+            state = (*state[:2], state[2][:, :d, :d], *state[3:])
         return state
 
-    softmax_update_group.on_dispatch = _softmax_fold_path_counter(mesh, ad, use_pallas)
+    softmax_update_group.on_dispatch = _softmax_fold_path_counter(
+        mesh, n_classes, ad, use_pallas)
     return softmax_update_group
 
 

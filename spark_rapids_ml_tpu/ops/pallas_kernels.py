@@ -30,6 +30,18 @@ places hand-tiling pays:
   ``models/logistic_regression.py`` ``_stream_grad_hess_shard_fn`` where
   its gate holds (``fit_logistic_stream``, the daemon's
   ``LogisticRegressionJob``).
+* ``softmax_fold_pallas`` — the streaming multinomial fold on the chip at
+  a width it keeps rows minor: the batch read transposed, as it lies, C
+  times (class outermost), every class's logits taken once in float32 on
+  the VPU, one class's lane-padded (dp, dp) curvature block VMEM-resident
+  and seedable at a time, its border in float32 and its product from the
+  tile cast to bfloat16 in VMEM; the loss, the class sums and the gradient
+  XLA's over the kernel's logits. Called by
+  ``models/logistic_regression.py`` ``_stream_softmax_shard_fn`` where
+  ``_fused_softmax_fold_applicable`` holds (``fit_multinomial_stream``, the
+  daemon's ``LogisticRegressionJob`` of three classes or more).
+* ``softmax_curvature_pallas`` — the multinomial curvature blocks at a
+  width on the lane grid (row-major), a class group a read of the rows.
 * ``hist_onehot_matmul_pallas`` — the forests' histogram contraction on
   the chip: a chunk's int8 operand times the bin one-hot of a feature
   block, the one-hot made tile by tile in VMEM from the bin ids (never in
@@ -876,6 +888,234 @@ def newton_fold_pallas(
     return (
         jnp.sum(gw, axis=1)[:d], s[0, 0], h, jnp.sum(hwb, axis=1)[:d],
         s[0, 1], s[0, 2], s[0, 3],
+    )
+
+
+# ---------------------------------------------------------------------------
+# The streaming multinomial fold: the batch read as the chip keeps it, one
+# class's lane-padded curvature block VMEM-resident at a time
+# ---------------------------------------------------------------------------
+
+
+# Shared with the fold's gate (models/logistic_regression.py
+# `_fused_softmax_fold_applicable`), so the two cannot drift.
+SOFTMAX_FOLD_ROW_MULTIPLE = 512  # the fold's gate: shard rows in multiples of this
+SOFTMAX_FOLD_VMEM_BUDGET = 80 * 2**20  # the most scoped VMEM the kernel may claim
+
+
+def softmax_fold_vmem_limit(d: int, n: int, n_classes: int, block_n: int) -> int:
+    """The scoped VMEM `softmax_fold_pallas` is compiled under: what it
+    holds — the one (dp, dp) float32 curvature block, the double-buffered
+    (dp, block_n) row tile, mask, iterate, the class's (dp, 128)
+    border partials and the batch's resident (C, n) logits — and its
+    temporaries (the weighted tile in float32, both bfloat16 casts), a
+    quarter over what is not the block, at least Mosaic's default 16 MiB.
+    65.6 MiB at d = 3000, 65,536 rows, three classes and 256-row blocks:
+    under the 96 MiB at which a kernel overwrites small arrays XLA keeps in
+    VMEM around it (`hist_onehot_vmem_limit`)."""
+    dp, c8 = _ceil_to(d, 128), _ceil_to(n_classes, 8)
+    held = 2 * (dp * block_n * 4 + 8 * block_n * 4 + dp * _ceil_to(n_classes, 128) * 4
+                + dp * 128 * 4 + c8 * n * 4)
+    temps = dp * block_n * (4 + 2 + 2) + 4 * c8 * block_n * 4
+    return max(16 * 2**20, 4 * dp * dp + (held + temps) * 5 // 4 + 2 * 2**20)
+
+
+def _softmax_fold_kernel(b_ref, xt_ref, m_ref, w_ref, *refs, d, seeded):
+    if seeded:
+        h0_ref, hwb_ref, h_ref, z_ref, acc_ref = refs
+    else:
+        hwb_ref, h_ref, z_ref, acc_ref = refs
+    c, i = pl.program_id(0), pl.program_id(1)
+    last = pl.num_programs(1) - 1
+    n_classes = z_ref.shape[0]
+
+    @pl.when(i == 0)
+    def _open_class():
+        # One class's block at a time is resident: a (dp, dp) scratch the
+        # running block is copied into from HBM (or zeroed), and copied back
+        # out at the class's last row block — one buffer, where an output
+        # block whose index moved with the class would be double-buffered.
+        if seeded:
+            pltpu.sync_copy(h0_ref.at[c], acc_ref)
+        else:
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+        hwb_ref[:] = jnp.zeros_like(hwb_ref)
+
+    dp, bn = xt_ref.shape
+    if d != dp:
+        # What lies past feature d is unspecified: zeroed before anything
+        # reads it, as `_newton_fold_kernel` does (the tile is fetched again
+        # for every class, so every class step does it).
+        d8 = (d // 8) * 8
+        feat = jax.lax.broadcasted_iota(jnp.int32, (dp - d8, bn), 0)
+        xt_ref[d8:, :] = jnp.where(feat < d - d8, xt_ref[d8:, :], 0.0)
+
+    xt = xt_ref[:]  # (dp, bn) float32: features on sublanes, rows on lanes
+    cols = pl.ds(pl.multiple_of(i * bn, 128), bn)
+
+    @pl.when(c == 0)
+    def _logits():
+        # Every class's logits of this row block, once: float32 from the
+        # float32 tile on the VPU (a multiply and a sublane reduction, not a
+        # six-pass `highest` matvec), kept for the class steps that follow
+        # in the resident (C, n) output the wrapper takes the rest from.
+        for k in range(n_classes):
+            z_ref[k:k + 1, cols] = (
+                jnp.sum(xt * w_ref[:, k:k + 1], axis=0, keepdims=True) + b_ref[k])
+
+    z = z_ref[:, cols]  # (C, bn)
+    e = jnp.exp(z - jnp.max(z, axis=0, keepdims=True))
+    cls = jax.lax.broadcasted_iota(jnp.int32, z.shape, 0)
+    p = jnp.sum(jnp.where(cls == c, e, 0.0), axis=0, keepdims=True) / jnp.sum(
+        e, axis=0, keepdims=True)  # (1, bn): class c's softmax
+    # The border Σ x·p_c: a float32 sum of the float32 rows, added on the
+    # VPU into (dp, 128) lane partials.
+    xw = xt * (p * m_ref[:])  # the weighting in float32, before the cast
+    hpart = xw[:, :128]
+    for j in range(1, bn // 128):
+        hpart = hpart + xw[:, j * 128:(j + 1) * 128]
+    hwb_ref[:] += hpart
+    # The FULL product (x·p_c)ᵀ x — every one of its 2·bn·dp² operations —
+    # single-pass on the MXU from bfloat16 operands with float32
+    # accumulation: the XLA body's `dot_general(xw, xh, DEFAULT)`.
+    acc_ref[:] += jax.lax.dot_general(
+        xw.astype(jnp.bfloat16), xt.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=jax.lax.Precision.DEFAULT,
+    )
+
+    @pl.when(i == last)
+    def _close_class():
+        pltpu.sync_copy(acc_ref, h_ref.at[c])
+
+
+@functools.partial(
+    ledgered_jit, "pallas.softmax_fold_pallas", static_argnames=("block_n", "interpret")
+)
+def softmax_fold_pallas(
+    xt: jax.Array,
+    y: jax.Array,
+    mask: jax.Array,
+    W: jax.Array,
+    b: jax.Array,
+    hw=None,
+    block_n: Optional[int] = None,
+    interpret: bool = False,
+):
+    """One batch's multinomial MM-Newton statistics at fixed (W, b) — the
+    body of the streaming multinomial fold on the chip at a width it keeps
+    rows minor: `models/logistic_regression.py` `_stream_softmax_shard_fn`
+    calls it where `_fused_softmax_fold_applicable` holds
+    (`fit_multinomial_stream`, the daemon's `LogisticRegressionJob` of three
+    classes or more, the benchmark's `logreg_mn3_d3000.mm_newton_cached`).
+
+    xt: **(d, n) float32, the batch TRANSPOSED**, as `newton_fold_pallas`
+    takes it (at d = 3000 the chip keeps an (n, d) float32 batch rows minor,
+    so `x.T` is a bitcast); any width whose lane-padded block fits the VMEM
+    budget, the features past d zeroed in VMEM. y: (n,) class numbers;
+    mask: (n,) a general 0/1 array. W: (d, C) float32; b: (C,) (prefetched
+    to SMEM).
+
+    Grid (class, row block), class outermost: one class's (dp, dp) float32
+    block is resident over all the batch's rows, so the rows are read C
+    times — under the shadow of C products. At the class-0 steps every
+    class's logits are taken from the float32 tile on the VPU and kept in a
+    resident (C, n) array, the kernel's output; at the step of class c,
+    from the tile and those logits: the class's softmax p_c, `x·p_c` in
+    float32, the border Σ x·p_c as float32 lane partials, and the full
+    product `(x·p_c)ᵀ x` from both operands cast to bfloat16 in VMEM,
+    single-pass on the MXU with float32 accumulation.
+
+    The rest is the XLA body's arithmetic over the kernel's logits: the
+    softmax, the loss, Σ(p − y), Σ p_c and the row count by XLA's
+    reductions, and the gradient Xᵀ(P − Y) by XLA's float32 `highest`
+    product over the batch — a second read of it. That product is kept
+    because it is what the fold is held to: on the multinomial benchmark
+    cell's data the gradient cancels ~600-fold, and on a TPU v5e XLA's
+    product lies 8.3e-6 of its norm off a float64 sum where the VPU's
+    float32 lane partials lie 4.9e-8 off it — the benchmark's reference is
+    XLA's product too, so the more exact sum disagrees with it by four
+    times its limit (PERF.md §6).
+
+    ``hw``: optional (C, dp, dp) float32 running curvature, ALREADY
+    lane-padded (zero past d), the blocks are SEEDED from — each class's
+    copied from HBM at its first row step and back at its last, the buffer
+    aliased to the output — so ``hw += batch`` is this one dispatch. The
+    other statistics are the batch's own.
+
+    Returns (gw (d, C), gb (C,), hw (C, dp, dp) — zero past d —, hwb (C, d),
+    hbb (C,), loss (), n ()), all float32 raw sums of this call's rows.
+    """
+    d, n = xt.shape
+    n_classes = W.shape[1]
+    dp = _ceil_to(d, 128)
+    bn = min(block_n or newton_fold_block_n(d, n), n)
+    if n % bn or bn % 128:
+        raise ValueError(f"n={n} not divisible by block_n={bn}, a multiple of 128")
+    limit = softmax_fold_vmem_limit(d, n, n_classes, bn)
+    if limit > SOFTMAX_FOLD_VMEM_BUDGET:
+        raise ValueError(
+            f"d={d}, n={n}, {n_classes} classes: {limit} bytes of VMEM exceed the budget")
+    if xt.dtype != jnp.float32:
+        raise ValueError(f"xt must be float32 rows, got {xt.dtype}")
+    seeded = hw is not None
+    if seeded and hw.shape != (n_classes, dp, dp):
+        raise ValueError(f"hw {hw.shape} is not lane-padded to ({n_classes}, {dp}, {dp})")
+    yf = y.astype(jnp.float32).reshape(1, n)
+    mf = mask.astype(jnp.float32).reshape(1, n)
+    wpad = jnp.zeros((dp, n_classes), jnp.float32).at[:d].set(W.astype(jnp.float32))
+    hwb, h, z = pl.pallas_call(
+        functools.partial(_softmax_fold_kernel, d=d, seeded=seeded),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_classes, n // bn),
+            in_specs=[
+                pl.BlockSpec((dp, bn), lambda c, i, b: (0, i)),
+                pl.BlockSpec((1, bn), lambda c, i, b: (0, i)),
+                pl.BlockSpec((dp, n_classes), lambda c, i, b: (0, 0)),
+            ]
+            + ([pl.BlockSpec(memory_space=pl.ANY)] if seeded else []),
+            out_specs=[
+                pl.BlockSpec((None, dp, 128), lambda c, i, b: (c, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((n_classes, n), lambda c, i, b: (0, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((dp, dp), jnp.float32)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((n_classes, dp, 128), jnp.float32),
+            jax.ShapeDtypeStruct((n_classes, dp, dp), jnp.float32),
+            jax.ShapeDtypeStruct((n_classes, n), jnp.float32),
+        ],
+        # operand 4 (after b, xt, mask, W) is the seed's curvature
+        input_output_aliases={4: 1} if seeded else {},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=limit
+        )
+        if not interpret
+        else None,
+        interpret=interpret,
+    )(
+        jnp.asarray(b, jnp.float32).reshape((n_classes,)),
+        xt,
+        mf,
+        wpad,
+        *([hw] if seeded else []),
+    )
+    # The XLA body's sums over the kernel's logits: the loss's accuracy is
+    # XLA's reduction's, not that of a running float32 sum of the grid's
+    # block partials, and the gradient is XLA's `highest` product.
+    m = mf[0]
+    onehot = jnp.arange(n_classes, dtype=jnp.float32)[:, None] == yf
+    p = jax.nn.softmax(z, axis=0)
+    r = (p - onehot.astype(jnp.float32)) * m
+    loss = jnp.sum((jax.nn.logsumexp(z, axis=0) - jnp.sum(jnp.where(onehot, z, 0.0), axis=0)) * m)
+    with jax.default_matmul_precision("float32"):  # `mm_precision`'s: `highest`
+        gw = jax.lax.dot_general(xt.T, r.T, (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+    return (
+        gw, jnp.sum(r, axis=1), h, jnp.sum(hwb, axis=2)[:, :d],
+        jnp.sum(p * m, axis=1), loss,
+        jnp.sum(mf.astype(jnp.int32)).astype(jnp.float32),
     )
 
 

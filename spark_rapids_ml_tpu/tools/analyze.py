@@ -283,6 +283,15 @@ def _parse_cached(relpath: str, source: str) -> ast.AST:
     return tree
 
 
+def _walk(node: ast.AST) -> List[ast.AST]:
+    """``ast.walk(node)`` as a list, kept on the node: the rules walk every
+    module and function many times over, and only ever read the tree."""
+    nodes = getattr(node, "_srml_walk", None)
+    if nodes is None:
+        nodes = node._srml_walk = list(ast.walk(node))  # type: ignore[attr-defined]
+    return nodes
+
+
 class Module:
     """One parsed source file plus the lazy per-line pragma map."""
 
@@ -403,7 +412,7 @@ def in_main_guard(mod: Module, node: ast.AST) -> bool:
 
 
 def iter_functions(mod: Module) -> Iterator[ast.AST]:
-    for node in ast.walk(mod.tree):
+    for node in _walk(mod.tree):
         if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
             yield node
 
@@ -516,7 +525,7 @@ class JitRegistry:
         candidates: List[Tuple[Module, ast.AST, List[ast.AST]]] = []
         for mod in modules:
             mh = reg.module_handles.setdefault(mod.relpath, {})
-            for node in ast.walk(mod.tree):
+            for node in _walk(mod.tree):
                 # name = ledgered_jit("x", f, donate_argnums=...)
                 if isinstance(node, ast.Assign) and isinstance(node.value, ast.Call):
                     don = _ledgered_jit_donate(node.value)
@@ -584,7 +593,7 @@ class JitRegistry:
         bindings (``self.update``) cross methods and stay module-wide."""
         bound: Dict[str, List[Tuple[Optional[ast.AST], Tuple[int, ...]]]] = {}
         own = self.module_handles.get(mod.relpath, {})
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if not isinstance(node, ast.Assign):
                 continue
             value = node.value
@@ -614,7 +623,7 @@ class JitRegistry:
         and ``from ... import gram as gram_ops`` + ``gram_ops.<handle>``
         (the dotted spelling is resolved at the call site)."""
         out: Dict[str, Tuple[int, ...]] = {}
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if isinstance(node, ast.ImportFrom) and node.module:
                 src = _pkg_module_relpath(node.module, known_mods)
                 if src is None:
@@ -630,7 +639,7 @@ class JitRegistry:
         ``from spark_rapids_ml_tpu.ops import gram as gram_ops`` →
         ``{"gram_ops": "ops/gram.py"}``."""
         out: Dict[str, str] = {}
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if isinstance(node, ast.ImportFrom) and node.module:
                 for alias in node.names:
                     src = _pkg_module_relpath(
@@ -798,7 +807,7 @@ class CallGraph:
         known = self.project._known_mods
         for mod in self.project.modules:
             mf = self.module_funcs.setdefault(mod.relpath, {})
-            for node in ast.walk(mod.tree):
+            for node in _walk(mod.tree):
                 if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                     qual = mod.enclosing_symbol(node)
                     cls = _enclosing_class(mod, node)
@@ -830,7 +839,7 @@ class CallGraph:
                     self.class_bases[(mod.relpath, node.name)] = bases
             # import resolution (functions by name, modules by alias)
             imports: Dict[str, Tuple[str, str]] = {}
-            for node in ast.walk(mod.tree):
+            for node in _walk(mod.tree):
                 if isinstance(node, ast.ImportFrom) and node.module:
                     src = _pkg_module_relpath(node.module, known)
                     if src is None:
@@ -985,7 +994,7 @@ class CallGraph:
     def _link(self) -> None:
         for key, fn in sorted(self.nodes.items()):
             sites = self.calls_out.setdefault(key, [])
-            for node in ast.walk(fn.fn):
+            for node in _walk(fn.fn):
                 if not isinstance(node, ast.Call):
                     continue
                 # a call inside a nested def belongs to the nested node
@@ -1092,7 +1101,7 @@ class CallGraph:
         # the graph. The witness chain records (file, symbol, line,
         # note) hops from the function's own call down to the primitive.
         for key, fn in sorted(self.nodes.items()):
-            for node in ast.walk(fn.fn):
+            for node in _walk(fn.fn):
                 if not isinstance(node, ast.Call):
                     continue
                 if _enclosing_function(fn.mod, node) is not fn.fn:
@@ -1141,7 +1150,7 @@ class CallGraph:
         # contended lock is not re-derived (documented honesty gap).
         long_held: Dict[str, Tuple[Tuple[str, str, int, str], ...]] = {}
         for key, fn in sorted(self.nodes.items()):
-            for node in ast.walk(fn.fn):
+            for node in _walk(fn.fn):
                 if not isinstance(node, ast.With):
                     continue
                 if _enclosing_function(fn.mod, node) is not fn.fn:
@@ -1194,7 +1203,7 @@ class CallGraph:
             for key, fn in sorted(self.nodes.items()):
                 if key in self.may_block:
                     continue
-                for node in ast.walk(fn.fn):
+                for node in _walk(fn.fn):
                     if not isinstance(node, ast.With):
                         continue
                     if _enclosing_function(fn.mod, node) is not fn.fn:
@@ -1240,7 +1249,7 @@ class CallGraph:
         # callable, which is the POSITIONAL `function` parameter
         # (Timer takes no `target=`): Timer(5.0, X) / function=X.
         for key, fn in sorted(self.nodes.items()):
-            for node in ast.walk(fn.fn):
+            for node in _walk(fn.fn):
                 if not isinstance(node, ast.Call):
                     continue
                 ctor = terminal_name(node.func)
@@ -1642,13 +1651,13 @@ def _check_device_lock(project: Project) -> List[Finding]:
         for fn_node in iter_functions(mod):
             if not fn_node.name.endswith("_locked"):
                 continue
-            for sub in ast.walk(fn_node):
+            for sub in _walk(fn_node):
                 if isinstance(sub, ast.Call) and _is_dispatch_call(
                     project, mod, sub, view
                 ):
                     dispatching_helpers.add(fn_node.name)
                     break
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if not isinstance(node, ast.Call):
                 continue
             name = terminal_name(node.func)
@@ -1715,7 +1724,7 @@ def _check_device_lock(project: Project) -> List[Finding]:
 def _check_compile_outside_lock(project: Project) -> List[Finding]:
     out: List[Finding] = []
     for mod in project.device_modules():
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if not isinstance(node, ast.Call):
                 continue
             name = terminal_name(node.func)
@@ -1751,7 +1760,7 @@ def _check_lock_order(project: Project) -> List[Finding]:
     # every lock ever taken downstream of a device section.
     out: List[Finding] = []
     for mod in project.modules:
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if not isinstance(node, ast.With):
                 continue
             inner_names = [
@@ -1793,7 +1802,7 @@ def _check_lock_graph_cycle(project: Project) -> List[Finding]:
     #: edge (outer lock id → inner lock id) → first witnessing site
     edges: Dict[Tuple[str, str], Tuple[Module, ast.AST, str]] = {}
     for mod in project.modules:
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if not isinstance(node, ast.With):
                 continue
             inner_names = [
@@ -1900,7 +1909,7 @@ def _check_blocking_under_device_lock(project: Project) -> List[Finding]:
     graph = project.graph
     out: List[Finding] = []
     for mod in project.modules:
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if not isinstance(node, ast.Call):
                 continue
             if "_DEVICE_LOCK" not in held_locks(mod, node):
@@ -1983,11 +1992,11 @@ def _check_thread_shared_state(project: Project) -> List[Finding]:
         #: module-global names this function declares with `global`
         declared_global: Set[str] = {
             name
-            for node in ast.walk(fn.fn)
+            for node in _walk(fn.fn)
             if isinstance(node, ast.Global)
             for name in node.names
         }
-        for node in ast.walk(fn.fn):
+        for node in _walk(fn.fn):
             if _enclosing_function(mod, node) is not fn.fn:
                 continue
             target: Optional[str] = None
@@ -2042,7 +2051,7 @@ def _accesses(fn_node: ast.AST, dotted: str) -> List[Tuple[Tuple[int, int], str]
     """All ordered (position, "load"|"store") accesses to ``dotted`` in
     the function — plain names and ``self.x``-style attributes."""
     acc: List[Tuple[Tuple[int, int], str]] = []
-    for node in ast.walk(fn_node):
+    for node in _walk(fn_node):
         dn = None
         ctx = None
         if isinstance(node, ast.Name):
@@ -2154,7 +2163,7 @@ def _check_use_after_donate(project: Project) -> List[Finding]:
     for mod in project.modules:
         view = project.jit_view(mod)
         for fn_node in iter_functions(mod):
-            for node in ast.walk(fn_node):
+            for node in _walk(fn_node):
                 if not isinstance(node, ast.Call):
                     continue
                 # One visit per call: nested defs are walked separately.
@@ -2265,7 +2274,7 @@ def _check_unsorted_iter(project: Project) -> List[Finding]:
     out: List[Finding] = []
     for mod in project.modules:
         iters: List[Tuple[ast.AST, ast.AST, Optional[ast.comprehension]]] = []
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if isinstance(node, (ast.For, ast.AsyncFor)):
                 iters.append((node, node.iter, None))
             elif isinstance(node, (ast.ListComp, ast.SetComp, ast.DictComp, ast.GeneratorExp)):
@@ -2325,7 +2334,7 @@ _SEEDED_RNG_CTORS = frozenset(
 def _check_wallclock_entropy(project: Project) -> List[Finding]:
     out: List[Finding] = []
     for mod in project.modules:
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if not isinstance(node, ast.Call):
                 continue
             dn = dotted_name(node.func)
@@ -2376,7 +2385,7 @@ def collect_dispatched_ops(mod: Module) -> Dict[str, int]:
         tn = terminal_name(e)
         return tn is not None and (tn == "op" or tn.endswith("_op"))
 
-    for node in ast.walk(mod.tree):
+    for node in _walk(mod.tree):
         if not isinstance(node, ast.Compare):
             continue
         operands = [node.left, *node.comparators]
@@ -2401,7 +2410,7 @@ def collect_dispatched_ops(mod: Module) -> Dict[str, int]:
 
 def collect_known_ops(mod: Module) -> Optional[Set[str]]:
     """The ``_KNOWN_OPS = frozenset((...))`` clamp literal, AST-parsed."""
-    for node in ast.walk(mod.tree):
+    for node in _walk(mod.tree):
         if not isinstance(node, ast.Assign):
             continue
         if not any(terminal_name(t) == "_KNOWN_OPS" for t in node.targets):
@@ -2484,7 +2493,7 @@ def _dict_return_keys(mod: Module) -> Dict[str, Set[str]]:
     returns: Dict[str, Set[str]] = {}
     for fn_node in iter_functions(mod):
         keys: Set[str] = set()
-        for ret in ast.walk(fn_node):
+        for ret in _walk(fn_node):
             if isinstance(ret, ast.Return) and isinstance(ret.value, ast.Dict):
                 for k in ret.value.keys:
                     s = const_str(k) if k is not None else None
@@ -2571,7 +2580,7 @@ def collect_ack_fields(mod: Module) -> Set[str]:
     (see :func:`_scrape_ack_call` for the resolution rules)."""
     returns = _dict_return_keys(mod)
     fields: Set[str] = set()
-    for node in ast.walk(mod.tree):
+    for node in _walk(mod.tree):
         if isinstance(node, ast.Call):
             _scrape_ack_call(mod, node, returns, fields)
     return fields
@@ -2802,7 +2811,7 @@ def collect_op_schemas(
     for fn_node in serve_fns:
         nodes = [
             n
-            for n in ast.walk(fn_node)
+            for n in _walk(fn_node)
             if id(n) not in arm_stmt_ids
             and _enclosing_function(mod, n) is fn_node
         ]
@@ -2962,7 +2971,7 @@ def _check_bare_print(project: Project) -> List[Finding]:
     for mod in project.modules:
         if mod.relpath.split("/", 1)[0] == "tools":
             continue
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if (
                 isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Name)
@@ -2999,7 +3008,7 @@ def _check_bare_collective(project: Project) -> List[Finding]:
     for mod in project.modules:
         if mod.relpath.split("/", 1)[0] == "parallel":
             continue
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if not isinstance(node, ast.Call):
                 continue
             fn = node.func
@@ -3031,7 +3040,7 @@ def _check_bare_collective(project: Project) -> List[Finding]:
 def _check_socket_timeout(project: Project) -> List[Finding]:
     out: List[Finding] = []
     for mod in project.modules:
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if not isinstance(node, ast.Call):
                 continue
             if dotted_name(node.func) != "socket.create_connection" and not (
@@ -3061,7 +3070,7 @@ def collect_ledgered_jit_names(mod: Module) -> List[Tuple[str, int]]:
     """(ledger name, line) of every ``ledgered_jit("area.fn", ...)`` /
     ``functools.partial(ledgered_jit, "area.fn", ...)`` registration."""
     out: List[Tuple[str, int]] = []
-    for node in ast.walk(mod.tree):
+    for node in _walk(mod.tree):
         if not isinstance(node, ast.Call):
             continue
         fn = terminal_name(node.func)
@@ -3104,7 +3113,7 @@ def _check_jit_ledger(project: Project) -> List[Finding]:
         if m.relpath.split("/", 1)[0] in ("ops", "models")
     ]
     for mod in scoped:
-        for node in ast.walk(mod.tree):
+        for node in _walk(mod.tree):
             if (
                 isinstance(node, ast.Call)
                 and dotted_name(node.func) == "jax.jit"
@@ -3197,7 +3206,7 @@ def _check_hot_path_span(project: Project) -> List[Finding]:
             spanned = any(
                 isinstance(sub, ast.Call)
                 and terminal_name(sub.func) == "trace_span"
-                for sub in ast.walk(fn_node)
+                for sub in _walk(fn_node)
             )
             if not spanned:
                 out.append(

@@ -311,3 +311,35 @@ def test_the_cached_batch_is_read_as_it_lies_at_d_3000(one_chip):
     # 786,432,000 bytes of rows: the unpadded batch, not 805 MB of padded lanes
     assert mem.argument_size_in_bytes < 4 * n * d + 4 * dp * dp + 2**20
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_multinomial_fold_reads_the_cached_batch_as_it_lies_at_d_3000(one_chip):
+    """The multinomial cell's batch through `softmax_fold_pallas`, compiled
+    for a v5e: no copy of the batch (its `x.T` a bitcast), the three class
+    blocks aliased in and out, the kernel's scoped VMEM claim under the 96
+    MiB at which a kernel overwrites XLA's small arrays around it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    n, d, c, dp = 65536, 3000, 3, 3072
+
+    def s(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def fold(x, y, mask, W, b, hw):
+        return pk.softmax_fold_pallas(x.T, y, mask, W, b, hw=hw)
+
+    assert pk.softmax_fold_vmem_limit(d, n, c, pk.newton_fold_block_n(d, n)) < 96 * 2**20
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            compiled = jax.jit(fold, donate_argnums=(5,)).lower(
+                s(n, d), s(n), s(n), s(d, c), s(c), s(c, dp, dp)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2**20  # the loss's reductions over (3, n) logits
+    assert mem.alias_size_in_bytes == 4 * c * dp * dp
+    assert mem.argument_size_in_bytes < 4 * n * d + 4 * c * dp * dp + 2**20
+    assert "tpu_custom_call" in compiled.as_text()

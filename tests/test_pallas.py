@@ -1,5 +1,7 @@
 """Pallas kernel parity tests (interpret mode — no TPU needed)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -642,6 +644,241 @@ def test_softmax_stats_fn_kernel_matches_xla(rng, mesh8):
         np.testing.assert_allclose(
             np.asarray(va), np.asarray(vb), rtol=1e-4, atol=1e-2
         )
+
+
+# ---------------------------------------------------------------------------
+# The multinomial fold at a width the chip keeps rows minor:
+# softmax_fold_pallas, its gate, and the programs that run it
+# ---------------------------------------------------------------------------
+
+SOFTMAX_STATE = ("gw", "gb", "hw", "hwb", "hbb", "loss", "n")
+
+
+def _softmax_batch(seed: int, n: int, d: int, n_classes: int):
+    """float32 rows, class labels, a mask with HOLES (not a prefix), a
+    non-zero iterate and a non-zero running state."""
+    from spark_rapids_ml_tpu.models.logistic_regression import stream_softmax_zero_state
+
+    r = np.random.default_rng(seed)
+    x = r.normal(size=(n, d)).astype(np.float32)
+    W = (r.normal(size=(d, n_classes)) / np.sqrt(d)).astype(np.float32)
+    b = (0.2 * r.normal(size=n_classes)).astype(np.float32)
+    y = r.integers(0, n_classes, size=n).astype(np.float32)
+    mask = (r.random(n) < 0.8).astype(np.float32)
+    state = tuple(r.normal(size=z.shape).astype(np.float32)
+                  for z in stream_softmax_zero_state(d, n_classes, "float32"))
+    return state, W, b, x, y, mask
+
+
+def _softmax_xla(mesh, n_classes, state, W, b, x, y, mask):
+    """The XLA body of the shard function, float32, `use_pallas` off."""
+    from spark_rapids_ml_tpu.models.logistic_regression import _stream_softmax_shard_fn
+
+    f = _stream_softmax_shard_fn(mesh, n_classes, "float32", "float32", False)
+    return [np.asarray(a) for a in f(*(jnp.asarray(a) for a in (*state, W, b, x, y, mask)))]
+
+
+def _softmax_kernel(state, W, b, x, y, mask, **kw):
+    from spark_rapids_ml_tpu.ops.pallas_kernels import softmax_fold_pallas
+
+    out = softmax_fold_pallas(jnp.asarray(x).T, jnp.asarray(y), jnp.asarray(mask),
+                              jnp.asarray(W), jnp.asarray(b), interpret=True, **kw)
+    return [np.asarray(a) for a in out]
+
+
+def _assert_same_softmax_stats(got, want, state):
+    """The batch's ADDITION to each leaf against the XLA body's: the
+    gradient, its intercept, Σ p_c, the border and n to the order of
+    float32 additions (the loss: two float32 sums of the same terms, a few
+    units in the last place apart), the curvature to one bfloat16
+    product's (the CPU's XLA body multiplies in float32, the kernel as the
+    chip does)."""
+    for name, g, w_, s in zip(SOFTMAX_STATE, got, want, state):
+        g, w_, s = (np.asarray(a, np.float64) for a in (g, w_, s))
+        scale = max(np.abs(w_ - s).max(), 1e-30)
+        tol = {"hw": 4e-3, "loss": 3e-7}.get(name, 2e-6)
+        assert np.abs(g - w_).max() <= tol * scale, (name, np.abs(g - w_).max() / scale)
+
+
+@pytest.mark.parametrize("d", [200, 1000])
+@pytest.mark.parametrize("n_classes", [2, 3, 5])
+def test_softmax_fold_matches_the_xla_body(mesh1, d, n_classes):
+    """Widths off the lane grid (200: whole sublane tiles past d; 1000: a
+    block of 24 zeroed features); the kernel's sums are the batch's own,
+    its curvature lane-padded with zeros."""
+    n = 1024
+    batch = _softmax_batch(d + n_classes, n, d, n_classes)
+    zero = tuple(np.zeros_like(s) for s in batch[0])
+    want = _softmax_xla(mesh1, n_classes, zero, *batch[1:])
+    got = _softmax_kernel(*batch, block_n=256)
+    dp = -(-d // 128) * 128
+    assert got[2].shape == (n_classes, dp, dp) and got[2].dtype == np.float32
+    assert not got[2][:, d:].any() and not got[2][:, :, d:].any()
+    got[2] = got[2][:, :d, :d]
+    assert [g.shape for g in got] == [w_.shape for w_ in want]
+    assert all(g.dtype == np.float32 for g in got)
+    _assert_same_softmax_stats(got, want, zero)
+    assert got[6] == batch[5].sum() and 0 < got[6] < n  # the mask has holes
+    # The loss is XLA's reduction over the kernel's logits: within 1e-7 of
+    # the float64 loss, or as close as the XLA body's own float32 sum.
+    _, W, b, x, y, mask = batch
+    z = x.astype(np.float64) @ W.astype(np.float64) + b
+    top = z.max(axis=1)
+    lse = top + np.log(np.exp(z - top[:, None]).sum(axis=1))
+    loss = float(((lse - z[np.arange(n), y.astype(int)]) * mask).sum())
+    assert abs(got[5] - loss) <= max(1e-7, 1.01 * abs(want[5] - loss) / loss) * loss
+
+
+def test_softmax_fold_seeded_adds_to_the_running_blocks_alone():
+    d, n, C = 200, 512, 3
+    batch = _softmax_batch(1, n, d, C)
+    plain = _softmax_kernel(*batch, block_n=256)
+    seed = np.zeros((C, 256, 256), np.float32)
+    seed[:, :d, :d] = batch[0][2]
+    seeded = _softmax_kernel(*batch, block_n=256, hw=jnp.asarray(seed))
+    np.testing.assert_allclose(seeded[2], seed + plain[2], rtol=0, atol=1e-4)
+    assert not seeded[2][:, d:].any() and not seeded[2][:, :, d:].any()
+    for i in (0, 1, 3, 4, 5, 6):
+        np.testing.assert_array_equal(seeded[i], plain[i])
+
+
+@pytest.mark.parametrize("what,kw,match", [
+    ("rows", {"block_n": 384}, "not divisible"),
+    ("block", {"block_n": 64}, "multiple of 128"),
+    ("dtype", {}, "float32 rows"),
+    ("seed", {"hw": np.zeros((3, 200, 200), np.float32)}, "lane-padded"),
+    ("classes", {"hw": np.zeros((2, 256, 256), np.float32)}, "lane-padded"),
+    ("budget", {}, "exceed the budget"),
+])
+def test_softmax_fold_refuses_what_it_cannot_fold(what, kw, match):
+    d = 4200 if what == "budget" else 200
+    state, W, b, x, y, mask = _softmax_batch(2, 512, d, 3)
+    if what == "dtype":
+        x = x.astype(np.float64)
+    with pytest.raises(ValueError, match=match):
+        _softmax_kernel(state, W, b, x, y, mask, **kw)
+
+
+@pytest.fixture
+def softmax_fused_on_cpu(monkeypatch):
+    """Steer the multinomial fold onto its kernels here: the gates are told
+    the backend is a TPU and both kernels run in interpret mode. The
+    program has no option for this: the test does it."""
+    from spark_rapids_ml_tpu import config
+    from spark_rapids_ml_tpu.models import logistic_regression as lg
+    from spark_rapids_ml_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(config, "backend_is_tpu", lambda: True)
+    monkeypatch.setattr(pk, "softmax_fold_pallas",
+                        functools.partial(pk.softmax_fold_pallas, interpret=True))
+    monkeypatch.setattr(pk, "softmax_curvature_pallas",
+                        functools.partial(pk.softmax_curvature_pallas, interpret=True))
+    caches = (lg._stream_softmax_stats_cached, lg._stream_softmax_stats_group_cached)
+    for c in caches:
+        c.cache_clear()
+    yield
+    for c in caches:
+        c.cache_clear()
+
+
+@pytest.mark.parametrize("tpu,d,n,dtype,ad,path", [
+    (True, 3000, 65536, np.float32, "float32", "fold"),  # the cell's batch
+    (True, 200, 512, np.float32, "float32", "fold"),     # 200 < 256: rows minor on the chip
+    (True, 1000, 1024, np.float32, "float32", "fold"),   # 1000 < 1024
+    (True, 256, 1024, np.float32, "float32", "curvature"),  # on the lane grid: row-major
+    (True, 1020, 1024, np.float32, "float32", "xla"),    # 1024 = 1024: a tie, row-major
+    (False, 200, 512, np.float32, "float32", "xla"),     # the CPU backend
+    (True, 200, 512, np.float64, "float32", "xla"),      # rows not float32
+    (True, 200, 512, np.float32, "float64", "xla"),      # accumulator not float32
+    (True, 200, 500, np.float32, "float32", "xla"),      # ragged shard rows
+    (True, 4200, 512, np.float32, "float32", "xla"),     # the block over the VMEM budget
+    (True, 3000, 262144, np.float32, "float32", "xla"),  # the shard's logits over it
+])
+def test_fused_softmax_fold_gate(softmax_fused_on_cpu, monkeypatch, tpu, d, n, dtype, ad, path):
+    """Rows-minor widths take `softmax_fold_pallas`, widths on the lane grid
+    keep `softmax_curvature_pallas`, everything else the XLA body; the
+    binary gate answers these shapes as it did before the multinomial one
+    existed (its own table: tests/test_logreg_fused_fold.py)."""
+    from spark_rapids_ml_tpu import config
+    from spark_rapids_ml_tpu.models import logistic_regression as lg
+
+    if not tpu:
+        monkeypatch.setattr(config, "backend_is_tpu", lambda: False)
+    fold = lg._fused_softmax_fold_applicable((n, d), dtype, ad, 3, True)
+    curvature = lg._softmax_curv_kernel_applicable((n, d), ad, True)
+    assert (fold, curvature) == (path == "fold", path == "curvature")
+    assert not lg._fused_softmax_fold_applicable((n, d), dtype, ad, 3, False)
+    binary = (tpu and dtype == np.float32 and ad == "float32" and n % 512 == 0
+              and -(-d // 8) * 8 < -(-d // 128) * 128 and d <= 4096)
+    assert lg._fused_newton_fold_applicable((n, d), dtype, ad, True) is binary
+
+
+@pytest.mark.parametrize("d", [200, 1000])
+def test_fused_softmax_body_matches_the_xla_body_from_a_running_state(
+        softmax_fused_on_cpu, mesh1, d):
+    from spark_rapids_ml_tpu.models import logistic_regression as lg
+
+    n, C = 1024, 3
+    batch = _softmax_batch(10 + d, n, d, C)
+    assert lg._fused_softmax_fold_applicable((n, d), np.float32, "float32", C, True)
+    f = lg._stream_softmax_shard_fn(mesh1, C, "float32", "float32", True)
+    got = [np.asarray(a) for a in f(*(jnp.asarray(a) for a in (*batch[0], *batch[1:])))]
+    assert [g.shape for g in got] == [s.shape for s in batch[0]]
+    _assert_same_softmax_stats(got, _softmax_xla(mesh1, C, *batch), batch[0])
+
+
+def test_fused_softmax_four_shards_give_the_one_device_fold(softmax_fused_on_cpu, mesh1, devices):
+    """On four data devices the kernel is unseeded and the blocks meet the
+    state after the psum; the mask's holes fall in every shard."""
+    from spark_rapids_ml_tpu.models import logistic_regression as lg
+    from spark_rapids_ml_tpu.parallel.mesh import make_mesh
+
+    n, d, C = 2048, 200, 3  # 512 rows a shard
+    mesh4 = make_mesh(data=4, model=1, devices=devices[:4])
+    batch = _softmax_batch(33, n, d, C)
+    assert lg._fused_softmax_fold_applicable((n // 4, d), np.float32, "float32", C, True)
+    f = lg._stream_softmax_shard_fn(mesh4, C, "float32", "float32", True)
+    got = [np.asarray(a) for a in f(*(jnp.asarray(a) for a in (*batch[0], *batch[1:])))]
+    _assert_same_softmax_stats(got, _softmax_xla(mesh1, C, *batch), batch[0])
+
+
+def _softmax_path_counts():
+    from spark_rapids_ml_tpu.utils import metrics
+
+    c = metrics.counter("srml_logreg_softmax_fold_path_total")
+    return {p: c.value(path=p) for p in ("fused", "xla")}
+
+
+@pytest.mark.parametrize("d", [200, 1000])
+def test_fused_softmax_group_is_its_batches_fed_singly_and_counts_fused(
+        softmax_fused_on_cpu, mesh1, d):
+    """The group pads the running blocks once and slices them once; a call
+    pads and slices every time (a zero pad and its slice are exact), so a
+    group of three is three calls bit for bit — and each dispatch of either
+    program counts once under `path=fused`."""
+    from spark_rapids_ml_tpu import config
+    from spark_rapids_ml_tpu.models import logistic_regression as lg
+
+    n, C = 512, 3
+    batches = [_softmax_batch(20 + i, n, d, C) for i in range(3)]
+    state, W, b = batches[0][:3]
+    with config.option("use_pallas", True):
+        update = lg._stream_softmax_stats_cached(mesh1, C, "float32", "bfloat16", True)
+        group = lg._stream_softmax_stats_group_cached(mesh1, C, "float32", "bfloat16", True)
+    before = _softmax_path_counts()
+    one = tuple(jnp.asarray(s) for s in state)
+    for bt in batches:
+        one = update(one, jnp.asarray(W), jnp.asarray(b), *(jnp.asarray(a) for a in bt[3:]))
+    mid = _softmax_path_counts()
+    xs, ys, ms = (tuple(jnp.asarray(bt[i]) for bt in batches) for i in (3, 4, 5))
+    three = group(tuple(jnp.asarray(s) for s in state), jnp.asarray(W), jnp.asarray(b),
+                  xs, ys, ms)
+    after = _softmax_path_counts()
+    assert mid["fused"] - before["fused"] == 3 and after["fused"] - mid["fused"] == 1
+    assert after["xla"] == before["xla"]
+    for name, a, c in zip(SOFTMAX_STATE, one, three):
+        assert a.shape == c.shape, name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c), err_msg=name)
 
 
 def _hist_product(lhs, bins, n_bins):
