@@ -12,6 +12,10 @@ Objective (Spark ML LogisticRegression, ``standardization=False``):
 
     min_w 1/n Σ log(1 + exp(−ŷᵢ·(xᵢw + b))) + λ/2·‖w‖₂²   (binary, L2)
 
+With elasticNetParam α > 0 the penalty is Spark's λ(α‖w‖₁ + (1−α)/2·‖w‖₂²)
+and each boundary takes a proximal-Newton step (binary only):
+_stream_prox_newton_step_fn.
+
 Binary labels are {0, 1}. Multinomial (softmax) runs MM-Newton: the exact
 gradient with per-class upper-bound curvature blocks (the (C·d)² Hessian is
 never materialized — see _stream_softmax_stats_fn). Intercept is
@@ -32,6 +36,7 @@ from spark_rapids_ml_tpu import config
 from spark_rapids_ml_tpu.core.dataset import as_column, as_matrix, with_column
 from spark_rapids_ml_tpu.core.params import (
     Estimator,
+    HasElasticNetParam,
     HasFeaturesCol,
     HasFitIntercept,
     HasLabelCol,
@@ -382,6 +387,7 @@ def fit_logistic_regression(
     max_iter: int = 100,
     tol: float = 1e-6,
     mesh: Optional[Mesh] = None,
+    elastic_net: float = 0.0,
 ) -> LogisticSolution:
     from spark_rapids_ml_tpu.parallel.sharding import require_single_process
 
@@ -399,9 +405,15 @@ def fit_logistic_regression(
         raise ValueError(
             f"labels must be 0..{n_classes - 1} (Spark ML convention); got {classes[:8]}"
         )
+    elastic_net = check_elastic_net(elastic_net, float(reg), n_classes)
     ad = config.get("accum_dtype")
     with trace_span("logreg fit"):
         xs, mask, n_true = shard_rows(x, mesh)
+        if elastic_net > 0.0:
+            ys, _, _ = shard_rows(y.astype(np.float32), mesh)
+            return _fit_elastic_net_resident(xs, ys, mask, n_true, float(reg), elastic_net,
+                                             bool(fit_intercept), int(max_iter),
+                                             float(tol), mesh, ad)
         if n_classes == 2:
             ys, _, _ = shard_rows(y.astype(np.float64), mesh)
             fn = _newton_fn(mesh, float(reg), bool(fit_intercept), int(max_iter), float(tol), ad)
@@ -451,6 +463,34 @@ def fit_logistic_regression(
             n_iter=n_iter,
             n_rows=n_true,
         )
+
+
+def _fit_elastic_net_resident(xs, ys, mask, n_true, reg, elastic_net, fit_intercept,
+                              max_iter, tol, mesh, ad) -> LogisticSolution:
+    """The in-process elastic-net fit over rows placed once: each iteration
+    the daemon job's pass — the streaming fold over the resident shards —
+    and its proximal-Newton step."""
+    accum = jnp.dtype(ad)
+    update = _stream_grad_hess_fn(mesh, ad)
+    step = _stream_prox_newton_step_fn(reg, elastic_net, fit_intercept, ad)
+    w = jnp.zeros((xs.shape[1],), accum)
+    b = jnp.zeros((), accum)
+    n_iter, loss = 0, float("nan")
+    for it in range(max_iter):
+        gw, gb, hww, hwb, hbb, lsum, n = update(
+            stream_zero_state(xs.shape[1], accum), w, b, xs, ys, mask)
+        loss = stream_elastic_net_objective(lsum, n, reg, elastic_net, w)
+        w, b, delta, _, _ = _counted_prox_step(step, gw, gb, hww, hwb, hbb, n, w, b)
+        n_iter = it + 1
+        if delta <= tol:
+            break
+    return LogisticSolution(
+        coefficients=np.asarray(jax.device_get(w), dtype=np.float64),
+        intercept=np.asarray(jax.device_get(b), dtype=np.float64),
+        n_iter=n_iter,
+        n_rows=n_true,
+        loss=loss,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -741,6 +781,185 @@ def _stream_newton_step_fn(reg: float, fit_intercept: bool, ad: str):
         return w - dw, b - db, delta
 
     return ledgered_jit("logreg.newton_step", step)
+
+
+_M_PROX_ITERATIONS = metrics.counter(
+    "srml_logreg_prox_iterations_total",
+    "Products of the Hessian in the proximal-Newton boundary's inner solve "
+    "(logreg.prox_newton_step, elastic_net > 0): the power iteration's that "
+    "set the step size, then one a FISTA iteration, rejected ones included",
+)
+_M_NONZERO = metrics.gauge(
+    "srml_logreg_nonzero_coefficients",
+    "Nonzero coefficients (the intercept not counted) of the iterate the "
+    "last proximal-Newton step (elastic_net > 0) left",
+)
+
+#: The inner solve of `_stream_prox_newton_step_fn`. It stops when the
+#: gradient mapping's largest entry is PROX_FORCING times its value at the
+#: current iterate (an inexact proximal-Newton forcing term), or at the
+#: float32 floor below, or after PROX_MAX_ITER FISTA iterations.
+PROX_FORCING = 1e-4
+PROX_MAX_ITER = 500
+#: power-iteration products that estimate the step size 1/L before FISTA
+PROX_POWER_ITER = 12
+
+
+def elastic_net_penalties(reg: float, elastic_net: float):
+    """Spark ML's split of regParam λ by elasticNetParam α: (L1 weight λα,
+    L2 weight λ(1 − α)) of λ(α‖w‖₁ + (1 − α)/2‖w‖²)."""
+    return reg * elastic_net, reg * (1.0 - elastic_net)
+
+
+def check_elastic_net(elastic_net, reg: float, n_classes: int) -> float:
+    """The elasticNetParam a fit runs with: in [0, 1]; 0 where regParam is
+    0 (no penalty to mix: the Newton path runs); refused for more than two
+    classes, whose MM-Newton boundary has no L1 step."""
+    elastic_net = float(elastic_net or 0.0)
+    if not 0.0 <= elastic_net <= 1.0:
+        raise ValueError(f"elastic_net must be in [0, 1]; got {elastic_net}")
+    if reg <= 0.0:
+        return 0.0
+    if elastic_net > 0.0 and n_classes > 2:
+        raise ValueError(
+            f"elastic_net {elastic_net} > 0 is not supported for multinomial "
+            f"logistic regression (n_classes={n_classes}): its MM-Newton "
+            "boundary has no L1 step; use elasticNetParam 0")
+    return elastic_net
+
+
+@functools.lru_cache(maxsize=64)
+def _stream_prox_newton_step_fn(reg: float, elastic_net: float, fit_intercept: bool,
+                                ad: str):
+    """Jitted proximal-Newton finalize of one pass (elastic_net > 0): scan
+    sums + current (w, b) -> (new_w, new_b, delta, products, nonzero).
+
+    The smooth part f = mean logloss + λ₂/2‖w‖² gives the gradient g and
+    the Hessian H (the bordered [w; b] system, H_ww = hww/n + λ₂I); the step
+    δ minimises the quadratic model plus the L1 term,
+
+        gᵀδ + ½δᵀHδ + λ₁‖w + δ_w‖₁    (b free and unpenalised),
+
+    by FISTA in δ from δ = 0 (the current iterate, whose support is the
+    best guess of the next), on the device, in one program. A product with
+    H reads the raw (d, d) `hww` once, as an elementwise product and a sum
+    over its rows — exact float32 at the speed of one read of it, with no
+    bordered or scaled copy. The step size 1/L starts from a power
+    iteration and grows (×2, momentum reset) whenever an iteration finds
+    the model's curvature along its move above L — the backtracking test
+    of a quadratic, free because H·y is kept by linearity; the momentum
+    restarts adaptively (O'Donoghue & Candès). It stops at `PROX_FORCING`
+    times the gradient mapping's largest entry at δ = 0 — or at 16 float32
+    ulps of the gradient's scale, which no float32 product resolves — or
+    after `PROX_MAX_ITER` iterations. `products` counts the products with
+    H (power iteration and FISTA), `nonzero` the new w's nonzeros."""
+    accum = jnp.dtype(ad)
+    l1, l2 = elastic_net_penalties(reg, elastic_net)
+
+    def prox_newton_step(gw, gb, hww, hwb, hbb, n, w, b):
+        n = jnp.maximum(n, 1.0)
+        free_b = jnp.asarray(1.0 if fit_intercept else 0.0, accum)
+        g_w = gw / n + l2 * w
+        g_b = free_b * gb / n
+        h_wb, h_bb = hwb / n, hbb / n
+
+        def hmul(v_w, v_b):
+            hv_w = jnp.sum(hww * v_w[:, None], axis=0) / n + l2 * v_w + h_wb * v_b
+            return hv_w, free_b * (h_wb @ v_w + h_bb * v_b)
+
+        def power(_, v):
+            v_w, v_b = hmul(*v)
+            norm = jnp.maximum(jnp.sqrt(v_w @ v_w + v_b * v_b), 1e-30)
+            return v_w / norm, v_b / norm
+
+        d = w.shape[0]
+        v0 = (jnp.full((d,), 1.0, accum) / jnp.sqrt(d + 1.0),
+              free_b / jnp.sqrt(d + 1.0))
+        v_w, v_b = jax.lax.fori_loop(0, PROX_POWER_ITER, power, v0)
+        hv_w, hv_b = hmul(v_w, v_b)
+        lip0 = jnp.maximum(1.1 * (v_w @ hv_w + v_b * hv_b), 1e-12)
+
+        def prox(y_w, y_b, gy_w, gy_b, lip):
+            """δ of the proximal gradient step from δ = y at step 1/lip, kept
+            in δ: sign(u)(|u| − t) − w = s − sign(u)·t for u = w + s, so a
+            move far below the iterate's ulp is not rounded away."""
+            s = y_w - gy_w / lip
+            u = w + s
+            t = l1 / lip
+            return (jnp.where(jnp.abs(u) > t, s - jnp.sign(u) * t, -w),
+                    y_b - free_b * gy_b / lip)
+
+        zero_w, zero_b = jnp.zeros_like(w), jnp.zeros((), accum)
+        p_w, p_b = prox(zero_w, zero_b, g_w, g_b, lip0)
+        map0 = lip0 * jnp.maximum(jnp.max(jnp.abs(p_w)), jnp.abs(p_b))
+        floor = 16.0 * jnp.finfo(accum).eps * (
+            jnp.maximum(jnp.max(jnp.abs(g_w)), jnp.abs(g_b)) + l1)
+        target = jnp.maximum(PROX_FORCING * map0, floor)
+
+        def cond(c):
+            k, done = c[-2], c[-1]
+            return jnp.logical_and(k < PROX_MAX_ITER, jnp.logical_not(done))
+
+        def body(c):
+            (v_w, v_b, vp_w, vp_b, hv_w, hv_b, hvp_w, hvp_b, t, lip, k, _) = c
+            t_next = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
+            beta = (t - 1.0) / t_next
+            y_w, y_b = v_w + beta * (v_w - vp_w), v_b + beta * (v_b - vp_b)
+            hy_w, hy_b = hv_w + beta * (hv_w - hvp_w), hv_b + beta * (hv_b - hvp_b)
+            n_w, n_b = prox(y_w, y_b, g_w + hy_w, g_b + hy_b, lip)
+            hn_w, hn_b = hmul(n_w, n_b)
+            s_w, s_b = n_w - y_w, n_b - y_b
+            curv = s_w @ (hn_w - hy_w) + s_b * (hn_b - hy_b)
+            fits = curv <= lip * (s_w @ s_w + s_b * s_b) * (1.0 + 1e-4)
+            gmap = lip * jnp.maximum(jnp.max(jnp.abs(s_w)), jnp.abs(s_b))
+            # momentum restarts where the move turns against the last one
+            turn = (n_w - v_w) @ (y_w - n_w) + (n_b - v_b) * (y_b - n_b) > 0.0
+            keep = lambda new, old: jnp.where(fits, new, old)  # noqa: E731
+            reset = jnp.logical_or(turn, jnp.logical_not(fits))
+            return (
+                keep(n_w, v_w), keep(n_b, v_b),
+                jnp.where(reset, keep(n_w, v_w), v_w),
+                jnp.where(reset, keep(n_b, v_b), v_b),
+                keep(hn_w, hv_w), keep(hn_b, hv_b),
+                jnp.where(reset, keep(hn_w, hv_w), hv_w),
+                jnp.where(reset, keep(hn_b, hv_b), hv_b),
+                jnp.where(reset, 1.0, t_next).astype(accum),
+                jnp.where(fits, lip, 2.0 * lip),
+                k + 1,
+                jnp.logical_and(fits, gmap <= target),
+            )
+
+        one = jnp.ones((), accum)
+        init = (zero_w, zero_b, zero_w, zero_b, zero_w, zero_b, zero_w, zero_b,
+                one, lip0, jnp.zeros((), jnp.int32), map0 <= target)
+        out = jax.lax.while_loop(cond, body, init)
+        d_w, d_b, k = out[0], out[1], out[-2]
+        new_w = w + d_w
+        delta = jnp.sqrt(d_w @ d_w + d_b * d_b)
+        nonzero = jnp.sum(new_w != 0.0).astype(jnp.int32)
+        return new_w, b + d_b, delta, k + PROX_POWER_ITER + 1, nonzero
+
+    return ledgered_jit("logreg.prox_newton_step", prox_newton_step)
+
+
+def _counted_prox_step(step, *args):
+    """One call of a `_stream_prox_newton_step_fn` program: (new_w, new_b)
+    on the device, the step's length, products and nonzero count read to
+    the host in one transfer and the last two booked in the two metrics."""
+    new_w, new_b, *scalars = step(*args)
+    delta, products, nonzero = jax.device_get(scalars)
+    delta, products, nonzero = float(delta), int(products), int(nonzero)
+    _M_PROX_ITERATIONS.inc(products)
+    _M_NONZERO.set(nonzero)
+    return new_w, new_b, delta, products, nonzero
+
+
+def stream_elastic_net_objective(lsum, n, reg: float, elastic_net: float, w) -> float:
+    """Training objective of an elastic-net fit at the iterate a pass
+    evaluated: `stream_objective` at the L2 weight λ(1 − α), plus λα‖w‖₁
+    (Spark ML's λ(α‖w‖₁ + (1 − α)/2‖w‖²))."""
+    l1, l2 = elastic_net_penalties(reg, elastic_net)
+    return stream_objective(lsum, n, l2, w) + l1 * float(jnp.sum(jnp.abs(w)))
 
 
 _M_SOFTMAX_FOLD_PATH = metrics.counter(
@@ -1289,6 +1508,7 @@ def fit_logistic_stream(
     tol: float = 1e-6,
     mesh: Optional[Mesh] = None,
     checkpoint_path: Optional[str] = None,
+    elastic_net: float = 0.0,
 ) -> LogisticSolution:
     """Binary Newton-IRLS over a re-scannable stream of host (x, y) batches
     — the capacity path for label datasets ≫ HBM (BASELINE.json config #4:
@@ -1305,7 +1525,9 @@ def fit_logistic_stream(
     difference is below the stopping precision).
 
     With ``checkpoint_path``, (w, b) persist after every iteration and an
-    interrupted fit resumes at the saved iteration.
+    interrupted fit resumes at the saved iteration. ``elastic_net`` > 0
+    (with ``reg`` > 0) takes a proximal-Newton step at each boundary
+    (`_stream_prox_newton_step_fn`) and reports the elastic-net objective.
 
     **Multi-host**: ``batch_source`` yields this process's local (x, y)
     stream; scans run in lockstep (``lockstep_labeled_batches`` — uneven
@@ -1320,7 +1542,19 @@ def fit_logistic_stream(
     ad = config.get("accum_dtype")
     accum = jnp.dtype(ad)
     update = _stream_grad_hess_fn(mesh, ad)
-    newton_step = _stream_newton_step_fn(float(reg), bool(fit_intercept), ad)
+    elastic_net = check_elastic_net(elastic_net, float(reg), 2)
+    if elastic_net > 0.0:
+        prox_step = _stream_prox_newton_step_fn(
+            float(reg), elastic_net, bool(fit_intercept), ad)
+
+        def newton_step(*args):
+            return _counted_prox_step(prox_step, *args)[:3]
+
+        def objective(lsum, n, reg, w):
+            return stream_elastic_net_objective(lsum, n, reg, elastic_net, w)
+    else:
+        newton_step = _stream_newton_step_fn(float(reg), bool(fit_intercept), ad)
+        objective = stream_objective
 
     w = jnp.zeros((n_cols,), accum)
     b = jnp.zeros((), accum)
@@ -1373,7 +1607,7 @@ def fit_logistic_stream(
         for it in range(start_iter, max_iter):
             (gw, gb, hww, hwb, hbb, lsum, n), n_true = scan(w, b)
             # Objective at the iterate the scan evaluated (pre-update w).
-            loss = stream_objective(lsum, n, reg, w)
+            loss = objective(lsum, n, reg, w)
             w, b, delta = newton_step(gw, gb, hww, hwb, hbb, n, w, b)
             n_iter = it + 1
             if checkpoint_path and (not multiproc or jax.process_index() == 0):
@@ -1391,7 +1625,7 @@ def fit_logistic_stream(
             # Resumed at/past max_iter: the loop never ran, so evaluate the
             # restored iterate once for a faithful (n_rows, loss).
             (_, _, _, _, _, lsum, n), n_true = scan(w, b)
-            loss = stream_objective(lsum, n, reg, w)
+            loss = objective(lsum, n, reg, w)
     if checkpoint_path and (not multiproc or jax.process_index() == 0):
         import os
 
@@ -1521,6 +1755,10 @@ class LogisticRegressionJob(JobAlgorithm):
     def step(self, state, params):
         reg = float(params.get("reg", 0.0))
         fit_intercept = bool(params.get("fit_intercept", True))
+        elastic_net = check_elastic_net(params.get("elastic_net", 0.0), reg,
+                                        self.n_classes)
+        if elastic_net > 0.0:
+            return self._prox_step(state, reg, elastic_net, fit_intercept)
         gw, gb, hww, hwb, hbb, lsum, n = state
         step_fn = self._step_fn(reg, fit_intercept, self.accum.name)
         # the loss's read is the wait for the pass's folds: before the
@@ -1531,6 +1769,20 @@ class LogisticRegressionJob(JobAlgorithm):
                 gw, gb, hww, hwb, hbb, n, self.w, self.b)
             delta = float(delta)
         return {"delta": delta, "loss": loss}
+
+    def _prox_step(self, state, reg, elastic_net, fit_intercept):
+        """The elastic-net boundary: one proximal-Newton step over the
+        pass's statistics (`_stream_prox_newton_step_fn`), under the same
+        spans as the Newton solve."""
+        gw, gb, hww, hwb, hbb, lsum, n = state
+        step_fn = _stream_prox_newton_step_fn(reg, elastic_net, fit_intercept,
+                                              self.accum.name)
+        loss = stream_elastic_net_objective(lsum, n, reg, elastic_net, self.w)
+        with trace_span(self.solve_span):
+            self.w, self.b, delta, products, nonzero = _counted_prox_step(
+                step_fn, gw, gb, hww, hwb, hbb, n, self.w, self.b)
+        return {"delta": delta, "loss": loss, "nonzero": nonzero,
+                "prox_products": products}
 
     def finalize(self, state, params, rows, iteration):
         w = np.asarray(jax.device_get(self.w))
@@ -1554,6 +1806,7 @@ class _LogisticRegressionParams(
     HasProbabilityCol,
     HasRawPredictionCol,
     HasRegParam,
+    HasElasticNetParam,
     HasFitIntercept,
     HasMaxIter,
     HasTol,
@@ -1567,6 +1820,7 @@ class _LogisticRegressionParams(
             probabilityCol="probability",
             rawPredictionCol="rawPrediction",
             regParam=0.0,
+            elasticNetParam=0.0,
             fitIntercept=True,
             maxIter=100,
             tol=1e-6,
@@ -1582,6 +1836,9 @@ class LogisticRegression(Estimator, _LogisticRegressionParams, MLWritable, MLRea
 
     def setRegParam(self, value: float) -> "LogisticRegression":
         return self._set(regParam=value)
+
+    def setElasticNetParam(self, value: float) -> "LogisticRegression":
+        return self._set(elasticNetParam=value)
 
     def setFitIntercept(self, value: bool) -> "LogisticRegression":
         return self._set(fitIntercept=value)
@@ -1606,6 +1863,7 @@ class LogisticRegression(Estimator, _LogisticRegressionParams, MLWritable, MLRea
             max_iter=self.getMaxIter(),
             tol=self.getTol(),
             mesh=self._mesh,
+            elastic_net=self.getElasticNetParam(),
         )
         model = LogisticRegressionModel(
             coefficients=sol.coefficients, intercept=sol.intercept

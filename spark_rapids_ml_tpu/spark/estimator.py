@@ -1000,6 +1000,14 @@ class _SparkAdapter:
                 # binary-Newton vs multinomial-MM daemon protocol.
                 n_classes = _probe_num_classes(sel, label_col)
                 feed_params = {"n_classes": n_classes}
+                # before a row is fed: a multinomial elastic-net fit is
+                # refused here, not after its first pass
+                from spark_rapids_ml_tpu.models.logistic_regression import (
+                    check_elastic_net,
+                )
+
+                check_elastic_net(core.getElasticNetParam(), core.getRegParam(),
+                                  n_classes)
             if algo == "kmeans":
                 k = core.getK()
                 feed_params = {
@@ -1915,6 +1923,7 @@ class _SparkAdapter:
                 info = {"loss": float("nan"), "iteration": 0}
                 step_params = {
                     "reg": core.getRegParam(),
+                    "elastic_net": core.getElasticNetParam(),
                     "fit_intercept": core.getFitIntercept(),
                 }
                 rows = 0
