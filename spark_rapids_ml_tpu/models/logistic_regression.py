@@ -480,7 +480,7 @@ def _fit_elastic_net_resident(xs, ys, mask, n_true, reg, elastic_net, fit_interc
         gw, gb, hww, hwb, hbb, lsum, n = update(
             stream_zero_state(xs.shape[1], accum), w, b, xs, ys, mask)
         loss = stream_elastic_net_objective(lsum, n, reg, elastic_net, w)
-        w, b, delta, _, _ = _counted_prox_step(step, gw, gb, hww, hwb, hbb, n, w, b)
+        w, b, delta, _, _ = _counted_prox_step(step(gw, gb, hww, hwb, hbb, n, w, b))
         n_iter = it + 1
         if delta <= tol:
             break
@@ -942,11 +942,12 @@ def _stream_prox_newton_step_fn(reg: float, elastic_net: float, fit_intercept: b
     return ledgered_jit("logreg.prox_newton_step", prox_newton_step)
 
 
-def _counted_prox_step(step, *args):
-    """One call of a `_stream_prox_newton_step_fn` program: (new_w, new_b)
-    on the device, the step's length, products and nonzero count read to
-    the host in one transfer and the last two booked in the two metrics."""
-    new_w, new_b, *scalars = step(*args)
+def _counted_prox_step(outputs):
+    """The outputs of one `_stream_prox_newton_step_fn` call: (new_w,
+    new_b) left on the device, the step's length, products and nonzero
+    count read to the host in one transfer and the last two booked in the
+    two metrics."""
+    new_w, new_b, *scalars = outputs
     delta, products, nonzero = jax.device_get(scalars)
     delta, products, nonzero = float(delta), int(products), int(nonzero)
     _M_PROX_ITERATIONS.inc(products)
@@ -959,7 +960,7 @@ def stream_elastic_net_objective(lsum, n, reg: float, elastic_net: float, w) -> 
     evaluated: `stream_objective` at the L2 weight λ(1 − α), plus λα‖w‖₁
     (Spark ML's λ(α‖w‖₁ + (1 − α)/2‖w‖²))."""
     l1, l2 = elastic_net_penalties(reg, elastic_net)
-    return stream_objective(lsum, n, l2, w) + l1 * float(jnp.sum(jnp.abs(w)))
+    return _read_objective(lsum, n, l2, w, l1)
 
 
 _M_SOFTMAX_FOLD_PATH = metrics.counter(
@@ -1318,25 +1319,16 @@ def _stream_multinomial_step_fn(reg: float, fit_intercept: bool, ad: str):
 def stream_softmax_zero_state(n_cols: int, n_classes: int, accum_dtype) -> tuple:
     """Zero (gw, gb, hw, hwb, hbb, loss, n) accumulator for one
     multinomial pass — shared by fit_multinomial_stream and the daemon."""
-    ad = jnp.dtype(accum_dtype)
     d, C = n_cols, n_classes
-    return (
-        jnp.zeros((d, C), ad),
-        jnp.zeros((C,), ad),
-        jnp.zeros((C, d, d), ad),
-        jnp.zeros((C, d), ad),
-        jnp.zeros((C,), ad),
-        jnp.zeros((), ad),
-        jnp.zeros((), ad),
-    )
+    return _zero_state_fn("logreg.softmax_zero_state",
+                          ((d, C), (C,), (C, d, d), (C, d), (C,), (), ()),
+                          jnp.dtype(accum_dtype).name)()
 
 
 def stream_softmax_objective(lsum, n, reg: float, W) -> float:
     """Mean multinomial CE + L2 — the objective both the streaming fit
     and the daemon report."""
-    return float(lsum / jnp.maximum(n, 1.0)) + 0.5 * float(reg) * float(
-        jnp.sum(W * W)
-    )
+    return _read_objective(lsum, n, reg, W)
 
 
 def validate_multiclass_labels(y: np.ndarray, n_classes: int) -> None:
@@ -1469,25 +1461,38 @@ def fit_multinomial_stream(
 def stream_zero_state(n_cols: int, accum_dtype) -> tuple:
     """Zero (gw, gb, hww, hwb, hbb, loss, n) accumulator for one Newton
     pass — shared by fit_logistic_stream and the data-plane daemon."""
-    ad = jnp.dtype(accum_dtype)
     d = n_cols
-    return (
-        jnp.zeros((d,), ad),
-        jnp.zeros((), ad),
-        jnp.zeros((d, d), ad),
-        jnp.zeros((d,), ad),
-        jnp.zeros((), ad),
-        jnp.zeros((), ad),
-        jnp.zeros((), ad),
-    )
+    return _zero_state_fn("logreg.zero_state",
+                          ((d,), (), (d, d), (d,), (), (), ()),
+                          jnp.dtype(accum_dtype).name)()
 
 
 def stream_objective(lsum, n, reg: float, w) -> float:
     """Training objective at the iterate a pass evaluated: mean data loss
     plus the L2 term — the single definition both streaming paths report."""
-    return float(lsum / jnp.maximum(n, 1.0)) + 0.5 * float(reg) * float(
-        jnp.sum(w * w)
-    )
+    return _read_objective(lsum, n, reg, w)
+
+
+def _read_objective(lsum, n, l2: float, w, l1: Optional[float] = None) -> float:
+    """Mean data loss + l2/2·‖w‖² (+ l1·‖w‖₁ where `l1` is given), the
+    device's two or three scalars read to the host in ONE transfer and
+    added there, in the order the terms are written."""
+    terms = [lsum / jnp.maximum(n, 1.0), jnp.sum(w * w)]
+    if l1 is not None:
+        terms.append(jnp.sum(jnp.abs(w)))
+    data, square, *absolute = (float(t) for t in jax.device_get(terms))
+    loss = data + 0.5 * float(l2) * square
+    return loss + l1 * absolute[0] if absolute else loss
+
+
+@functools.lru_cache(maxsize=32)
+def _zero_state_fn(name: str, shapes: tuple, ad: str):
+    """One program that makes a pass's zero accumulator of `shapes`: one
+    dispatch in place of an eager `jnp.zeros` a leaf, fresh buffers every
+    call (a fold donates them), uncommitted and placed as `jnp.zeros`
+    places them."""
+    accum = jnp.dtype(ad)
+    return ledgered_jit(name, lambda: tuple(jnp.zeros(s, accum) for s in shapes))
 
 
 def validate_binary_labels(y: np.ndarray) -> None:
@@ -1548,7 +1553,7 @@ def fit_logistic_stream(
             float(reg), elastic_net, bool(fit_intercept), ad)
 
         def newton_step(*args):
-            return _counted_prox_step(prox_step, *args)[:3]
+            return _counted_prox_step(prox_step(*args))[:3]
 
         def objective(lsum, n, reg, w):
             return stream_elastic_net_objective(lsum, n, reg, elastic_net, w)
@@ -1645,6 +1650,15 @@ def fit_logistic_stream(
 # ---------------------------------------------------------------------------
 
 
+_M_NEXT_STATE = metrics.counter(
+    "srml_logreg_next_state_total",
+    "Next passes' opening states a logistic job handed the daemon "
+    "(next_pass_state): made=ahead (made by its step, dispatched behind the "
+    "step program while the pass's folds still ran) or made=on_demand (no "
+    "step had made one: made when asked)",
+)
+
+
 class LogisticRegressionJob(JobAlgorithm):
     """Newton passes (binary) or MM-Newton passes (``n_classes`` > 2: the
     same feed/step/finalize op sequence over a per-class state). The
@@ -1664,9 +1678,11 @@ class LogisticRegressionJob(JobAlgorithm):
         self._require_gram_capacity()
         self.n_classes = self.feed_classes(params)
         ad = config.get("accum_dtype")
+        # the next pass's zero state, made by `step` before it waits
+        self._ahead = None
         # The boundary's span names what the device waits for between two
-        # passes: the wait for the pass's folds (the loss's read), the
-        # solve (its own span), the zero state, the snapshot.
+        # passes: the wait for the pass's folds, the solve (its own span:
+        # the loss's and the step's reads), the snapshot.
         if self.n_classes > 2:
             self.w = jnp.zeros((n_cols, self.n_classes), self.accum)
             self.b = jnp.zeros((self.n_classes,), self.accum)
@@ -1741,6 +1757,11 @@ class LogisticRegressionJob(JobAlgorithm):
                 self.n_cols, self.n_classes, self.accum)
         return stream_zero_state(self.n_cols, self.accum)
 
+    def next_pass_state(self):
+        ahead, self._ahead = self._ahead, None
+        _M_NEXT_STATE.inc(made="on_demand" if ahead is None else "ahead")
+        return self.zero_state() if ahead is None else ahead
+
     def place_columns(self, target, y=None, n=0, partition=None, offset=0):
         return (self._place_column(y, target, np.float32),)
 
@@ -1753,36 +1774,38 @@ class LogisticRegressionJob(JobAlgorithm):
         return self._update_group(state, self.w, self.b, xs, ys, ms)
 
     def step(self, state, params):
+        """One boundary, in the order that keeps the device busy: the step
+        program and the next pass's zero state are dispatched while the
+        pass's folds still run, and only then does the host wait — for the
+        folds, with no transfer, while the device goes on to the step.
+        The solve's span holds the rest: the loss at the iterate the pass
+        evaluated, and the step's scalars, two transfers in all. With
+        elastic_net > 0 the step is the proximal-Newton one."""
         reg = float(params.get("reg", 0.0))
         fit_intercept = bool(params.get("fit_intercept", True))
         elastic_net = check_elastic_net(params.get("elastic_net", 0.0), reg,
                                         self.n_classes)
+        gw, gb, hww, hwb, hbb, lsum, n = state
         if elastic_net > 0.0:
-            return self._prox_step(state, reg, elastic_net, fit_intercept)
-        gw, gb, hww, hwb, hbb, lsum, n = state
-        step_fn = self._step_fn(reg, fit_intercept, self.accum.name)
-        # the loss's read is the wait for the pass's folds: before the
-        # solve's span, so that the span holds the solve alone
-        loss = self._objective(lsum, n, reg, self.w)
+            step_fn = _stream_prox_newton_step_fn(reg, elastic_net, fit_intercept,
+                                                  self.accum.name)
+        else:
+            step_fn = self._step_fn(reg, fit_intercept, self.accum.name)
+        w = self.w
+        outputs = step_fn(gw, gb, hww, hwb, hbb, n, self.w, self.b)
+        self._ahead = self.zero_state()
+        # the folds' end; their state is not donated (a caller may hold it)
+        jax.block_until_ready(state)
         with trace_span(self.solve_span):
-            self.w, self.b, delta = step_fn(
-                gw, gb, hww, hwb, hbb, n, self.w, self.b)
-            delta = float(delta)
+            if elastic_net > 0.0:
+                loss = stream_elastic_net_objective(lsum, n, reg, elastic_net, w)
+                self.w, self.b, delta, products, nonzero = _counted_prox_step(outputs)
+                return {"delta": delta, "loss": loss, "nonzero": nonzero,
+                        "prox_products": products}
+            loss = self._objective(lsum, n, reg, w)
+            self.w, self.b, delta = outputs
+            delta = float(jax.device_get(delta))
         return {"delta": delta, "loss": loss}
-
-    def _prox_step(self, state, reg, elastic_net, fit_intercept):
-        """The elastic-net boundary: one proximal-Newton step over the
-        pass's statistics (`_stream_prox_newton_step_fn`), under the same
-        spans as the Newton solve."""
-        gw, gb, hww, hwb, hbb, lsum, n = state
-        step_fn = _stream_prox_newton_step_fn(reg, elastic_net, fit_intercept,
-                                              self.accum.name)
-        loss = stream_elastic_net_objective(lsum, n, reg, elastic_net, self.w)
-        with trace_span(self.solve_span):
-            self.w, self.b, delta, products, nonzero = _counted_prox_step(
-                step_fn, gw, gb, hww, hwb, hbb, n, self.w, self.b)
-        return {"delta": delta, "loss": loss, "nonzero": nonzero,
-                "prox_products": products}
 
     def finalize(self, state, params, rows, iteration):
         w = np.asarray(jax.device_get(self.w))
