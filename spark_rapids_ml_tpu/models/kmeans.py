@@ -33,7 +33,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_rapids_ml_tpu import config
 from spark_rapids_ml_tpu.core.dataset import as_matrix, with_column
@@ -368,6 +368,14 @@ _M_FOLD_PATH = metrics.counter(
     "over the kernel's VMEM budget)",
 )
 
+_M_NEXT_STATE = metrics.counter(
+    "srml_kmeans_next_state_total",
+    "Next passes' opening states a KMeans job handed the daemon "
+    "(next_pass_state): made=ahead (made by its step, in the one boundary "
+    "program with the update) or made=on_demand (no step had made one: "
+    "made when asked)",
+)
+
 
 #: Largest row-block of the streaming fold's kernel call. The fold is one
 #: call a BATCH, and a call's first block is fetched with nothing to overlap
@@ -565,17 +573,44 @@ def apply_lloyd_update(sums, counts, centers):
     return new_centers, moved2
 
 
+def _lloyd_boundary_fn(mesh: Mesh):
+    """One Lloyd pass boundary as ONE program: (sums, counts, centers) ->
+    (new_centers, moved2, the next pass's zero (sums, counts, cost)).
+
+    The update is :func:`apply_lloyd_update` as the module holds it when
+    a job or a fit is made (the rule is part of the program's key), the
+    zeros :func:`stream_zero_state`'s. Every output is replicated over
+    `mesh`, as the fold's state is between two of its programs, so the
+    fold meets no argument sharding a pass did not already give it. One
+    dispatch, not one a primitive: on a cached pass the host's dispatches,
+    not the device, set the boundary's length."""
+    return _lloyd_boundary_cached(mesh, apply_lloyd_update)
+
+
+@functools.lru_cache(maxsize=32)
+def _lloyd_boundary_cached(mesh: Mesh, rule):
+    @functools.partial(ledgered_jit, "kmeans.lloyd_boundary",
+                       out_shardings=NamedSharding(mesh, P()))
+    def boundary(sums, counts, centers):
+        new_centers, moved2 = rule(sums, counts, centers)
+        return new_centers, moved2, stream_zero_state(*sums.shape, sums.dtype)
+
+    return boundary
+
+
 class KMeansJob(JobAlgorithm):
     """Lloyd passes as a daemon job: the iterate is the (k, d) centers, a
     pass's statistics are (sums, counts, cost) at those centers, a pass
-    boundary is :func:`apply_lloyd_update`. The centers come from a
+    boundary is :func:`apply_lloyd_update` (one program with the next
+    pass's zeros, :func:`_lloyd_boundary_fn`). The centers come from a
     ``seed`` op, a pushed iterate, or the first unpartitioned batch."""
 
     name = "kmeans"
     iterative = True
     cacheable = True
-    # what the device waits for between two passes — the wait for the
-    # pass's folds, the update, the two scalars to the host, the snapshot
+    # what the device waits for between two passes — the update's one
+    # dispatch, the wait for the pass's folds and the two scalars' landing
+    # on the host, the snapshot
     boundary_span = "lloyd.boundary"
     no_iterate = {
         "staged_feed": (
@@ -600,6 +635,9 @@ class KMeansJob(JobAlgorithm):
         cd, ad = config.get("compute_dtype"), config.get("accum_dtype")
         self._update = _stream_step_fn(mesh, self.k, cd, ad)
         self._update_group = _stream_group_fn(mesh, self.k, cd, ad)
+        self._boundary = _lloyd_boundary_fn(mesh)
+        # the next pass's zero state, made by `step` with the update
+        self._ahead = None
 
     @classmethod
     def check_first_batch(cls, params, x):
@@ -639,6 +677,11 @@ class KMeansJob(JobAlgorithm):
     def zero_state(self):
         return stream_zero_state(self.k, self.n_cols, self.accum)
 
+    def next_pass_state(self):
+        ahead, self._ahead = self._ahead, None
+        _M_NEXT_STATE.inc(made="on_demand" if ahead is None else "ahead")
+        return self.zero_state() if ahead is None else ahead
+
     def fold(self, state, xs, ms, columns=(), n=0):
         return self._update(state, self.centers, xs, ms)
 
@@ -646,8 +689,16 @@ class KMeansJob(JobAlgorithm):
         return self._update_group(state, self.centers, xs, ms)
 
     def step(self, state, params):
+        """One boundary, one dispatch: the update and the next pass's zero
+        state (kept for `next_pass_state`) queue behind the pass's folds,
+        and both scalars are sent to the host as soon as the device has
+        them, so the caller's read after its wait makes no round trip.
+        `cost` is the fold's own array, not a program output: an output
+        that only forwards an input may be that input anyway."""
         sums, counts, cost = state
-        self.centers, moved2 = apply_lloyd_update(sums, counts, self.centers)
+        self.centers, moved2, self._ahead = self._boundary(sums, counts, self.centers)
+        moved2.copy_to_host_async()
+        cost.copy_to_host_async()
         return {"moved2": moved2, "cost": cost}
 
     def finalize(self, state, params, rows, iteration):
@@ -706,6 +757,7 @@ def fit_kmeans_stream(
     mesh = mesh or default_mesh()
     cd, ad = config.get("compute_dtype"), config.get("accum_dtype")
     update = _stream_step_fn(mesh, k, cd, ad)
+    boundary = _lloyd_boundary_fn(mesh)
     accum_dtype = jnp.dtype(ad)
 
     start_iter = 0
@@ -772,8 +824,7 @@ def fit_kmeans_stream(
                 else _random_init(sample, k, rng)
             )
 
-    def scan(centers_dev):
-        state = stream_zero_state(k, n_cols, accum_dtype)
+    def scan(centers_dev, state):
         n_rows = 0
         for batch in lockstep_batches(batch_source(), n_cols):
             # shard_rows pads, casts f64→f32 via the threaded native bridge
@@ -787,10 +838,11 @@ def fit_kmeans_stream(
     n_true = 0
     n_iter = start_iter
     centers_dev = jnp.asarray(centers, accum_dtype)
+    zeros = stream_zero_state(k, n_cols, accum_dtype)
     with trace_span("lloyd-stream"):
         for it in range(start_iter, max_iter):
-            (sums, counts, _), n_true = scan(centers_dev)
-            centers_dev, moved2 = apply_lloyd_update(sums, counts, centers_dev)
+            (sums, counts, _), n_true = scan(centers_dev, zeros)
+            centers_dev, moved2, zeros = boundary(sums, counts, centers_dev)
             moved2 = float(moved2)
             n_iter = it + 1
             if checkpoint_path and (not multiproc or jax.process_index() == 0):
@@ -802,7 +854,7 @@ def fit_kmeans_stream(
             if moved2 <= float(tol) ** 2:
                 break
         # Exact cost at the final centers (one cost-only scan).
-        (_, _, cost), n_true = scan(centers_dev)
+        (_, _, cost), n_true = scan(centers_dev, zeros)
     if checkpoint_path and (not multiproc or jax.process_index() == 0):
         import os
 
